@@ -14,6 +14,10 @@ over threads/Aeron/Spark (ref: parallelism/ParallelWrapper.java:218).
 
 __version__ = "0.1.0"
 
+from deeplearning4j_tpu.ops import bucketing as _bucketing
+
+_bucketing.configure_compile_cache()  # before anything here can compile
+
 from deeplearning4j_tpu.nn.conf.network import (  # noqa: F401
     NeuralNetConfiguration,
     MultiLayerConfiguration,

@@ -22,15 +22,6 @@ import numpy as np
 from deeplearning4j_tpu.nn import params as param_util
 
 
-def _enable_x64():
-    """jax.enable_x64 across versions (top-level export is recent;
-    older jax ships the context manager in jax.experimental)."""
-    if hasattr(jax, "enable_x64"):
-        return jax.enable_x64(True)
-    from jax.experimental import enable_x64
-    return enable_x64()
-
-
 def check_gradients(net, x, y, *, epsilon: float = 1e-6,
                     max_rel_error: float = 1e-3, min_abs_error: float = 1e-8,
                     fmask=None, lmask=None, subset: Optional[int] = 128,
@@ -42,12 +33,12 @@ def check_gradients(net, x, y, *, epsilon: float = 1e-6,
     (None = exhaustive, as the reference does).
     Returns True if every checked param's relative error is within bounds.
 
-    float64 is enabled locally via the jax.experimental.enable_x64 context
+    float64 is enabled locally via the jax.enable_x64 context
     (the reference forces double precision the same way,
     GradientCheckUtil.java:87-92) so callers/tests don't leak x64 into the
     rest of the process.
     """
-    with _enable_x64():
+    with jax.enable_x64(True):
         return _check_gradients_x64(
             net, x, y, epsilon=epsilon, max_rel_error=max_rel_error,
             min_abs_error=min_abs_error, fmask=fmask, lmask=lmask,
@@ -152,7 +143,7 @@ def check_computation_graph_gradients(
 
     inputs/labels: list-like ordered by network_inputs/network_outputs.
     """
-    with _enable_x64():
+    with jax.enable_x64(True):
         return _check_cg_x64(graph, inputs, labels, epsilon=epsilon,
                              max_rel_error=max_rel_error,
                              min_abs_error=min_abs_error, fmasks=fmasks,
@@ -238,7 +229,7 @@ def check_pretrain_gradients(layer, params, x, *, epsilon: float = 1e-6,
     deterministic by fixing the rng across both analytic and numeric
     evaluation, so the finite difference probes the same realized loss.
     """
-    with _enable_x64():
+    with jax.enable_x64(True):
         rng = jax.random.PRNGKey(seed)
         p64 = jax.tree_util.tree_map(
             lambda a: jnp.asarray(np.asarray(a), jnp.float64), params)

@@ -286,7 +286,8 @@ class ComputationGraph:
             new_params, new_opts = self._apply_updates(params, opts, grads, it)
             return new_params, new_states, new_opts, score
 
-        return step
+        from deeplearning4j_tpu.parallel import fsdp
+        return fsdp.partitioned_if_sharded(self, step)
 
     def _apply_updates(self, params, opts, grads, it):
         """Traceable gradient→param update over the vertex dict (per-layer
@@ -348,7 +349,6 @@ class ComputationGraph:
         semantics and caveats as MultiLayerNetwork.fit(fused_steps=K):
         listeners fire once per launch, ragged/mixed groups fall back,
         TBPTT and iterations>1 ignore the flag."""
-        bucketing.maybe_enable_persistent_cache()
         if labels is not None:
             data = MultiDataSet([np.asarray(data)], [np.asarray(labels)])
         if isinstance(data, DataSet):
@@ -645,7 +645,7 @@ class ComputationGraph:
                 getattr(self, "_sharding_plan", None)):
             return
         self._sharding_plan = plan
-        self._step_fn = None
+        self._step_fn = self._score_fn = None
         self._fused_fns = None
         # inference entry points re-jit too: the output path carries the
         # plan's in/out_shardings (sharded serving, ROADMAP 3a)
@@ -982,11 +982,12 @@ class ComputationGraph:
                                                   False, jax.random.PRNGKey(0))
                 return tuple(policy.cast_to_param(acts[n])
                              for n in self.conf.network_outputs)
+            from deeplearning4j_tpu.parallel import fsdp
+            out_fn = fsdp.partitioned_if_sharded(self, out_fn)
             out_plan = getattr(self, "_sharding_plan", None)
             if out_plan is not None:
                 # sharded serving (ROADMAP 3a): pjit'd output with the
                 # plan's in/out shardings — see MultiLayerNetwork.output
-                from deeplearning4j_tpu.parallel import fsdp
                 self._output_fn = fsdp.jit_sharded_output(
                     out_fn, out_plan, self.net_params)
             else:
@@ -1115,6 +1116,8 @@ class ComputationGraph:
                                      else jnp.sum(per_ex))
                 return total + self._reg_penalty(params)
 
+            from deeplearning4j_tpu.parallel import fsdp
+            score_fn = fsdp.partitioned_if_sharded(self, score_fn)
             self._score_fn = jax.jit(score_fn)
         data, bucket = self._maybe_bucket_train(data)
         xs = tuple(jnp.asarray(f) for f in data.features)

@@ -263,7 +263,7 @@ class MultiLayerNetwork:
                 getattr(self, "_sharding_plan", None)):
             return
         self._sharding_plan = plan
-        self._step_fn = None
+        self._step_fn = self._score_fn = None
         self._fused_fns = None
         # inference entry points re-jit too: the output path carries the
         # plan's in/out_shardings (sharded serving, ROADMAP 3a)
@@ -381,7 +381,8 @@ class MultiLayerNetwork:
                                                        grads, it)
             return new_params, new_states, new_opts, score
 
-        return step
+        from deeplearning4j_tpu.parallel import fsdp
+        return fsdp.partitioned_if_sharded(self, step)
 
     def _apply_updates(self, params, opts, grads, it):
         """Traceable gradient→param update: per-layer gradient
@@ -448,6 +449,8 @@ class MultiLayerNetwork:
             score = jnp.mean(per_ex) if g.mini_batch else jnp.sum(per_ex)
             return score + self._reg_penalty(params)
 
+        from deeplearning4j_tpu.parallel import fsdp
+        score_fn = fsdp.partitioned_if_sharded(self, score_fn)
         return jax.jit(score_fn)
 
     def _build_output_fn(self):
@@ -465,13 +468,14 @@ class MultiLayerNetwork:
             out, _, _ = self._forward(pc, state, xc, fmc, False,
                                       jax.random.PRNGKey(0))
             return policy.cast_to_param(out)
+        from deeplearning4j_tpu.parallel import fsdp
+        output_fn = fsdp.partitioned_if_sharded(self, output_fn)
         plan = getattr(self, "_sharding_plan", None)
         if plan is not None:
             # sharded serving (ROADMAP 3a): a model that only fits
             # sharded serves through the same plan the fit path uses —
             # params stay in their fsdp layout, the batch shards over
             # data(+fsdp), the output all-gathers on device
-            from deeplearning4j_tpu.parallel import fsdp
             return fsdp.jit_sharded_output(output_fn, plan, self.net_params)
         return jax.jit(output_fn)
 
@@ -512,7 +516,6 @@ class MultiLayerNetwork:
         assert isinstance(data, DataSetIterator)
         if self.net_params is None:
             self.init()
-        bucketing.maybe_enable_persistent_cache()
         # warm-validate the fused-kernel helper tier (ops/helpers.py)
         # BEFORE the first step traces: a Mosaic rejection flips that
         # tier's kill switch here instead of killing the training run

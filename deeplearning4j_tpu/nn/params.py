@@ -40,7 +40,9 @@ def flatten(params: List[dict]) -> jnp.ndarray:
     FSDP-trained model carry heterogeneous NamedShardings, and op-by-op
     ``jnp.concatenate`` over mixed committed shardings miscomputes on
     multi-axis meshes (observed on jax 0.4.37, CPU 2x4 data×fsdp mesh —
-    values silently wrong, not an error).  Per-leaf ``np.asarray`` is
+    values silently wrong, not an error; a 20-trial re-check on jax
+    0.9.0 did not reproduce it, which does not prove it gone).
+    Per-leaf ``np.asarray`` is
     the always-correct gather, and the flat vector is the portable
     cross-mesh checkpoint format anyway (parallel/fsdp.py).  Under a
     jit trace (the line-search solvers flatten inside their value-and-

@@ -30,15 +30,14 @@ for hardware-limit throughput; this module enforces it:
   ``bench_ragged`` workload, so compile-behavior regressions are
   measurable instead of anecdotal.
 
-* **Persistent compilation cache**
-  (:func:`maybe_enable_persistent_cache`): env-gated
-  (``DL4J_PERSISTENT_CACHE=<dir>``) wiring of JAX's on-disk compilation
-  cache so repeated runs skip cold compiles entirely.
+* **Persistent compilation cache** (:func:`configure_compile_cache`):
+  JAX's on-disk compilation cache at ``$JAX_COMPILATION_CACHE_DIR`` or,
+  unset, at the fixed ``<checkout>/.jax_cache``, configured once at
+  package import so repeated runs skip cold compiles.
 """
 
 from __future__ import annotations
 
-import functools
 import math
 import os
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -515,36 +514,22 @@ class CompileTelemetry:
 
 
 # ---------------------------------------------------------------------------
-# Persistent compilation cache (env-gated)
+# Persistent compilation cache
 # ---------------------------------------------------------------------------
-@functools.lru_cache(maxsize=1)
-def maybe_enable_persistent_cache() -> bool:
-    """Point JAX's on-disk compilation cache at ``$DL4J_PERSISTENT_CACHE``
-    (created if missing) so repeated runs skip cold compiles.  No-op
-    (False) when the env var is unset or the config knobs don't exist.
-    Idempotent and cheap — call from any fit entry point."""
-    d = os.environ.get("DL4J_PERSISTENT_CACHE")
-    if not d:
-        return False
-    try:
+_CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def configure_compile_cache() -> None:
+    """Place JAX's on-disk compilation cache; the package calls this
+    once at import, before anything compiles (JAX latches the cache off
+    at the first compile that finds no directory).  Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and nothing
+    is set here; otherwise the cache goes to the one fixed path
+    ``<checkout>/.jax_cache`` — the path is part of the cache key, so it
+    must never carry a pid, a time or a temporary name.  JAX's own
+    thresholds decide which programs are worth an entry."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         import jax
-        os.makedirs(d, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", os.path.abspath(d))
-        # cache EVERY program: the default thresholds skip sub-second
-        # compiles, but ragged streams are exactly many small programs
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        try:
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        except Exception:
-            pass  # knob name varies across jax versions; best-effort
-        # jax latches the cache as disabled on the FIRST jit execution if
-        # the dir wasn't configured yet (anything compiles during net
-        # init) — reset so the next access re-initializes with our dir
-        try:
-            from jax._src import compilation_cache as _cc
-            _cc.reset_cache()
-        except Exception:
-            pass
-    except Exception:
-        return False
-    return True
+        jax.config.update("jax_compilation_cache_dir", _CHECKOUT_CACHE_DIR)

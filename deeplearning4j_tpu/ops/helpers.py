@@ -35,7 +35,9 @@ Kill switches, most-specific wins:
 * :func:`deeplearning4j_tpu.ops.pallas_kernels.disable_kernels` — the
   runtime per-tier switch :func:`kernel_self_test` flips when a Mosaic
   compile fails on the real chip, so one bad kernel degrades to XLA
-  without taking down the healthy tiers.
+  without taking down the healthy tiers.  The flip is logged at
+  WARNING with the compiler's message; ``chip_smoke.py`` and
+  ``bench.py`` treat any disabled tier as a failed run.
 
 :func:`ensure_validated` is the warm-validation hook both engines call
 at the top of ``fit()``: the first time any fused tier could engage it
@@ -45,6 +47,7 @@ disables that tier) BEFORE the first real training step compiles.
 
 from __future__ import annotations
 
+import logging
 import os
 import threading
 from typing import Callable, Dict, NamedTuple, Optional, Sequence
@@ -53,6 +56,8 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.ops import pallas_kernels as pk
+
+log = logging.getLogger(__name__)
 
 
 class Helper(NamedTuple):
@@ -96,7 +101,8 @@ def record_selection(op: str, fused: bool) -> None:
 def available(op: str) -> bool:
     """Is the fused tier for ``op`` eligible at all (before the per-call
     shape/dtype predicate)?  Order: global kill → runtime kill switch →
-    per-tier env force → platform."""
+    per-tier env force → platform, and there only outside a step that
+    GSPMD partitions (pallas_kernels.partitioned_trace)."""
     tier = _HELPERS[op].tier
     if os.environ.get("DL4J_PALLAS") == "0":  # dl4j: noqa[DL4J103] env kill switch read at trace time by design (fixed per process)
         return False
@@ -107,7 +113,7 @@ def available(op: str) -> bool:
         return False
     if env == "1":
         return True
-    return pk._on_tpu()
+    return pk._on_tpu() and not pk.partitioned_trace_active()
 
 
 # ---------------------------------------------------------------------------
@@ -246,18 +252,24 @@ def _selftest_xent():
 def _selftest_conv():
     import numpy as np
     rng = np.random.default_rng(0)
-    x = jnp.asarray(rng.normal(size=(2, 3, 10, 10)), jnp.float32)
-    w = jnp.asarray(rng.normal(size=(8, 3, 3, 3)) * 0.2, jnp.float32)
-    b = jnp.asarray(rng.normal(size=(8,)), jnp.float32)
 
     def loss(x, w, b):
-        return jnp.sum(pk.fused_conv2d_bias_act(
-            x, w, b, border_mode="same", activation="relu") ** 2)
+        y = pk.fused_conv2d_bias_act(
+            x, w, b, border_mode="same", activation="relu")
+        return jnp.sum(y.astype(jnp.float32) ** 2)
     vg = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
-    out, grads = vg(x, w, b)
-    jax.block_until_ready(grads)
-    if not bool(jnp.isfinite(out)):
-        raise FloatingPointError("non-finite fused conv loss")
+    # f32 AND bf16: bf16 is what the chip's default policy feeds the
+    # tier, and its backward (the reference conv's transpose) is a
+    # different trace from the f32 one
+    for dtype in (jnp.float32, jnp.bfloat16):
+        x = jnp.asarray(rng.normal(size=(2, 3, 10, 10)), dtype)
+        w = jnp.asarray(rng.normal(size=(8, 3, 3, 3)) * 0.2, dtype)
+        b = jnp.asarray(rng.normal(size=(8,)), dtype)
+        out, grads = vg(x, w, b)
+        jax.block_until_ready(grads)
+        if not bool(jnp.isfinite(out)):
+            raise FloatingPointError(
+                f"non-finite fused conv loss ({jnp.dtype(dtype).name})")
 
 
 def _selftest_lstm():
@@ -316,10 +328,11 @@ def kernel_self_test(disable_on_error: bool = True,
                      ops: Optional[Sequence[str]] = None) -> dict:
     """Compile+run every registered helper once on small shapes through
     the REAL dispatch path (interpret only off-TPU).  On error the
-    offending TIER is disabled via pallas_kernels.disable_kernels —
-    callers silently fall back to dense XLA — and every verdict lands in
-    ``dl4j_pallas_selftest_ok{op=}`` (1 passed / 0 failed) plus the
-    per-tier ``dl4j_pallas_tier_disabled`` gauge."""
+    offending TIER is disabled via pallas_kernels.disable_kernels, so
+    library callers keep training on dense XLA, and the switch-off is
+    logged once at WARNING with the compiler's message.  Every verdict
+    lands in ``dl4j_pallas_selftest_ok{op=}`` (1 passed / 0 failed) plus
+    the per-tier ``dl4j_pallas_tier_disabled`` gauge."""
     results: dict = {}
     # snapshot BEFORE any test can flip a kill switch: the mode the
     # tests actually ran under, not the post-disable state
@@ -344,7 +357,11 @@ def kernel_self_test(disable_on_error: bool = True,
         except Exception as e:  # Mosaic/XLA compile or runtime failure
             results[h.test_name] = f"error: {type(e).__name__}: {e}"[:300]
             ok = 0
-            if disable_on_error:
+            if disable_on_error and h.tier not in pk._disabled:
+                log.warning(
+                    "Pallas tier '%s' failed its self-test and is DISABLED "
+                    "for this process; '%s' runs on dense XLA. %s: %s",
+                    h.tier, op, type(e).__name__, e)
                 pk.disable_kernels(
                     f"{h.test_name} self-test failed: {e}", tier=h.tier)
         if gauge is not None:

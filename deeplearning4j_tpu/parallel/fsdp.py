@@ -30,6 +30,7 @@ path is byte-identical to the replica-style one.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import logging
 import warnings
 from typing import Dict, Optional, Tuple
@@ -40,6 +41,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deeplearning4j_tpu import monitor
 from deeplearning4j_tpu.ops import bucketing
+from deeplearning4j_tpu.ops import pallas_kernels as pk
 from deeplearning4j_tpu.parallel import mesh as mesh_util
 
 log = logging.getLogger(__name__)
@@ -143,6 +145,34 @@ def plan_from_mesh(mesh: Mesh, replicate_below: int = 0) -> ShardingPlan:
 # --------------------------------------------------------------------------
 # The sharded step
 # --------------------------------------------------------------------------
+
+def _spans_devices(tree) -> bool:
+    """Does any leaf live on more than one device?  (numpy leaves and
+    uncommitted arrays have no multi-device sharding.)"""
+    shardings = (getattr(a, "sharding", None)
+                 for a in jax.tree_util.tree_leaves(tree))
+    return any(sh is not None and len(sh.device_set) > 1
+               for sh in shardings)
+
+
+def partitioned_if_sharded(model, raw):
+    """``raw``, traced under pallas_kernels.partitioned_trace() when
+    GSPMD will partition it: the model has a plan, or its params already
+    live on more than one device (a ParallelWrapper-trained model keeps
+    its mesh-committed params after the wrapper's plan is gone).  The
+    engines build their raw step (per-step and fused scan), score and
+    output functions through this, so the fused Mosaic tiers (not
+    partitionable) stay out of those traces."""
+    if getattr(model, "_sharding_plan", None) is None \
+            and not _spans_devices(model.net_params):
+        return raw
+
+    @functools.wraps(raw)
+    def traced(*args):
+        with pk.partitioned_trace():
+            return raw(*args)
+    return traced
+
 
 def jit_sharded_step(raw_step, plan: ShardingPlan, params, opts):
     """pjit the engines' raw train step with the plan's layouts:

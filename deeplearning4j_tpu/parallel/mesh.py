@@ -20,26 +20,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 AXES = ("data", "fsdp", "model", "seq", "expert")
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """``jax.shard_map`` across jax versions: the top-level export and
-    its ``check_vma`` kwarg are recent; older jax ships it as
-    ``jax.experimental.shard_map.shard_map`` with ``check_rep`` (same
-    meaning: verify per-device replication of unmapped outputs)."""
-    import inspect
-    try:
-        from jax import shard_map as sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-    kw = {}
-    if check_vma is not None:
-        params = inspect.signature(sm).parameters
-        if "check_vma" in params:
-            kw["check_vma"] = check_vma
-        elif "check_rep" in params:
-            kw["check_rep"] = check_vma
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
 @dataclasses.dataclass(frozen=True)
 class MeshConfig:
     """How many devices along each named axis (product must divide the

@@ -24,8 +24,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-from deeplearning4j_tpu.parallel.mesh import shard_map_compat as shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 PIPELINE_AXIS = "model"  # default: reuse the mesh's 'model' axis for stages

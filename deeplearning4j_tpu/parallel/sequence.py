@@ -35,8 +35,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-from deeplearning4j_tpu.parallel.mesh import shard_map_compat as shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 NEG_INF = -1e30
@@ -426,14 +425,6 @@ def dense_attention(q, k, v, *, causal: bool = False, key_mask=None,
 
 
 # ---------------------------------------------------------------------------
-def _axis_size(axis_name: str):
-    """lax.axis_size across jax versions (older jax has no such export;
-    the size of a mapped axis is the psum of 1 over it)."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
-
 # Ring attention (per-shard body; run under shard_map over 'seq').
 
 
@@ -442,7 +433,7 @@ def _ring_attention_sharded(q, k, v, key_mask, *, axis_name: str,
     """Online-softmax ring scan.  Per-shard shapes: q,k,v [B, H, Tl, D],
     key_mask [B, Tl] or None.  The device's global block index comes from
     ``lax.axis_index`` so causal masking uses *global* positions."""
-    S = _axis_size(axis_name)
+    S = lax.axis_size(axis_name)
     B, H, Tl, D = q.shape
     scale = scale if scale is not None else 1.0 / (D ** 0.5)
     idx = lax.axis_index(axis_name)
@@ -532,7 +523,7 @@ def _ulysses_sharded(q, k, v, key_mask, *, axis_name: str, causal: bool,
                      scale: Optional[float]):
     """Per-shard: [B, H, Tl, D] → all_to_all → [B, H/S, T, D] → dense
     attention → all_to_all back."""
-    S = _axis_size(axis_name)
+    S = lax.axis_size(axis_name)
     a2a = partial(lax.all_to_all, axis_name=axis_name, split_axis=1,
                   concat_axis=2, tiled=True)
     qg, kg, vg = a2a(q), a2a(k), a2a(v)                  # [B, H/S, T, D]

@@ -73,7 +73,7 @@ class ParallelWrapper:
         if fsdp.plan_key(getattr(m, "_sharding_plan", None)) != \
                 fsdp.plan_key(plan):
             m._sharding_plan = plan
-            m._step_fn = None
+            m._step_fn = m._score_fn = m._output_fn = None
             m._fused_fns = None
 
     def _build_sharded_step(self):

@@ -161,8 +161,6 @@ class DeepLearning4jEntryPoint:
         the step compiles once per bucket (ops/bucketing.py); retrace
         telemetry is returned alongside the score."""
         from deeplearning4j_tpu.nn.serialization import write_model
-        from deeplearning4j_tpu.ops import bucketing
-        bucketing.maybe_enable_persistent_cache()
         model = self.model_cache.get(model_path)
         if shape_bucketing is not None:
             model.conf.global_conf.shape_bucketing = bool(shape_bucketing)

@@ -144,9 +144,14 @@ def test_ragged_rnn_fit_parity_mixed_time_and_masks():
     raw, bucketed = rnn_net(False), rnn_net(True)
     raw.fit(ListDataSetIterator(list(batches)))
     bucketed.fit(ListDataSetIterator(list(batches)))
+    # atol from the observed f32 reduction-order noise: padding a batch
+    # to its bucket changes XLA:CPU's summation order, and after four
+    # f32 steps jax 0.9.0 leaves 1 of 517 elements 1.7e-6 apart (a
+    # near-zero weight, so rtol does not cover it).  3x that, still far
+    # under anything a bucketing bug would produce.
     np.testing.assert_allclose(np.asarray(raw.params()),
                                np.asarray(bucketed.params()),
-                               rtol=1e-5, atol=1e-6)
+                               rtol=1e-5, atol=5e-6)
     snap = bucketed.compile_telemetry.snapshot()
     assert snap["by_kind"]["train_step"] <= len(snap["bucket_hits"])
     # score + per-example parity on masked AND unmasked ragged batches
@@ -327,20 +332,24 @@ def test_globalconf_bucketing_serde_roundtrip():
         .global_conf.shape_bucketing is False
 
 
-def test_persistent_cache_env_gate(tmp_path, monkeypatch):
-    import jax
-    bucketing.maybe_enable_persistent_cache.cache_clear()
-    monkeypatch.delenv("DL4J_PERSISTENT_CACHE", raising=False)
-    assert bucketing.maybe_enable_persistent_cache() is False
-    bucketing.maybe_enable_persistent_cache.cache_clear()
-    cache_dir = tmp_path / "xla-cache"
-    monkeypatch.setenv("DL4J_PERSISTENT_CACHE", str(cache_dir))
-    prev = jax.config.jax_compilation_cache_dir
-    try:
-        assert bucketing.maybe_enable_persistent_cache() is True
-        assert jax.config.jax_compilation_cache_dir == \
-            os.path.abspath(str(cache_dir))
-        assert cache_dir.is_dir()
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
-        bucketing.maybe_enable_persistent_cache.cache_clear()
+@pytest.mark.parametrize("env_set", [True, False])
+def test_compile_cache_placed_from_outside(tmp_path, env_set):
+    """A fresh process that imports the package: with
+    JAX_COMPILATION_CACHE_DIR set JAX keeps that directory and the code
+    sets no other; unset, the cache goes to the one fixed path inside
+    the checkout."""
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    want = os.path.join(root, ".jax_cache")
+    if env_set:
+        want = env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "xla-cache")
+    p = subprocess.run(
+        [sys.executable, "-c",
+         "import deeplearning4j_tpu, jax; "
+         "print(jax.config.jax_compilation_cache_dir)"],
+        capture_output=True, text=True, timeout=120, env=env, cwd=root)
+    assert p.returncode == 0, p.stderr[-2000:]
+    assert p.stdout.strip().splitlines()[-1] == want

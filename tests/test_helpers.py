@@ -331,3 +331,142 @@ class TestFallbackEquivalence:
         monkeypatch.setenv("DL4J_PALLAS_XENT", "0")
         b = np.asarray(losses.mcxent(y, logits, "softmax"))
         assert np.array_equal(a, b)
+
+
+# ---------------------------------------------------------------------------
+# The selections the chip makes by default, on the CPU: bf16 policy + every
+# fused tier (interpret mode).  The f32/dense branches the rest of the suite
+# runs are NOT the ones platform.is_tpu() picks.
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def chip_default_selections(monkeypatch):
+    from deeplearning4j_tpu.ops import dtypes
+    for env in helpers._ENV_TIER.values():
+        monkeypatch.setenv(env, "1")
+    dtypes.set_default_policy(dtypes.BF16)
+    yield
+    dtypes.set_default_policy(None)
+
+
+def _lenet():
+    from deeplearning4j_tpu.models.lenet import lenet
+    return lenet(), (1, 28, 28)
+
+
+def _vgg16_cifar10():
+    from deeplearning4j_tpu.models.vgg import vgg16_cifar10
+    return vgg16_cifar10(), (3, 32, 32)
+
+
+def _resnet18():
+    from deeplearning4j_tpu.models.resnet import resnet18
+    return resnet18(height=32, width=32, channels=3, n_classes=10), \
+        (3, 32, 32)
+
+
+class TestChipDefaultSelections:
+    @pytest.mark.parametrize("build", [_lenet, _vgg16_cifar10, _resnet18],
+                             ids=["lenet", "vgg16_cifar10", "resnet18-graph"])
+    def test_one_fit_step_bf16_all_tiers(self, chip_default_selections,
+                                         build):
+        from deeplearning4j_tpu.datasets.dataset import DataSet
+        net, in_shape = build()
+        net.init()
+        rng = np.random.default_rng(0)
+        x = rng.normal(size=(8,) + in_shape).astype(np.float32)
+        y = np.eye(10, dtype=np.float32)[rng.integers(0, 10, 8)]
+        before = _counter_value("dl4j_pallas_selected_total", "conv2d")
+        net.fit(DataSet(x, y))
+        assert np.isfinite(float(net.score()))
+        assert _counter_value("dl4j_pallas_selected_total",
+                              "conv2d") > before
+        assert not pk._disabled       # the warm self-test passed in bf16 too
+
+    def test_disabled_tier_is_logged_once_with_the_message(
+            self, monkeypatch, caplog):
+        def boom(*a, **k):
+            raise RuntimeError("mosaic rejected block shape")
+        monkeypatch.setattr(pk, "fused_conv2d_bias_act", boom)
+        with caplog.at_level("WARNING", logger=helpers.log.name):
+            helpers.kernel_self_test()
+            helpers.kernel_self_test()
+        said = [r for r in caplog.records if "conv" in r.getMessage()]
+        assert len(said) == 1 and said[0].levelname == "WARNING"
+        assert "mosaic rejected block shape" in said[0].getMessage()
+
+    def test_self_test_can_report_without_disabling(self, monkeypatch):
+        def boom(*a, **k):
+            raise RuntimeError("mosaic rejected")
+        monkeypatch.setattr(pk, "fused_conv2d_bias_act", boom)
+        st = helpers.kernel_self_test(disable_on_error=False)
+        assert st["conv2d_bias_act"].startswith("error")
+        assert not pk._disabled and "disabled" not in st
+
+
+class TestPartitionedTrace:
+    """A Mosaic kernel cannot be partitioned by GSPMD: under a sharding
+    plan the tiers leave automatic selection.  DL4J_TPU=1 steers the
+    chip's branches; a kernel that WAS selected would then be lowered
+    without interpret mode and the CPU backend would refuse it, so these
+    fits passing is the proof that none was."""
+
+    def test_tiers_leave_selection_inside_the_scope(self, monkeypatch):
+        monkeypatch.setenv("DL4J_TPU", "1")
+        assert all(helpers.available(op) for op in helpers.OPS)
+        with pk.partitioned_trace():
+            assert not any(helpers.available(op) for op in helpers.OPS)
+            monkeypatch.setenv("DL4J_PALLAS_CONV", "1")
+            assert helpers.available("conv2d")   # an explicit force wins
+        assert helpers.available("lstm_step")
+
+    @pytest.mark.parametrize("how", ["parallel_wrapper", "conf_sharding"])
+    def test_sharded_model_traces_dense_ops(self, monkeypatch, how):
+        import jax
+        from deeplearning4j_tpu.datasets.dataset import DataSet
+        from deeplearning4j_tpu.datasets.iterators import ListDataSetIterator
+        from deeplearning4j_tpu.nn.conf import layers as L
+        from deeplearning4j_tpu.nn.conf.inputs import InputType
+        from deeplearning4j_tpu.nn.conf.network import \
+            NeuralNetConfiguration
+        from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
+        from deeplearning4j_tpu.parallel import (
+            MeshConfig, ParallelWrapper, make_mesh)
+        monkeypatch.setenv("DL4J_TPU", "1")
+        b = (NeuralNetConfiguration.builder().seed(7).learning_rate(0.05)
+             .updater("sgd"))
+        if how == "conf_sharding":
+            b.sharding(data=2, fsdp=4)
+            # fit() warm-validates eligible tiers first; on the CPU that
+            # self-test cannot pass, and it is not what is under test
+            monkeypatch.setattr(helpers, "ensure_validated", lambda: {})
+        conf = (b.list()
+                .layer(L.ConvolutionLayer(n_out=8, kernel=(3, 3),
+                                          activation="relu",
+                                          convolution_mode="same"))
+                .layer(L.SubsamplingLayer())
+                .layer(L.DenseLayer(n_out=16, activation="relu"))
+                .layer(L.OutputLayer(n_out=10, activation="softmax",
+                                     loss="mcxent"))
+                .set_input_type(InputType.convolutional(8, 8, 1))
+                .build())
+        net = MultiLayerNetwork(conf).init()
+        rng = np.random.default_rng(0)
+        ds = DataSet(rng.normal(size=(16, 1, 8, 8)).astype(np.float32),
+                     np.eye(10, dtype=np.float32)[rng.integers(0, 10, 16)])
+        selected = _counter_value("dl4j_pallas_selected_total", "conv2d")
+        fallback = _counter_value("dl4j_pallas_fallback_total", "conv2d")
+        if how == "parallel_wrapper":
+            mesh = make_mesh(MeshConfig(data=2, fsdp=2),
+                             devices=jax.devices()[:4])
+            ParallelWrapper(net, mesh).fit(ListDataSetIterator([ds]))
+        else:
+            net.fit(ds)
+        # the trained model's params stay on the mesh: score() and
+        # output() are partitioned programs too
+        assert np.isfinite(net.score(ds))
+        assert np.all(np.isfinite(np.asarray(net.output(ds.features))))
+        assert _counter_value("dl4j_pallas_selected_total",
+                              "conv2d") == selected
+        assert _counter_value("dl4j_pallas_fallback_total",
+                              "conv2d") >= fallback + 3

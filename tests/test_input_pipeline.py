@@ -480,10 +480,9 @@ def test_conf_pipeline_settings_roundtrip():
 
 
 def test_bench_dry_run_emits_record_on_cpu():
-    """bench.py must degrade to a JSON record under JAX_PLATFORMS=cpu
-    (regression guard for the r03 backend-init crash: rc=1 before any
-    bench ran).  Dry-run skips every config but walks the whole
-    record/registry path."""
+    """bench.py asked for the CPU by name (DL4J_BENCH_PLATFORM=cpu)
+    emits its JSON record.  Dry-run skips every config but walks the
+    whole record/registry path."""
     env = dict(os.environ)
     env.update({"JAX_PLATFORMS": "cpu", "DL4J_BENCH_PLATFORM": "cpu",
                 "DL4J_BENCH_DRY_RUN": "1"})
@@ -511,20 +510,23 @@ def test_bench_dry_run_emits_record_on_cpu():
         rec.get("platform", ""))
 
 
-def test_bench_falls_back_to_cpu_when_backend_unavailable():
-    """The exact r03 crash shape: a backend that raises 'Unable to
-    initialize' at device enumeration must degrade to cpu-fallback, not
-    exit 1 before any bench runs."""
-    env = dict(os.environ)
+@pytest.mark.parametrize("env_update,needle", [
+    ({"DL4J_BENCH_PLATFORM": "bogus"}, "bogus"),     # backend cannot init
+    ({"JAX_PLATFORMS": "cpu"}, "needs a TPU"),       # no chip, cpu not named
+])
+def test_bench_fails_without_a_chip(env_update, needle):
+    """No chip is an error, never a quiet move to the CPU: unless the
+    CPU is asked for by name, bench.py exits non-zero, and its one JSON
+    line carries the error and no config."""
+    env = dict(os.environ, DL4J_BENCH_DRY_RUN="1")
     env.pop("JAX_PLATFORMS", None)
-    env.update({"DL4J_BENCH_PLATFORM": "bogus", "DL4J_BENCH_DRY_RUN": "1"})
+    env.pop("DL4J_BENCH_PLATFORM", None)
+    env.update(env_update)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     p = subprocess.run([sys.executable, os.path.join(root, "bench.py")],
                        capture_output=True, text=True, timeout=240,
                        env=env, cwd=root)
-    assert p.returncode == 0, p.stderr[-2000:]
+    assert p.returncode != 0, p.stdout[-2000:]
     rec = json.loads(p.stdout.strip().splitlines()[-1])
-    assert rec["backend"] == "cpu-fallback"
-    assert "backend_error" in rec
-    assert rec["configs"], "no configs registered after fallback"
-    assert "fatal_error" not in rec
+    assert needle in rec["fatal_error"]
+    assert "platform" not in rec and not rec.get("configs")

@@ -56,8 +56,7 @@ def test_policy_resolution():
 
 def test_cast_to_compute_leaves_f64_and_ints_alone():
     p = dtype_ops.BF16
-    from deeplearning4j_tpu.nn.gradientcheck import _enable_x64
-    with _enable_x64():
+    with jax.enable_x64(True):
         tree = {"w": jnp.ones((2, 2), jnp.float32),
                 "idx": jnp.zeros((3,), jnp.int32),
                 "check": jnp.ones((2,), jnp.float64)}

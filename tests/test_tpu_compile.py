@@ -1,0 +1,149 @@
+"""The fused kernel tiers, asked of the TPU's own compiler with no TPU.
+
+Interpret mode (every other kernel test) cannot see what Mosaic refuses:
+block shapes that do not tile, slices off the tiling, too much VMEM.
+libtpu is installed here and compiles for a chip that is described, not
+attached, so each tier's forward+backward is lowered and compiled for a
+``v5e:2x2`` topology at its self-test shape and at one real width, and
+the compiled HLO must carry the kernel (``tpu_custom_call``).  Nothing
+runs: these say "the chip's compiler accepts it", never "it is right" or
+"it is fast".
+
+The topology, the sharding and the shapes are built inside fixtures of
+THIS file only (one process may hold libtpu; under pytest-xdist that is
+the worker this file lands on), and the non-interpret branch is steered
+from the test with ``DL4J_TPU=1``."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from deeplearning4j_tpu.ops import pallas_kernels as pk
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    mp = pytest.MonkeyPatch()
+    mp.setenv("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip: keep it out
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        try:
+            desc = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield desc
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        cc.reset_cache()
+        mp.undo()
+
+
+@pytest.fixture
+def compile_for_chip(topo, monkeypatch):
+    """compile(fn, (shape, dtype), ...) → HLO text of ``fn`` compiled
+    for one described v5e chip, on the branches ``is_tpu()`` selects."""
+    from jax.sharding import SingleDeviceSharding
+    monkeypatch.setenv("DL4J_TPU", "1")
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def compile_(fn, *specs):
+        args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+                for shape, dtype in specs]
+        return jax.jit(fn).lower(*args).compile().as_text()
+    return compile_
+
+
+def _sq(y):
+    return jnp.sum(y.astype(jnp.float32) ** 2)
+
+
+@pytest.mark.parametrize("x_shape,w_shape,dtype", [
+    ((2, 3, 10, 10), (8, 3, 3, 3), jnp.float32),       # self-test shape
+    ((2, 3, 10, 10), (8, 3, 3, 3), jnp.bfloat16),
+    ((32, 64, 32, 32), (64, 64, 3, 3), jnp.float32),   # a VGG block
+    ((32, 64, 32, 32), (64, 64, 3, 3), jnp.bfloat16),
+], ids=["selftest-f32", "selftest-bf16", "vgg-f32", "vgg-bf16"])
+def test_conv_bias_act_compiles(compile_for_chip, x_shape, w_shape, dtype):
+    assert pk.conv_fused_supported(x_shape, w_shape, dtype,
+                                   activation="relu", border_mode="same")
+
+    def step(x, w, b):
+        return jax.value_and_grad(
+            lambda x, w, b: _sq(pk.fused_conv2d_bias_act(
+                x, w, b, border_mode="same", activation="relu")),
+            argnums=(0, 1, 2))(x, w, b)
+    hlo = compile_for_chip(step, (x_shape, dtype), (w_shape, dtype),
+                           ((w_shape[0],), dtype))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("n,h", [(4, 16), (32, 200)],
+                         ids=["selftest", "charrnn-32x200"])
+def test_lstm_step_compiles(compile_for_chip, n, h):
+    assert pk.lstm_fused_supported(n, h, jnp.float32)
+
+    def step(zx, hh, c, rw, p3):
+        def loss(zx, hh, c, rw, p3):
+            c_new, h_new = pk.fused_lstm_step(zx, hh, c, rw, p3)
+            return _sq(c_new) + _sq(h_new)
+        return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))(
+            zx, hh, c, rw, p3)
+    f32 = jnp.float32
+    hlo = compile_for_chip(step, ((n, 4 * h), f32), ((n, h), f32),
+                           ((n, h), f32), ((h, 4 * h), f32), ((3, h), f32))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("n,v,dtype", [
+    (256, 512, jnp.float32),
+    (4096, 50304, jnp.bfloat16),
+], ids=["selftest", "lm-head-4096x50304-bf16"])
+def test_softmax_xent_compiles(compile_for_chip, n, v, dtype):
+    def step(logits, labels):
+        return jax.value_and_grad(
+            lambda lg: pk.softmax_xent_rows(lg, labels).mean())(logits)
+    hlo = compile_for_chip(step, ((n, v), dtype), ((n, v), dtype))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("shape", [(64, 128), (128, 4096)],
+                         ids=["selftest", "fc-128x4096"])
+def test_threshold_dropout_compiles(compile_for_chip, shape):
+    assert pk.dropout_fused_supported(shape, jnp.float32)
+    key = jax.random.PRNGKey(7)
+
+    def step(x):
+        return jax.value_and_grad(
+            lambda x: _sq(pk.fused_threshold_dropout(x, 0.8, key)))(x)
+    hlo = compile_for_chip(step, (shape, jnp.float32))
+    # forward and backward are the same kernel, launched twice
+    assert hlo.count("tpu_custom_call") >= 2
+
+
+@pytest.mark.parametrize("shape,causal", [
+    ((1, 2, 256, 64), True),      # self-test shape
+    ((2, 4, 512, 64), True),
+    ((2, 4, 512, 64), False),
+    ((2, 4, 200, 64), True),      # ragged T: padded to 256 inside
+], ids=["selftest", "T512-causal", "T512-full", "ragged-T200"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_flash_attention_compiles(compile_for_chip, shape, causal, dtype):
+    B, H, T, D = shape
+    assert pk.flash_attention_supported(jax.ShapeDtypeStruct(shape, dtype))
+
+    def step(q, k, v, km):
+        return jax.value_and_grad(
+            lambda q, k, v: _sq(pk.flash_attention(q, k, v, km,
+                                                   causal=causal)),
+            argnums=(0, 1, 2))(q, k, v)
+    hlo = compile_for_chip(step, (shape, dtype), (shape, dtype),
+                           (shape, dtype), ((B, T), jnp.float32))
+    # forward, dq and dk/dv kernels
+    assert hlo.count("tpu_custom_call") >= 3
