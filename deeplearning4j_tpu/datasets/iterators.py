@@ -211,7 +211,8 @@ def _pipeline_metrics():
 _METRICS = None
 
 
-def _batch_nbytes(d) -> int:
+def _batch_arrays(d) -> list:
+    """Every array of a DataSet or MultiDataSet (or the bare array)."""
     if isinstance(d, MultiDataSet):
         arrs = list(d.features) + list(d.labels)
         for ms in (d.features_masks, d.labels_masks):
@@ -221,8 +222,11 @@ def _batch_nbytes(d) -> int:
         arrs = [d.features, d.labels, d.features_mask, d.labels_mask]
     else:
         arrs = [d]
-    return sum(int(getattr(a, "nbytes", 0) or 0) for a in arrs
-               if a is not None)
+    return [a for a in arrs if a is not None]
+
+
+def _batch_nbytes(d) -> int:
+    return sum(int(getattr(a, "nbytes", 0) or 0) for a in _batch_arrays(d))
 
 
 def _device_put_batch(d):
@@ -248,15 +252,26 @@ def _device_put_batch(d):
 def _make_etl(collate, normalizer, transform, device_put):
     """The worker-side ETL chain as a closure over plain values — it
     must NOT capture the iterator (running threads would pin it and the
-    GC-finalizer shutdown path could never fire)."""
+    GC-finalizer shutdown path could never fire).  Its two stages are
+    timed where they run, on the worker: span ``pipeline/batch``, phase
+    ``transform`` and, when staging to the device, ``h2d``."""
+    import jax
+    from deeplearning4j_tpu import monitor
+
     def etl(raw):
-        d = collate(raw) if collate is not None else raw
-        if normalizer is not None:
-            d = normalizer.transform(d)
-        if transform is not None:
-            d = transform(d)
+        with monitor.span("pipeline/batch", phase="transform"):
+            d = collate(raw) if collate is not None else raw
+            if normalizer is not None:
+                d = normalizer.transform(d)
+            if transform is not None:
+                d = transform(d)
         if device_put:
-            d = _device_put_batch(d)
+            with monitor.span("pipeline/batch", phase="h2d"):
+                d = _device_put_batch(d)
+                # device_put returns before the bytes have moved: wait
+                # here, on the worker, so that the phase times the
+                # transfer and a staged batch is one that has arrived
+                jax.block_until_ready(_batch_arrays(d))
         return d
     return etl
 

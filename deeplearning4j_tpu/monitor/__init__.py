@@ -27,20 +27,23 @@ journal tail plus a registry snapshot to a timestamped JSON file;
 Chrome trace-event export).
 
 Env knobs: ``DL4J_PROFILE=<dir>`` wraps every fit in
-``jax.profiler.start_trace``; ``DL4J_TRACE_ANNOTATIONS=1`` mirrors
+``jax.profiler.start_trace`` and writes its ``summary.json``
+(monitor/profile.py); ``DL4J_TRACE_ANNOTATIONS=1`` mirrors
 spans into XLA profiler dumps; ``DL4J_SPANS=0`` disables span timing;
 ``DL4J_JOURNAL=0`` disables the event journal; ``DL4J_FLIGHT_DIR``
 places flight-recorder dumps.  Full catalog: docs/OBSERVABILITY.md.
 """
 
-from deeplearning4j_tpu.monitor import events, flight  # noqa: F401
+from deeplearning4j_tpu.monitor import (  # noqa: F401
+    compile_stages, events, flight)
 from deeplearning4j_tpu.monitor.events import (  # noqa: F401
     EventJournal, chrome_trace, chrome_trace_fleet, get_journal,
     new_request_id, request_scope)
 from deeplearning4j_tpu.monitor.registry import (  # noqa: F401
     Counter, Gauge, Histogram, MetricsRegistry, get_registry)
 from deeplearning4j_tpu.monitor.tracing import (  # noqa: F401
-    Span, current, enable_jax_annotations, profile_if_configured, span)
+    Span, StepSpans, current, enable_jax_annotations,
+    profile_if_configured, span)
 from deeplearning4j_tpu.monitor.exposition import (  # noqa: F401
     CONTENT_TYPE, merge_snapshots, parse_prometheus, render_json,
     render_prometheus, snapshot_from_parsed, summarize)
@@ -50,6 +53,8 @@ from deeplearning4j_tpu.monitor.system import (  # noqa: F401
 # Device/host memory is only knowable at scrape time — refresh it on
 # every snapshot of the process registry.
 get_registry().register_collector(memory_collector)
+# JAX's compile-stage timers land in dl4j_compile_seconds{stage,span}
+compile_stages.install()
 
 
 def record_fit_step(batch_size: int, seconds: float,

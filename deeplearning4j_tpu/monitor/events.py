@@ -101,6 +101,7 @@ EVENT_TYPES = (
     "fit.start",
     "fit.end",
     "compile.retrace",
+    "compile.stage",
     "sanitizer.violation",
     "readyz.flip",
     "flight.dump",

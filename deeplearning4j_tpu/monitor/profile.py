@@ -1,0 +1,332 @@
+"""From a profiler trace to where a ``fit()``'s time went.
+
+    python -m deeplearning4j_tpu.monitor.profile <trace dir or .xplane.pb>
+
+reads a trace that ``DL4J_PROFILE=<dir>`` (or any ``jax.profiler`` run
+with ``DL4J_TRACE_ANNOTATIONS=1``) wrote and prints one JSON summary;
+``DL4J_PROFILE`` writes the same as ``<dir>/fitN/summary.json`` when
+the ``fit()`` returns.  Per chip it holds
+
+* ``busy_s`` / ``window_s``: the union of the device's operations, and
+  the stretch from the first to the last;
+* ``device_s``: device seconds by direction (``fwd``, ``bwd``,
+  ``update``, ``loss``, ``unscoped``) and layer type, read from the
+  ``jax.named_scope`` names the engines put on the step
+  (``fwd/<LayerType>/<index or vertex>``, ``loss``, ``update``; JAX
+  wraps the backward's operations in ``transpose(jvp(...))`` of the
+  forward's name), and ``top_scopes``, the ten longest scopes;
+* ``idle_s``: the device's idle seconds by the host phase they fell in.
+  Device and host events share the trace's clock, so each gap between
+  device operations is shared out over the ``fit/step`` phases it
+  overlaps, by intersection of intervals; what no phase covers goes to
+  ``outside_fit``;
+* ``wait_lag_s``: the median time from the device's last operation
+  inside a ``block_until_ready`` phase to that phase's end: how late
+  the host learns that a step is done.  It is also the check on the one
+  clock: the profiler sets the device's timestamps against the host's
+  to about a millisecond, and a session whose lag reads a millisecond
+  more than another's has its idle time that much too early;
+* ``host_s``: seconds and count of every ``fit/step`` phase.
+
+An operation's scope is its HLO ``op_name`` metadata.  The TPU runtime
+writes it into the trace as the ``tf_op`` stat of the event's
+*metadata* (one record an instruction, which ``jax.profiler.ProfileData``
+does not show), so this module reads the ``.xplane.pb`` itself: the file
+is one protobuf message whose few fields it needs are walked by hand
+(:func:`_fields`; field numbers from tsl's ``xplane.proto``).  A fusion
+carries the ``op_name`` of its root instruction; copies the compiler
+inserted carry none and read ``unscoped``.
+"""
+
+from __future__ import annotations
+
+import bisect
+import glob
+import json
+import os
+import re
+import statistics
+import sys
+from typing import (
+    Dict, Iterable, Iterator, List, Optional, Sequence, Tuple)
+
+OPS_LINE = "XLA Ops"
+DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
+PHASE = re.compile(r"^fit/step/(\w+)$")
+#: the stat of a device event's metadata that holds the HLO op_name
+SCOPE_STATS = ("tf_op",)
+LAYER_SCOPE = re.compile(r"fwd/(\w+)/([^/()]+)")
+UPDATE_SCOPE = re.compile(r"(?:^|[/(])update(?:[/)]|$)")
+LOSS_SCOPE = re.compile(r"(?:^|[/(])loss(?:[/)]|$)")
+OUTSIDE = "outside_fit"
+#: the phase in which the host waits for the device
+WAIT = "block_until_ready"
+
+Interval = Tuple[float, float]
+
+
+def _varint(buf, i: int) -> Tuple[int, int]:
+    value = shift = 0
+    while True:
+        byte = buf[i]
+        i += 1
+        value |= (byte & 0x7F) << shift
+        if not byte & 0x80:
+            return value, i
+        shift += 7
+
+
+def _fields(buf) -> Iterator[Tuple[int, object]]:
+    """(field number, value) of every field of one protobuf message:
+    an int for a varint, a memoryview for a length-delimited field."""
+    i, n = 0, len(buf)
+    while i < n:
+        key, i = _varint(buf, i)
+        wire = key & 7
+        if wire == 0:
+            value, i = _varint(buf, i)
+        elif wire == 2:
+            size, i = _varint(buf, i)
+            value, i = buf[i:i + size], i + size
+        else:                       # fixed64 / fixed32
+            size = 8 if wire == 1 else 4
+            value, i = buf[i:i + size], i + size
+        yield key >> 3, value
+
+
+def _text(value) -> str:
+    return bytes(value).decode("utf-8", "replace")
+
+
+def _map_entry(buf):
+    key = value = None
+    for f, v in _fields(buf):
+        if f == 1:
+            key = v
+        elif f == 2:
+            value = v
+    return key, value
+
+
+def _plane(buf) -> Tuple[str, list]:
+    """(name, [(line name, [(start_ns, duration_ns, event name,
+    op_name)])]) of one XPlane."""
+    name, lines, metadata, stat_names = "", [], {}, {}
+    for f, v in _fields(buf):
+        if f == 2:
+            name = _text(v)
+        elif f == 3:
+            lines.append(v)
+        elif f == 4:                # map<int64, XEventMetadata>
+            key, value = _map_entry(v)
+            metadata[key] = value
+        elif f == 5:                # map<int64, XStatMetadata>
+            key, value = _map_entry(v)
+            stat_names[key] = next(
+                (_text(x) for g, x in _fields(value) if g == 2), "")
+    scope_ids = {i for i, n in stat_names.items() if n in SCOPE_STATS}
+    named = {}
+    for key, value in metadata.items():
+        ev_name = op_name = ""
+        for f, v in _fields(value):
+            if f == 2:
+                ev_name = _text(v)
+            elif f == 5 and not op_name:            # XStat
+                stat = dict(_fields(v))
+                if stat.get(1) in scope_ids:
+                    op_name = (_text(stat[5]) if 5 in stat
+                               else stat_names.get(stat.get(7), ""))
+        named[key] = (ev_name, op_name)
+    out = []
+    for line in lines:
+        line_name, t0, events = "", 0, []
+        for f, v in _fields(line):
+            if f == 2:
+                line_name = _text(v)
+            elif f == 3:
+                t0 = v
+            elif f == 4:
+                events.append(v)
+        rows = []
+        for ev in events:
+            e = dict(_fields(ev))
+            rows.append((t0 + e.get(2, 0) / 1e3, e.get(3, 0) / 1e3)
+                        + named.get(e.get(1), ("", "")))
+        out.append((line_name, rows))
+    return name, out
+
+
+def load(path: str) -> List[Tuple[str, list]]:
+    """The planes of the newest ``.xplane.pb`` under ``path`` (or of
+    ``path`` itself), as :func:`_plane` gives them."""
+    if not path.endswith(".pb"):
+        found = sorted(glob.glob(os.path.join(path, "**", "*.xplane.pb"),
+                                 recursive=True))
+        if not found:
+            raise FileNotFoundError(f"no .xplane.pb under {path}")
+        path = found[-1]
+    with open(path, "rb") as f:
+        space = memoryview(f.read())
+    return [_plane(v) for f, v in _fields(space) if f == 1]
+
+
+def classify(op_name: str) -> Tuple[str, str, str]:
+    """(direction, layer type, scope) of an HLO ``op_name``."""
+    if UPDATE_SCOPE.search(op_name):
+        return "update", "update", "update"
+    m = LAYER_SCOPE.search(op_name)
+    if m:
+        direction = "bwd" if "transpose(" in op_name else "fwd"
+        return direction, m.group(1), f"{direction}/{m.group(1)}/{m.group(2)}"
+    if LOSS_SCOPE.search(op_name):
+        return "loss", "loss", "loss"
+    return "unscoped", "unscoped", "unscoped"
+
+
+def union(intervals: Iterable[Interval]) -> Tuple[float, List[Interval]]:
+    """Total length, and the gaps, of a set of (start, end) intervals."""
+    busy, gaps, end = 0.0, [], None
+    for s, e in sorted(intervals):
+        if end is None:
+            busy, end = e - s, e
+        elif s > end:
+            gaps.append((end, s))
+            busy, end = busy + (e - s), e
+        elif e > end:
+            busy, end = busy + (e - end), e
+    return busy, gaps
+
+
+def disjoint(phases: Sequence[Tuple[float, float, str]]):
+    """The phases sorted by start, each clipped to begin no earlier than
+    the one before it ended (spans of one loop tile; a nested span of
+    another path must not count a nanosecond twice)."""
+    out, end = [], float("-inf")
+    for s, e, name in sorted(phases):
+        s = max(s, end)
+        if e > s:
+            out.append((s, e, name))
+            end = e
+    return out
+
+
+def share_gap(gap: Interval, phases: Sequence[Tuple[float, float, str]],
+              starts: Optional[List[float]] = None) -> Dict[str, float]:
+    """Share one idle gap out over the (disjoint, sorted) host phases
+    it overlaps; the remainder goes to ``outside_fit``.  The values sum
+    to the gap's length."""
+    a, b = gap
+    if starts is None:
+        starts = [s for s, _, _ in phases]
+    out: Dict[str, float] = {}
+    covered = 0.0
+    i = max(0, bisect.bisect_right(starts, a) - 1)
+    while i < len(phases) and phases[i][0] < b:
+        s, e, name = phases[i]
+        overlap = min(b, e) - max(a, s)
+        if overlap > 0:
+            out[name] = out.get(name, 0.0) + overlap
+            covered += overlap
+        i += 1
+    if b - a - covered > 0:
+        out[OUTSIDE] = b - a - covered
+    return out
+
+
+def host_phases(planes) -> List[Tuple[float, float, str]]:
+    """[(start_ns, end_ns, phase)] of the ``fit/step`` annotations."""
+    out = []
+    for name, lines in planes:
+        if not name.startswith("/host:"):
+            continue
+        for _, events in lines:
+            for start, duration, ev_name, _ in events:
+                m = PHASE.match(ev_name)
+                if m:
+                    out.append((start, start + duration, m.group(1)))
+    return out
+
+
+def device_events(planes) -> Dict[int, List[Tuple[float, float, str]]]:
+    """{chip: [(start_ns, duration_ns, op_name)]} of the ops lines."""
+    out: Dict[int, List[Tuple[float, float, str]]] = {}
+    for name, lines in planes:
+        m = DEVICE_PLANE.match(name)
+        if not m:
+            continue
+        for line_name, events in lines:
+            if line_name == OPS_LINE:
+                out[int(m.group(1))] = [(s, d, op) for s, d, _, op in events]
+    return out
+
+
+def summarize(planes) -> dict:
+    """The summary of one trace (see the module's docstring).  A trace
+    with no device plane (a CPU run) has no ``chips``."""
+    phases = disjoint(host_phases(planes))
+    starts = [s for s, _, _ in phases]
+    host: Dict[str, List[float]] = {}
+    for s, e, name in phases:
+        h = host.setdefault(name, [0.0, 0])
+        h[0] += (e - s) / 1e9
+        h[1] += 1
+    chips = {}
+    for chip, evs in sorted(device_events(planes).items()):
+        if not evs:
+            continue
+        busy, gaps = union((s, s + d) for s, d, _ in evs)
+        by_dir: Dict[str, Dict[str, float]] = {}
+        by_scope: Dict[str, float] = {}
+        for _, d, op_name in evs:
+            direction, kind, scope = classify(op_name)
+            by_kind = by_dir.setdefault(direction, {})
+            by_kind[kind] = by_kind.get(kind, 0.0) + d / 1e9
+            by_scope[scope] = by_scope.get(scope, 0.0) + d / 1e9
+        idle: Dict[str, float] = {}
+        for gap in gaps:
+            for name, ns in share_gap(gap, phases, starts).items():
+                idle[name] = idle.get(name, 0.0) + ns / 1e9
+        ops_s = sum(d for _, d, _ in evs) / 1e9
+        ends = sorted(s + d for s, d, _ in evs)
+        lags = []
+        for s, e, name in phases:
+            if name == WAIT:
+                i = bisect.bisect_right(ends, e) - 1
+                if i >= 0 and ends[i] >= s:
+                    lags.append((e - ends[i]) / 1e9)
+        chips[str(chip)] = {
+            "busy_s": busy / 1e9,
+            "window_s": (max(s + d for s, d, _ in evs)
+                         - min(s for s, _, _ in evs)) / 1e9,
+            "scoped_share": 1.0 - by_scope.get("unscoped", 0.0) / ops_s
+            if ops_s else 0.0,
+            "device_s": by_dir,
+            "top_scopes": sorted(by_scope.items(),
+                                 key=lambda kv: -kv[1])[:10],
+            "idle_s": dict(sorted(idle.items(), key=lambda kv: -kv[1])),
+            "wait_lag_s": statistics.median(lags) if lags else None,
+        }
+    return {"chips": chips, "host_s": host}
+
+
+def write_summary(trace_dir: str) -> str:
+    """Summarize the trace under ``trace_dir`` into its
+    ``summary.json``; returns the file's path."""
+    out = os.path.join(trace_dir, "summary.json")
+    with open(out, "w") as f:
+        json.dump(summarize(load(trace_dir)), f, indent=1)
+    return out
+
+
+def main(argv: Optional[Sequence[str]] = None) -> int:
+    argv = sys.argv[1:] if argv is None else list(argv)
+    if len(argv) != 1:
+        print(__doc__.split("\n\n")[1].strip(), file=sys.stderr)
+        return 2
+    json.dump(summarize(load(argv[0])), sys.stdout, indent=1)
+    print()
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

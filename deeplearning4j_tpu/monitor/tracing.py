@@ -8,6 +8,12 @@ jitted call vs blocking on the device) is a scrape away instead of a
 profiler session ("Array Languages Make Neural Networks Fast":
 whole-framework speedups start from knowing which phase dominates).
 
+A loop whose phases must leave no time unnamed owns a
+:class:`StepSpans` for its length: ``with steps.span("fit/step",
+phase="jit_call"):`` is the same span, and whatever runs between two
+phases is the phase ``glue``, so the phases tile the loop and there is
+no "between".
+
 Two optional bridges into JAX's own profiler:
 
 * ``DL4J_TRACE_ANNOTATIONS=1`` (or :func:`enable_jax_annotations`)
@@ -15,8 +21,9 @@ Two optional bridges into JAX's own profiler:
   as named regions inside XLA profiler dumps;
 * ``DL4J_PROFILE=<dir>`` makes :func:`profile_if_configured` (which
   ``MultiLayerNetwork.fit``/``ComputationGraph.fit`` enter) wrap the
-  whole fit call in ``jax.profiler.start_trace(<dir>/fitN)`` — a full
-  XPlane/TensorBoard trace per fit with zero code changes.
+  whole fit call in ``jax.profiler.start_trace(<dir>/fitN)`` with the
+  annotations on, and write ``<dir>/fitN/summary.json`` on exit
+  (monitor/profile.py: device time by scope, idle time by host phase).
 
 ``DL4J_SPANS=0`` turns span timing into a no-op (the A/B lever for
 measuring span overhead; see bench.py's serving workload).
@@ -24,6 +31,7 @@ measuring span overhead; see bench.py's serving workload).
 
 from __future__ import annotations
 
+import logging
 import os
 import threading
 import time
@@ -36,20 +44,22 @@ from deeplearning4j_tpu.monitor.registry import (
 
 PHASE_METRIC = "dl4j_phase_seconds"
 
+log = logging.getLogger(__name__)
+
 _local = threading.local()
 _flags = {"jax_annotations": None, "enabled": None}
 _profile = {"active": False, "count": 0, "lock": threading.Lock()}
 
 
 class Span:
-    __slots__ = ("name", "phase", "parent", "wall_start", "duration")
+    __slots__ = ("name", "phase", "parent", "iteration", "duration")
 
     def __init__(self, name: str, phase: Optional[str],
-                 parent: Optional["Span"]):
+                 parent: Optional["Span"], iteration: Optional[int] = None):
         self.name = name
         self.phase = phase
         self.parent = parent
-        self.wall_start = time.time()
+        self.iteration = iteration
         self.duration: Optional[float] = None
 
     def __repr__(self):
@@ -92,6 +102,65 @@ def _annotations_enabled() -> bool:
     return os.environ.get("DL4J_TRACE_ANNOTATIONS") == "1"
 
 
+def _annotate(name: str, phase: Optional[str]):
+    """Open a region named ``name/phase`` in the profiler's trace."""
+    try:
+        import jax
+        ann = jax.profiler.TraceAnnotation(
+            f"{name}/{phase}" if phase else name)
+        ann.__enter__()
+        return ann
+    except Exception:
+        return None
+
+
+def _annotate_end(ann) -> None:
+    if ann is not None:
+        try:
+            ann.__exit__(None, None, None)
+        except Exception:
+            pass
+
+
+def _begin(name: str, phase: Optional[str],
+           iteration: Optional[int] = None) -> Span:
+    """Open a span on this thread's stack.  The caller reads the clock
+    (and opens the trace annotation, last, so that it covers the body
+    alone)."""
+    st = _stack()
+    s = Span(name, phase, st[-1] if st else None, iteration)
+    st.append(s)
+    # the journal sees every span close with its trace context
+    # (request_id / session_id / fit_id ride on the contextvars scope) —
+    # this is what lets "why was THIS predict slow" be answered from the
+    # event log.  Open events are verbose-only: close carries the
+    # duration, and doubling hot-path emits breaks the ≤5% budget.
+    if events.verbose():
+        events.emit("span.open", span=name, phase=phase or "")
+    return s
+
+
+def _series(registry: Optional[MetricsRegistry], name: str,
+            phase: Optional[str]):
+    """The histogram series of one (span, phase)."""
+    reg = registry if registry is not None else get_registry()
+    return reg.histogram(
+        PHASE_METRIC, "span phase wall time (seconds)",
+        labels=("span", "phase"),
+    ).labels(span=name, phase=phase or "")
+
+
+def _end(s: Span, duration: float, series) -> None:
+    """Close a span opened by :func:`_begin`: histogram, journal."""
+    s.duration = duration
+    st = _stack()
+    if st and st[-1] is s:
+        st.pop()
+    series.observe(duration)
+    events.emit("span.close", span=s.name, phase=s.phase or "",
+                duration_s=duration)
+
+
 @contextmanager
 def span(name: str, phase: Optional[str] = None,
          registry: Optional[MetricsRegistry] = None) -> Iterator[Span]:
@@ -102,82 +171,156 @@ def span(name: str, phase: Optional[str] = None,
     if not enabled():
         yield Span(name, phase, None)
         return
-    st = _stack()
-    s = Span(name, phase, st[-1] if st else None)
-    st.append(s)
-    # the journal sees every span close with its trace context
-    # (request_id / session_id / fit_id ride on the contextvars scope) —
-    # this is what lets "why was THIS predict slow" be answered from the
-    # event log.  Open events are verbose-only: close carries the
-    # duration, and doubling hot-path emits breaks the ≤5% budget.
-    if events.verbose():
-        events.emit("span.open", span=name, phase=phase or "")
-    ann = None
-    if _annotations_enabled():
-        try:
-            import jax
-            ann = jax.profiler.TraceAnnotation(
-                f"{name}/{phase}" if phase else name)
-            ann.__enter__()
-        except Exception:
-            ann = None
+    s = _begin(name, phase)
+    ann = _annotate(name, phase) if _annotations_enabled() else None
     t0 = time.perf_counter()
     try:
         yield s
     finally:
-        s.duration = time.perf_counter() - t0
-        if ann is not None:
-            try:
-                ann.__exit__(None, None, None)
-            except Exception:
-                pass
-        if st and st[-1] is s:
-            st.pop()
-        reg = registry if registry is not None else get_registry()
-        reg.histogram(
-            PHASE_METRIC, "span phase wall time (seconds)",
-            labels=("span", "phase"),
-        ).labels(span=name, phase=phase or "").observe(s.duration)
-        events.emit("span.close", span=name, phase=phase or "",
-                    duration_s=s.duration)
+        duration = time.perf_counter() - t0
+        _annotate_end(ann)
+        _end(s, duration, _series(registry, name, phase))
+
+
+GLUE = "glue"
+
+
+class StepSpans:
+    """The phases of one loop, with no time between them.
+
+    ``with steps.span(name, phase):`` is :func:`span`: the same
+    :class:`Span`, histogram series, journal event and trace annotation,
+    around the body alone.  What runs between one phase's end and the
+    next one's start (the loop's own statements, the spans' own
+    bookkeeping) is the phase ``glue`` of the same span: a histogram
+    observation at every start, and, when annotating, a region
+    ``<name>/glue`` that closes as the next phase's opens.  So the
+    phases and their glue tile the loop from this object's construction
+    to its last phase, in the registry and in the trace alike.  The loop
+    owns one for the length of a ``fit()``: ``DL4J_SPANS`` and
+    ``DL4J_TRACE_ANNOTATIONS`` are read once, here, and each series is
+    looked up once, not at every span.  Not thread-safe: one loop, one
+    thread."""
+
+    __slots__ = ("_on", "_annotating", "_registry", "_t", "_series",
+                 "_glue")
+
+    def __init__(self, annotate: Optional[bool] = None,
+                 registry: Optional[MetricsRegistry] = None):
+        self._on = enabled()
+        self._annotating = self._on and (
+            _annotations_enabled() if annotate is None else annotate)
+        self._registry = registry
+        self._series: dict = {}
+        self._glue = None                 # the open glue annotation
+        self._t = time.perf_counter()     # where the last phase ended
+
+    def span(self, name: str, phase: str,
+             iteration: Optional[int] = None) -> "_Phase":
+        return _Phase(self, name, phase, iteration)
+
+    def close(self) -> None:
+        """The loop is over, or hands over to code that times itself:
+        end the open glue region."""
+        _annotate_end(self._glue)
+        self._glue = None
+
+    def restart(self) -> None:
+        """Back from code that timed itself (after :meth:`close`): the
+        glue of the next phase starts now."""
+        self._t = time.perf_counter()
+
+    def _get(self, name: str, phase: str):
+        series = self._series.get((name, phase))
+        if series is None:
+            series = self._series[name, phase] = _series(
+                self._registry, name, phase)
+        return series
+
+
+class _Phase:
+    """One ``with steps.span(...)``: yields the open :class:`Span`, or
+    None when span timing is off."""
+
+    __slots__ = ("_steps", "_args", "_span", "_ann")
+
+    def __init__(self, steps: StepSpans, name: str, phase: str,
+                 iteration: Optional[int]):
+        self._steps = steps
+        self._args = (name, phase, iteration)
+        self._span: Optional[Span] = None
+        self._ann = None
+
+    def __enter__(self) -> Optional[Span]:
+        steps = self._steps
+        if steps._on:
+            name, phase, iteration = self._args
+            self._span = _begin(name, phase, iteration)
+            glue = steps._get(name, GLUE)
+            if steps._annotating:
+                steps.close()
+                self._ann = _annotate(name, phase)
+            t = time.perf_counter()
+            glue.observe(t - steps._t)
+            steps._t = t
+        return self._span
+
+    def __exit__(self, *exc) -> bool:
+        s = self._span
+        if s is not None:
+            steps = self._steps
+            t = time.perf_counter()
+            if steps._annotating:
+                _annotate_end(self._ann)
+                steps._glue = _annotate(s.name, GLUE)
+            _end(s, t - steps._t, steps._get(s.name, s.phase))
+            steps._t = t
+        return False
 
 
 @contextmanager
-def profile_if_configured(tag: str = "fit") -> Iterator[None]:
-    """No-op unless ``DL4J_PROFILE=<dir>`` is set; then the body runs
-    under ``jax.profiler.start_trace(<dir>/<tag><N>)``.  Re-entrant
-    calls (fit inside fit, concurrent fits) skip — JAX allows one live
-    trace per process."""
+def profile_if_configured(tag: str = "fit") -> Iterator[bool]:
+    """Yields False unless ``DL4J_PROFILE=<dir>`` is set; then the body
+    runs under ``jax.profiler.start_trace(<dir>/<tag><N>)`` (Python
+    tracer off: it slows the host it measures), yields True so that the
+    caller mirrors its spans into the trace, and on exit
+    ``<dir>/<tag><N>/summary.json`` is written (monitor/profile.py).
+    Re-entrant calls (fit inside fit, concurrent fits) skip — JAX
+    allows one live trace per process."""
     d = os.environ.get("DL4J_PROFILE")
     if not d:
-        yield
+        yield False
         return
     with _profile["lock"]:
-        if _profile["active"]:
-            started = False
-        else:
+        started = not _profile["active"]
+        if started:
             _profile["active"] = True
             path = os.path.join(d, f"{tag}{_profile['count']}")
             _profile["count"] += 1
-            started = True
     if not started:
-        yield
+        yield False
         return
     try:
         import jax
         os.makedirs(path, exist_ok=True)
-        jax.profiler.start_trace(path)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(path, profiler_options=opts)
     except Exception:
         with _profile["lock"]:
             _profile["active"] = False
-        yield
+        yield False
         return
     try:
-        yield
+        yield True
     finally:
         try:
             jax.profiler.stop_trace()
+            from deeplearning4j_tpu.monitor import profile
+            profile.write_summary(path)
         except Exception:
-            pass
-        with _profile["lock"]:
-            _profile["active"] = False
+            log.warning("DL4J_PROFILE: no summary for %s", path,
+                        exc_info=True)
+        finally:
+            with _profile["lock"]:
+                _profile["active"] = False

@@ -31,7 +31,7 @@ from deeplearning4j_tpu.ops import bucketing
 from deeplearning4j_tpu.ops import dtypes as dtype_ops
 from deeplearning4j_tpu.ops import updaters as upd_ops
 from deeplearning4j_tpu.nn.multilayer import (
-    BIAS_KEYS, WEIGHT_KEYS, _updater_for)
+    BIAS_KEYS, WEIGHT_KEYS, _updater_for, dispatch_train_step)
 
 
 class ComputationGraph:
@@ -55,58 +55,61 @@ class ComputationGraph:
         self.last_batch_size = 0
         self.last_etl_time_ms = 0.0
         self.compile_telemetry = bucketing.CompileTelemetry()
+        self._steps: Optional[monitor.StepSpans] = None   # fit() owns it
         self._bucket_train_ok: Optional[bool] = None
 
     # ------------------------------------------------------------------
     def init(self, params: Optional[Dict[str, dict]] = None) -> "ComputationGraph":
-        conf = self.conf
-        types: Dict[str, Any] = {}
-        if conf.input_types:
-            types.update(dict(zip(conf.network_inputs, conf.input_types)))
-        key = jax.random.PRNGKey(conf.global_conf.seed)
-        ps: Dict[str, dict] = {}
-        ss: Dict[str, dict] = {}
-        for name in self.order:
-            v = conf.vertices[name]
-            in_names = conf.vertex_inputs[name]
-            in_types = [types.get(i) for i in in_names]
-            if any(t is None for t in in_types):
-                # inputs without declared types: best effort via layer n_in
-                if isinstance(v, LayerVertex):
-                    lc = v.layer_conf()
-                    from deeplearning4j_tpu.nn.conf.layers import FrozenLayerConf
-                    if isinstance(lc, FrozenLayerConf):
-                        lc = lc._inner()
-                    n_in = getattr(lc, "n_in", None)
-                    if n_in:
-                        from deeplearning4j_tpu.nn.conf.inputs import InputType
-                        from deeplearning4j_tpu.nn.conf import layers as L
-                        if isinstance(lc, (L.GravesLSTM, L.GravesBidirectionalLSTM,
-                                           L.RnnOutputLayer)):
-                            in_types = [InputType.recurrent(n_in)]
+        with monitor.span("net/init", phase="default_weights"):
+            conf = self.conf
+            types: Dict[str, Any] = {}
+            if conf.input_types:
+                types.update(dict(zip(conf.network_inputs, conf.input_types)))
+            key = jax.random.PRNGKey(conf.global_conf.seed)
+            ps: Dict[str, dict] = {}
+            ss: Dict[str, dict] = {}
+            for name in self.order:
+                v = conf.vertices[name]
+                in_names = conf.vertex_inputs[name]
+                in_types = [types.get(i) for i in in_names]
+                if any(t is None for t in in_types):
+                    # inputs without declared types: best effort via layer n_in
+                    if isinstance(v, LayerVertex):
+                        lc = v.layer_conf()
+                        from deeplearning4j_tpu.nn.conf.layers import FrozenLayerConf
+                        if isinstance(lc, FrozenLayerConf):
+                            lc = lc._inner()
+                        n_in = getattr(lc, "n_in", None)
+                        if n_in:
+                            from deeplearning4j_tpu.nn.conf.inputs import InputType
+                            from deeplearning4j_tpu.nn.conf import layers as L
+                            if isinstance(lc, (L.GravesLSTM, L.GravesBidirectionalLSTM,
+                                               L.RnnOutputLayer)):
+                                in_types = [InputType.recurrent(n_in)]
+                            else:
+                                in_types = [InputType.feed_forward(n_in)]
                         else:
-                            in_types = [InputType.feed_forward(n_in)]
+                            raise ValueError(
+                                f"Vertex '{name}': set_input_types() required or "
+                                f"explicit n_in on the layer")
                     else:
                         raise ValueError(
-                            f"Vertex '{name}': set_input_types() required or "
-                            f"explicit n_in on the layer")
-                else:
-                    raise ValueError(
-                        f"Vertex '{name}': upstream type unknown — call "
-                        f"set_input_types() on the GraphBuilder")
-            key, sub = jax.random.split(key)
-            p, s, out_t = v.initialize(sub, in_types)
-            ps[name] = p
-            ss[name] = s
-            types[name] = out_t
-        self.net_params = params if params is not None else ps
-        self.net_state = ss
-        self.updaters = {name: _updater_for(self._vertex_layer(name))
-                         if isinstance(conf.vertices[name], LayerVertex)
-                         else upd_ops.make("sgd")
-                         for name in self.order}
-        self.opt_states = {name: self.updaters[name].init(self.net_params[name])
-                           for name in self.order}
+                            f"Vertex '{name}': upstream type unknown — call "
+                            f"set_input_types() on the GraphBuilder")
+                key, sub = jax.random.split(key)
+                p, s, out_t = v.initialize(sub, in_types)
+                ps[name] = p
+                ss[name] = s
+                types[name] = out_t
+        with monitor.span("net/init", phase="given_weights"):
+            self.net_params = params if params is not None else ps
+            self.net_state = ss
+            self.updaters = {name: _updater_for(self._vertex_layer(name))
+                             if isinstance(conf.vertices[name], LayerVertex)
+                             else upd_ops.make("sgd")
+                             for name in self.order}
+            self.opt_states = {name: self.updaters[name].init(self.net_params[name])
+                               for name in self.order}
         return self
 
     def _vertex_layer(self, name: str):
@@ -149,29 +152,36 @@ class ComputationGraph:
                 ins = ins + [acts[v.ts_input]]
                 ms = ms + [out_masks.get(v.ts_input)]
             r = jax.random.fold_in(rng, vi)
-            if name in preout_for:
-                lc = v.layer_conf()
-                x = ins[0]
-                if train:
-                    x = lc._maybe_dropout(x, True, r)
-                pre = lc.preoutput(
-                    lc._maybe_drop_connect(params[name], train, r), x)
-                preouts[name] = pre
-                new_states[name] = state[name]
-                acts[name] = lc._act(pre)
-                out_masks[name] = ms[0] if ms else None
-            else:
-                def fwd(p, s, ins_, ms_, v=v, r=r):
-                    return v.forward(p, s, ins_, train=train, rng=r,
-                                     masks=ms_)
-                if train and self.conf.global_conf.gradient_checkpointing:
-                    # per-vertex remat: recompute this vertex's forward in
-                    # the backward pass instead of storing activations
-                    fwd = jax.checkpoint(fwd)
-                y, ns, m = fwd(params[name], state[name], ins, ms)
-                acts[name] = y
-                new_states[name] = ns
-                out_masks[name] = m
+            kind = type(v.layer_conf() if isinstance(v, LayerVertex)
+                        else v).__name__
+            # the scope names this vertex's operations in a device trace;
+            # JAX wraps the backward's in transpose(jvp(...)) of the same
+            with jax.named_scope(f"fwd/{kind}/{name}"):
+                if name in preout_for:
+                    lc = v.layer_conf()
+                    x = ins[0]
+                    if train:
+                        x = lc._maybe_dropout(x, True, r)
+                    pre = lc.preoutput(
+                        lc._maybe_drop_connect(params[name], train, r), x)
+                    preouts[name] = pre
+                    new_states[name] = state[name]
+                    acts[name] = lc._act(pre)
+                    out_masks[name] = ms[0] if ms else None
+                else:
+                    def fwd(p, s, ins_, ms_, v=v, r=r):
+                        return v.forward(p, s, ins_, train=train, rng=r,
+                                         masks=ms_)
+                    if train and \
+                            self.conf.global_conf.gradient_checkpointing:
+                        # per-vertex remat: recompute this vertex's
+                        # forward in the backward pass instead of storing
+                        # activations
+                        fwd = jax.checkpoint(fwd)
+                    y, ns, m = fwd(params[name], state[name], ins, ms)
+                    acts[name] = y
+                    new_states[name] = ns
+                    out_masks[name] = m
         return acts, preouts, new_states, out_masks
 
     def _reg_penalty(self, params):
@@ -264,11 +274,12 @@ class ComputationGraph:
                     pc, state, inputs, masks, True, rng, preout_for=out_names)
                 preouts = {n: policy.cast_to_accum(v) for n, v in preouts.items()}
                 new_states = policy.cast_to_param(new_states)
-                score = self._assemble_training_score(
-                    p, preouts, new_states, out_masks, ys, lmasks,
-                    out_confs, out_pos)
-                if not g.minimize:
-                    score = -score  # maximize: parity with the MLN step
+                with jax.named_scope("loss"):
+                    score = self._assemble_training_score(
+                        p, preouts, new_states, out_masks, ys, lmasks,
+                        out_confs, out_pos)
+                    if not g.minimize:
+                        score = -score  # maximize: parity with the MLN step
                 return score, new_states
 
             (score, new_states), grads = jax.value_and_grad(
@@ -280,14 +291,18 @@ class ComputationGraph:
     def _build_step_raw(self):
         grad_step = self._build_grad_raw()
 
-        def step(params, state, opts, xs, ys, fmasks, lmasks, it, rng):
+        # the name is what a compile event and a trace show (jit_<name>)
+        def cg_train_step(params, state, opts, xs, ys, fmasks, lmasks, it,
+                          rng):
             score, new_states, grads = grad_step(params, state, xs, ys,
                                                  fmasks, lmasks, rng)
-            new_params, new_opts = self._apply_updates(params, opts, grads, it)
+            with jax.named_scope("update"):
+                new_params, new_opts = self._apply_updates(params, opts,
+                                                           grads, it)
             return new_params, new_states, new_opts, score
 
         from deeplearning4j_tpu.parallel import fsdp
-        return fsdp.partitioned_if_sharded(self, step)
+        return fsdp.partitioned_if_sharded(self, cg_train_step)
 
     def _apply_updates(self, params, opts, grads, it):
         """Traceable gradient→param update over the vertex dict (per-layer
@@ -370,7 +385,8 @@ class ComputationGraph:
         # same contract as MultiLayerNetwork.fit: a kernel rejection
         # disables its tier before the first step traces
         from deeplearning4j_tpu.ops import helpers as pallas_helpers
-        pallas_helpers.ensure_validated()
+        with monitor.span("fit/setup", phase="kernel_self_test"):
+            pallas_helpers.ensure_validated()
         self._check_trace_token()
         self._ensure_sharding()
         # elastic cluster training (conf.distributed(...)) — same
@@ -393,39 +409,17 @@ class ComputationGraph:
         if dist_sess is not None:
             skip_epochs, skip_batches = dist_sess.resume_position(
                 self, skip_epochs, skip_batches)
-        if isinstance(data, MultiDataSet):
-            batches = [data]
-            with sanitizer.armed_fit(self), \
-                    monitor.profile_if_configured("fit"), \
-                    events.scope(fit_id=events.new_request_id(),
-                                 model=type(self).__name__):
-                events.emit("fit.start", epochs=epochs,
-                            iteration=self.iteration)
-                for ep_i in range(epochs):
-                    if ep_i < skip_epochs:
-                        continue
-                    to_skip = skip_batches if ep_i == skip_epochs else 0
-                    self._epoch_start_iter = self.iteration - to_skip
-                    epoch_hook("on_epoch_start")
-                    for mds in batches:
-                        if to_skip > 0:
-                            to_skip -= 1
-                            continue
-                        self._fit_batch(mds)
-                    epoch_hook("on_epoch_end")
-                    self.epoch += 1
-                events.emit("fit.end", iteration=self.iteration,
-                            epoch=self.epoch)
-            return self
         # iterator of DataSet or MultiDataSet — wrapped in the parallel
         # input pipeline so ETL + H2D overlap the jitted step (the MLN
-        # fit path's AsyncDataSetIterator, multi-head flavored)
+        # fit path's AsyncDataSetIterator, multi-head flavored); one
+        # MultiDataSet is a one-batch epoch
         from deeplearning4j_tpu.datasets.iterators import (
             AsyncDataSetIterator, AsyncMultiDataSetIterator,
             reader_retry_from_conf)
-        it = data
+        single = isinstance(data, MultiDataSet)
+        it = [data] if single else data
         g = self.conf.global_conf
-        if (g.pipeline_workers > 0
+        if (not single and g.pipeline_workers > 0
                 and not isinstance(it, AsyncDataSetIterator)
                 and getattr(it, "async_supported", lambda: True)()):
             bucket_on = self._bucket_train_enabled()
@@ -456,22 +450,31 @@ class ComputationGraph:
                         and callable(getattr(it, "next", None)))
 
         def batches():
-            if has_protocol:
-                while it.has_next():
-                    with monitor.span("fit/step", phase="data_wait"):
-                        item = it.next()
-                    yield item
-            else:
+            if not has_protocol:
                 yield from it
+                return
+            while True:
+                with steps.span("fit/step", phase="has_next"):
+                    more = it.has_next()
+                if not more:
+                    return
+                with steps.span("fit/step", phase="data_wait"):
+                    item = it.next()
+                yield item
 
         try:
             # DL4J_SANITIZE: debug-nans/rank checks for the duration,
             # retrace-budget assertion on clean exit (analysis/sanitizer);
             # the events.scope correlates every span/event under one fit
             with sanitizer.armed_fit(self), \
-                    monitor.profile_if_configured("fit"), \
+                    monitor.profile_if_configured("fit") as profiling, \
                     events.scope(fit_id=events.new_request_id(),
                                  model=type(self).__name__):
+                # the phases of fit/step tile the loop from here to the
+                # pipeline's close (a profiled fit mirrors them into its
+                # trace whatever DL4J_TRACE_ANNOTATIONS says)
+                self._steps = steps = monitor.StepSpans(
+                    annotate=profiling or None)
                 events.emit("fit.start", epochs=epochs,
                             iteration=self.iteration)
                 for ep_i in range(epochs):
@@ -479,9 +482,10 @@ class ComputationGraph:
                         continue  # resumed past this epoch entirely
                     to_skip = skip_batches if ep_i == skip_epochs else 0
                     self._epoch_start_iter = self.iteration - to_skip
-                    epoch_hook("on_epoch_start")
-                    if callable(getattr(it, "reset", None)):
-                        it.reset()
+                    with steps.span("fit/step", phase="epoch"):
+                        epoch_hook("on_epoch_start")
+                        if callable(getattr(it, "reset", None)):
+                            it.reset()
                     pending = []
                     for item in batches():
                         if to_skip > 0:
@@ -493,7 +497,7 @@ class ComputationGraph:
                             item = MultiDataSet(
                                 [item.features], [item.labels],
                                 [item.features_mask], [item.labels_mask])
-                        if fuse > 1:
+                        if fuse > 1 and not single:
                             pending.append(item)
                             if len(pending) == fuse:
                                 self._fit_fused_group(pending)
@@ -502,13 +506,19 @@ class ComputationGraph:
                             self._fit_batch(item)
                     for item in pending:
                         self._fit_batch(item)
-                    epoch_hook("on_epoch_end")
-                    self.epoch += 1
+                    with steps.span("fit/step", phase="epoch"):
+                        epoch_hook("on_epoch_end")
+                        self.epoch += 1
+                if isinstance(it, AsyncDataSetIterator):
+                    with steps.span("fit/step", phase="epoch"):
+                        it.close()
                 events.emit("fit.end", iteration=self.iteration,
                             epoch=self.epoch)
         finally:
             if isinstance(it, AsyncDataSetIterator):
                 it.close()
+            if self._steps is not None:
+                self._steps.close()
         return self
 
     def _build_fused_step(self, k: int):
@@ -521,7 +531,7 @@ class ComputationGraph:
             return {n: {kk: v for kk, v in s.items() if kk != "rnn_state"}
                     for n, s in state.items()}
 
-        def k_steps(params, state, opts, xs, ys, fms, lms, it0, key):
+        def cg_fused_steps(params, state, opts, xs, ys, fms, lms, it0, key):
             def body(carry, inp):
                 p, s, o = carry
                 i, x, y, fm, lm = inp
@@ -533,7 +543,7 @@ class ComputationGraph:
                 (jnp.arange(k), xs, ys, fms, lms))
             return params, state, opts, scores[-1]
 
-        return jax.jit(k_steps, donate_argnums=(0, 1, 2))  # dl4j: noqa[DL4J104] one jitted fn per k, cached in _fused_fns[k]
+        return jax.jit(cg_fused_steps, donate_argnums=(0, 1, 2))  # dl4j: noqa[DL4J104] one jitted fn per k, cached in _fused_fns[k]
 
     def _fit_fused_group(self, group):
         if self.net_params is None:
@@ -548,7 +558,8 @@ class ComputationGraph:
         sizes = [m.num_examples() for m in group]
         # ragged groups become bucket-uniform and stay on the fused scan
         # path instead of degrading to per-step (see MultiLayerNetwork)
-        group = [self._maybe_bucket_train(m)[0] for m in group]
+        with self._steps.span("fit/step", phase="bucket"):
+            group = [self._maybe_bucket_train(m)[0] for m in group]
 
         def shape_sig(m):
             # per-ELEMENT mask presence: MultiDataSet wraps a missing
@@ -583,36 +594,17 @@ class ComputationGraph:
                  if get(group[0])[i] is not None else None)
                 for i in range(n_el))
 
-        xs = tuple(jnp.stack([jnp.asarray(m.features[i]) for m in group])
-                   for i in range(len(group[0].features)))
-        ys = tuple(jnp.stack([jnp.asarray(m.labels[i]) for m in group])
-                   for i in range(len(group[0].labels)))
-        fms = stack_tuple(lambda m: m.features_masks,
-                          group[0].features_masks is not None)
-        lms = stack_tuple(lambda m: m.labels_masks,
-                          group[0].labels_masks is not None)
-        fresh = self.compile_telemetry.record(f"fused_step_k{k}",
-                                              (xs, ys, fms, lms))
-        self._key, sub = jax.random.split(self._key)
-        it_arr = jnp.asarray(self.iteration, jnp.int32)
         t_step = time.perf_counter()
-        with monitor.span("fit/step", phase="jit_call"), \
-                sanitizer.guard_step(compiling=fresh):
-            (self.net_params, self.net_state, self.opt_states,
-             score) = self._fused_fns[k](
-                self.net_params, self.net_state, self.opt_states,
-                xs, ys, fms, lms, it_arr, sub)
-        with monitor.span("fit/step", phase="block_until_ready"):
-            jax.block_until_ready(score)
-        self._strip_rnn_state()
-        self._score = score
-        self.iteration += k
+        with self._steps.span("fit/step", phase="h2d"):
+            batch = (stack_tuple(lambda m: m.features, True),
+                     stack_tuple(lambda m: m.labels, True),
+                     stack_tuple(lambda m: m.features_masks,
+                                 group[0].features_masks is not None),
+                     stack_tuple(lambda m: m.labels_masks,
+                                 group[0].labels_masks is not None))
         self.last_batch_size = sum(sizes)
-        monitor.record_fit_step(self.last_batch_size,
-                                time.perf_counter() - t_step, score)
-        with monitor.span("fit/step", phase="listeners"):
-            for lst in self.listeners:
-                lst.iteration_done(self, self.iteration)
+        dispatch_train_step(self, self._fused_fns[k], f"fused_step_k{k}",
+                            batch, batch, t_step, k=k)
 
     def _check_trace_token(self):
         """See MultiLayerNetwork._check_trace_token — retrace when the
@@ -684,67 +676,52 @@ class ComputationGraph:
             self.init()
         if self.conf.backprop_type == "truncatedbptt" \
                 and any(f.ndim == 3 for f in mds.features):
-            self._fit_tbptt(mds)
+            with self._steps.span("fit/step", phase="tbptt"):
+                self._fit_tbptt(mds)
             return
         dist_sess = getattr(self, "_dist_session", None)
         if dist_sess is not None:
             # cluster step — see MultiLayerNetwork._fit_batch
             from deeplearning4j_tpu.distributed import worker as dist_worker
+            self._steps.close()     # timed by the worker's own spans
             dist_worker.fit_batch(self, mds, dist_sess, is_graph=True)
+            self._steps.restart()
             return
-        self._check_trace_token()
-        if self._step_fn is None:
-            self._step_fn = self._build_step()
+        steps = self._steps
+        with steps.span("fit/step", phase="dispatch_prep"):
+            # is the step function still the one to dispatch?  (the list
+            # engine asks once a fit(); here it is a step's cost)
+            self._check_trace_token()
+            if self._step_fn is None:
+                self._step_fn = self._build_step()
         self.last_batch_size = mds.num_examples()
         t_step = time.perf_counter()
         plan = getattr(self, "_sharding_plan", None)
         if plan is not None:
             from deeplearning4j_tpu.parallel import fsdp
-            with monitor.span("fit/step", phase="bucket"):
+            with steps.span("fit/step", phase="bucket"):
                 norm = fsdp.normalize_batch(self, mds, plan.n_data,
                                             is_graph=True)
             if norm is None:
                 return
-            batch, n, bucket = norm
+            sig_args, n, bucket = norm
             self.last_batch_size = n
-            fresh = self.compile_telemetry.record("sharded_step", batch,
-                                                  bucket=bucket)
-            with monitor.span("fit/step", phase="shard_h2d"):
-                xs, ys, fm, lm = fsdp.shard_put(plan, batch)
+            kind = "sharded_step"
+            with steps.span("fit/step", phase="shard_h2d"):
+                batch = fsdp.shard_put(plan, sig_args)
         else:
-            with monitor.span("fit/step", phase="bucket"):
+            with steps.span("fit/step", phase="bucket"):
                 mds, bucket = self._maybe_bucket_train(mds)
-            with monitor.span("fit/step", phase="h2d"):
-                xs = tuple(jnp.asarray(f) for f in mds.features)
-                ys = tuple(jnp.asarray(l) for l in mds.labels)
-                fm = (tuple(None if m is None else jnp.asarray(m)
-                            for m in mds.features_masks)
-                      if mds.features_masks is not None else None)
-                lm = (tuple(None if m is None else jnp.asarray(m)
-                            for m in mds.labels_masks)
-                      if mds.labels_masks is not None else None)
-            fresh = self.compile_telemetry.record(
-                "train_step", (xs, ys, fm, lm), bucket=bucket)
-        self._key, sub = jax.random.split(self._key)
-        # the iteration scalar moves H2D here, OUTSIDE the guarded
-        # dispatch — inside it every transfer is a bug
-        it_arr = jnp.asarray(self.iteration, jnp.int32)
-        with monitor.span("fit/step", phase="jit_call"), \
-                sanitizer.guard_step(compiling=fresh):
-            (self.net_params, self.net_state, self.opt_states,
-             score) = self._step_fn(
-                self.net_params, self.net_state, self.opt_states, xs, ys,
-                fm, lm, it_arr, sub)
-        with monitor.span("fit/step", phase="block_until_ready"):
-            jax.block_until_ready(score)
-        self._strip_rnn_state()
-        self._score = score
-        self.iteration += 1
-        monitor.record_fit_step(self.last_batch_size,
-                                time.perf_counter() - t_step, score)
-        with monitor.span("fit/step", phase="listeners"):
-            for lst in self.listeners:
-                lst.iteration_done(self, self.iteration)
+            kind = "train_step"
+            with steps.span("fit/step", phase="h2d"):
+                def put(arrs):
+                    return None if arrs is None else tuple(
+                        None if a is None else jnp.asarray(a) for a in arrs)
+                batch = sig_args = (put(mds.features), put(mds.labels),
+                                    put(mds.features_masks),
+                                    put(mds.labels_masks))
+        dispatch_train_step(self, self._step_fn, kind, sig_args, batch,
+                            t_step, bucket=bucket)
 
     def _strip_rnn_state(self):
         if self.net_state is None:

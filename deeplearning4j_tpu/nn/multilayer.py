@@ -77,6 +77,45 @@ def _updater_for(layer: Layer) -> upd_ops.Updater:
     return upd_ops.make(name, **hyper)
 
 
+def dispatch_train_step(net, step_fn, kind, sig_args, batch, t_step,
+                        bucket=None, k=1, repeats=1):
+    """Launch ``step_fn`` on a staged ``batch`` and account for it: the
+    one tail of every step path of both engines.  ``kind``/``sig_args``/
+    ``bucket`` are what ``CompileTelemetry`` keys the signature on, ``k``
+    the iterations one launch advances (a fused group), ``repeats`` the
+    launches on this batch (``conf.iterations``)."""
+    steps = net._steps
+    fresh = None
+    for _ in range(repeats):
+        with steps.span("fit/step", phase="dispatch_prep"):
+            if fresh is None:
+                fresh = net.compile_telemetry.record(kind, sig_args,
+                                                     bucket=bucket)
+            net._key, sub = jax.random.split(net._key)
+            # the iteration scalar moves H2D here, OUTSIDE the guarded
+            # dispatch — inside it every transfer is a bug
+            it_arr = jnp.asarray(net.iteration, jnp.int32)
+        with steps.span("fit/step", phase="jit_call",
+                        iteration=net.iteration), \
+                sanitizer.guard_step(compiling=fresh):
+            (net.net_params, net.net_state, net.opt_states,
+             score) = step_fn(net.net_params, net.net_state,
+                              net.opt_states, *batch, it_arr, sub)
+        with steps.span("fit/step", phase="block_until_ready"):
+            jax.block_until_ready(score)
+        with steps.span("fit/step", phase="bookkeeping"):
+            net._strip_rnn_state()
+            net._score = score
+            net.iteration += k
+            monitor.record_fit_step(net.last_batch_size,
+                                    time.perf_counter() - t_step, score)
+        with steps.span("fit/step", phase="listeners"):
+            for lst in net.listeners:
+                lst.iteration_done(net, net.iteration)
+        t_step = time.perf_counter()
+        fresh = False
+
+
 class MultiLayerNetwork:
     def __init__(self, conf: MultiLayerConfiguration):
         self.conf = conf
@@ -98,6 +137,7 @@ class MultiLayerNetwork:
         self.last_batch_size = 0
         self.last_etl_time_ms = 0.0
         self.compile_telemetry = bucketing.CompileTelemetry()
+        self._steps: Optional[monitor.StepSpans] = None   # fit() owns it
         self._bucket_train_ok: Optional[bool] = None
         self.frozen: List[bool] = [type(l).__name__ == "FrozenLayerConf"
                                    for l in self.layers]
@@ -107,20 +147,22 @@ class MultiLayerNetwork:
     # ------------------------------------------------------------------
     def init(self, params: Optional[List[dict]] = None) -> "MultiLayerNetwork":
         """Build param/state pytrees (ref: MultiLayerNetwork.init :411)."""
-        cur = self._input_type_chain_start()
-        key = jax.random.PRNGKey(self.conf.global_conf.seed)
-        ps, ss = [], []
-        for i, layer in enumerate(self.layers):
-            if i in self.conf.preprocessors:
-                cur = self.conf.preprocessors[i].output_type(cur)
-            key, sub = jax.random.split(key)
-            p, s, cur = layer.initialize(sub, cur)
-            ps.append(p)
-            ss.append(s)
-        self.net_params = params if params is not None else ps
-        self.net_state = ss
-        self.opt_states = [self.updaters[i].init(self.net_params[i])
-                           for i in range(len(self.layers))]
+        with monitor.span("net/init", phase="default_weights"):
+            cur = self._input_type_chain_start()
+            key = jax.random.PRNGKey(self.conf.global_conf.seed)
+            ps, ss = [], []
+            for i, layer in enumerate(self.layers):
+                if i in self.conf.preprocessors:
+                    cur = self.conf.preprocessors[i].output_type(cur)
+                key, sub = jax.random.split(key)
+                p, s, cur = layer.initialize(sub, cur)
+                ps.append(p)
+                ss.append(s)
+        with monitor.span("net/init", phase="given_weights"):
+            self.net_params = params if params is not None else ps
+            self.net_state = ss
+            self.opt_states = [self.updaters[i].init(self.net_params[i])
+                               for i in range(len(self.layers))]
         return self
 
     def _input_type_chain_start(self) -> InputType:
@@ -164,13 +206,16 @@ class MultiLayerNetwork:
         new_states = []
         layers = self.layers if stop is None else self.layers[:stop]
         for i, layer in enumerate(layers):
-            if i in self.conf.preprocessors:
-                x, mask = self.conf.preprocessors[i](x, mask)
             s = state[i]
             if not stateful_rnn and "rnn_state" in s:
                 s = {k: v for k, v in s.items() if k != "rnn_state"}
-            x, ns, mask = self._layer_step(layer, i, train, rng)(
-                params[i], s, x, mask)
+            # the scope names this layer's operations in a device trace;
+            # JAX wraps the backward's in transpose(jvp(...)) of the same
+            with jax.named_scope(f"fwd/{type(layer).__name__}/{i}"):
+                if i in self.conf.preprocessors:
+                    x, mask = self.conf.preprocessors[i](x, mask)
+                x, ns, mask = self._layer_step(layer, i, train, rng)(
+                    params[i], s, x, mask)
             new_states.append(ns)
             if collect_acts:
                 acts.append(x)
@@ -190,13 +235,15 @@ class MultiLayerNetwork:
         x, new_states, mask, _ = self._forward_core(
             params, state, x, mask, train, rng, stateful_rnn, stop=n - 1)
         last = self.layers[-1]
-        if (n - 1) in self.conf.preprocessors:
-            x, mask = self.conf.preprocessors[n - 1](x, mask)
-        if train:
-            x = last._maybe_dropout(x, True, jax.random.fold_in(rng, n - 1))
-        preout = last.preoutput(
-            last._maybe_drop_connect(params[-1], train,
-                                     jax.random.fold_in(rng, n - 1)), x)
+        with jax.named_scope(f"fwd/{type(last).__name__}/{n - 1}"):
+            if (n - 1) in self.conf.preprocessors:
+                x, mask = self.conf.preprocessors[n - 1](x, mask)
+            if train:
+                x = last._maybe_dropout(x, True,
+                                        jax.random.fold_in(rng, n - 1))
+            preout = last.preoutput(
+                last._maybe_drop_connect(params[-1], train,
+                                         jax.random.fold_in(rng, n - 1)), x)
         new_states.append(state[-1])
         return preout, new_states, mask, x
 
@@ -345,20 +392,23 @@ class MultiLayerNetwork:
                 new_states = policy.cast_to_param(new_states)
                 lm = lmask if lmask is not None else (
                     m if (m is not None and m.ndim == preout.ndim - 1) else None)
-                if getattr(out_layer, "requires_features_for_score", False):
-                    per_ex = out_layer.compute_score_with_features(
-                        y, preout, policy.cast_to_accum(feats), p[-1], lm)
-                else:
-                    per_ex = out_layer.compute_score(y, preout, lm)
-                score = jnp.mean(per_ex) if g.mini_batch else jnp.sum(per_ex)
-                score = score + self._reg_penalty(p)
-                # auxiliary losses surfaced by layers through their state
-                # (e.g. MoE load-balancing, nn/conf/layers.py MoE layer)
-                for s in new_states:
-                    if isinstance(s, dict) and "moe_aux_loss" in s:
-                        score = score + s["moe_aux_loss"]
-                if not g.minimize:
-                    score = -score
+                with jax.named_scope("loss"):
+                    if getattr(out_layer, "requires_features_for_score",
+                               False):
+                        per_ex = out_layer.compute_score_with_features(
+                            y, preout, policy.cast_to_accum(feats), p[-1], lm)
+                    else:
+                        per_ex = out_layer.compute_score(y, preout, lm)
+                    score = (jnp.mean(per_ex) if g.mini_batch
+                             else jnp.sum(per_ex))
+                    score = score + self._reg_penalty(p)
+                    # auxiliary losses surfaced by layers through their
+                    # state (e.g. MoE load-balancing, the MoE layer)
+                    for s in new_states:
+                        if isinstance(s, dict) and "moe_aux_loss" in s:
+                            score = score + s["moe_aux_loss"]
+                    if not g.minimize:
+                        score = -score
                 return score, new_states
 
             (score, new_states), grads = jax.value_and_grad(
@@ -374,15 +424,17 @@ class MultiLayerNetwork:
         byte-identical to the pre-split single-closure form."""
         grad_step = self._build_grad_raw()
 
-        def step(params, state, opts, x, y, fmask, lmask, it, rng):
+        # the name is what a compile event and a trace show (jit_<name>)
+        def mln_train_step(params, state, opts, x, y, fmask, lmask, it, rng):
             score, new_states, grads = grad_step(params, state, x, y,
                                                  fmask, lmask, rng)
-            new_params, new_opts = self._apply_updates(params, opts,
-                                                       grads, it)
+            with jax.named_scope("update"):
+                new_params, new_opts = self._apply_updates(params, opts,
+                                                           grads, it)
             return new_params, new_states, new_opts, score
 
         from deeplearning4j_tpu.parallel import fsdp
-        return fsdp.partitioned_if_sharded(self, step)
+        return fsdp.partitioned_if_sharded(self, mln_train_step)
 
     def _apply_updates(self, params, opts, grads, it):
         """Traceable gradient→param update: per-layer gradient
@@ -520,7 +572,8 @@ class MultiLayerNetwork:
         # BEFORE the first step traces: a Mosaic rejection flips that
         # tier's kill switch here instead of killing the training run
         from deeplearning4j_tpu.ops import helpers as pallas_helpers
-        pallas_helpers.ensure_validated()
+        with monitor.span("fit/setup", phase="kernel_self_test"):
+            pallas_helpers.ensure_validated()
         self._check_trace_token()
         self._ensure_sharding()
         if self._step_fn is None:
@@ -590,9 +643,14 @@ class MultiLayerNetwork:
             # The events.scope gives this fit a correlation ID so every
             # fit/step span and checkpoint event journals under it.
             with sanitizer.armed_fit(self), \
-                    monitor.profile_if_configured("fit"), \
+                    monitor.profile_if_configured("fit") as profiling, \
                     events.scope(fit_id=events.new_request_id(),
                                  model=type(self).__name__):
+                # the phases of fit/step tile the loop from here to the
+                # pipeline's close (a profiled fit mirrors them into its
+                # trace whatever DL4J_TRACE_ANNOTATIONS says)
+                self._steps = steps = monitor.StepSpans(
+                    annotate=profiling or None)
                 events.emit("fit.start", epochs=epochs,
                             iteration=self.iteration)
                 for ep_i in range(epochs):
@@ -603,14 +661,19 @@ class MultiLayerNetwork:
                     # CheckpointListener subtracts to record how many
                     # batches into the epoch a save landed
                     self._epoch_start_iter = self.iteration - to_skip
-                    for lst in self.listeners:
-                        if isinstance(lst, TrainingListener):
-                            lst.on_epoch_start(self)
-                    it.reset()
+                    with steps.span("fit/step", phase="epoch"):
+                        for lst in self.listeners:
+                            if isinstance(lst, TrainingListener):
+                                lst.on_epoch_start(self)
+                        it.reset()
                     t_etl = time.perf_counter()
                     pending = []
-                    while it.has_next():
-                        with monitor.span("fit/step", phase="data_wait"):
+                    while True:
+                        with steps.span("fit/step", phase="has_next"):
+                            more = it.has_next()
+                        if not more:
+                            break
+                        with steps.span("fit/step", phase="data_wait"):
                             ds = it.next()
                         if to_skip > 0:
                             # replay-skip: consume (keeps the stream
@@ -631,10 +694,14 @@ class MultiLayerNetwork:
                         t_etl = time.perf_counter()
                     for ds in pending:  # ragged tail: per-step path
                         self._fit_batch(ds)
-                    for lst in self.listeners:
-                        if isinstance(lst, TrainingListener):
-                            lst.on_epoch_end(self)
-                    self.epoch += 1
+                    with steps.span("fit/step", phase="epoch"):
+                        for lst in self.listeners:
+                            if isinstance(lst, TrainingListener):
+                                lst.on_epoch_end(self)
+                        self.epoch += 1
+                if isinstance(it, AsyncDataSetIterator):
+                    with steps.span("fit/step", phase="epoch"):
+                        it.close()
                 events.emit("fit.end", iteration=self.iteration,
                             epoch=self.epoch)
         finally:
@@ -643,6 +710,8 @@ class MultiLayerNetwork:
             # idempotent and the iterator restarts lazily if reused)
             if isinstance(it, AsyncDataSetIterator):
                 it.close()
+            if self._steps is not None:
+                self._steps.close()
         return self
 
     def _build_fused_step(self, k: int):
@@ -659,7 +728,7 @@ class MultiLayerNetwork:
             return [{kk: v for kk, v in s.items() if kk != "rnn_state"}
                     for s in state]
 
-        def k_steps(params, state, opts, xs, ys, fms, lms, it0, key):
+        def mln_fused_steps(params, state, opts, xs, ys, fms, lms, it0, key):
             def body(carry, inp):
                 p, s, o = carry
                 i, x, y, fm, lm = inp
@@ -671,7 +740,7 @@ class MultiLayerNetwork:
                 (jnp.arange(k), xs, ys, fms, lms))
             return params, state, opts, scores[-1]
 
-        return jax.jit(k_steps, donate_argnums=(0, 1, 2))  # dl4j: noqa[DL4J104] one jitted fn per k, cached in _fused_fns[k]
+        return jax.jit(mln_fused_steps, donate_argnums=(0, 1, 2))  # dl4j: noqa[DL4J104] one jitted fn per k, cached in _fused_fns[k]
 
     def _fit_fused_group(self, group):
         if getattr(self, "_sharding_plan", None) is not None:
@@ -681,7 +750,8 @@ class MultiLayerNetwork:
         # bucketing makes ragged groups (mixed batch sizes / RNN time
         # lengths, the tail of any real stream) bucket-uniform so they
         # STAY on the fused scan path instead of degrading to per-step
-        group = [self._maybe_bucket_train(d)[0] for d in group]
+        with self._steps.span("fit/step", phase="bucket"):
+            group = [self._maybe_bucket_train(d)[0] for d in group]
         k = len(group)
         shapes = {(d.features.shape, d.labels.shape,
                    d.features.dtype, d.labels.dtype,
@@ -704,34 +774,17 @@ class MultiLayerNetwork:
         if k not in self._fused_fns:
             self._fused_fns[k] = self._build_fused_step(k)
         t_step = time.perf_counter()
-        with monitor.span("fit/step", phase="h2d"):
+        with self._steps.span("fit/step", phase="h2d"):
             xs = jnp.stack([jnp.asarray(d.features) for d in group])
             ys = jnp.stack([jnp.asarray(d.labels) for d in group])
             fms = (jnp.stack([jnp.asarray(d.features_mask) for d in group])
                    if group[0].features_mask is not None else None)
             lms = (jnp.stack([jnp.asarray(d.labels_mask) for d in group])
                    if group[0].labels_mask is not None else None)
-        fresh = self.compile_telemetry.record(f"fused_step_k{k}",
-                                              (xs, ys, fms, lms))
-        self._key, sub = jax.random.split(self._key)
-        it_arr = jnp.asarray(self.iteration, jnp.int32)
-        with monitor.span("fit/step", phase="jit_call"), \
-                sanitizer.guard_step(compiling=fresh):
-            (self.net_params, self.net_state, self.opt_states,
-             score) = self._fused_fns[k](
-                self.net_params, self.net_state, self.opt_states,
-                xs, ys, fms, lms, it_arr, sub)
-        with monitor.span("fit/step", phase="block_until_ready"):
-            jax.block_until_ready(score)
-        self._strip_rnn_state()
-        self._score = score
-        self.iteration += k
         self.last_batch_size = sum(sizes)
-        monitor.record_fit_step(self.last_batch_size,
-                                time.perf_counter() - t_step, score)
-        with monitor.span("fit/step", phase="listeners"):
-            for lst in self.listeners:
-                lst.iteration_done(self, self.iteration)
+        batch = (xs, ys, fms, lms)
+        dispatch_train_step(self, self._fused_fns[k], f"fused_step_k{k}",
+                            batch, batch, t_step, k=k)
 
     def _fit_fused_group_sharded(self, group):
         """fused_steps=K under a sharding plan: each batch is padded to
@@ -769,49 +822,34 @@ class MultiLayerNetwork:
         if k not in self._fused_fns:
             self._fused_fns[k] = self._build_fused_step(k)
         t_step = time.perf_counter()
-        with monitor.span("fit/step", phase="shard_h2d"):
-            xs, ys, fms, lms = fsdp.stack_for_scan(
-                plan, [b for b, _, _ in norms])
-        fresh = self.compile_telemetry.record(f"fused_step_k{k}",
-                                              (xs, ys, fms, lms))
-        self._key, sub = jax.random.split(self._key)
-        it_arr = jnp.asarray(self.iteration, jnp.int32)
-        with monitor.span("fit/step", phase="jit_call"), \
-                sanitizer.guard_step(compiling=fresh):
-            (self.net_params, self.net_state, self.opt_states,
-             score) = self._fused_fns[k](
-                self.net_params, self.net_state, self.opt_states,
-                xs, ys, fms, lms, it_arr, sub)
-        with monitor.span("fit/step", phase="block_until_ready"):
-            jax.block_until_ready(score)
-        self._strip_rnn_state()
-        self._score = score
-        self.iteration += k
+        with self._steps.span("fit/step", phase="shard_h2d"):
+            batch = fsdp.stack_for_scan(plan, [b for b, _, _ in norms])
         self.last_batch_size = sum(n for _, n, _ in norms)
-        monitor.record_fit_step(self.last_batch_size,
-                                time.perf_counter() - t_step, score)
-        with monitor.span("fit/step", phase="listeners"):
-            for lst in self.listeners:
-                lst.iteration_done(self, self.iteration)
+        dispatch_train_step(self, self._fused_fns[k], f"fused_step_k{k}",
+                            batch, batch, t_step, k=k)
 
     def _fit_batch(self, ds):
         g = self.conf.global_conf
         self.last_batch_size = ds.num_examples()
         if self.conf.backprop_type == "truncatedbptt" and ds.features.ndim == 3:
-            self._fit_tbptt(ds)
+            with self._steps.span("fit/step", phase="tbptt"):
+                self._fit_tbptt(ds)
             return
         dist_sess = getattr(self, "_dist_session", None)
         if dist_sess is not None:
             # cluster step: shard-local grads → coordinator all-reduce →
             # updater apply (docs/DISTRIBUTED.md); TBPTT stays local
             from deeplearning4j_tpu.distributed import worker as dist_worker
+            self._steps.close()     # timed by the worker's own spans
             dist_worker.fit_batch(self, ds, dist_sess, is_graph=False)
+            self._steps.restart()
             return
         t_step = time.perf_counter()
+        steps = self._steps
         plan = getattr(self, "_sharding_plan", None)
         if plan is not None:
             from deeplearning4j_tpu.parallel import fsdp
-            with monitor.span("fit/step", phase="bucket"):
+            with steps.span("fit/step", phase="bucket"):
                 # pad (mask-exact) or trim the batch to the data degree;
                 # shape bucketing, when on, subsumes this by lifting the
                 # bucket to a data-degree multiple
@@ -819,53 +857,28 @@ class MultiLayerNetwork:
                                             is_graph=False)
             if norm is None:
                 return
-            batch, n, bucket = norm
+            sig_args, n, bucket = norm
             self.last_batch_size = n
-            fresh = self.compile_telemetry.record("sharded_step", batch,
-                                                  bucket=bucket)
-            with monitor.span("fit/step", phase="shard_h2d"):
+            kind = "sharded_step"
+            with steps.span("fit/step", phase="shard_h2d"):
                 # host→mesh scatter: each device receives only its batch
                 # shard (the sharded step's in_shardings layout)
-                feats, labels, fmask, lmask = fsdp.shard_put(plan, batch)
+                batch = fsdp.shard_put(plan, sig_args)
         else:
-            with monitor.span("fit/step", phase="bucket"):
+            with steps.span("fit/step", phase="bucket"):
                 ds, bucket = self._maybe_bucket_train(ds)
-            fresh = self.compile_telemetry.record(
-                "train_step", (ds.features, ds.labels, ds.features_mask,
-                               ds.labels_mask), bucket=bucket)
-            with monitor.span("fit/step", phase="h2d"):
+            kind = "train_step"
+            sig_args = (ds.features, ds.labels, ds.features_mask,
+                        ds.labels_mask)
+            with steps.span("fit/step", phase="h2d"):
                 # no-op when the async iterator already device_put the
                 # batch; otherwise this is the host→device transfer,
                 # timed apart from the jitted call it used to hide inside
-                feats = jnp.asarray(ds.features)
-                labels = jnp.asarray(ds.labels)
-                fmask = (None if ds.features_mask is None
-                         else jnp.asarray(ds.features_mask))
-                lmask = (None if ds.labels_mask is None
-                         else jnp.asarray(ds.labels_mask))
-        for _ in range(max(1, g.iterations)):
-            self._key, sub = jax.random.split(self._key)
-            # the iteration scalar moves H2D here, OUTSIDE the guarded
-            # dispatch — inside it every transfer is a bug
-            it_arr = jnp.asarray(self.iteration, jnp.int32)
-            with monitor.span("fit/step", phase="jit_call"), \
-                    sanitizer.guard_step(compiling=fresh):
-                (self.net_params, self.net_state, self.opt_states,
-                 score) = self._step_fn(
-                    self.net_params, self.net_state, self.opt_states,
-                    feats, labels, fmask, lmask, it_arr, sub)
-            with monitor.span("fit/step", phase="block_until_ready"):
-                jax.block_until_ready(score)
-            self._strip_rnn_state()
-            self._score = score
-            self.iteration += 1
-            monitor.record_fit_step(self.last_batch_size,
-                                    time.perf_counter() - t_step, score)
-            with monitor.span("fit/step", phase="listeners"):
-                for lst in self.listeners:
-                    lst.iteration_done(self, self.iteration)
-            t_step = time.perf_counter()
-            fresh = False
+                batch = tuple(None if a is None else jnp.asarray(a)
+                              for a in sig_args)
+        dispatch_train_step(self, self._step_fn, kind, sig_args, batch,
+                            t_step, bucket=bucket,
+                            repeats=max(1, g.iterations))
 
     def _fit_tbptt(self, ds):
         """Truncated BPTT over time segments, carrying RNN state

@@ -242,6 +242,7 @@ def _flash_fwd(q, k, v, key_mask, *, causal: bool, scale: float,
             jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
             jax.ShapeDtypeStruct((B * H, T, 1), jnp.float32),
         ],
+        name="dl4j_flash_fwd",
         interpret=_interpret(),
     )(qf, kf, vf, mask)
     return out.reshape(B, H, T, D), lse
@@ -384,6 +385,7 @@ def _flash_bwd(q, k, v, key_mask, out, lse, g, *, causal: bool,
         ],
         out_specs=pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
+        name="dl4j_flash_dq",
         interpret=_interpret(),
     )(qf, kf, vf, mask, dof, lse, delta)
 
@@ -408,6 +410,7 @@ def _flash_bwd(q, k, v, key_mask, out, lse, g, *, causal: bool,
             jax.ShapeDtypeStruct((B * H, T, D), k.dtype),
             jax.ShapeDtypeStruct((B * H, T, D), v.dtype),
         ],
+        name="dl4j_flash_dkv",
         interpret=_interpret(),
     )(qf, kf, vf, mask, dof, lse, delta)
     return (dq.reshape(B, H, T, D), dk.reshape(B, H, T, D),
@@ -545,6 +548,7 @@ def fused_softmax_xent(logits, labels, block_rows: Optional[int] = None):
             jax.ShapeDtypeStruct((Np, 1), logits.dtype),
             jax.ShapeDtypeStruct((Np, V), logits.dtype),
         ],
+        name="dl4j_softmax_xent",
         interpret=_interpret(),
     )(logits, labels)
     return loss[:N, 0], grad[:N]
@@ -645,6 +649,7 @@ def _conv_forward(xp, w, b2, act_name: str):
         ],
         out_specs=pl.BlockSpec((None, OH, OW, Cout), lambda n: (n, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((N, OH, OW, Cout), xp.dtype),
+        name="dl4j_conv_bias_act",
         interpret=_interpret(),
     )(xp, w, b2)
 
@@ -766,6 +771,7 @@ def _lstm_forward(zx, h, c, rw, p3):
         _lstm_step_kernel,
         out_shape=[jax.ShapeDtypeStruct((N, H), c.dtype),
                    jax.ShapeDtypeStruct((N, H), h.dtype)],
+        name="dl4j_lstm_step",
         interpret=_interpret(),
     )(zx, h, c, rw, p3)
 
@@ -882,6 +888,7 @@ def _dropout_forward(x2d, seed, rate: float):
         ],
         out_specs=pl.BlockSpec((br, _DROPOUT_WIDTH), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x2d.shape, x2d.dtype),
+        name="dl4j_dropout",
         interpret=_interpret(),
     )(x2d, seed)
 

@@ -153,6 +153,28 @@ def test_hot_span_transfer_positive_and_negative(tmp_path):
     assert [f.line for f in findings] == [8]
 
 
+def test_hot_span_transfer_sees_the_step_helpers_phases(tmp_path):
+    """fit()'s phases are entered through a StepSpans object: the rule
+    must go on looking at them."""
+    findings, _ = run_lint(tmp_path, {"m.py": """
+        import numpy as np
+        import jax
+
+        def loop(net, steps, score):
+            with steps.span("fit/step", phase="bookkeeping"):
+                net.score = np.asarray(score)    # positive: implicit sync
+            with net._steps.span("fit/step", phase="listeners",
+                                 iteration=3):
+                bad = score.item()               # positive
+            with steps.span("fit/step", phase="bookkeeping"):
+                ok = np.asarray(jax.device_get(score))   # negative
+            with steps.span("pipeline/batch", phase="h2d"):
+                cold = np.asarray(score)         # negative: not a hot span
+            return bad, ok, cold
+    """}, rules=["DL4J105"])
+    assert [f.line for f in findings] == [7, 10]
+
+
 def test_fp64_promotion_positive_and_negative(tmp_path):
     findings, _ = run_lint(tmp_path, {"m.py": """
         import jax
