@@ -43,9 +43,10 @@ import urllib.request
 # error compounds over LeNet's four weighted layers.
 SERVE_ATOL = 2e-2
 # score of the sharded fit against its single-device twin, per step:
-# |a - b| <= SHARD_RTOL * max(|a|, |b|) + SHARD_ATOL.  The twins differ in
-# how bf16 convs are computed (fused Pallas tier on one device, XLA conv
-# under the mesh) and in reduction order across the four batch shards.
+# |a - b| <= SHARD_RTOL * max(|a|, |b|) + SHARD_ATOL.  Both twins run XLA's
+# own bf16 convolutions (no default selects the conv tier, and this model
+# engages no other); they differ in how XLA tiles a quarter of the batch
+# and in reduction order across the four batch shards.
 SHARD_RTOL, SHARD_ATOL = 0.05, 0.05
 # vgg16_cifar10's own default (0.01, Nesterov) overshoots on one repeated
 # batch from a random start: the score jumps to ~11 and the net dies at
@@ -187,6 +188,15 @@ def _check_learning(scores, where: str) -> None:
           f"{where}: score did not fall: {scores[0]} -> {scores[-1]}")
 
 
+def _check_convs_went_to_xla(run: dict, where: str) -> None:
+    """No default selects the conv tier (ops/helpers.available): every
+    convolution of the traced step is XLA's own."""
+    check("conv2d" not in run["pallas_selected"]
+          and run["pallas_fallback"].get("conv2d", 0) > 0,
+          f"{where}: convolutions did not all take XLA's path: "
+          f"{run['pallas_selected']}, {run['pallas_fallback']}")
+
+
 def phase_fit(seed: int = 0, batch: int = 256, steps: int = 12,
               chip: bool = True) -> dict:
     import jax.numpy as jnp
@@ -215,9 +225,7 @@ def phase_fit(seed: int = 0, batch: int = 256, steps: int = 12,
     if chip:
         check(info["precision"] == "bfloat16",
               f"fit: default precision on the chip is {info['precision']}")
-        check(run["pallas_selected"].get("conv2d", 0) > 0,
-              f"fit: conv2d never selected the fused tier: "
-              f"{run['pallas_selected']}, {run['pallas_fallback']}")
+        _check_convs_went_to_xla(run, "fit")
     return info
 
 
@@ -383,8 +391,7 @@ def phase_sharded(seed: int = 0, batch: int = 256, steps: int = 8,
               <= 0.01 * res["total_bytes"],
               f"sharded: {name} bytes uneven across devices: {per}")
     if chip:
-        check(single["pallas_selected"].get("conv2d", 0) > 0,
-              "sharded: the single-device twin never selected the conv tier")
+        _check_convs_went_to_xla(single, "sharded: the single-device twin")
         check(not shard["pallas_selected"],
               f"sharded: a Mosaic tier was selected under the mesh: "
               f"{shard['pallas_selected']}")

@@ -10,7 +10,7 @@ Every op with a fused implementation registers a :class:`Helper` here:
 ==============  ======  =============================  =====================
 op              tier    fused kernel (pallas_kernels)  dense XLA fallback
 ==============  ======  =============================  =====================
-``conv2d``      conv    fused_conv2d_bias_act          ops/convolution.conv2d + activation
+``conv2d``      conv*   fused_conv2d_bias_act          ops/convolution.conv2d + activation
 ``lstm_step``   lstm    fused_lstm_step                ops/recurrent._lstm_cell_pre
 ``dropout``     dropout fused_threshold_dropout        ops/normalization.dropout
 ``softmax_xent`` xent   softmax_xent_rows              stable logsumexp form in ops/losses
@@ -24,6 +24,12 @@ metered (``dl4j_pallas_selected_total`` / ``dl4j_pallas_fallback_total``
 by op).  Off-TPU nothing fuses by default — the fallback IS the
 pre-helper code path, byte-identical — but each tier can be forced for
 testing (the kernels then run under ``interpret=True``).
+
+(*) The conv tier is the exception on a TPU too: nothing selects it unless
+``DL4J_PALLAS_CONV=1`` forces it.  XLA's own convolution, which lays
+out the whole graph itself, beat the kernel with its NCHW/NHWC copies
+wherever both were measured (PERF.md section 6, PR 28), so every
+``ConvolutionLayer`` takes the dense chain by default.
 
 Kill switches, most-specific wins:
 
@@ -66,6 +72,7 @@ class Helper(NamedTuple):
     tier: str                    # kill-switch tier name (conv, lstm, ...)
     test_name: str               # key in the kernel_self_test() report
     self_test: Callable[[], None]  # small-shape compile+run validation
+    on_tpu_default: bool = True  # selected on a TPU when nothing forces it
 
 
 _ENV_TIER = {"conv": "DL4J_PALLAS_CONV", "lstm": "DL4J_PALLAS_LSTM",
@@ -101,9 +108,11 @@ def record_selection(op: str, fused: bool) -> None:
 def available(op: str) -> bool:
     """Is the fused tier for ``op`` eligible at all (before the per-call
     shape/dtype predicate)?  Order: global kill → runtime kill switch →
-    per-tier env force → platform, and there only outside a step that
-    GSPMD partitions (pallas_kernels.partitioned_trace)."""
-    tier = _HELPERS[op].tier
+    per-tier env force → platform, and there only a tier that is on by
+    default (every one but conv) outside a step that GSPMD partitions
+    (pallas_kernels.partitioned_trace)."""
+    helper = _HELPERS[op]
+    tier = helper.tier
     if os.environ.get("DL4J_PALLAS") == "0":  # dl4j: noqa[DL4J103] env kill switch read at trace time by design (fixed per process)
         return False
     if tier in pk._disabled:
@@ -113,7 +122,8 @@ def available(op: str) -> bool:
         return False
     if env == "1":
         return True
-    return pk._on_tpu() and not pk.partitioned_trace_active()
+    return (helper.on_tpu_default and pk._on_tpu()
+            and not pk.partitioned_trace_active())
 
 
 # ---------------------------------------------------------------------------
@@ -123,10 +133,11 @@ def available(op: str) -> bool:
 def conv2d_bias_act(x, w, b, stride=(1, 1), pad=(0, 0), dilation=(1, 1),
                     border_mode: str = "truncate",
                     activation: Optional[str] = "identity"):
-    """Conv + bias + activation for ConvolutionLayer.forward: one fused
-    VMEM pass when the conv tier selects, else the dense
-    conv-HLO → bias-add → activation chain (byte-identical to the
-    pre-helper path)."""
+    """Conv + bias + activation for ConvolutionLayer.forward: the dense
+    conv-HLO → bias-add → activation chain, which XLA fuses and lays
+    out itself (byte-identical to the pre-helper path), unless
+    ``DL4J_PALLAS_CONV=1`` forces the conv tier's one fused VMEM pass
+    for a shape it supports."""
     act = (activation or "identity").lower()
     if available("conv2d") and pk.conv_fused_supported(
             x.shape, w.shape, x.dtype, stride, dilation, act, pad,
@@ -308,7 +319,8 @@ def _selftest_dropout():
 
 
 _HELPERS: Dict[str, Helper] = {
-    "conv2d": Helper("conv2d", "conv", "conv2d_bias_act", _selftest_conv),
+    "conv2d": Helper("conv2d", "conv", "conv2d_bias_act", _selftest_conv,
+                     on_tpu_default=False),
     "lstm_step": Helper("lstm_step", "lstm", "lstm_step", _selftest_lstm),
     "dropout": Helper("dropout", "dropout", "dropout", _selftest_dropout),
     "softmax_xent": Helper("softmax_xent", "xent", "softmax_xent",

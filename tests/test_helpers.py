@@ -62,10 +62,19 @@ class TestAvailability:
         pk.disable_kernels("mosaic said no", tier="lstm")
         assert not helpers.available("lstm_step")
 
-    def test_fake_tpu_enables_all(self, monkeypatch):
+    @pytest.mark.parametrize("op", helpers.OPS)
+    def test_fake_tpu_default(self, monkeypatch, op):
+        # on a TPU every tier selects by default but conv: XLA's own
+        # convolution is the default there too (PERF.md 6, PR 28)
         monkeypatch.setenv("DL4J_TPU", "1")
-        for op in helpers.OPS:
-            assert helpers.available(op)
+        assert helpers.available(op) == (op != "conv2d")
+
+    def test_fake_tpu_conv_only_by_force(self, monkeypatch):
+        monkeypatch.setenv("DL4J_TPU", "1")
+        monkeypatch.setenv("DL4J_PALLAS_CONV", "1")
+        assert helpers.available("conv2d")
+        monkeypatch.setenv("DL4J_PALLAS", "0")    # the global kill still wins
+        assert not helpers.available("conv2d")
 
     def test_disable_all_tiers(self, monkeypatch):
         monkeypatch.setenv("DL4J_TPU", "1")
@@ -209,6 +218,23 @@ class TestWarmValidation:
         assert res["dropout"] == "ok"
         assert "conv2d_bias_act" not in res        # only eligible tiers run
 
+    def test_fake_tpu_validation_leaves_conv_out(self, monkeypatch):
+        # what fit/setup runs on a chip; the kernels themselves need the
+        # chip's compiler (tests/test_tpu_compile.py), so stand-ins here
+        monkeypatch.setenv("DL4J_TPU", "1")
+        for op in helpers.OPS:
+            monkeypatch.setitem(
+                helpers._HELPERS, op,
+                helpers.helper_for(op)._replace(self_test=lambda: None))
+        res = helpers.ensure_validated()
+        ran = {k for k, v in res.items() if v == "ok"}
+        assert ran == {helpers.helper_for(op).test_name
+                       for op in helpers.OPS if op != "conv2d"}
+        assert "conv2d_bias_act" not in res
+        monkeypatch.setenv("DL4J_PALLAS_CONV", "1")
+        helpers.reset_validation()
+        assert helpers.ensure_validated()["conv2d_bias_act"] == "ok"
+
 
 # ---------------------------------------------------------------------------
 # Fallback equivalence through the public fit()/output() path
@@ -339,13 +365,17 @@ class TestFallbackEquivalence:
 # runs are NOT the ones platform.is_tpu() picks.
 # ---------------------------------------------------------------------------
 
-@pytest.fixture
-def chip_default_selections(monkeypatch):
+@pytest.fixture(params=["conv-forced", "conv-default"])
+def chip_default_selections(monkeypatch, request):
+    """bf16 and every tier a chip selects, forced (interpret mode); the
+    conv tier, which no chip selects by default, forced too or left."""
     from deeplearning4j_tpu.ops import dtypes
-    for env in helpers._ENV_TIER.values():
-        monkeypatch.setenv(env, "1")
+    conv_forced = request.param == "conv-forced"
+    for tier, env in helpers._ENV_TIER.items():
+        if tier != "conv" or conv_forced:
+            monkeypatch.setenv(env, "1")
     dtypes.set_default_policy(dtypes.BF16)
-    yield
+    yield conv_forced
     dtypes.set_default_policy(None)
 
 
@@ -377,11 +407,34 @@ class TestChipDefaultSelections:
         x = rng.normal(size=(8,) + in_shape).astype(np.float32)
         y = np.eye(10, dtype=np.float32)[rng.integers(0, 10, 8)]
         before = _counter_value("dl4j_pallas_selected_total", "conv2d")
+        fallback = _counter_value("dl4j_pallas_fallback_total", "conv2d")
         net.fit(DataSet(x, y))
         assert np.isfinite(float(net.score()))
-        assert _counter_value("dl4j_pallas_selected_total",
-                              "conv2d") > before
+        selected = _counter_value("dl4j_pallas_selected_total",
+                                  "conv2d") - before
+        if chip_default_selections:
+            assert selected > 0
+        else:
+            assert selected == 0
+            assert _counter_value("dl4j_pallas_fallback_total",
+                                  "conv2d") > fallback
         assert not pk._disabled       # the warm self-test passed in bf16 too
+
+    def test_fake_tpu_cnn_step_holds_no_pallas_call(self, monkeypatch):
+        # the step a chip traces for a CNN: bf16 convolutions as XLA's own
+        # HLO, no kernel call (and no NCHW/NHWC transposes around one)
+        monkeypatch.setenv("DL4J_TPU", "1")
+        net, in_shape = _resnet18()
+        net.init()
+        args = (net.net_params, net.net_state, net.opt_states,
+                (jnp.zeros((4,) + in_shape),), (jnp.zeros((4, 10)),),
+                None, None, jnp.int32(0), jax.random.PRNGKey(0))
+        jaxpr = str(jax.make_jaxpr(net._build_step_raw())(*args))
+        assert "conv_general_dilated" in jaxpr and "bf16" in jaxpr
+        assert "pallas_call" not in jaxpr
+        monkeypatch.setenv("DL4J_PALLAS_CONV", "1")      # the force still works
+        forced = str(jax.make_jaxpr(net._build_step_raw())(*args))
+        assert "pallas_call" in forced
 
     def test_disabled_tier_is_logged_once_with_the_message(
             self, monkeypatch, caplog):
@@ -413,7 +466,8 @@ class TestPartitionedTrace:
 
     def test_tiers_leave_selection_inside_the_scope(self, monkeypatch):
         monkeypatch.setenv("DL4J_TPU", "1")
-        assert all(helpers.available(op) for op in helpers.OPS)
+        assert all(helpers.available(op) for op in helpers.OPS
+                   if op != "conv2d")
         with pk.partitioned_trace():
             assert not any(helpers.available(op) for op in helpers.OPS)
             monkeypatch.setenv("DL4J_PALLAS_CONV", "1")
