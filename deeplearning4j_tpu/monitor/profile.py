@@ -10,11 +10,19 @@ the ``fit()`` returns.  Per chip it holds
 * ``busy_s`` / ``window_s``: the union of the device's operations, and
   the stretch from the first to the last;
 * ``device_s``: device seconds by direction (``fwd``, ``bwd``,
-  ``update``, ``loss``, ``unscoped``) and layer type, read from the
+  ``update``, ``loss``, ``kernel``, ``unscoped``) and layer type, read from the
   ``jax.named_scope`` names the engines put on the step
   (``fwd/<LayerType>/<index or vertex>``, ``loss``, ``update``; JAX
   wraps the backward's operations in ``transpose(jvp(...))`` of the
-  forward's name), and ``top_scopes``, the ten longest scopes;
+  forward's name), ``top_scopes``, the ten longest scopes, and
+  ``sub_scope_s``, the seconds of the parts a layer names inside its
+  own scope (``<direction>/<LayerType>/<part>``), over all vertices of
+  that type.  An operation the compiler expands into kernels of its own
+  name loses its scope (XLA's grouped matrix product, ``ragged-dot-*``).
+  The layer classes declare both, their parts and the kernels they
+  claim for a part (:func:`layer_tables`); a claimed kernel reads under
+  the direction ``kernel``, since forward and backward cannot be told
+  apart;
 * ``idle_s``: the device's idle seconds by the host phase they fell in.
   Device and host events share the trace's clock, so each gap between
   device operations is shared out over the ``fit/step`` phases it
@@ -55,7 +63,9 @@ DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 PHASE = re.compile(r"^fit/step/(\w+)$")
 #: the stat of a device event's metadata that holds the HLO op_name
 SCOPE_STATS = ("tf_op",)
-LAYER_SCOPE = re.compile(r"fwd/(\w+)/([^/()]+)")
+# JAX closes jvp( and transpose( right after the vertex's name: a part's
+# name follows the parentheses (``jvp(fwd/<Type>/<vertex>)/<part>/<op>``)
+LAYER_SCOPE = re.compile(r"fwd/(\w+)/([^/()]+)\)*(?:/(\w+))?")
 UPDATE_SCOPE = re.compile(r"(?:^|[/(])update(?:[/)]|$)")
 LOSS_SCOPE = re.compile(r"(?:^|[/(])loss(?:[/)]|$)")
 OUTSIDE = "outside_fit"
@@ -170,8 +180,33 @@ def load(path: str) -> List[Tuple[str, list]]:
     return [_plane(v) for f, v in _fields(space) if f == 1]
 
 
-def classify(op_name: str) -> Tuple[str, str, str]:
+Tables = Tuple[Dict[str, frozenset], Dict[str, Tuple[str, str]]]
+
+
+def layer_tables() -> Tables:
+    """({layer type: the parts it names inside its scope}, {prefix of a
+    kernel's name: (layer type, part) that claims it}), as the registered
+    layer classes declare them (``Layer.scope_parts``,
+    ``Layer.scope_kernels``)."""
+    from deeplearning4j_tpu.nn.conf.layers import LAYER_REGISTRY
+    parts, kernels = {}, {}
+    for kind, cls in LAYER_REGISTRY.items():
+        if cls.scope_parts:
+            parts[kind] = frozenset(cls.scope_parts)
+        for prefix, part in cls.scope_kernels.items():
+            if prefix in kernels:
+                raise ValueError(f"kernels {prefix}* are claimed by "
+                                 f"{kernels[prefix][0]} and by {kind}")
+            kernels[prefix] = (kind, part)
+    return parts, kernels
+
+
+def classify(op_name: str, tables: Optional[Tables] = None
+             ) -> Tuple[str, str, str]:
     """(direction, layer type, scope) of an HLO ``op_name``."""
+    owner = _claimed(op_name, (tables or layer_tables())[1])
+    if owner:
+        return "kernel", owner[0], f"kernel/{owner[0]}"
     if UPDATE_SCOPE.search(op_name):
         return "update", "update", "update"
     m = LAYER_SCOPE.search(op_name)
@@ -181,6 +216,36 @@ def classify(op_name: str) -> Tuple[str, str, str]:
     if LOSS_SCOPE.search(op_name):
         return "loss", "loss", "loss"
     return "unscoped", "unscoped", "unscoped"
+
+
+def _instruction(event_name: str) -> str:
+    """``%ragged-dot-none.3 = bf16[...] custom-call(...)`` ->
+    ``ragged-dot-none.3``"""
+    return event_name.split(" = ", 1)[0].lstrip("%")
+
+
+def _claimed(op_name: str, kernels) -> Optional[Tuple[str, str]]:
+    """(layer type, part) of a kernel the compiler named itself."""
+    for prefix, owner in kernels.items():
+        if op_name.startswith(prefix):
+            return owner
+    return None
+
+
+def sub_scope(op_name: str, tables: Optional[Tables] = None
+              ) -> Optional[str]:
+    """``<direction>/<LayerType>/<part>`` of an operation inside a part
+    its layer names (anything else that follows a vertex's name is an
+    operation's own name), else None."""
+    parts, kernels = tables or layer_tables()
+    owner = _claimed(op_name, kernels)
+    if owner:
+        return f"kernel/{owner[0]}/{owner[1]}"
+    m = LAYER_SCOPE.search(op_name)
+    if not m or m.group(3) not in parts.get(m.group(1), ()):
+        return None
+    direction = "bwd" if "transpose(" in op_name else "fwd"
+    return f"{direction}/{m.group(1)}/{m.group(3)}"
 
 
 def union(intervals: Iterable[Interval]) -> Tuple[float, List[Interval]]:
@@ -247,22 +312,30 @@ def host_phases(planes) -> List[Tuple[float, float, str]]:
     return out
 
 
-def device_events(planes) -> Dict[int, List[Tuple[float, float, str]]]:
+def device_events(planes, tables: Optional[Tables] = None
+                  ) -> Dict[int, List[Tuple[float, float, str]]]:
     """{chip: [(start_ns, duration_ns, op_name)]} of the ops lines."""
     out: Dict[int, List[Tuple[float, float, str]]] = {}
+    kernels = (tables or layer_tables())[1]
     for name, lines in planes:
         m = DEVICE_PLANE.match(name)
         if not m:
             continue
         for line_name, events in lines:
             if line_name == OPS_LINE:
-                out[int(m.group(1))] = [(s, d, op) for s, d, _, op in events]
+                # a kernel the compiler named itself goes by the name of
+                # its instruction (the event's name is the HLO's text)
+                out[int(m.group(1))] = [
+                    (s, d, ins if _claimed(ins, kernels) else op)
+                    for s, d, ins, op in ((s, d, _instruction(name), op)
+                                          for s, d, name, op in events)]
     return out
 
 
 def summarize(planes) -> dict:
     """The summary of one trace (see the module's docstring).  A trace
     with no device plane (a CPU run) has no ``chips``."""
+    tables = layer_tables()
     phases = disjoint(host_phases(planes))
     starts = [s for s, _, _ in phases]
     host: Dict[str, List[float]] = {}
@@ -271,17 +344,22 @@ def summarize(planes) -> dict:
         h[0] += (e - s) / 1e9
         h[1] += 1
     chips = {}
-    for chip, evs in sorted(device_events(planes).items()):
+    for chip, evs in sorted(device_events(planes, tables).items()):
         if not evs:
             continue
         busy, gaps = union((s, s + d) for s, d, _ in evs)
         by_dir: Dict[str, Dict[str, float]] = {}
         by_scope: Dict[str, float] = {}
+        by_sub: Dict[str, float] = {}
         for _, d, op_name in evs:
-            direction, kind, scope = classify(op_name)
+            direction, kind, scope = classify(op_name, tables)
             by_kind = by_dir.setdefault(direction, {})
             by_kind[kind] = by_kind.get(kind, 0.0) + d / 1e9
             by_scope[scope] = by_scope.get(scope, 0.0) + d / 1e9
+            sub = (sub_scope(op_name, tables)
+                   if direction in ("fwd", "bwd", "kernel") else None)
+            if sub:
+                by_sub[sub] = by_sub.get(sub, 0.0) + d / 1e9
         idle: Dict[str, float] = {}
         for gap in gaps:
             for name, ns in share_gap(gap, phases, starts).items():
@@ -301,6 +379,7 @@ def summarize(planes) -> dict:
             "scoped_share": 1.0 - by_scope.get("unscoped", 0.0) / ops_s
             if ops_s else 0.0,
             "device_s": by_dir,
+            "sub_scope_s": by_sub,
             "top_scopes": sorted(by_scope.items(),
                                  key=lambda kv: -kv[1])[:10],
             "idle_s": dict(sorted(idle.items(), key=lambda kv: -kv[1])),

@@ -77,6 +77,15 @@ class Layer:
     gradient_normalization: Optional[str] = None
     gradient_normalization_threshold: Optional[float] = None
 
+    # ---- what monitor/profile.py reads of a layer (no fields) ----
+    #: the parts the layer names (``jax.named_scope``) inside its own
+    #: scope, summed as ``sub_scope_s``
+    scope_parts = ()
+    #: {prefix of a kernel's name: part} for operations of a part that
+    #: the compiler expands into kernels it names itself, dropping the
+    #: scope; a prefix belongs to one layer type
+    scope_kernels = {}
+
     # ---- capability flags ----
     def has_params(self) -> bool:
         return True
@@ -158,21 +167,46 @@ class DenseLayer(Layer):
 
     n_in: Optional[int] = None
     n_out: int = 0
+    bias: bool = True       # False: y = act(x @ W), no "b" leaf
+
+    def _affine_params(self, key, n_in, dtype):
+        kW, _ = jax.random.split(key)
+        params = {"W": self._winit(kW, (n_in, self.n_out), dtype)}
+        if self.bias:
+            params["b"] = self._binit((self.n_out,), dtype)
+        return params
 
     def initialize(self, key, input_type, dtype=jnp.float32):
         n_in = self.n_in or input_type.flat_size()
-        kW, _ = jax.random.split(key)
-        params = {"W": self._winit(kW, (n_in, self.n_out), dtype),
-                  "b": self._binit((self.n_out,), dtype)}
-        return params, {}, InputType.feed_forward(self.n_out)
+        return (self._affine_params(key, n_in, dtype), {},
+                InputType.feed_forward(self.n_out))
 
     def forward(self, params, state, x, *, train, rng, mask=None):
         x = self._maybe_dropout(x, train, rng)
         p = self._maybe_drop_connect(params, train, rng)
-        return self._act(x @ p["W"] + p["b"]), state, mask
+        return self._act(_affine(p, x)), state, mask
 
     def output_type(self, input_type):
         return InputType.feed_forward(self.n_out)
+
+
+def _affine(params, x):
+    """x @ W, plus b where the layer has one."""
+    y = x @ params["W"]
+    return y + params["b"] if "b" in params else y
+
+
+RMS_EPS = 1e-5
+
+
+def _rms_norm(x, gamma, eps=RMS_EPS):
+    """x / sqrt(mean(x^2) + eps) * gamma over the last axis, the
+    statistics in float32 (float64 under a gradient check) whatever the
+    compute dtype."""
+    xf = x.astype(jnp.promote_types(x.dtype, jnp.float32))
+    y = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+                           + eps)
+    return y.astype(x.dtype) * gamma
 
 
 @dataclasses.dataclass
@@ -188,7 +222,7 @@ class BaseOutputLayer(DenseLayer):
                                        self.activation or "softmax", mask)
 
     def preoutput(self, params, x):
-        return x @ params["W"] + params["b"]
+        return _affine(params, x)
 
 
 @register_layer
@@ -267,28 +301,38 @@ class EmbeddingLayer(Layer):
     """Index → embedding row lookup; input is int indices [N] or one-hot
     (ref: nn/layers/feedforward/embedding/EmbeddingLayer.java — mathematically
     a dense layer with one-hot input; here a gather, which XLA lowers to a
-    dynamic-slice on TPU)."""
+    dynamic-slice on TPU).  Sequence form: int ids [N, T] under a
+    recurrent input type (``InputType.recurrent(vocab, T)``) give
+    [N, T, n_out]."""
 
     n_in: Optional[int] = None  # vocab size
     n_out: int = 0
+    bias: bool = True
 
     def initialize(self, key, input_type, dtype=jnp.float32):
         n_in = self.n_in or input_type.flat_size()
         kW, _ = jax.random.split(key)
-        params = {"W": self._winit(kW, (n_in, self.n_out), dtype),
-                  "b": self._binit((self.n_out,), dtype)}
-        return params, {}, InputType.feed_forward(self.n_out)
+        params = {"W": self._winit(kW, (n_in, self.n_out), dtype)}
+        if self.bias:
+            params["b"] = self._binit((self.n_out,), dtype)
+        return params, {}, self.output_type(input_type)
 
     def forward(self, params, state, x, *, train, rng, mask=None):
         if jnp.issubdtype(x.dtype, jnp.integer):
-            idx = x.reshape(x.shape[0]) if x.ndim > 1 else x
+            # [N] and [N, 1] are one index a row; [N, T] is a sequence
+            idx = x.reshape(x.shape[0]) if x.ndim > 1 and x.shape[1] == 1 \
+                else x
             emb = params["W"][idx]
         else:
             # one-hot [N, vocab] input
             emb = x @ params["W"]
-        return self._act(emb + params["b"]), state, mask
+        if "b" in params:
+            emb = emb + params["b"]
+        return self._act(emb), state, mask
 
     def output_type(self, input_type):
+        if input_type is not None and input_type.kind == "rnn":
+            return InputType.recurrent(self.n_out, input_type.timesteps)
         return InputType.feed_forward(self.n_out)
 
 
@@ -590,25 +634,37 @@ class GravesBidirectionalLSTM(Layer):
 @dataclasses.dataclass
 class RnnOutputLayer(BaseOutputLayer):
     """Per-timestep dense + loss over [N, T, C]
-    (ref: nn/conf/layers/RnnOutputLayer.java)."""
+    (ref: nn/conf/layers/RnnOutputLayer.java).  Labels are [N, T, C], or
+    int class ids [N, T] (``mcxent``).  ``time_reduction``: an example's
+    score is the ``sum`` over its timesteps, as the reference scores, or
+    their ``mean`` (over the unmasked ones), which makes the minibatch
+    score the mean over tokens when the sequences are equally long."""
+
+    time_reduction: str = "sum"     # sum | mean
 
     def initialize(self, key, input_type, dtype=jnp.float32):
         n_in = self.n_in or input_type.size
-        kW, _ = jax.random.split(key)
-        params = {"W": self._winit(kW, (n_in, self.n_out), dtype),
-                  "b": self._binit((self.n_out,), dtype)}
-        return params, {}, InputType.recurrent(self.n_out, input_type.timesteps)
+        return (self._affine_params(key, n_in, dtype), {},
+                InputType.recurrent(self.n_out, input_type.timesteps))
 
     def forward(self, params, state, x, *, train, rng, mask=None):
         x = self._maybe_dropout(x, train, rng)
-        return self._act(x @ params["W"] + params["b"]), state, mask
+        return self._act(_affine(params, x)), state, mask
 
     def compute_score(self, labels, preout, mask=None):
         # labels/preout: [N, T, C]; mask [N, T].  Score per example sums
         # over time (masked), matching reference RnnOutputLayer scoring.
         m = mask[..., None] if mask is not None else None
-        return loss_ops.get(self.loss)(labels, preout,
-                                       self.activation or "softmax", m)
+        per_ex = loss_ops.get(self.loss)(labels, preout,
+                                         self.activation or "softmax", m)
+        if self.time_reduction == "mean":
+            steps = (jnp.maximum(jnp.sum(mask, axis=1), 1.0)
+                     if mask is not None else preout.shape[1])
+            return per_ex / steps
+        if self.time_reduction != "sum":
+            raise ValueError(f"unknown time_reduction "
+                             f"{self.time_reduction!r} (sum | mean)")
+        return per_ex
 
     def output_type(self, input_type):
         return InputType.recurrent(self.n_out, input_type.timesteps)
@@ -635,6 +691,104 @@ class LastTimeStepLayer(Layer):
 
     def output_type(self, input_type):
         return InputType.feed_forward(input_type.size)
+
+
+# ==========================================================================
+# Pre-norm sequence blocks: RMS normalisation, the gated MLP and the gated
+# short convolution.  All three act on the last axis of [N, T, C] (or
+# [N, C]) and have no bias.
+# ==========================================================================
+
+@register_layer
+@dataclasses.dataclass
+class RMSNormLayer(Layer):
+    """y = x / sqrt(mean(x^2) + eps) * gamma over the feature axis
+    (Zhang & Sennrich 2019); no mean, no shift."""
+
+    activation: Optional[str] = "identity"
+    eps: float = RMS_EPS
+
+    def initialize(self, key, input_type, dtype=jnp.float32):
+        return ({"gamma": jnp.ones((input_type.flat_size(),), dtype)}, {},
+                input_type)
+
+    def forward(self, params, state, x, *, train, rng, mask=None):
+        return self._act(_rms_norm(x, params["gamma"], self.eps)), state, mask
+
+    def output_type(self, input_type):
+        return input_type
+
+
+@register_layer
+@dataclasses.dataclass
+class GatedDenseLayer(Layer):
+    """Gated MLP (Shazeer 2020): y = (act(x W1) * (x W3)) W2, ``act``
+    the layer's activation (swish = SiLU by default), width ``hidden``."""
+
+    activation: Optional[str] = "swish"
+    n_in: Optional[int] = None
+    n_out: int = 0
+    hidden: int = 0
+
+    def initialize(self, key, input_type, dtype=jnp.float32):
+        n_in = self.n_in or input_type.flat_size()
+        k1, k2, k3 = jax.random.split(key, 3)
+        params = {"W1": self._winit(k1, (n_in, self.hidden), dtype),
+                  "W3": self._winit(k3, (n_in, self.hidden), dtype),
+                  "W2": self._winit(k2, (self.hidden, self.n_out), dtype)}
+        return params, {}, self.output_type(input_type)
+
+    def forward(self, params, state, x, *, train, rng, mask=None):
+        x = self._maybe_dropout(x, train, rng)
+        y = (self._act(x @ params["W1"]) * (x @ params["W3"])) @ params["W2"]
+        return y, state, mask
+
+    def output_type(self, input_type):
+        if input_type.kind == "rnn":
+            return InputType.recurrent(self.n_out, input_type.timesteps)
+        return InputType.feed_forward(self.n_out)
+
+
+@register_layer
+@dataclasses.dataclass
+class GatedShortConvLayer(Layer):
+    """Gated short convolution over [N, T, C]: ``[b, c, z] = split3(x
+    W_in)``; a depthwise causal convolution of length ``kernel`` over
+    ``s = b * z`` (``g_t = sum_j K[j] s_{t-(kernel-1)+j}``, zeros before
+    the sequence); ``y = (c * g) W_out``."""
+
+    activation: Optional[str] = "identity"
+    n_in: Optional[int] = None
+    n_out: int = 0
+    kernel: int = 3
+
+    def initialize(self, key, input_type, dtype=jnp.float32):
+        n_in = self.n_in or input_type.size
+        ki, kk, ko = jax.random.split(key, 3)
+        params = {
+            "W_in": self._winit(ki, (n_in, 3 * self.n_out), dtype),
+            "K": self._winit(kk, (self.kernel, self.n_out), dtype,
+                             fan_in=self.kernel, fan_out=self.kernel),
+            "W_out": self._winit(ko, (self.n_out, self.n_out), dtype)}
+        return params, {}, InputType.recurrent(self.n_out,
+                                               input_type.timesteps)
+
+    def forward(self, params, state, x, *, train, rng, mask=None):
+        x = self._maybe_dropout(x, train, rng)
+        T, L = x.shape[1], self.kernel
+        b, c, z = jnp.split(x @ params["W_in"], 3, axis=-1)
+        s = b * z
+        if mask is not None:    # a padded step feeds no later one
+            s = s * mask[:, :, None].astype(s.dtype)
+        sp = jnp.pad(s, ((0, 0), (L - 1, 0), (0, 0)))
+        g = sum(params["K"][j] * sp[:, j:j + T] for j in range(L))
+        y = self._act((c * g) @ params["W_out"])
+        if mask is not None:
+            y = y * mask[:, :, None].astype(y.dtype)
+        return y, state, mask
+
+    def output_type(self, input_type):
+        return InputType.recurrent(self.n_out, input_type.timesteps)
 
 
 # ==========================================================================
@@ -675,40 +829,94 @@ class SelfAttentionLayer(Layer):
     strategy: str = "auto"      # auto | ring | ulysses | dense
     project_output: bool = True
     cache_window: Optional[int] = None   # KV-ring length for decode
+    # grouped-query heads: keys and values have n_kv_heads heads (a
+    # divisor of n_heads), each serving n_heads / n_kv_heads consecutive
+    # query heads; None = one a query head
+    n_kv_heads: Optional[int] = None
+    # rotary position embedding over the whole head, halves paired
+    # (i with i + Dh/2), base rotary_theta; None = no position signal
+    rotary_theta: Optional[float] = None
+    # RMSNorm (eps RMS_EPS) with a learned weight over each head of q and
+    # of k, before the rotation ("q_norm", "k_norm" leaves of [Dh])
+    qk_norm: bool = False
+    bias: bool = True           # False: no bq/bk/bv/bo leaves
 
     def initialize(self, key, input_type, dtype=jnp.float32):
         n_in = self.n_in or input_type.size
         if self.n_out % self.n_heads:
             raise ValueError(f"n_out={self.n_out} % n_heads={self.n_heads}")
+        Hkv = self.n_kv_heads or self.n_heads
+        if self.n_heads % Hkv:
+            raise ValueError(f"n_heads={self.n_heads} % n_kv_heads={Hkv}")
+        Dh = self.n_out // self.n_heads
+        if self.rotary_theta is not None and Dh % 2:
+            raise ValueError(f"rotary embedding pairs halves: head dim "
+                             f"{Dh} is odd")
         if self.cache_window is None:
             self.cache_window = int(getattr(input_type, "timesteps", None)
                                     or 128)
         kq, kk, kv, ko = jax.random.split(key, 4)
         params = {
             "Wq": self._winit(kq, (n_in, self.n_out), dtype),
-            "Wk": self._winit(kk, (n_in, self.n_out), dtype),
-            "Wv": self._winit(kv, (n_in, self.n_out), dtype),
-            "bq": self._binit((self.n_out,), dtype),
-            "bk": self._binit((self.n_out,), dtype),
-            "bv": self._binit((self.n_out,), dtype),
+            "Wk": self._winit(kk, (n_in, Hkv * Dh), dtype),
+            "Wv": self._winit(kv, (n_in, Hkv * Dh), dtype),
         }
+        if self.bias:
+            params["bq"] = self._binit((self.n_out,), dtype)
+            params["bk"] = self._binit((Hkv * Dh,), dtype)
+            params["bv"] = self._binit((Hkv * Dh,), dtype)
+        if self.qk_norm:
+            params["q_norm"] = jnp.ones((Dh,), dtype)
+            params["k_norm"] = jnp.ones((Dh,), dtype)
         if self.project_output:
             params["Wo"] = self._winit(ko, (self.n_out, self.n_out), dtype)
-            params["bo"] = self._binit((self.n_out,), dtype)
+            if self.bias:
+                params["bo"] = self._binit((self.n_out,), dtype)
         return params, {}, InputType.recurrent(self.n_out, input_type.timesteps)
+
+    @staticmethod
+    def _rotate(a, theta):
+        """Rotary embedding of [B, H, T, Dh] at positions 0..T-1, the
+        angles and the rotation in float32."""
+        T, Dh = a.shape[2], a.shape[3]
+        ft = jnp.promote_types(a.dtype, jnp.float32)
+        inv = 1.0 / (theta ** (jnp.arange(0, Dh, 2, dtype=ft) / Dh))
+        ang = jnp.arange(T, dtype=ft)[:, None] * inv[None, :]   # [T, Dh/2]
+        cos, sin = jnp.cos(ang), jnp.sin(ang)
+        a1, a2 = jnp.split(a.astype(ft), 2, axis=-1)
+        return jnp.concatenate([a1 * cos - a2 * sin, a2 * cos + a1 * sin],
+                               axis=-1).astype(a.dtype)
 
     def forward(self, params, state, x, *, train, rng, mask=None):
         from deeplearning4j_tpu.parallel import sequence as seq_ops
         x = self._maybe_dropout(x, train, rng)
         B, T, _ = x.shape
         H, Dh = self.n_heads, self.n_out // self.n_heads
+        Hkv = self.n_kv_heads or H
 
-        def split(a):  # [B, T, n_out] -> [B, H, T, Dh]
-            return a.reshape(B, T, H, Dh).transpose(0, 2, 1, 3)
+        def heads(w, b, n):  # [B, T, F] -> [B, n, T, Dh]
+            a = x @ params[w]
+            if b in params:
+                a = a + params[b]
+            return a.reshape(B, T, n, Dh).transpose(0, 2, 1, 3)
 
-        q = split(x @ params["Wq"] + params["bq"])
-        k = split(x @ params["Wk"] + params["bk"])
-        v = split(x @ params["Wv"] + params["bv"])
+        q = heads("Wq", "bq", H)
+        k = heads("Wk", "bk", Hkv)
+        v = heads("Wv", "bv", Hkv)
+        if self.qk_norm:
+            q = _rms_norm(q, params["q_norm"])
+            k = _rms_norm(k, params["k_norm"])
+        if self.rotary_theta is not None:
+            if seq_ops.kv_decode_active() and not train:
+                raise NotImplementedError(
+                    "SelfAttentionLayer: rotary positions are not carried "
+                    "in the KV-ring decode step")
+            q = self._rotate(q, self.rotary_theta)
+            k = self._rotate(k, self.rotary_theta)
+        if Hkv != H:
+            # the attention core wants one key/value head a query head
+            k = jnp.repeat(k, H // Hkv, axis=1)
+            v = jnp.repeat(v, H // Hkv, axis=1)
         new_state = state
         if seq_ops.kv_decode_active() and not train:
             # incremental decode: append this chunk's K/V to the
@@ -759,7 +967,9 @@ class SelfAttentionLayer(Layer):
                                     key_mask=mask, strategy=self.strategy)
         out = out.transpose(0, 2, 1, 3).reshape(B, T, self.n_out)
         if self.project_output:
-            out = out @ params["Wo"] + params["bo"]
+            out = out @ params["Wo"]
+            if "bo" in params:
+                out = out + params["bo"]
         out = self._act(out)
         if mask is not None:
             out = out * mask[:, :, None].astype(out.dtype)
@@ -772,25 +982,75 @@ class SelfAttentionLayer(Layer):
 @register_layer
 @dataclasses.dataclass
 class MixtureOfExpertsLayer(Layer):
-    """Sparse mixture-of-experts feed-forward block (GShard-style top-1
-    dispatch).  No reference analog — DL4J predates MoE; this layer
-    exists so the mesh's 'expert' axis is a first-class layout: expert
-    weight stacks [E, ...] shard over 'expert'
-    (parallel/mesh.param_sharding) and XLA partitions the dispatch/
-    combine einsums into expert-parallel all-to-alls.
+    """Sparse mixture-of-experts feed-forward block.  No reference analog
+    — DL4J predates MoE; this layer exists so the mesh's 'expert' axis is
+    a first-class layout: expert weight stacks [E, ...] shard over
+    'expert' (parallel/mesh.param_sharding).
 
-    Routing: softmax gate → top-1 expert per token, fixed capacity
-    ``capacity_factor·N/E`` per expert; overflow tokens pass through
-    unchanged (residual).  Aux load-balancing loss is returned in state
-    under "moe_aux_loss" (mean over experts of fraction·probability,
-    scaled by ``aux_loss_weight``)."""
+    ``top_k=None`` is the GShard-style top-1 path: softmax gate → top-1
+    expert per token, fixed capacity ``capacity_factor·N/E`` per expert,
+    a dense [N, E, C] dispatch whose einsums XLA partitions into
+    expert-parallel all-to-alls; overflow tokens pass through unchanged
+    (the block adds its input).  Aux load-balancing loss is returned in
+    state under "moe_aux_loss" (mean over experts of
+    fraction·probability, scaled by ``aux_loss_weight``); ``top_k=k`` has
+    none, and leaves it at zero.
+
+    ``top_k=k`` routes without capacity and drops no token.  Scores are
+    ``scoring`` (sigmoid | softmax) of the router's product, which runs
+    in float32 whatever the compute dtype so that no selection flips on
+    the rounding of a score; the k experts with the largest score (plus,
+    with ``expert_bias``, a constant per-expert bias held in state, which
+    steers the selection and not the weights) are selected, and their
+    scores renormalised over the k (``norm_topk``).  The N·k assignments
+    are sorted by expert into rows of static shape, the group sizes are
+    data (no step retraces whatever the routing), and the expert
+    products are grouped matrix products over the rows
+    (``jax.lax.ragged_dot``).  Memory grows with N·k, not N·E·C.
+    ``gated`` experts are ``(silu(x W1) * (x W3)) W2``, plain ones
+    ``gelu(x W1) W2``; neither has a bias.
+
+    ``experts_held`` names the experts whose weights this layer holds
+    (expert parallelism's share of the layer; None = all): the router
+    keeps its width ``n_experts``, selection and renormalisation run
+    over all of them, and the layer returns the part of the sum that its
+    own experts give.  Nothing stands in for the others.  The per-expert
+    assignment counts of the step are left in state under
+    "moe_expert_counts" (published by the fit loop as
+    ``dl4j_moe_assignments_total`` / ``dl4j_moe_expert_load_max_over_mean``).
+    ``residual=False`` returns the routed sum alone (a graph adds the
+    residual with an ElementWiseVertex).  The four parts are named
+    inside the layer's scope (``scope_parts``), and the grouped product's
+    kernels, which the chip's compiler names itself, are claimed for
+    ``experts`` (``scope_kernels``)."""
+
+    scope_parts = ("route", "dispatch", "experts", "combine")
+    scope_kernels = {"ragged-dot": "experts"}
 
     n_in: Optional[int] = None
     n_out: int = 0
     n_experts: int = 4
     hidden: Optional[int] = None       # expert MLP width (default 4×n_out)
     capacity_factor: float = 1.25
-    aux_loss_weight: float = 0.01
+    aux_loss_weight: float = 0.01      # the top-1 path's
+    top_k: Optional[int] = None        # None: the top-1 capacity path
+    # the top_k path's:
+    scoring: str = "softmax"           # softmax | sigmoid
+    norm_topk: bool = True
+    expert_bias: bool = False
+    gated: bool = False
+    experts_held: Optional[Tuple[int, ...]] = None
+    residual: bool = True
+
+    def _held(self) -> Tuple[int, ...]:
+        held = tuple(range(self.n_experts)) if self.experts_held is None \
+            else tuple(int(e) for e in self.experts_held)
+        if not held or list(held) != sorted(set(held)) \
+                or held[0] < 0 or held[-1] >= self.n_experts:
+            raise ValueError(f"experts_held={self.experts_held!r}: want "
+                             f"distinct ascending ids in [0, "
+                             f"{self.n_experts})")
+        return held
 
     def initialize(self, key, input_type, dtype=jnp.float32):
         n_in = self.n_in or input_type.size
@@ -798,24 +1058,125 @@ class MixtureOfExpertsLayer(Layer):
             raise ValueError("MoE block is residual: n_out must equal n_in "
                              f"(got n_in={n_in}, n_out={self.n_out})")
         H = self.hidden or 4 * self.n_out
-        kg, k1, k2 = jax.random.split(key, 3)
+        kg, k1, k2, k3, kb = jax.random.split(key, 5)
         E = self.n_experts
-        params = {
-            "Wg": self._winit(kg, (n_in, E), dtype),
-            "W1": self._winit(k1, (E, n_in, H), dtype, fan_in=n_in,
-                              fan_out=H),
-            "b1": jnp.zeros((E, H), dtype),
-            "W2": self._winit(k2, (E, H, self.n_out), dtype, fan_in=H,
-                              fan_out=self.n_out),
-            "b2": jnp.zeros((E, self.n_out), dtype),
-        }
         # aux loss lives in state from step 0 so the state pytree
         # structure never changes (jit/sharding trees are built once)
         state = {"moe_aux_loss": jnp.zeros((), dtype)}
+        if self.top_k is None:
+            params = {
+                "Wg": self._winit(kg, (n_in, E), dtype),
+                "W1": self._winit(k1, (E, n_in, H), dtype, fan_in=n_in,
+                                  fan_out=H),
+                "b1": jnp.zeros((E, H), dtype),
+                "W2": self._winit(k2, (E, H, self.n_out), dtype, fan_in=H,
+                                  fan_out=self.n_out),
+                "b2": jnp.zeros((E, self.n_out), dtype),
+            }
+            return params, state, input_type
+        if not 1 <= self.top_k <= E:
+            raise ValueError(f"top_k={self.top_k} of {E} experts")
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown scoring {self.scoring!r} "
+                             "(softmax | sigmoid)")
+        G = len(self._held())
+        params = {
+            "Wg": self._winit(kg, (n_in, E), dtype),
+            "W1": self._winit(k1, (G, n_in, H), dtype, fan_in=n_in,
+                              fan_out=H),
+            "W2": self._winit(k2, (G, H, self.n_out), dtype, fan_in=H,
+                              fan_out=self.n_out),
+        }
+        if self.gated:
+            params["W3"] = self._winit(k3, (G, n_in, H), dtype, fan_in=n_in,
+                                       fan_out=H)
+        state["moe_expert_counts"] = jnp.zeros((E,), jnp.int32)
+        if self.expert_bias:
+            state["expert_bias"] = 0.01 * jax.random.normal(kb, (E,), dtype)
         return params, state, input_type
+
+    def _forward_top_k(self, params, state, x, mask):
+        """The routed sum of the top_k path, [.., n_out], and the new
+        state."""
+        import numpy as np
+        shape = x.shape
+        D = shape[-1]
+        tokens = x.reshape(-1, D)                       # [N, D]
+        N, E, k = tokens.shape[0], self.n_experts, self.top_k
+        held = self._held()
+        G = len(held)
+        tok_mask = (mask.reshape(-1) > 0
+                    if mask is not None and x.ndim == 3 else None)
+        with jax.named_scope("route"):
+            ft = jnp.promote_types(x.dtype, jnp.float32)
+            logits = jnp.dot(tokens.astype(ft), params["Wg"].astype(ft),
+                             precision=jax.lax.Precision.HIGHEST)
+            scores = (jax.nn.sigmoid(logits) if self.scoring == "sigmoid"
+                      else jax.nn.softmax(logits, axis=-1))       # [N, E]
+            select = scores
+            if self.expert_bias:
+                select = scores + state["expert_bias"].astype(ft)
+            _, top_e = jax.lax.top_k(select, k)                   # [N, k]
+            w = jnp.take_along_axis(scores, top_e, axis=1)
+            if self.norm_topk:
+                w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+            if tok_mask is not None:
+                w = w * tok_mask[:, None]
+        with jax.named_scope("dispatch"):
+            # the held experts' position in this layer's stacks; G = "not
+            # here", which sorts last and belongs to no group
+            where_held = np.full((E,), G, np.int32)
+            where_held[list(held)] = np.arange(G, dtype=np.int32)
+            local = jnp.asarray(where_held)[top_e]                # [N, k]
+            if tok_mask is not None:    # padding claims no expert
+                local = jnp.where(tok_mask[:, None], local, G)
+            flat = local.reshape(-1)                              # [N·k]
+            perm = jnp.argsort(flat, stable=True)
+            sizes = jnp.sum(flat[:, None] == jnp.arange(G + 1)[None, :],
+                            axis=0, dtype=jnp.int32)              # [G + 1]
+            group_sizes = sizes[:G]
+            valid = (jnp.arange(N * k) < jnp.sum(group_sizes))[:, None]
+            # row r of the sorted order is assignment perm[r] = token
+            # perm[r] // k: a gather with unique indices from the k-fold
+            # repeat, so that its transpose is a scatter without
+            # collisions followed by a sum over k
+            rows = jnp.broadcast_to(tokens[:, None, :], (N, k, D)).reshape(
+                N * k, D).at[perm].get(unique_indices=True,
+                                       mode="promise_in_bounds")
+            # rows beyond the groups belong to other holders: the grouped
+            # products leave them undefined, in both directions
+            rows = jnp.where(valid, rows, 0)
+        with jax.named_scope("experts"):
+            h = jax.lax.ragged_dot(rows, params["W1"], group_sizes)
+            if self.gated:
+                h = jax.nn.silu(h) * jax.lax.ragged_dot(
+                    rows, params["W3"], group_sizes)
+            else:
+                h = jax.nn.gelu(h)
+            y = jax.lax.ragged_dot(h, params["W2"], group_sizes)
+            y = jnp.where(valid, y, 0)
+        with jax.named_scope("combine"):
+            back = jnp.zeros((N * k,), jnp.int32).at[perm].set(
+                jnp.arange(N * k, dtype=jnp.int32), unique_indices=True)
+            y = y.at[back].get(unique_indices=True,
+                               mode="promise_in_bounds").reshape(N, k, -1)
+            routed = jnp.einsum("nko,nk->no", y, w.astype(y.dtype))
+        counted = top_e if tok_mask is None \
+            else jnp.where(tok_mask[:, None], top_e, E)
+        counts = jnp.sum(counted.reshape(-1)[:, None]
+                         == jnp.arange(E)[None, :], axis=0, dtype=jnp.int32)
+        new_state = dict(state)
+        new_state["moe_expert_counts"] = counts
+        return routed.reshape(shape[:-1] + (self.n_out,)), new_state
 
     def forward(self, params, state, x, *, train, rng, mask=None):
         x = self._maybe_dropout(x, train, rng)
+        if self.top_k is not None:
+            routed, new_state = self._forward_top_k(params, state, x, mask)
+            out = self._act(x + routed if self.residual else routed)
+            if mask is not None and out.ndim == 3:
+                out = out * mask[:, :, None].astype(out.dtype)
+            return out, new_state, mask
         shape = x.shape
         D = shape[-1]
         tokens = x.reshape(-1, D)                       # [N, D]
@@ -854,8 +1215,7 @@ class MixtureOfExpertsLayer(Layer):
 
         # residual: routed contribution is zero for overflow/unrouted
         # tokens, so they pass through unchanged
-        out = tokens + routed
-        out = out.reshape(shape[:-1] + (self.n_out,))
+        out = (tokens + routed).reshape(shape[:-1] + (self.n_out,))
 
         # load-balance aux loss (Switch/GShard): E·Σ_e fraction_e·prob_e
         # — averaged over VALID tokens only

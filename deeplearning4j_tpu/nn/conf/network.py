@@ -534,8 +534,9 @@ def _needs(layer: Layer) -> str:
         return "cnn"
     if isinstance(layer, (L.GravesLSTM, L.GravesBidirectionalLSTM, L.RnnOutputLayer)):
         return "rnn"
-    if isinstance(layer, (L.DenseLayer, L.EmbeddingLayer)):
+    if isinstance(layer, L.DenseLayer):
         return "ff"
+    # an EmbeddingLayer takes [N] indices or an [N, T] id sequence
     return "any"
 
 

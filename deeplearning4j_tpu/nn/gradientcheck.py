@@ -167,8 +167,9 @@ def _check_cg_x64(graph, inputs, labels, *, epsilon, max_rel_error,
         if np.asarray(a).dtype.kind == "f" else jnp.asarray(a), t)
     params64 = to64(graph.net_params)
     state64 = to64(graph.net_state)
-    xs64 = [jnp.asarray(np.asarray(x), jnp.float64) for x in inputs]
-    ys64 = [jnp.asarray(np.asarray(y), jnp.float64) for y in labels]
+    # integer inputs (token ids) and labels (class ids) stay integers
+    xs64 = to64(list(inputs))
+    ys64 = to64(list(labels))
 
     def score(p):
         ins = dict(zip(graph.conf.network_inputs, xs64))

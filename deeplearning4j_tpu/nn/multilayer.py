@@ -28,7 +28,8 @@ from deeplearning4j_tpu.analysis import sanitizer
 from deeplearning4j_tpu.monitor import events
 from deeplearning4j_tpu.nn import params as param_util
 from deeplearning4j_tpu.nn.conf.inputs import InputType
-from deeplearning4j_tpu.nn.conf.layers import BaseOutputLayer, Layer, LossLayer
+from deeplearning4j_tpu.nn.conf.layers import (
+    BaseOutputLayer, Layer, LossLayer, MixtureOfExpertsLayer)
 from deeplearning4j_tpu.nn.conf.network import MultiLayerConfiguration
 from deeplearning4j_tpu.nn.listeners import IterationListener, TrainingListener
 from deeplearning4j_tpu.ops import bucketing
@@ -77,6 +78,55 @@ def _updater_for(layer: Layer) -> upd_ops.Updater:
     return upd_ops.make(name, **hyper)
 
 
+def _expert_layers(net) -> dict:
+    """{index or vertex name: ids of the experts held} of the net's
+    expert layers that route without capacity (they leave their
+    per-expert assignment counts in state)."""
+    if hasattr(net, "order"):
+        from deeplearning4j_tpu.nn.conf.graph_conf import LayerVertex
+        confs = {n: v.layer_conf() for n, v in net.conf.vertices.items()
+                 if isinstance(v, LayerVertex)}
+    else:
+        confs = dict(enumerate(net.layers))
+    return {k: list(l._held()) for k, l in confs.items()
+            if isinstance(l, MixtureOfExpertsLayer) and l.top_k is not None}
+
+
+def publish_expert_load(net) -> None:
+    """The step's expert load, out of the state the step returned and
+    into counters: ``dl4j_moe_assignments_total{vertex, held}`` (token x
+    expert assignments, by whether this net holds the expert) and
+    ``dl4j_moe_expert_load_max_over_mean{vertex}`` (the fullest expert's
+    assignments over the mean over all experts, this step).  The state
+    holds one step's counts, so a dispatch of ``fused_steps=k`` publishes
+    its last step's and the counter reads a k-th of the routing.  A net
+    without such a layer publishes nothing and pays one attribute
+    read."""
+    held = getattr(net, "_expert_layers", None)
+    if held is None:
+        held = net._expert_layers = _expert_layers(net)
+    if not held:
+        return
+    reg = monitor.get_registry()
+    total = reg.counter(
+        "dl4j_moe_assignments_total",
+        "token x expert assignments routed in the last step of each "
+        "dispatch, by whether this net holds the expert",
+        labels=("vertex", "held"))
+    skew = reg.gauge(
+        "dl4j_moe_expert_load_max_over_mean",
+        "fullest expert's assignments over the mean expert's, last step",
+        labels=("vertex",))
+    counts = jax.device_get({k: net.net_state[k]["moe_expert_counts"]
+                             for k in held})
+    for k, c in counts.items():
+        here = int(c[held[k]].sum())
+        total.labels(vertex=str(k), held="1").inc(here)
+        total.labels(vertex=str(k), held="0").inc(int(c.sum()) - here)
+        if c.sum():
+            skew.labels(vertex=str(k)).set(float(c.max() / c.mean()))
+
+
 def dispatch_train_step(net, step_fn, kind, sig_args, batch, t_step,
                         bucket=None, k=1, repeats=1):
     """Launch ``step_fn`` on a staged ``batch`` and account for it: the
@@ -109,6 +159,7 @@ def dispatch_train_step(net, step_fn, kind, sig_args, batch, t_step,
             net.iteration += k
             monitor.record_fit_step(net.last_batch_size,
                                     time.perf_counter() - t_step, score)
+            publish_expert_load(net)
         with steps.span("fit/step", phase="listeners"):
             for lst in net.listeners:
                 lst.iteration_done(net, net.iteration)

@@ -251,13 +251,15 @@ def _selftest_xent():
     labels = jnp.asarray(np.eye(V, dtype=np.float32)[
         rng.integers(0, V, N)])
 
-    def loss(lg):
-        return pk.softmax_xent_rows(lg, labels).mean()
+    def loss(lg, lab):
+        return pk.softmax_xent_rows(lg, lab).mean()
     vg = jax.jit(jax.value_and_grad(loss))
-    out, g = vg(logits)
-    jax.block_until_ready(g)
-    if not bool(jnp.isfinite(out)):
-        raise FloatingPointError("non-finite fused xent loss")
+    # both label forms: rows of class weights, and integer class ids
+    for lab in (labels, jnp.argmax(labels, axis=1).astype(jnp.int32)):
+        out, g = vg(logits, lab)
+        jax.block_until_ready(g)
+        if not bool(jnp.isfinite(out)):
+            raise FloatingPointError("non-finite fused xent loss")
 
 
 def _selftest_conv():

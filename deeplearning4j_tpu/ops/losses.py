@@ -79,7 +79,8 @@ def _fused_xent_wanted(labels, preout, mask) -> bool:
     path); platform/size selection delegated to the helper tier
     (ops/helpers.softmax_xent_wanted, which also meters the decision and
     honors the DL4J_FUSED_XENT=1|0 test override)."""
-    if preout.ndim < 2 or preout.shape != labels.shape:
+    if preout.ndim < 2 or (preout.shape != labels.shape
+                           and not _class_ids(labels, preout)):
         return False
     if mask is not None and mask.ndim == preout.ndim \
             and mask.shape[-1] == preout.shape[-1] and preout.shape[-1] != 1:
@@ -92,10 +93,55 @@ def _fused_xent_wanted(labels, preout, mask) -> bool:
     return helpers.softmax_xent_wanted(n_rows, V)
 
 
+def _class_ids(labels, preout) -> bool:
+    """Are the labels integer class ids, one a row of ``preout``?"""
+    return (jnp.issubdtype(labels.dtype, jnp.integer)
+            and labels.shape == preout.shape[:-1])
+
+
+def _mcxent_ids(ids, preout, activation, mask):
+    """``mcxent`` on integer class ids [...] against preout [..., V]:
+    what the one-hot labels would give, without the [..., V] label
+    array.  An id outside [0, V) is a row without a label: it scores 0
+    and sends no gradient, as an all-zero one-hot row does."""
+    V = preout.shape[-1]
+    if activation == "softmax" and _fused_xent_wanted(ids, preout, mask):
+        from deeplearning4j_tpu.ops import pallas_kernels as pk
+        rows = pk.softmax_xent_rows(
+            preout.reshape(-1, V), ids.reshape(-1)).reshape(ids.shape)
+    else:
+        labelled = (ids >= 0) & (ids < V)
+        at = jnp.clip(ids, 0, V - 1)[..., None]
+        if activation == "softmax":
+            logp = jnp.take_along_axis(preout, at, axis=-1)[..., 0] \
+                - jax.nn.logsumexp(preout, axis=-1)
+        else:
+            out = jnp.clip(_activate(preout, activation), EPS, 1.0 - EPS)
+            logp = jnp.log(jnp.take_along_axis(out, at, axis=-1)[..., 0])
+        rows = jnp.where(labelled, -logp, 0.0)
+    return _sum_rows(rows, mask)
+
+
+def _sum_rows(rows, mask):
+    """Per-example score from per-row losses [N, ...] under a row-level
+    mask (an expanded [..., 1] mask is squeezed)."""
+    if mask is not None:
+        m = mask
+        if m.ndim == rows.ndim + 1 and m.shape[-1] == 1:
+            m = m[..., 0]
+        rows = rows * m
+    axes = tuple(range(1, rows.ndim))
+    return jnp.sum(rows, axis=axes) if axes else rows
+
+
 def mcxent(labels, preout, activation="softmax", mask=None):
     """Multi-class cross-entropy.  Stable fused path when activation is
     softmax; above the size threshold the softmax+CE+grad runs as one
-    Pallas VMEM pass (ref analog: the fused libnd4j SoftMaxWithLoss op)."""
+    Pallas VMEM pass (ref analog: the fused libnd4j SoftMaxWithLoss op).
+    Labels are rows of class weights shaped like ``preout``, or integer
+    class ids with one axis less."""
+    if _class_ids(labels, preout):
+        return _mcxent_ids(labels, preout, activation, mask)
     if activation == "softmax":
         if _fused_xent_wanted(labels, preout, mask):
             from deeplearning4j_tpu.ops import pallas_kernels as pk
@@ -103,13 +149,7 @@ def mcxent(labels, preout, activation="softmax", mask=None):
             rows = pk.softmax_xent_rows(
                 preout.reshape(-1, V), labels.reshape(-1, V)
             ).reshape(labels.shape[:-1])
-            if mask is not None:
-                m = mask
-                if m.ndim == rows.ndim + 1 and m.shape[-1] == 1:
-                    m = m[..., 0]
-                rows = rows * m
-            axes = tuple(range(1, rows.ndim))
-            return jnp.sum(rows, axis=axes) if axes else rows
+            return _sum_rows(rows, mask)
         logz = jax.nn.logsumexp(preout, axis=-1, keepdims=True)
         per = -labels * (preout - logz)
     else:

@@ -63,6 +63,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 
@@ -516,12 +517,35 @@ def _softmax_xent_kernel(logits_ref, labels_ref, loss_ref, grad_ref):
         grad_ref.dtype)
 
 
+def _softmax_xent_ids_kernel(logits_ref, ids_ref, loss_ref, grad_ref):
+    """The same pass on integer class ids [br, 1]: the one-hot row is a
+    comparison with the lane index and never exists in HBM.  An id
+    outside [0, V) matches no lane: loss 0, gradient 0, as a zero label
+    row gives above."""
+    x = logits_ref[...].astype(jnp.float32)
+    ids = ids_ref[...]
+    hit = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) == ids
+    m = x.max(axis=1, keepdims=True)
+    e = jnp.exp(x - m)
+    z = e.sum(axis=1, keepdims=True)
+    logp = (x - m) - jnp.log(z)
+    labelled = ((ids >= 0) & (ids < x.shape[1])).astype(jnp.float32)
+    loss_ref[...] = -jnp.where(hit, logp, 0.0).sum(
+        axis=1, keepdims=True).astype(loss_ref.dtype)
+    grad_ref[...] = (e / z * labelled - hit.astype(jnp.float32)).astype(
+        grad_ref.dtype)
+
+
 def fused_softmax_xent(logits, labels, block_rows: Optional[int] = None):
-    """Returns (per_row_loss [N], dlogits [N, V]) in one fused pass.
+    """Returns (per_row_loss [N], dlogits [N, V]) in one fused pass;
+    ``labels`` are rows of class weights [N, V] or integer class ids [N].
     Rows are padded to the block size; the block height adapts to V so
     ~8 live br×V fp32 buffers (2 in, 1 out, temps) stay under the ~10 MB
     scoped-VMEM budget."""
     N, V = logits.shape
+    by_id = labels.ndim == 1
+    if by_id:
+        labels = labels.astype(jnp.int32)[:, None]
     if block_rows is None:
         budget = 10 << 20  # observed ~8 live br x V buffers in-kernel
         block_rows = max(8, min(256, budget // (V * 4 * 8) // 8 * 8))
@@ -530,15 +554,17 @@ def fused_softmax_xent(logits, labels, block_rows: Optional[int] = None):
     if pad:
         logits = jnp.concatenate(
             [logits, jnp.zeros((pad, V), logits.dtype)])
+        # a padded row has no label: zero weights, or an id no lane has
         labels = jnp.concatenate(
-            [labels, jnp.zeros((pad, V), labels.dtype)])
+            [labels, jnp.full((pad, labels.shape[1]), -1 if by_id else 0,
+                              labels.dtype)])
     Np = logits.shape[0]
     loss, grad = pl.pallas_call(
-        _softmax_xent_kernel,
+        _softmax_xent_ids_kernel if by_id else _softmax_xent_kernel,
         grid=(Np // br,),
         in_specs=[
             pl.BlockSpec((br, V), lambda i: (i, 0)),
-            pl.BlockSpec((br, V), lambda i: (i, 0)),
+            pl.BlockSpec((br, labels.shape[1]), lambda i: (i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((br, 1), lambda i: (i, 0)),
@@ -566,13 +592,17 @@ def softmax_xent_rows(logits, labels):
 
 def _sxr_fwd(logits, labels):
     loss, grad = fused_softmax_xent(logits, labels)
-    return loss, grad
+    return loss, (grad, labels.shape if labels.ndim == 1 else None)
 
 
-def _sxr_bwd(grad, g):
+def _sxr_bwd(res, g):
+    grad, ids_shape = res
     # labels cotangent is never consumed (labels are data); zeros keeps the
-    # vjp signature total and XLA dead-code-eliminates it
-    return grad * g[:, None], jnp.zeros_like(grad)
+    # vjp signature total and XLA dead-code-eliminates it (integer ids
+    # take the float0 zero JAX gives integers)
+    zero = (jnp.zeros_like(grad) if ids_shape is None
+            else np.zeros(ids_shape, jax.dtypes.float0))
+    return grad * g[:, None], zero
 
 
 softmax_xent_rows.defvjp(_sxr_fwd, _sxr_bwd)

@@ -1,0 +1,597 @@
+"""The LFM2-MoE decoder (models/lfm2.py) and the layers it brought, on the
+CPU at a small size, against the plain reference the benchmark keeps
+(benchmark/reference/lfm2_moe.py): hidden 64, 4 query heads over 2
+key/value heads, 8 experts top-2 (4 held), 2 + 4 layers, vocabulary 256,
+32 tokens a sequence, seeded weights.
+
+Tolerances.  Both sides compute in float32 on the CPU and differ only in
+the order of their sums (a grouped product over sorted rows against a
+dense product masked by the routing; one fused step against a sequence at
+a time), so agreement is to round-off: 2e-5 of the largest value
+compared, a few hundred float32 ulps through six layers.  A selection
+that flipped on such a difference would show as a gap of order 1e-1, and
+none does on these seeds."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.modes.fit_tokens import PUBLISHED
+from benchmark.reference import lfm2_moe as ref
+from deeplearning4j_tpu.datasets.dataset import DataSet
+from deeplearning4j_tpu.datasets.iterators import ListDataSetIterator
+from deeplearning4j_tpu.models.lfm2 import lfm2_moe
+from deeplearning4j_tpu.nn.conf import layers as L
+from deeplearning4j_tpu.nn.conf.graph_conf import GraphBuilder
+from deeplearning4j_tpu.nn.conf.inputs import InputType
+from deeplearning4j_tpu.nn.conf.network import GlobalConf
+from deeplearning4j_tpu.nn.graph import ComputationGraph
+from deeplearning4j_tpu.ops import helpers, losses
+from deeplearning4j_tpu.ops import pallas_kernels as pk
+
+RTOL = 2e-5     # of the largest value compared; see the module's docstring
+
+CFG = {
+    "hidden_size": 64, "num_attention_heads": 4, "num_key_value_heads": 2,
+    "intermediate_size": 96, "moe_intermediate_size": 48,
+    "num_experts_published": 8, "num_experts": 4, "experts_held": [1, 2, 5, 6],
+    "num_experts_per_tok": 2, "vocab_size": 256, "seq_len": 32,
+    "layer_types": ["conv", "conv", "full_attention", "conv", "conv", "conv"],
+    "num_dense_layers": 2, "layers_run": [0, 1, 2, 3, 4, 5],
+    "conv_L_cache": 3, "norm_eps": 1e-5, "rope_theta": 1e6,
+    "norm_topk_prob": True, "routed_scaling_factor": 1,
+    "use_expert_bias": True,
+}
+ADAM = dict(lr=3e-4, beta1=0.9, beta2=0.95, eps=1e-8)
+VERTICES = (["embed", "final_norm", "head"]
+            + [f"l{i}_{part}" for i in range(6)
+               for part in ("op_norm", "ff_norm",
+                            "attn" if i == 2 else "conv",
+                            "mlp" if i < 2 else "moe")])
+
+
+def _close(got, want, rtol=RTOL):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    scale = max(float(np.abs(want).max()), 1e-30)
+    assert got.shape == want.shape
+    assert float(np.abs(got - want).max()) <= rtol * scale, \
+        (float(np.abs(got - want).max()), scale)
+
+
+def _net(cfg=CFG, **over):
+    args = {k: cfg[k] for k in PUBLISHED}
+    args.update(num_experts=cfg["num_experts_published"],
+                layers=cfg["layers_run"], experts_held=cfg["experts_held"],
+                seq_len=cfg["seq_len"], **over)
+    return lfm2_moe(**args)
+
+
+@pytest.fixture(scope="module")
+def seeded():
+    """The program with the reference's seeded weights and bias, two
+    batches of token ids, and both sides' first gradient."""
+    key = jax.random.PRNGKey(29)
+    weights = ref.init_params(CFG, key)
+    bias = ref.init_expert_bias(CFG, key)
+    net = _net()
+    net.init(params={n: weights.get(n, {}) for n in net.order})
+    for v, b in bias.items():
+        net.net_state[v] = {**net.net_state[v], "expert_bias": jnp.array(b)}
+    rng = np.random.default_rng(29)
+    ids = rng.integers(0, CFG["vocab_size"], (3, 2, CFG["seq_len"] + 1),
+                       dtype=np.int32)
+    batches = [(b[:, :-1], b[:, 1:]) for b in ids]
+    x, y = (jnp.asarray(a) for a in batches[0])
+    grad_step = jax.jit(net._build_grad_raw())
+    score, _, grads = grad_step(
+        net.net_params, net.net_state, (x,), (y,), None, None,
+        jax.random.PRNGKey(0))
+    ref_loss, ref_grads = jax.value_and_grad(
+        ref.loss_fn(CFG, "float32", bias))(weights, x, y)
+    return dict(net=net, weights=weights, bias=bias, batches=batches,
+                score=float(score), grads=grads, ref_loss=float(ref_loss),
+                ref_grads=ref_grads)
+
+
+# --- the whole model against the reference ---------------------------------
+def test_logits_and_loss_match_the_reference(seeded):
+    net, (x, y) = seeded["net"], seeded["batches"][0]
+    _, preouts, _, _ = net._forward_all(
+        net.net_params, net.net_state, {"ids": jnp.asarray(x)}, {}, True,
+        jax.random.PRNGKey(0), preout_for=["head"])
+    logits = ref.logits_fn(CFG, "float32", seeded["bias"])
+    for s in range(x.shape[0]):
+        _close(preouts["head"][s], logits(seeded["weights"], x[s]))
+    assert seeded["score"] == pytest.approx(seeded["ref_loss"], rel=RTOL)
+    # the mean over tokens: about ln(256) from a random start
+    assert 5.0 < seeded["score"] < 6.5
+
+
+@pytest.mark.parametrize("vertex", VERTICES)
+def test_every_leaf_gradient_matches_the_reference(seeded, vertex):
+    want = seeded["ref_grads"][vertex]
+    assert set(seeded["grads"][vertex]) == set(want)
+    for leaf in want:
+        assert float(jnp.abs(want[leaf]).max()) > 0
+        _close(seeded["grads"][vertex][leaf], want[leaf])
+
+
+@pytest.fixture(scope="module")
+def trained(seeded):
+    """Three Adam steps through ComputationGraph.fit() on a fresh copy,
+    and the reference's three steps written out."""
+    net = _net()
+    # fit() donates the parameters it is given to its step: hand it copies
+    net.init(params={n: jax.tree_util.tree_map(
+        jnp.array, seeded["weights"].get(n, {})) for n in net.order})
+    for v, b in seeded["bias"].items():
+        net.net_state[v] = {**net.net_state[v], "expert_bias": jnp.array(b)}
+    scores = []
+
+    class Scores:
+        def iteration_done(self, model, iteration):
+            scores.append(float(model._score))
+    net.set_listeners(Scores())
+    net.fit(ListDataSetIterator([DataSet(x, y)
+                                 for x, y in seeded["batches"]]))
+    out = ref.follow(ref.loss_fn(CFG, "float32", seeded["bias"]),
+                     seeded["weights"], seeded["batches"], ADAM["lr"],
+                     ADAM["beta1"], ADAM["beta2"], ADAM["eps"])
+    return net, scores, out
+
+
+def test_three_adam_steps_through_fit_follow_the_reference_losses(trained):
+    net, scores, out = trained
+    assert net.iteration == 3 and len(scores) == 3
+    assert scores == pytest.approx(out["losses"], rel=RTOL)
+    # the bias is state: three steps leave it as it was seeded
+    assert net.compile_telemetry.retraces <= 1
+
+
+@pytest.mark.parametrize("vertex", VERTICES)
+def test_three_adam_steps_move_every_leaf_as_the_reference_does(
+        seeded, trained, vertex):
+    net, _, out = trained
+    for leaf, start in seeded["weights"][vertex].items():
+        moved = np.asarray(net.net_params[vertex][leaf]) - np.asarray(start)
+        norm = float(np.sqrt(np.sum(np.square(moved.astype(np.float64)))))
+        # Adam's first steps are near lr * sign(g): every leaf moves, and
+        # the norm of its move is the reference's to round-off of the
+        # parameters (1e-7 of a weight of order 1 against a move of 1e-3)
+        assert norm > 0
+        assert norm == pytest.approx(float(out["change_norms"][vertex][leaf]),
+                                     rel=2e-3)
+
+
+def test_the_expert_bias_is_state_and_stays_as_seeded(seeded, trained):
+    net = trained[0]
+    for v, b in seeded["bias"].items():
+        np.testing.assert_array_equal(np.asarray(net.net_state[v]["expert_bias"]),
+                                      np.asarray(b))
+        assert "expert_bias" not in net.net_params[v]
+
+
+def test_the_builder_states_the_updater_the_configuration_assumes():
+    import json
+    import os
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "lfm2_8b_a1b_ep4.json")) as f:
+        u = json.load(f)["updater"]
+    g = _net().conf.global_conf
+    assert (g.updater, g.learning_rate, g.adam_mean_decay, g.adam_var_decay,
+            g.epsilon) == (u["name"], u["learning_rate"], u["beta1"],
+                           u["beta2"], u["epsilon"])
+
+
+def test_fit_publishes_the_expert_load(trained):
+    from deeplearning4j_tpu import monitor
+    snap = monitor.get_registry().snapshot()
+    per = {}
+    for s in snap["dl4j_moe_assignments_total"]["samples"]:
+        if s["labels"]["vertex"] == "l3_moe":
+            per[s["labels"]["held"]] = s["value"]
+    # 2 sequences x 32 tokens x top-2 a step, every step a whole number
+    assert (per["1"] + per["0"]) % (2 * 32 * 2) == 0 and per["1"] > 0 < per["0"]
+    skew = {s["labels"]["vertex"]: s["value"] for s in
+            snap["dl4j_moe_expert_load_max_over_mean"]["samples"]}
+    assert all(skew[f"l{i}_moe"] >= 1.0 for i in (2, 3, 4, 5))
+
+
+# --- each new layer alone, against its lines of the reference ---------------
+def _u(seed=3, t=32, d=64):
+    return jax.random.normal(jax.random.PRNGKey(seed), (2, t, d), jnp.float32)
+
+
+def _apply(layer, params, x, state=None):
+    y, _, _ = layer.forward(params, state or {}, x, train=True, rng=None)
+    return y
+
+
+def test_rms_norm_layer():
+    w = 1.0 + 0.1 * jax.random.normal(jax.random.PRNGKey(1), (64,))
+    y = _apply(L.RMSNormLayer(eps=1e-5), {"gamma": w}, _u())
+    _close(y, ref.rms_norm(_u(), w, 1e-5))
+    p, s, t = L.RMSNormLayer().initialize(jax.random.PRNGKey(0),
+                                          InputType.recurrent(64, 32))
+    assert p["gamma"].shape == (64,) and not s and t.size == 64
+
+
+def test_gated_short_conv_layer(seeded):
+    p = seeded["weights"]["l0_conv"]
+    y = _apply(L.GatedShortConvLayer(n_out=64, kernel=3), p, _u())
+    conv = ref.blocks(CFG)["conv"]
+    for s in range(2):
+        _close(y[s], conv(p, _u()[s]))
+    # causal: a later token changes no earlier output
+    x2 = _u().at[:, 20:].set(0.0)
+    y2 = _apply(L.GatedShortConvLayer(n_out=64, kernel=3), p, x2)
+    np.testing.assert_array_equal(np.asarray(y[:, :20]), np.asarray(y2[:, :20]))
+
+
+def test_grouped_query_attention_with_rotary_and_qk_norm(seeded):
+    p = seeded["weights"]["l2_attn"]
+    layer = L.SelfAttentionLayer(
+        n_out=64, n_heads=4, n_kv_heads=2, causal=True, rotary_theta=1e6,
+        qk_norm=True, bias=False, activation="identity")
+    y = _apply(layer, p, _u())
+    attention = ref.blocks(CFG)["attention"]
+    for s in range(2):
+        _close(y[s], attention(p, _u()[s]))
+    init, _, _ = layer.initialize(jax.random.PRNGKey(0),
+                                  InputType.recurrent(64, 32))
+    assert {k: v.shape for k, v in init.items()} == {
+        k: v.shape for k, v in p.items()}
+
+
+def test_gated_dense_layer(seeded):
+    p = seeded["weights"]["l0_mlp"]
+    y = _apply(L.GatedDenseLayer(n_out=64, hidden=96), p, _u())
+    _close(y[0], ref.blocks(CFG)["mlp"](p, _u()[0]))
+
+
+def _moe(held, **over):
+    return L.MixtureOfExpertsLayer(
+        n_out=64, n_experts=8, hidden=48, top_k=2, scoring="sigmoid",
+        expert_bias=True, gated=True, residual=False,
+        activation="identity", experts_held=held, **over)
+
+
+def test_expert_layer_gives_the_held_experts_part(seeded):
+    p, bias = seeded["weights"]["l3_moe"], seeded["bias"]["l3_moe"]
+    layer = _moe((1, 2, 5, 6))
+    state = {"moe_aux_loss": jnp.zeros(()), "expert_bias": bias,
+             "moe_expert_counts": jnp.zeros((8,), jnp.int32)}
+    y, new_state, _ = layer.forward(p, state, _u(), train=True, rng=None)
+    experts = ref.blocks(CFG)["experts"]
+    for s in range(2):
+        _close(y[s], experts(p, bias, _u()[s]))
+    assert int(new_state["moe_expert_counts"].sum()) == 2 * 32 * 2
+    np.testing.assert_array_equal(np.asarray(new_state["expert_bias"]),
+                                  np.asarray(bias))
+
+
+def test_embedding_layer_takes_id_sequences():
+    layer = L.EmbeddingLayer(n_in=11, n_out=5, bias=False,
+                             activation="identity")
+    p, _, out = layer.initialize(jax.random.PRNGKey(0),
+                                 InputType.recurrent(11, 7))
+    assert set(p) == {"W"} and out.kind == "rnn" and out.timesteps == 7
+    ids = jnp.asarray([[0, 3, 10], [2, 2, 9]], jnp.int32)
+    y = _apply(layer, p, ids)
+    assert y.shape == (2, 3, 5)
+    np.testing.assert_array_equal(np.asarray(y[1, 2]), np.asarray(p["W"][9]))
+    # one index a row still gives one row an example
+    assert _apply(layer, p, ids[:, :1]).shape == (2, 5)
+
+
+# --- the share: expert parallelism's four parts make the whole --------------
+@pytest.fixture(scope="module")
+def whole_layer():
+    key = jax.random.PRNGKey(5)
+    layer = _moe(None)
+    p, s, _ = layer.initialize(key, InputType.recurrent(64, 32))
+    # an uneven bias: experts 0 and 7 are favoured, 3 is nearly shut out
+    s["expert_bias"] = jnp.asarray([0.3, 0, 0, -0.5, 0, 0.05, 0, 0.2])
+    return layer, p, s
+
+
+def test_the_four_shares_add_up_to_the_uncut_layer(whole_layer):
+    layer, p, s = whole_layer
+    x = _u(7)
+    whole, st, _ = layer.forward(p, s, x, train=True, rng=None)
+    total = 0.0
+    counted = 0
+    for share in range(4):
+        held = (2 * share, 2 * share + 1)
+        part, st_part, _ = _moe(held).forward(
+            {"Wg": p["Wg"], **{k: p[k][jnp.asarray(held)] for k in ("W1", "W2", "W3")}},
+            s, x, train=True, rng=None)
+        total = total + part
+        # every share routes over all eight and counts them alike
+        np.testing.assert_array_equal(np.asarray(st_part["moe_expert_counts"]),
+                                      np.asarray(st["moe_expert_counts"]))
+        counted += int(st_part["moe_expert_counts"][jnp.asarray(held)].sum())
+    _close(total, whole)
+    assert counted == 2 * 32 * 2            # each assignment held once
+    assert float(jnp.abs(whole).max()) > 0.1
+    counts = np.asarray(st["moe_expert_counts"])
+    assert counts[0] > counts[3]            # the bias steers the selection
+
+
+def test_no_token_is_dropped_when_one_expert_is_sent_every_token(whole_layer):
+    layer, p, s = whole_layer
+    x = _u(9)
+    s = {**s, "expert_bias": jnp.zeros((8,)).at[4].set(100.0)}
+    y, st, _ = layer.forward(p, s, x, train=True, rng=None)
+    counts = np.asarray(st["moe_expert_counts"])
+    assert counts[4] == 2 * 32 and counts.sum() == 2 * 32 * 2
+    # expert 4's own part, token by token, with nothing left out: hold it
+    # alone and compare with its dense product under the same weights
+    alone, _, _ = _moe((4,)).forward(
+        {"Wg": p["Wg"], **{k: p[k][4:5] for k in ("W1", "W2", "W3")}},
+        s, x, train=True, rng=None)
+    tokens = x.reshape(-1, 64)
+    scores = jax.nn.sigmoid(tokens @ p["Wg"])
+    _, sel = jax.lax.top_k(scores + s["expert_bias"], 2)
+    w = jnp.take_along_axis(scores, sel, axis=1)
+    w = w / (w.sum(-1, keepdims=True) + 1e-6)
+    w4 = jnp.sum(jnp.where(sel == 4, w, 0.0), axis=-1)
+    dense = (jax.nn.silu(tokens @ p["W1"][4]) * (tokens @ p["W3"][4])) @ p["W2"][4]
+    assert float(w4.min()) > 0                      # every token is sent
+    _close(alone.reshape(-1, 64), w4[:, None] * dense)
+    assert float(jnp.abs(y - alone).max()) > 0      # and its second expert
+
+
+def test_routing_changes_no_shape_and_retraces_nothing(whole_layer):
+    layer, p, s = whole_layer
+    fn = jax.jit(lambda s, x: layer.forward(p, s, x, train=True, rng=None)[0])
+    fn(s, _u(1))
+    fn({**s, "expert_bias": jnp.zeros((8,)).at[2].set(100.0)}, _u(2))
+    assert fn._cache_size() == 1
+
+
+def test_padding_claims_no_expert(whole_layer):
+    layer, p, s = whole_layer
+    mask = jnp.ones((2, 32)).at[1, 16:].set(0.0)
+    y, st, _ = layer.forward(p, s, _u(4), train=True, rng=None, mask=mask)
+    assert int(st["moe_expert_counts"].sum()) == (32 + 16) * 2
+    assert float(jnp.abs(y[1, 16:]).max()) == 0.0
+
+
+@pytest.mark.parametrize("held", [(), (3, 1), (0, 0), (8,)])
+def test_experts_held_must_be_distinct_ascending_ids(held):
+    with pytest.raises(ValueError):
+        _moe(held).initialize(jax.random.PRNGKey(0),
+                              InputType.recurrent(64, 32))
+
+
+def test_the_top_1_capacity_path_is_as_it_was():
+    layer = L.MixtureOfExpertsLayer(n_out=16, n_experts=4,
+                                    activation="identity")
+    p, s, _ = layer.initialize(jax.random.PRNGKey(0),
+                               InputType.recurrent(16, 8))
+    assert set(p) == {"Wg", "W1", "b1", "W2", "b2"} and set(s) == {"moe_aux_loss"}
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, 16))
+    y, ns, _ = layer.forward(p, s, x, train=True, rng=None)
+    # the block adds its input: a token over capacity passes unchanged
+    full = dataclasses.replace(layer, capacity_factor=1e-9)   # C = 1
+    y1, _, _ = full.forward(p, s, x, train=True, rng=None)
+    moved = np.abs(np.asarray(y1 - x)).reshape(16, 16).max(axis=1) > 0
+    assert 1 <= moved.sum() <= 4 < (np.asarray(y) != np.asarray(x)).any(-1).sum()
+    assert float(ns["moe_aux_loss"]) > 0
+
+
+# --- attention: the flash tier against the dense core, grouped heads --------
+def test_flash_path_agrees_with_dense_attention_on_grouped_heads(monkeypatch):
+    layer = L.SelfAttentionLayer(
+        n_out=128, n_heads=4, n_kv_heads=2, causal=True, rotary_theta=1e4,
+        qk_norm=True, bias=False, activation="identity", weight_init="normal")
+    p, _, _ = layer.initialize(jax.random.PRNGKey(2),
+                               InputType.recurrent(128, 128))
+    x = jax.random.normal(jax.random.PRNGKey(3), (1, 128, 128), jnp.float32)
+
+    def run():
+        helpers.reset_validation()
+        pk._disabled.clear()
+        return jax.value_and_grad(
+            lambda p: jnp.sum(jnp.square(_apply(layer, p, x))))(p)
+
+    monkeypatch.setenv("DL4J_PALLAS_FLASH", "0")
+    dense, dense_g = run()
+    monkeypatch.setenv("DL4J_PALLAS_FLASH", "1")     # interpret mode here
+    flash, flash_g = run()
+    helpers.reset_validation()
+    # the kernel's online softmax sums in blocks of 128: round-off of a
+    # float32 softmax over 128 keys, through a sum of squares
+    assert float(flash) == pytest.approx(float(dense), rel=1e-4)
+    for k in dense_g:
+        _close(flash_g[k], dense_g[k], rtol=1e-3)
+
+
+def test_rotary_attention_refuses_the_carried_decode_step():
+    from deeplearning4j_tpu.parallel import sequence as seq_ops
+    layer = L.SelfAttentionLayer(n_out=16, n_heads=2, rotary_theta=1e4,
+                                 causal=True, activation="identity")
+    p, _, _ = layer.initialize(jax.random.PRNGKey(0),
+                               InputType.recurrent(16, 8))
+    with seq_ops.kv_decode_scope(True), pytest.raises(NotImplementedError):
+        layer.forward(p, {}, jnp.zeros((1, 1, 16)), train=False, rng=None)
+
+
+# --- integer labels against one-hot ones ------------------------------------
+def _labels(n=6, t=5, v=160, seed=0):
+    rng = np.random.default_rng(seed)
+    z = jnp.asarray(rng.normal(size=(n, t, v)), jnp.float32)
+    ids = jnp.asarray(rng.integers(0, v, (n, t)), jnp.int32)
+    return z, ids, jax.nn.one_hot(ids, v, dtype=jnp.float32)
+
+
+@pytest.mark.parametrize("fused", ["0", "1"], ids=["dense", "fused-kernel"])
+@pytest.mark.parametrize("masked", [False, True], ids=["whole", "masked"])
+def test_integer_labels_score_as_one_hot_ones(monkeypatch, fused, masked):
+    monkeypatch.setenv("DL4J_FUSED_XENT", fused)     # "1": interpret mode
+    z, ids, onehot = _labels()
+    mask = (jnp.ones(ids.shape).at[2, 3:].set(0.0)[..., None]
+            if masked else None)
+    want, want_g = jax.value_and_grad(
+        lambda z: jnp.sum(losses.mcxent(onehot, z, "softmax", mask)))(z)
+    got, got_g = jax.value_and_grad(
+        lambda z: jnp.sum(losses.mcxent(ids, z, "softmax", mask)))(z)
+    # the same sums in the same order but for the kernel's row blocks
+    assert float(got) == pytest.approx(float(want), rel=1e-6)
+    _close(got_g, want_g, rtol=1e-5)
+
+
+@pytest.mark.parametrize("fused", ["0", "1"], ids=["dense", "fused-kernel"])
+def test_an_id_outside_the_classes_is_a_row_without_a_label(monkeypatch, fused):
+    monkeypatch.setenv("DL4J_FUSED_XENT", fused)
+    z, ids, onehot = _labels()
+    ids = ids.at[0, 0].set(-1).at[1, 1].set(160)
+    onehot = onehot.at[0, 0].set(0.0).at[1, 1].set(0.0)
+    want, want_g = jax.value_and_grad(
+        lambda z: jnp.sum(losses.mcxent(onehot, z, "softmax", None)))(z)
+    got, got_g = jax.value_and_grad(
+        lambda z: jnp.sum(losses.mcxent(ids, z, "softmax", None)))(z)
+    assert float(got) == pytest.approx(float(want), rel=1e-6)
+    _close(got_g, want_g, rtol=1e-5)
+    assert float(jnp.abs(got_g[0, 0]).max()) == 0.0
+
+
+def test_integer_labels_under_another_activation():
+    z, ids, onehot = _labels(v=7)
+    _close(losses.mcxent(ids, z, "sigmoid", None),
+           losses.mcxent(onehot, z, "sigmoid", None), rtol=1e-6)
+
+
+@pytest.mark.parametrize("reduction,scale", [("sum", 5.0), ("mean", 1.0)])
+def test_rnn_output_layer_scores_ids_and_reduces_over_time(reduction, scale):
+    z, ids, onehot = _labels()
+    layer = L.RnnOutputLayer(n_out=160, activation="softmax", loss="mcxent",
+                             time_reduction=reduction)
+    per_ex = layer.compute_score(ids, z)
+    _close(per_ex, layer.compute_score(onehot, z), rtol=1e-6)
+    per_token = losses.mcxent(ids.reshape(-1), z.reshape(-1, 160))
+    assert float(jnp.mean(per_ex)) == pytest.approx(
+        scale * float(jnp.mean(per_token)), rel=1e-6)
+    mask = jnp.ones((6, 5)).at[0, 2:].set(0.0)
+    masked = layer.compute_score(ids, z, mask)
+    assert float(masked[0]) == pytest.approx(
+        float(per_token[:2].sum()) / (2.0 if reduction == "mean" else 1.0),
+        rel=1e-6)
+    with pytest.raises(ValueError):
+        dataclasses.replace(layer, time_reduction="max").compute_score(ids, z)
+
+
+@pytest.mark.parametrize("layer,leaves", [
+    (L.DenseLayer(n_out=4, bias=False, activation="identity"), {"W"}),
+    (L.OutputLayer(n_out=4, bias=False), {"W"}),
+    (L.RnnOutputLayer(n_out=4, bias=False), {"W"}),
+    (L.DenseLayer(n_out=4, activation="identity"), {"W", "b"}),
+])
+def test_layers_without_a_bias_have_no_bias_leaf(layer, leaves):
+    t = InputType.recurrent(3, 2) if isinstance(layer, L.RnnOutputLayer) \
+        else InputType.feed_forward(3)
+    p, _, _ = layer.initialize(jax.random.PRNGKey(0), t)
+    assert set(p) == leaves
+    x = jnp.ones((2, 2, 3) if t.kind == "rnn" else (2, 3))
+    want = x @ p["W"] + (p["b"] if "b" in p else 0.0)
+    _close(layer.preoutput(p, x) if hasattr(layer, "preoutput")
+           else _apply(layer, p, x), want)
+
+
+# --- f64 numeric gradient checks (nn/gradientcheck.py) ----------------------
+def _one_layer_graph(layer, n_in=8, t=6, classes=5):
+    g = GlobalConf(seed=3, learning_rate=0.1, updater="sgd",
+                   activation="identity", weight_init="xavier")
+    b = (GraphBuilder(g).add_inputs("in")
+         .add_layer("layer", layer, "in")
+         .add_layer("out", L.RnnOutputLayer(n_out=classes, activation="softmax",
+                                            loss="mcxent"), "layer"))
+    net = ComputationGraph(b.set_outputs("out").set_input_types(
+        InputType.recurrent(n_in, t)).build())
+    net.init()
+    rng = np.random.default_rng(0)
+    x = rng.normal(size=(3, t, n_in))
+    ids = rng.integers(0, classes, (3, t)).astype(np.int32)
+    return net, x, ids
+
+
+@pytest.mark.parametrize("layer", [
+    L.SelfAttentionLayer(n_out=8, n_heads=4, n_kv_heads=2, causal=True,
+                         rotary_theta=1e4, qk_norm=True, bias=False),
+    L.GatedShortConvLayer(n_out=8, kernel=3),
+    L.MixtureOfExpertsLayer(n_out=8, n_experts=6, hidden=5, top_k=2,
+                            scoring="sigmoid", expert_bias=True, gated=True,
+                            experts_held=(0, 2, 3, 5), residual=False),
+    L.MixtureOfExpertsLayer(n_out=8, n_experts=4, hidden=5, top_k=2,
+                            scoring="softmax", gated=False),
+    L.GatedDenseLayer(n_out=8, hidden=7),
+    L.RMSNormLayer(),
+], ids=["attention-gqa-rotary-qknorm", "gated-short-conv", "experts-held-4-of-6",
+        "experts-softmax-gelu", "gated-dense", "rms-norm"])
+def test_numeric_gradients_in_float64(layer):
+    from deeplearning4j_tpu.nn.gradientcheck import (
+        check_computation_graph_gradients)
+    net, x, ids = _one_layer_graph(layer)
+    # integer class ids as labels: the check keeps them integers
+    assert check_computation_graph_gradients(net, [x], [ids], subset=48,
+                                             print_results=False)
+
+
+# --- the device trace's scopes -----------------------------------------------
+@pytest.mark.parametrize("op_name,want", [
+    ("jit(cg_train_step)/jvp(fwd/MixtureOfExpertsLayer/l2_moe)/experts/"
+     "ragged_dot_general", "fwd/MixtureOfExpertsLayer/experts"),
+    ("jit(cg_train_step)/transpose(jvp(fwd/MixtureOfExpertsLayer/l2_moe))/"
+     "dispatch/scatter-add", "bwd/MixtureOfExpertsLayer/dispatch"),
+    ("jit(cg_train_step)/jvp(fwd/MixtureOfExpertsLayer/l2_moe)/route/"
+     "jit(top_k)/top_k", "fwd/MixtureOfExpertsLayer/route"),
+    ("ragged-dot-none.7", "kernel/MixtureOfExpertsLayer/experts"),
+    ("jit(cg_train_step)/jvp(fwd/DenseLayer/fc)/dot_general", None),
+    ("jit(cg_train_step)/jvp(fwd/MixtureOfExpertsLayer/l2_moe)/eq", None),
+    ("jit(cg_train_step)/update/mul", None),
+])
+def test_profile_splits_a_layer_by_the_parts_it_names(op_name, want):
+    from deeplearning4j_tpu.monitor import profile
+    assert profile.sub_scope(op_name) == want
+    if want:
+        direction, kind, _ = profile.classify(op_name)
+        assert f"{direction}/{kind}" == want.rsplit("/", 1)[0]
+
+
+def test_the_parts_and_kernels_come_from_the_layer_classes():
+    from deeplearning4j_tpu.monitor import profile
+    parts, kernels = profile.layer_tables()
+    assert parts == {"MixtureOfExpertsLayer": frozenset(
+        L.MixtureOfExpertsLayer.scope_parts)}
+    assert kernels == {"ragged-dot": ("MixtureOfExpertsLayer", "experts")}
+
+    class Another(L.Layer):
+        scope_kernels = {"ragged-dot": "products"}
+    L.LAYER_REGISTRY["Another"] = Another
+    try:
+        with pytest.raises(ValueError, match="claimed by"):
+            profile.layer_tables()
+    finally:
+        del L.LAYER_REGISTRY["Another"]
+    # a part is a part only of the layer that names it
+    assert profile.sub_scope(
+        "jit(s)/jvp(fwd/DenseLayer/fc)/experts/dot_general") is None
+
+
+def test_the_step_names_the_expert_layers_parts():
+    import re
+    from deeplearning4j_tpu.monitor import profile
+    net = _net(dict(CFG, layers_run=[0, 3]))
+    net.init()
+    ids = jnp.zeros((2, 32), jnp.int32)
+    hlo = jax.jit(net._build_step_raw()).lower(
+        net.net_params, net.net_state, net.opt_states, (ids,), (ids,), None,
+        None, jnp.int32(0), jax.random.PRNGKey(0)).as_text(debug_info=True)
+    seen = {profile.sub_scope(n) for n in re.findall(r'"(jit\([^"]+)"', hlo)}
+    assert {f"{d}/MixtureOfExpertsLayer/{p}" for d in ("fwd", "bwd")
+            for p in ("route", "dispatch", "experts", "combine")} <= seen

@@ -835,14 +835,17 @@ def test_profile_reads_an_xplane_file_by_hand(tmp_path):
     line = (_pb(2, "XLA Ops") + _pb(3, 1000)
             + _pb(4, _pb(1, 7) + _pb(2, 5_000_000) + _pb(3, 2_000_000))
             + _pb(4, _pb(1, 8) + _pb(2, 9_000_000) + _pb(3, 1_000_000))
-            + _pb(4, _pb(1, 9) + _pb(3, 500_000)))
+            + _pb(4, _pb(1, 9) + _pb(3, 500_000))
+            + _pb(4, _pb(1, 10) + _pb(2, 12_000_000) + _pb(3, 250_000)))
     plane = (_pb(2, "/device:TPU:0") + _pb(3, line)
              + stat_meta(1, "flops") + stat_meta(2, "tf_op")
              + stat_meta(3, op_name)
              + event_meta(7, "%fusion.7 = ...", _pb(1, 1) + _pb(3, 64),
                           _pb(1, 2) + _pb(5, op_name))
              + event_meta(8, "%fusion.8 = ...", _pb(1, 2) + _pb(7, 3))
-             + event_meta(9, "%copy.9 = ..."))
+             + event_meta(9, "%copy.9 = ...")
+             + event_meta(10, "%ragged-dot-none.2 = bf16[8,4]{1,0} custom-call(...)",
+                          _pb(1, 2) + _pb(5, "ragged-dot-none")))
     path = tmp_path / "t.xplane.pb"
     path.write_bytes(_pb(1, plane) + _pb(1, _pb(2, "/host:CPU")))
     planes = profile.load(str(tmp_path))
@@ -850,10 +853,20 @@ def test_profile_reads_an_xplane_file_by_hand(tmp_path):
     assert planes[0][1] == [("XLA Ops", [
         (6000.0, 2000.0, "%fusion.7 = ...", op_name),
         (10000.0, 1000.0, "%fusion.8 = ...", op_name),
-        (1000.0, 500.0, "%copy.9 = ...", "")])]
+        (1000.0, 500.0, "%copy.9 = ...", ""),
+        (13000.0, 250.0,
+         "%ragged-dot-none.2 = bf16[8,4]{1,0} custom-call(...)",
+         "ragged-dot-none")])]
+    # a kernel the compiler named itself goes by its instruction's name,
+    # which gives it back to the layer that emits it
     assert profile.device_events(planes) == {0: [
         (6000.0, 2000.0, op_name), (10000.0, 1000.0, op_name),
-        (1000.0, 500.0, "")]}
+        (1000.0, 500.0, ""), (13000.0, 250.0, "ragged-dot-none.2")]}
+    chip = profile.summarize(planes)["chips"]["0"]
+    assert chip["device_s"]["kernel"] == {
+        "MixtureOfExpertsLayer": pytest.approx(250e-9)}
+    assert chip["sub_scope_s"] == {
+        "kernel/MixtureOfExpertsLayer/experts": pytest.approx(250e-9)}
     with pytest.raises(FileNotFoundError):
         profile.load(str(tmp_path / "nothing_here"))
 

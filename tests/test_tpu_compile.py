@@ -112,6 +112,34 @@ def test_softmax_xent_compiles(compile_for_chip, n, v, dtype):
     assert "tpu_custom_call" in hlo
 
 
+@pytest.mark.parametrize("n,v,dtype", [
+    (256, 512, jnp.float32),
+    (8192, 16384, jnp.float32),
+], ids=["selftest", "lm-head-8192x16384-f32"])
+def test_softmax_xent_on_class_ids_compiles(compile_for_chip, n, v, dtype):
+    def step(logits, ids):
+        return jax.value_and_grad(
+            lambda lg: pk.softmax_xent_rows(lg, ids).mean())(logits)
+    hlo = compile_for_chip(step, ((n, v), dtype), ((n,), jnp.int32))
+    assert "tpu_custom_call" in hlo
+
+
+def test_grouped_expert_products_compile_to_a_kernel(compile_for_chip):
+    """The expert layer's grouped products (``jax.lax.ragged_dot``, 8
+    held experts of 2048 x 1792 over 8192 x 4 sorted rows, forward and
+    both gradients): the chip's compiler takes each as its own grouped
+    matmul kernel and not as one dense product a group."""
+    def step(rows, w, sizes):
+        return jax.value_and_grad(
+            lambda r, w: _sq(jax.lax.ragged_dot(r, w, sizes)),
+            argnums=(0, 1))(rows, w)
+    bf16 = jnp.bfloat16
+    hlo = compile_for_chip(step, ((32768, 2048), bf16),
+                           ((8, 2048, 1792), bf16), ((8,), jnp.int32))
+    assert hlo.count('custom_call_target="tpu_custom_call"') >= 3
+    assert "ragged-dot" in hlo
+
+
 @pytest.mark.parametrize("shape", [(64, 128), (128, 4096)],
                          ids=["selftest", "fc-128x4096"])
 def test_threshold_dropout_compiles(compile_for_chip, shape):
