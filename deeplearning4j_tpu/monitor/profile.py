@@ -17,8 +17,13 @@ the ``fit()`` returns.  Per chip it holds
   forward's name), ``top_scopes``, the ten longest scopes, and
   ``sub_scope_s``, the seconds of the parts a layer names inside its
   own scope (``<direction>/<LayerType>/<part>``), over all vertices of
-  that type.  An operation the compiler expands into kernels of its own
-  name loses its scope (XLA's grouped matrix product, ``ragged-dot-*``).
+  that type.  A part's scope stands outside the control flow the part
+  contains (``.../experts/while/body/...`` reads ``experts``); the
+  ``while``, ``conditional`` or ``call`` event itself spans the events
+  of the instructions it runs, which are on the same line, so it counts
+  as busy time and is left out of every sum.  An operation the compiler
+  expands into kernels of its own name loses its scope (XLA's grouped
+  matrix product, ``ragged-dot-*``).
   The layer classes declare both, their parts and the kernels they
   claim for a part (:func:`layer_tables`); a claimed kernel reads under
   the direction ``kernel``, since forward and backward cannot be told
@@ -68,6 +73,10 @@ SCOPE_STATS = ("tf_op",)
 LAYER_SCOPE = re.compile(r"fwd/(\w+)/([^/()]+)\)*(?:/(\w+))?")
 UPDATE_SCOPE = re.compile(r"(?:^|[/(])update(?:[/)]|$)")
 LOSS_SCOPE = re.compile(r"(?:^|[/(])loss(?:[/)]|$)")
+#: an instruction that runs other instructions: its event spans theirs
+CONTAINER = re.compile(r"\s(?:while|conditional|call)\(")
+#: the op_name such an event goes by: counted as busy, summed nowhere
+SPANS_OTHERS = "<spans others>"
 OUTSIDE = "outside_fit"
 #: the phase in which the host waits for the device
 WAIT = "block_until_ready"
@@ -323,13 +332,21 @@ def device_events(planes, tables: Optional[Tables] = None
             continue
         for line_name, events in lines:
             if line_name == OPS_LINE:
-                # a kernel the compiler named itself goes by the name of
-                # its instruction (the event's name is the HLO's text)
                 out[int(m.group(1))] = [
-                    (s, d, ins if _claimed(ins, kernels) else op)
-                    for s, d, ins, op in ((s, d, _instruction(name), op)
-                                          for s, d, name, op in events)]
+                    (s, d, _goes_by(text, op, kernels))
+                    for s, d, text, op in events]
     return out
+
+
+def _goes_by(text: str, op_name: str, kernels) -> str:
+    """What an event of the ops line is summed under: an instruction
+    that runs others under no scope at all, a kernel the compiler named
+    itself under its instruction's name (the event's name is the HLO's
+    text), anything else under its ``op_name``."""
+    if CONTAINER.search(text):
+        return SPANS_OTHERS
+    ins = _instruction(text)
+    return ins if _claimed(ins, kernels) else op_name
 
 
 def summarize(planes) -> dict:
@@ -351,7 +368,8 @@ def summarize(planes) -> dict:
         by_dir: Dict[str, Dict[str, float]] = {}
         by_scope: Dict[str, float] = {}
         by_sub: Dict[str, float] = {}
-        for _, d, op_name in evs:
+        timed = [(d, op) for _, d, op in evs if op != SPANS_OTHERS]
+        for d, op_name in timed:
             direction, kind, scope = classify(op_name, tables)
             by_kind = by_dir.setdefault(direction, {})
             by_kind[kind] = by_kind.get(kind, 0.0) + d / 1e9
@@ -364,7 +382,7 @@ def summarize(planes) -> dict:
         for gap in gaps:
             for name, ns in share_gap(gap, phases, starts).items():
                 idle[name] = idle.get(name, 0.0) + ns / 1e9
-        ops_s = sum(d for _, d, _ in evs) / 1e9
+        ops_s = sum(d for d, _ in timed) / 1e9
         ends = sorted(s + d for s, d, _ in evs)
         lags = []
         for s, e, name in phases:

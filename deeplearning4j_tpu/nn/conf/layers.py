@@ -30,6 +30,7 @@ from deeplearning4j_tpu.ops import initializers
 from deeplearning4j_tpu.ops import losses as loss_ops
 from deeplearning4j_tpu.ops import normalization as norm_ops
 from deeplearning4j_tpu.ops import recurrent as rnn_ops
+from deeplearning4j_tpu.ops import row_segments
 
 LAYER_REGISTRY: Dict[str, type] = {}
 
@@ -1003,12 +1004,25 @@ class MixtureOfExpertsLayer(Layer):
     with ``expert_bias``, a constant per-expert bias held in state, which
     steers the selection and not the weights) are selected, and their
     scores renormalised over the k (``norm_topk``).  The N·k assignments
-    are sorted by expert into rows of static shape, the group sizes are
-    data (no step retraces whatever the routing), and the expert
-    products are grouped matrix products over the rows
-    (``jax.lax.ragged_dot``).  Memory grows with N·k, not N·E·C.
-    ``gated`` experts are ``(silu(x W1) * (x W3)) W2``, plain ones
-    ``gelu(x W1) W2``; neither has a bias.
+    are sorted by held expert, the others behind them, into a buffer of
+    rows cut into segments of static shape (``segment_shape``: the
+    even-load share N·k·G/E of the G experts held, so the segment count
+    follows the share and nothing else; no argument sets it).  The group
+    sizes are data, and so is the number of segments that hold a row of
+    a held expert: those run, forward and backward, and the rest are
+    skipped on the device by a loop whose trip count it reads (no step
+    retraces whatever the routing, no token is dropped: with every
+    assignment on a held expert every segment runs).  Per segment the
+    rows are gathered, the expert products are grouped matrix products
+    over them (``jax.lax.ragged_dot`` with the global group sizes clipped
+    to the segment) and gated and masked; the weights' gradients are one
+    grouped product each over the whole buffer, and the way back is a
+    gather over every assignment, in both directions
+    (``ops/row_segments.py``).  A layer that holds every expert has one
+    segment: the same expressions once over the whole buffer, no loop.
+    Memory grows with N·k, not N·E·C.  ``gated`` experts are
+    ``(silu(x W1) * (x W3)) W2``, plain ones ``gelu(x W1) W2``; neither
+    has a bias.
 
     ``experts_held`` names the experts whose weights this layer holds
     (expert parallelism's share of the layer; None = all): the router
@@ -1017,7 +1031,9 @@ class MixtureOfExpertsLayer(Layer):
     own experts give.  Nothing stands in for the others.  The per-expert
     assignment counts of the step are left in state under
     "moe_expert_counts" (published by the fit loop as
-    ``dl4j_moe_assignments_total`` / ``dl4j_moe_expert_load_max_over_mean``).
+    ``dl4j_moe_assignments_total`` / ``dl4j_moe_expert_load_max_over_mean``),
+    the segments run and skipped under "moe_row_segments"
+    (``dl4j_moe_row_segments_total``).
     ``residual=False`` returns the routed sum alone (a graph adds the
     residual with an ElementWiseVertex).  The four parts are named
     inside the layer's scope (``scope_parts``), and the grouped product's
@@ -1091,9 +1107,17 @@ class MixtureOfExpertsLayer(Layer):
             params["W3"] = self._winit(k3, (G, n_in, H), dtype, fan_in=n_in,
                                        fan_out=H)
         state["moe_expert_counts"] = jnp.zeros((E,), jnp.int32)
+        state["moe_row_segments"] = jnp.zeros((2,), jnp.int32)
         if self.expert_bias:
             state["expert_bias"] = 0.01 * jax.random.normal(kb, (E,), dtype)
         return params, state, input_type
+
+    def segment_shape(self, rows: int) -> Tuple[int, int]:
+        """(rows of a segment, segments) into which the top_k path cuts
+        its ``rows`` = tokens x k sorted assignments: from the share of
+        the experts held, nothing else (``ops/row_segments.py``)."""
+        return row_segments.segment_rows(rows, len(self._held()),
+                                         self.n_experts)
 
     def _forward_top_k(self, params, state, x, mask):
         """The routed sum of the top_k path, [.., n_out], and the new
@@ -1122,6 +1146,7 @@ class MixtureOfExpertsLayer(Layer):
                 w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
             if tok_mask is not None:
                 w = w * tok_mask[:, None]
+        seg, n_seg = self.segment_shape(N * k)
         with jax.named_scope("dispatch"):
             # the held experts' position in this layer's stacks; G = "not
             # here", which sorts last and belongs to no group
@@ -1131,42 +1156,42 @@ class MixtureOfExpertsLayer(Layer):
             if tok_mask is not None:    # padding claims no expert
                 local = jnp.where(tok_mask[:, None], local, G)
             flat = local.reshape(-1)                              # [N·k]
-            perm = jnp.argsort(flat, stable=True)
+            # whole segments: the filling sorts behind every row
+            fill = jnp.full((n_seg * seg - N * k,), G, flat.dtype)
+            perm = jnp.argsort(jnp.concatenate([flat, fill]) if fill.size
+                               else flat, stable=True)
             sizes = jnp.sum(flat[:, None] == jnp.arange(G + 1)[None, :],
                             axis=0, dtype=jnp.int32)              # [G + 1]
             group_sizes = sizes[:G]
-            valid = (jnp.arange(N * k) < jnp.sum(group_sizes))[:, None]
             # row r of the sorted order is assignment perm[r] = token
-            # perm[r] // k: a gather with unique indices from the k-fold
-            # repeat, so that its transpose is a scatter without
-            # collisions followed by a sum over k
-            rows = jnp.broadcast_to(tokens[:, None, :], (N, k, D)).reshape(
-                N * k, D).at[perm].get(unique_indices=True,
-                                       mode="promise_in_bounds")
-            # rows beyond the groups belong to other holders: the grouped
-            # products leave them undefined, in both directions
-            rows = jnp.where(valid, rows, 0)
+            # perm[r] // k, and back[n, j] the row of assignment (n, j).
+            # Rows beyond the groups belong to other holders: the grouped
+            # products leave them undefined, in both directions, so they
+            # are zeroed
+            held_rows = jnp.sum(group_sizes)
+            back = jnp.zeros((n_seg * seg,), jnp.int32).at[perm].set(
+                jnp.arange(n_seg * seg, dtype=jnp.int32),
+                unique_indices=True)[:N * k].reshape(N, k)
+            rows = row_segments.gather_rows(tokens, perm, back, held_rows,
+                                            seg, k)
         with jax.named_scope("experts"):
-            h = jax.lax.ragged_dot(rows, params["W1"], group_sizes)
-            if self.gated:
-                h = jax.nn.silu(h) * jax.lax.ragged_dot(
-                    rows, params["W3"], group_sizes)
-            else:
-                h = jax.nn.gelu(h)
-            y = jax.lax.ragged_dot(h, params["W2"], group_sizes)
-            y = jnp.where(valid, y, 0)
+            w_in = (params["W1"], params["W3"]) if self.gated \
+                else (params["W1"],)
+            y = row_segments.expert_products(
+                rows, w_in, params["W2"], group_sizes, seg,
+                row_segments.gated_silu if self.gated else jax.nn.gelu)
         with jax.named_scope("combine"):
-            back = jnp.zeros((N * k,), jnp.int32).at[perm].set(
-                jnp.arange(N * k, dtype=jnp.int32), unique_indices=True)
-            y = y.at[back].get(unique_indices=True,
-                               mode="promise_in_bounds").reshape(N, k, -1)
-            routed = jnp.einsum("nko,nk->no", y, w.astype(y.dtype))
+            routed = row_segments.weighted_sum(
+                y, w.astype(y.dtype), perm, back, held_rows, seg)
         counted = top_e if tok_mask is None \
             else jnp.where(tok_mask[:, None], top_e, E)
         counts = jnp.sum(counted.reshape(-1)[:, None]
                          == jnp.arange(E)[None, :], axis=0, dtype=jnp.int32)
         new_state = dict(state)
         new_state["moe_expert_counts"] = counts
+        run = row_segments.segments_run(held_rows, seg)
+        new_state["moe_row_segments"] = jnp.stack(
+            [run, n_seg - run]).astype(jnp.int32)
         return routed.reshape(shape[:-1] + (self.n_out,)), new_state
 
     def forward(self, params, state, x, *, train, rng, mask=None):

@@ -97,7 +97,10 @@ def publish_expert_load(net) -> None:
     into counters: ``dl4j_moe_assignments_total{vertex, held}`` (token x
     expert assignments, by whether this net holds the expert) and
     ``dl4j_moe_expert_load_max_over_mean{vertex}`` (the fullest expert's
-    assignments over the mean over all experts, this step).  The state
+    assignments over the mean over all experts, this step) and
+    ``dl4j_moe_row_segments_total{vertex, outcome}`` (the segments of its
+    sorted rows the layer ran and skipped, as the device counted them in
+    "moe_row_segments").  The state
     holds one step's counts, so a dispatch of ``fused_steps=k`` publishes
     its last step's and the counter reads a k-th of the routing.  A net
     without such a layer publishes nothing and pays one attribute
@@ -117,9 +120,17 @@ def publish_expert_load(net) -> None:
         "dl4j_moe_expert_load_max_over_mean",
         "fullest expert's assignments over the mean expert's, last step",
         labels=("vertex",))
-    counts = jax.device_get({k: net.net_state[k]["moe_expert_counts"]
+    segments = reg.counter(
+        "dl4j_moe_row_segments_total",
+        "segments of an expert layer's sorted rows, by whether the device "
+        "ran or skipped them, last step of each dispatch",
+        labels=("vertex", "outcome"))
+    counts = jax.device_get({k: (net.net_state[k]["moe_expert_counts"],
+                                 net.net_state[k]["moe_row_segments"])
                              for k in held})
-    for k, c in counts.items():
+    for k, (c, (run, skipped)) in counts.items():
+        segments.labels(vertex=str(k), outcome="run").inc(int(run))
+        segments.labels(vertex=str(k), outcome="skipped").inc(int(skipped))
         here = int(c[held[k]].sum())
         total.labels(vertex=str(k), held="1").inc(here)
         total.labels(vertex=str(k), held="0").inc(int(c.sum()) - here)

@@ -299,12 +299,38 @@ def whole_layer():
     return layer, p, s
 
 
-def test_the_four_shares_add_up_to_the_uncut_layer(whole_layer):
+def _segments_run(layer, st, rows):
+    """Segments the layer ran, by its own arithmetic on the held count."""
+    from deeplearning4j_tpu.ops import row_segments
+    held = int(np.asarray(st["moe_expert_counts"])[list(layer._held())].sum())
+    seg, n_seg = layer.segment_shape(rows)
+    run = row_segments.segments_run(held, seg)
+    # and the device counted the same
+    assert list(np.asarray(st["moe_row_segments"])) == [run, n_seg - run]
+    return run, n_seg
+
+
+# a share holds 2 of 8 experts: 4 segments of 32 of the 128 sorted rows.  The
+# uneven bias runs some of each share's; with every token on experts 0 and 1
+# the first share runs all of its own and the others their first alone
+ROUTINGS = {
+    "uneven-bias-some-segments": [0.3, 0, 0, -0.5, 0, 0.05, 0, 0.2],
+    "even-bias-some-segments": [0.0] * 8,
+    "two-experts-take-all-one-share-runs-every-segment":
+        [100.0, 50.0, 0, 0, 0, 0, 0, 0],
+}
+
+
+@pytest.mark.parametrize("bias", list(ROUTINGS.values()), ids=list(ROUTINGS))
+def test_the_four_shares_add_up_to_the_uncut_layer(whole_layer, bias):
     layer, p, s = whole_layer
+    s = {**s, "expert_bias": jnp.asarray(bias)}
     x = _u(7)
     whole, st, _ = layer.forward(p, s, x, train=True, rng=None)
+    assert layer.segment_shape(2 * 32 * 2) == (2 * 32 * 2, 1)
     total = 0.0
     counted = 0
+    ran = []
     for share in range(4):
         held = (2 * share, 2 * share + 1)
         part, st_part, _ = _moe(held).forward(
@@ -315,51 +341,263 @@ def test_the_four_shares_add_up_to_the_uncut_layer(whole_layer):
         np.testing.assert_array_equal(np.asarray(st_part["moe_expert_counts"]),
                                       np.asarray(st["moe_expert_counts"]))
         counted += int(st_part["moe_expert_counts"][jnp.asarray(held)].sum())
+        ran.append(_segments_run(_moe(held), st_part, 2 * 32 * 2))
     _close(total, whole)
     assert counted == 2 * 32 * 2            # each assignment held once
     assert float(jnp.abs(whole).max()) > 0.1
-    counts = np.asarray(st["moe_expert_counts"])
-    assert counts[0] > counts[3]            # the bias steers the selection
+    assert all(n_seg == 4 for _, n_seg in ran)
+    if bias[0] == 100.0:
+        assert [run for run, _ in ran] == [4, 1, 1, 1]
+    else:
+        assert all(run < 4 for run, _ in ran) and max(ran)[0] > 1
+    if bias[3] < 0:
+        counts = np.asarray(st["moe_expert_counts"])
+        assert counts[0] > counts[3]        # the bias steers the selection
 
 
-def test_no_token_is_dropped_when_one_expert_is_sent_every_token(whole_layer):
-    layer, p, s = whole_layer
-    x = _u(9)
-    s = {**s, "expert_bias": jnp.zeros((8,)).at[4].set(100.0)}
-    y, st, _ = layer.forward(p, s, x, train=True, rng=None)
-    counts = np.asarray(st["moe_expert_counts"])
-    assert counts[4] == 2 * 32 and counts.sum() == 2 * 32 * 2
-    # expert 4's own part, token by token, with nothing left out: hold it
-    # alone and compare with its dense product under the same weights
-    alone, _, _ = _moe((4,)).forward(
-        {"Wg": p["Wg"], **{k: p[k][4:5] for k in ("W1", "W2", "W3")}},
-        s, x, train=True, rng=None)
+def _dense_part(p, s, x, held):
+    """The held experts' part of the routed sum by dense products, token
+    by token with nothing left out, and each token's weight on them."""
     tokens = x.reshape(-1, 64)
     scores = jax.nn.sigmoid(tokens @ p["Wg"])
     _, sel = jax.lax.top_k(scores + s["expert_bias"], 2)
     w = jnp.take_along_axis(scores, sel, axis=1)
     w = w / (w.sum(-1, keepdims=True) + 1e-6)
-    w4 = jnp.sum(jnp.where(sel == 4, w, 0.0), axis=-1)
-    dense = (jax.nn.silu(tokens @ p["W1"][4]) * (tokens @ p["W3"][4])) @ p["W2"][4]
-    assert float(w4.min()) > 0                      # every token is sent
-    _close(alone.reshape(-1, 64), w4[:, None] * dense)
-    assert float(jnp.abs(y - alone).max()) > 0      # and its second expert
+    total, weights = 0.0, []
+    for e in held:
+        w_e = jnp.sum(jnp.where(sel == e, w, 0.0), axis=-1)
+        dense = (jax.nn.silu(tokens @ p["W1"][e]) * (tokens @ p["W3"][e])) @ p["W2"][e]
+        total = total + w_e[:, None] * dense
+        weights.append(w_e)
+    return total, weights
 
 
-def test_routing_changes_no_shape_and_retraces_nothing(whole_layer):
+@pytest.mark.parametrize("held,second,run", [
+    ((4,), 0.0, 4),         # expert 4 alone: 64 of 128 rows, 4 of 8 segments of 16
+    ((4, 5), 50.0, 4),      # every assignment on a held expert: all 4 of 32 run
+    ((3, 4), 0.0, None),    # the second choices of some tokens beside it
+    ((0,), 0.0, 1),         # an expert few tokens choose: the first alone
+], ids=["held-alone-half-the-segments", "every-assignment-held-all-segments",
+        "held-with-a-neighbour-some-segments", "another-held-one-segment"])
+def test_no_token_is_dropped_when_one_expert_is_sent_every_token(
+        whole_layer, held, second, run):
     layer, p, s = whole_layer
-    fn = jax.jit(lambda s, x: layer.forward(p, s, x, train=True, rng=None)[0])
-    fn(s, _u(1))
-    fn({**s, "expert_bias": jnp.zeros((8,)).at[2].set(100.0)}, _u(2))
+    x = _u(9)
+    s = {**s, "expert_bias": jnp.zeros((8,)).at[4].set(100.0).at[5].set(second)}
+    y, st, _ = layer.forward(p, s, x, train=True, rng=None)
+    counts = np.asarray(st["moe_expert_counts"])
+    assert counts[4] == 2 * 32 and counts.sum() == 2 * 32 * 2
+    # the held experts' own part, token by token, with nothing left out:
+    # hold them alone and compare with their dense products under the
+    # same weights
+    part = _moe(held)
+    alone, st_part, _ = part.forward(
+        {"Wg": p["Wg"], **{k: p[k][jnp.asarray(held)] for k in ("W1", "W2", "W3")}},
+        s, x, train=True, rng=None)
+    dense, weights = _dense_part(p, s, x, held)
+    if 4 in held:
+        assert float(weights[held.index(4)].min()) > 0      # every token is sent
+    _close(alone.reshape(-1, 64), dense)
+    ran, n_seg = _segments_run(part, st_part, 2 * 32 * 2)
+    assert n_seg > 1 and (ran == run if run else n_seg // 2 < ran < n_seg)
+    if len(held) < 2 or second == 0.0:
+        assert float(jnp.abs(y - alone).max()) > 0      # and its second expert
+
+
+@pytest.mark.parametrize("held", [None, (4,), (0, 1, 2, 3, 4, 5)],
+                         ids=["whole", "held-1-of-8", "held-6-of-8"])
+def test_routing_changes_no_shape_and_retraces_nothing(whole_layer, held):
+    whole, p, s = whole_layer
+    layer = _moe(held)
+    if held:
+        p = {"Wg": p["Wg"], **{k: p[k][jnp.asarray(held)] for k in ("W1", "W2", "W3")}}
+
+    @jax.jit
+    def fn(s, x):
+        def loss(p, x):
+            y, st, _ = layer.forward(p, s, x, train=True, rng=None)
+            return jnp.sum(y), st
+        (_, st), g = jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(p, x)
+        return st, g
+
+    ran = set()
+    # loads on both sides of a segment's edge, and every segment
+    for bias in (s["expert_bias"], jnp.zeros((8,)).at[2].set(100.0),
+                 jnp.zeros((8,)).at[4].set(100.0),
+                 jnp.zeros((8,)).at[4].set(100.0).at[3].set(50.0)):
+        st, g = fn({**s, "expert_bias": bias}, _u(len(ran) + 1))
+        assert all(bool(jnp.isfinite(v).all()) for v in jax.tree_util.tree_leaves(g))
+        ran.add(_segments_run(layer, st, 2 * 32 * 2)[0])
     assert fn._cache_size() == 1
+    assert len(ran) > 1 if held else ran == {1}
 
 
-def test_padding_claims_no_expert(whole_layer):
-    layer, p, s = whole_layer
+@pytest.mark.parametrize("held", [None, (0, 1), (4, 5, 6, 7)],
+                         ids=["whole", "held-2-of-8", "held-4-of-8"])
+def test_padding_claims_no_expert(whole_layer, held):
+    _, whole_p, s = whole_layer
+    layer, p = _moe(held), whole_p
+    if held:
+        p = {"Wg": p["Wg"], **{k: p[k][jnp.asarray(held)] for k in ("W1", "W2", "W3")}}
     mask = jnp.ones((2, 32)).at[1, 16:].set(0.0)
     y, st, _ = layer.forward(p, s, _u(4), train=True, rng=None, mask=mask)
     assert int(st["moe_expert_counts"].sum()) == (32 + 16) * 2
     assert float(jnp.abs(y[1, 16:]).max()) == 0.0
+    assert float(jnp.abs(y[1, :16]).max()) > 0.0
+    # the padding's rows sort behind every held row, into segments that
+    # do not run or rows that are zeroed: the rest is as without it
+    want, _ = _dense_part(whole_p, s, _u(4), held or tuple(range(8)))
+    _close(y.reshape(-1, 64)[:48], want[:48])
+
+
+# --- the segments: the same numbers as the uncut buffers, at every edge ------
+def _unsegmented(monkeypatch):
+    from deeplearning4j_tpu.ops import row_segments
+    monkeypatch.setattr(row_segments, "segment_rows",
+                        lambda rows, held, experts: (rows, 1))
+
+
+def _out_and_grads(layer, p, s, x, mask):
+    def loss(p, x):
+        y, st, _ = layer.forward(p, s, x, train=True, rng=None, mask=mask)
+        # a cotangent that differs from row to row
+        return jnp.sum(y * jnp.cos(jnp.arange(y.size).reshape(y.shape))), (y, st)
+    (_, (y, st)), (gp, gx) = jax.value_and_grad(
+        loss, argnums=(0, 1), has_aux=True)(p, x)
+    return y, st, {**gp, "x": gx}
+
+
+@pytest.mark.parametrize("edge", [1, 2], ids=lambda e: f"edge{e}")
+@pytest.mark.parametrize("off", [-1, 0, 1], ids=["one-under", "at", "one-over"])
+def test_segments_agree_with_the_uncut_buffers_at_an_edge(
+        whole_layer, monkeypatch, edge, off):
+    """Held experts 4 and 6 of 8: segments of 32 of the 128 sorted
+    rows.  Every token is sent to expert 4 and to its best other, so a
+    token holds one row or two, and the mask picks tokens until the held
+    count is the edge, one under, or one over it."""
+    held = (4, 6)
+    _, p, s = whole_layer
+    layer = _moe(held)
+    p = {"Wg": p["Wg"], **{k: p[k][jnp.asarray(held)] for k in ("W1", "W2", "W3")}}
+    s = {**s, "expert_bias": jnp.zeros((8,)).at[4].set(100.0)}
+    x = _u(11)
+    seg, n_seg = layer.segment_shape(2 * 32 * 2)
+    assert (seg, n_seg) == (32, 4)
+    target = edge * seg + off
+    scores = jax.nn.sigmoid(x.reshape(-1, 64) @ p["Wg"])
+    _, sel = jax.lax.top_k(scores + s["expert_bias"], 2)
+    rows_of = np.isin(np.asarray(sel), held).sum(axis=1)      # 1 or 2 a token
+    mask, have = np.zeros((64,), np.float32), 0
+    for t in np.argsort(-rows_of, kind="stable"):   # the twos first, then ones
+        if have + rows_of[t] <= target:
+            mask[t] = 1.0
+            have += int(rows_of[t])
+    assert have == target <= int(rows_of.sum())
+    mask = jnp.asarray(mask.reshape(2, 32))
+    y, st, grads = _out_and_grads(layer, p, s, x, mask)
+    counts = np.asarray(st["moe_expert_counts"])
+    assert int(counts[list(held)].sum()) == target
+    assert (counts[[4, 6]] > 0).all()           # groups on both sides of an edge
+    assert _segments_run(layer, st, 128)[0] == -(-target // seg)
+    _unsegmented(monkeypatch)
+    assert layer.segment_shape(128) == (128, 1)
+    y1, st1, grads1 = _out_and_grads(layer, p, s, x, mask)
+    np.testing.assert_array_equal(np.asarray(st1["moe_expert_counts"]), counts)
+    _close(y, y1)
+    assert set(grads) == {"Wg", "W1", "W2", "W3", "x"}
+    for leaf in grads:
+        assert float(jnp.abs(grads1[leaf]).max()) > 0
+        _close(grads[leaf], grads1[leaf])
+
+
+def test_rows_that_do_not_fill_whole_segments_are_filled_behind_the_last():
+    """36 rows in segments of 32: the sorted buffer is filled to 64, and
+    the filling sorts behind every row, held or not."""
+    layer = L.MixtureOfExpertsLayer(
+        n_out=8, n_experts=6, hidden=5, top_k=2, scoring="sigmoid", gated=True,
+        experts_held=(0, 2, 3, 5), residual=False, activation="identity")
+    assert layer.segment_shape(36) == (32, 2)
+    p, s, _ = layer.initialize(jax.random.PRNGKey(0), InputType.recurrent(8, 6))
+    x = jax.random.normal(jax.random.PRNGKey(1), (3, 6, 8))
+    y, st, grads = _out_and_grads(layer, p, s, x, None)
+    with pytest.MonkeyPatch.context() as m:
+        _unsegmented(m)
+        y1, _, grads1 = _out_and_grads(layer, p, s, x, None)
+    assert _segments_run(layer, st, 36)[0] >= 1
+    _close(y, y1)
+    for leaf in grads:
+        _close(grads[leaf], grads1[leaf])
+
+
+@pytest.mark.parametrize("rows,held,experts,want", [
+    (32768, 8, 32, (8192, 4)),      # the benchmark's cell
+    (32768, 32, 32, (32768, 1)),    # every expert held: one segment
+    (128, 2, 8, (32, 4)), (128, 3, 8, (48, 3)), (128, 1, 8, (16, 8)),
+    (36, 4, 6, (32, 2)),            # rounded up to the row tile, 64 rows
+    (16, 1, 8, (16, 1)),            # a segment as long as the buffer
+])
+def test_the_segment_follows_the_share_held(rows, held, experts, want):
+    from deeplearning4j_tpu.ops import row_segments
+    assert row_segments.segment_rows(rows, held, experts) == want
+    seg, n_seg = want
+    assert seg * n_seg >= rows > seg * (n_seg - 1)
+    # the first always runs; an edge belongs to the segment before it
+    assert [row_segments.segments_run(h, seg) for h in
+            (0, 1, seg, min(seg + 1, rows), rows)] == [
+        1, 1, 1, min(2, n_seg), n_seg]
+
+
+def test_a_layer_that_holds_every_expert_lowers_without_control_flow(whole_layer):
+    layer, p, s = whole_layer
+
+    def lowered(layer, p):
+        def loss(p, x):
+            return jnp.sum(layer.forward(p, s, x, train=True, rng=None)[0])
+        return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(p, _u()).as_text()
+
+    whole = lowered(layer, p)
+    for word in ("while", "conditional", "stablehlo.case", "stablehlo.if"):
+        assert word not in whole
+    held = (2, 3)
+    cut = lowered(_moe(held), {"Wg": p["Wg"], **{
+        k: p[k][jnp.asarray(held)] for k in ("W1", "W2", "W3")}})
+    # the rows out, the products forward and backward, the weighted sum's
+    # backward: four loops whose trip count is data
+    assert cut.count("stablehlo.while") == 4
+
+
+def test_fit_counts_the_row_segments_run_and_skipped():
+    from deeplearning4j_tpu import monitor
+
+    def read():
+        out = {}
+        for s_ in monitor.get_registry().snapshot().get(
+                "dl4j_moe_row_segments_total", {"samples": []})["samples"]:
+            if s_["labels"]["vertex"] == "l3_moe":
+                out[s_["labels"]["outcome"]] = s_["value"]
+        return out.get("run", 0), out.get("skipped", 0)
+
+    net = _net(dict(CFG, layers_run=[0, 3]))
+    net.init()
+    layer = net.conf.vertices["l3_moe"].layer_conf()
+    seg, n_seg = layer.segment_shape(2 * 32 * 2)
+    assert (seg, n_seg) == (64, 2)           # 4 of 8 held: half of the 128 rows
+    rng = np.random.default_rng(3)
+    # the last batch is half padding: the segments are still those of the
+    # 128 rows the device sorted, not of the 64 assignments counted
+    masks = [None, None, np.ones((2, 32), np.float32)]
+    masks[2][:, 16:] = 0.0
+    for mask in masks:
+        ids = rng.integers(0, CFG["vocab_size"], (2, 33), dtype=np.int32)
+        run0, skipped0 = read()
+        net.fit(ListDataSetIterator([DataSet(
+            ids[:, :-1], ids[:, 1:], features_mask=mask, labels_mask=mask)]))
+        run1, skipped1 = read()
+        counts = np.asarray(net.net_state["l3_moe"]["moe_expert_counts"])
+        assert int(counts.sum()) == (128 if mask is None else 64)
+        held = int(counts[list(layer._held())].sum())
+        assert run1 - run0 == -(-held // seg) and 0 < held < 128
+        assert (run1 - run0) + (skipped1 - skipped0) == n_seg
 
 
 @pytest.mark.parametrize("held", [(), (3, 1), (0, 0), (8,)])
@@ -551,6 +789,22 @@ def test_numeric_gradients_in_float64(layer):
     ("jit(cg_train_step)/jvp(fwd/MixtureOfExpertsLayer/l2_moe)/route/"
      "jit(top_k)/top_k", "fwd/MixtureOfExpertsLayer/route"),
     ("ragged-dot-none.7", "kernel/MixtureOfExpertsLayer/experts"),
+    # a part's scope stands outside the control flow the part contains: a
+    # loop's body or a branch under it is the part's, in both directions
+    ("jit(cg_train_step)/jvp(fwd/MixtureOfExpertsLayer/l2_moe)/experts/"
+     "while/body/ragged_dot_general", "fwd/MixtureOfExpertsLayer/experts"),
+    ("jit(cg_train_step)/jvp(fwd/MixtureOfExpertsLayer/l2_moe)/dispatch/"
+     "while/cond/lt", "fwd/MixtureOfExpertsLayer/dispatch"),
+    ("jit(cg_train_step)/transpose(jvp(fwd/MixtureOfExpertsLayer/l2_moe))/"
+     "combine/while/body/gather", "bwd/MixtureOfExpertsLayer/combine"),
+    ("jit(cg_train_step)/transpose(jvp(fwd/MixtureOfExpertsLayer/l2_moe))/"
+     "experts/while/body/transpose(jvp())/ragged_dot_general",
+     "bwd/MixtureOfExpertsLayer/experts"),
+    ("jit(cg_train_step)/transpose(jvp(fwd/MixtureOfExpertsLayer/l2_moe))/"
+     "dispatch/cond/branch_1_fun/scatter", "bwd/MixtureOfExpertsLayer/dispatch"),
+    # control flow that holds the parts is no part
+    ("jit(cg_train_step)/jvp(fwd/MixtureOfExpertsLayer/l2_moe)/while/body/"
+     "dispatch/gather", None),
     ("jit(cg_train_step)/jvp(fwd/DenseLayer/fc)/dot_general", None),
     ("jit(cg_train_step)/jvp(fwd/MixtureOfExpertsLayer/l2_moe)/eq", None),
     ("jit(cg_train_step)/update/mul", None),
@@ -561,6 +815,41 @@ def test_profile_splits_a_layer_by_the_parts_it_names(op_name, want):
     if want:
         direction, kind, _ = profile.classify(op_name)
         assert f"{direction}/{kind}" == want.rsplit("/", 1)[0]
+
+
+def test_profile_leaves_an_event_that_spans_others_out_of_the_sums():
+    """A ``while`` (or ``conditional``, ``call``) event lies over the
+    events of the instructions it runs, on the same line: it is busy
+    time, and no scope's."""
+    from deeplearning4j_tpu.monitor import profile
+    part = "jit(cg_train_step)/transpose(jvp(fwd/MixtureOfExpertsLayer/l2_moe))/experts"
+    planes = [("/device:TPU:0", [("XLA Ops", [
+        (0.0, 10.0, "%while.3 = (s32[]{:T(128)}, bf16[64,8]{1,0}) while((s32[], "
+         "bf16[64,8]) %tuple.1), condition=%cond.2, body=%body.2", part + "/while"),
+        (0.0, 4.0, "%fusion.7 = bf16[16,8]{1,0} fusion(...)", part + "/while/body/mul"),
+        (5.0, 3.0, "%ragged-dot-none.2 = bf16[16,8]{1,0} custom-call(...)",
+         "ragged-dot-none"),
+        (8.0, 1.0, "%fusion.8 = bf16[16,8]{1,0} fusion(...)",
+         part + "/while/body/dynamic_update_slice"),
+        (12.0, 2.0, "%conditional.1 = bf16[8]{0} conditional(s32[] %p, ...), "
+         "branch_computations={%b0, %b1}", part + "/cond"),
+        (12.0, 2.0, "%fusion.9 = bf16[8]{0} fusion(...)",
+         part + "/cond/branch_1_fun/add"),
+        (15.0, 1.0, "%copy.4 = bf16[8]{0} copy(...)", "")])])]
+    evs = profile.device_events(planes)[0]
+    assert [op for _, _, op in evs].count(profile.SPANS_OTHERS) == 2
+    chip = profile.summarize(planes)["chips"]["0"]
+    ns = 1e-9
+    assert chip["busy_s"] == pytest.approx(13 * ns)     # 0-10, 12-14, 15-16
+    assert chip["sub_scope_s"] == {
+        "bwd/MixtureOfExpertsLayer/experts": pytest.approx(7 * ns),
+        "kernel/MixtureOfExpertsLayer/experts": pytest.approx(3 * ns)}
+    assert chip["device_s"] == {
+        "bwd": {"MixtureOfExpertsLayer": pytest.approx(7 * ns)},
+        "kernel": {"MixtureOfExpertsLayer": pytest.approx(3 * ns)},
+        "unscoped": {"unscoped": pytest.approx(1 * ns)}}
+    # of the 11 ns of instructions that ran, 1 has no scope
+    assert chip["scoped_share"] == pytest.approx(10 / 11)
 
 
 def test_the_parts_and_kernels_come_from_the_layer_classes():

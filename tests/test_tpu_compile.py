@@ -14,6 +14,8 @@ THIS file only (one process may hold libtpu; under pytest-xdist that is
 the worker this file lands on), and the non-interpret branch is steered
 from the test with ``DL4J_TPU=1``."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -138,6 +140,39 @@ def test_grouped_expert_products_compile_to_a_kernel(compile_for_chip):
                            ((8, 2048, 1792), bf16), ((8,), jnp.int32))
     assert hlo.count('custom_call_target="tpu_custom_call"') >= 3
     assert "ragged-dot" in hlo
+
+
+def test_expert_layer_compiles_with_its_segments_skipped_on_the_chip(
+        compile_for_chip):
+    """The expert layer of the language-model cell, forward and backward
+    (8 of 32 experts held, top-4 over 8,192 tokens of 2,048: 4 segments
+    of 8,192 sorted rows): four loops whose trip count the chip reads,
+    the grouped products inside them and the weights' gradients outside
+    as the compiler's own kernels."""
+    from deeplearning4j_tpu.nn.conf import layers as L
+    layer = L.MixtureOfExpertsLayer(
+        n_out=2048, n_experts=32, hidden=1792, top_k=4, scoring="sigmoid",
+        expert_bias=True, gated=True, residual=False, activation="identity",
+        experts_held=tuple(range(8)))
+    assert layer.segment_shape(8192 * 4) == (8192, 4)
+
+    def step(x, wg, w1, w2, w3, bias):
+        def loss(p, x):
+            state = {"expert_bias": bias,
+                     "moe_expert_counts": jnp.zeros((32,), jnp.int32)}
+            y, st, _ = layer.forward(p, state, x, train=True, rng=None)
+            return _sq(y), st["moe_expert_counts"]
+        return jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(
+            {"Wg": wg, "W1": w1, "W2": w2, "W3": w3}, x)
+    bf16 = jnp.bfloat16
+    hlo = compile_for_chip(
+        step, ((2, 4096, 2048), bf16), ((2048, 32), bf16),
+        ((8, 2048, 1792), bf16), ((8, 1792, 2048), bf16),
+        ((8, 2048, 1792), bf16), ((32,), bf16))
+    assert len(re.findall(r"\s(while)\(", hlo)) == 4
+    assert " conditional(" not in hlo
+    # three products forward, three to the rows and three to the weights
+    assert len(set(re.findall(r"%(ragged-dot[\w.\-]*) = ", hlo))) >= 9
 
 
 @pytest.mark.parametrize("shape", [(64, 128), (128, 4096)],
