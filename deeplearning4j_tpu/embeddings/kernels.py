@@ -41,8 +41,7 @@ MAX_EXP = 6.0
 
 # Pairs per scan step.  Small enough that duplicate-row staleness is
 # negligible even for tiny vocabs, large enough to keep the MXU busy.
-# DL4J_W2V_CHUNK overrides for on-chip throughput tuning (the bench
-# records the value used — BASELINE.md word2vec protocol).
+# DL4J_W2V_CHUNK overrides for on-chip throughput tuning.
 import os as _os
 try:
     CHUNK = max(1, int(_os.environ.get("DL4J_W2V_CHUNK", "64")))

@@ -46,7 +46,7 @@ from deeplearning4j_tpu.monitor.tracing import (  # noqa: F401
     profile_if_configured, span)
 from deeplearning4j_tpu.monitor.exposition import (  # noqa: F401
     CONTENT_TYPE, merge_snapshots, parse_prometheus, render_json,
-    render_prometheus, snapshot_from_parsed, summarize)
+    render_prometheus, snapshot_from_parsed)
 from deeplearning4j_tpu.monitor.system import (  # noqa: F401
     memory_collector, memory_snapshot)
 

@@ -28,9 +28,7 @@ answers "what happened, in what order, to WHICH request".  Three pieces:
 
 ``DL4J_JOURNAL=0`` is the kill switch: :func:`emit` returns immediately
 — events become no-ops, not queued.  ``DL4J_JOURNAL_CAPACITY`` sizes
-the ring (default 2048).  The overhead A/B lever for benchmarks is
-:func:`set_enabled` (``bench_serving`` reports ``journal_overhead_pct``,
-required ≤ 5%).
+the ring (default 2048).  The overhead A/B lever is :func:`set_enabled`.
 """
 
 from __future__ import annotations
@@ -131,7 +129,7 @@ _env_cache: Dict[str, Optional[bool]] = {"enabled": None, "verbose": None}
 
 def set_enabled(on: Optional[bool]) -> None:
     """Force the journal on/off; ``None`` restores the env default
-    (``DL4J_JOURNAL``, re-read from the environment) — the bench A/B
+    (``DL4J_JOURNAL``, re-read from the environment) — the overhead A/B
     lever, mirroring ``tracing.set_enabled``."""
     _flags["enabled"] = None if on is None else bool(on)
     _env_cache["enabled"] = None

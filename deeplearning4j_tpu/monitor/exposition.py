@@ -296,58 +296,3 @@ def merge_snapshots(sources: Dict[str, Dict[str, dict]]) -> Dict[str, dict]:
                     "label_names": sorted(m["_names"]),
                     "samples": samples}
     return out
-
-
-# ---------------------------------------------------------------------------
-# Compact summary (bench.py embeds this in every BENCH_*.json record)
-# ---------------------------------------------------------------------------
-def summarize(snapshot: Dict[str, dict]) -> dict:
-    """Perf-trajectory digest of a snapshot: retrace counts by jit entry
-    point, per-(span, phase) time breakdown, throughput/score gauges and
-    serving latency percentiles — enough to attribute a bench regression
-    to a phase without shipping the full registry."""
-    out: dict = {}
-
-    fam = snapshot.get("dl4j_compile_retraces_total")
-    if fam:
-        by_kind = {s["labels"].get("kind", ""): s["value"]
-                   for s in fam["samples"]}
-        out["retraces"] = by_kind
-        out["retraces_total"] = sum(by_kind.values())
-
-    fam = snapshot.get("dl4j_phase_seconds")
-    if fam:
-        phases = {}
-        for s in fam["samples"]:
-            key = "/".join(p for p in (s["labels"].get("span", ""),
-                                       s["labels"].get("phase", "")) if p)
-            phases[key] = {"count": s["count"],
-                           "sum_sec": round(s["sum"], 4),
-                           "p50_ms": None if s["p50"] is None
-                           else round(s["p50"] * 1e3, 3)}
-        out["phase_seconds"] = phases
-
-    for gname, key in (("dl4j_fit_examples_per_sec", "examples_per_sec"),
-                       ("dl4j_fit_score", "score"),
-                       ("dl4j_fit_last_step_ms", "last_step_ms")):
-        fam = snapshot.get(gname)
-        if fam and fam["samples"]:
-            out[key] = fam["samples"][0]["value"]
-
-    fam = snapshot.get("dl4j_serving_total_seconds")
-    if fam:
-        out["serving_total_ms"] = {
-            (s["labels"].get("model") or "default"): {
-                "count": s["count"],
-                "p50": None if s["p50"] is None else round(s["p50"] * 1e3, 3),
-                "p95": None if s["p95"] is None else round(s["p95"] * 1e3, 3),
-            } for s in fam["samples"]}
-
-    cache = {}
-    for cname in ("hits", "misses", "stale_reloads", "evictions"):
-        fam = snapshot.get(f"dl4j_model_cache_{cname}_total")
-        if fam and fam["samples"]:
-            cache[cname] = fam["samples"][0]["value"]
-    if cache:
-        out["model_cache"] = cache
-    return out

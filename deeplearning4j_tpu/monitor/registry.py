@@ -263,9 +263,9 @@ class MetricsRegistry:
 
     def snapshot(self) -> Dict[str, dict]:
         """{family_name: {type, help, label_names, samples: [...]}} —
-        the contract every renderer (exposition.py), the gateway stats
-        RPC and bench.py's summary walk.  Collector failures are
-        swallowed: a scrape must never take the server down."""
+        the contract of every renderer (exposition.py) and the gateway
+        stats RPC.  Collector failures are swallowed: a scrape must never
+        take the server down."""
         with self._lock:
             collectors = list(self._collectors)
         for fn in collectors:
@@ -282,6 +282,6 @@ _REGISTRY = MetricsRegistry()
 
 
 def get_registry() -> MetricsRegistry:
-    """THE process-wide registry — train, serving, UI and bench all
+    """THE process-wide registry — train, serving and UI all
     meter into this one instance so a single scrape sees everything."""
     return _REGISTRY

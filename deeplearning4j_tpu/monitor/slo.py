@@ -31,9 +31,8 @@ out.  States/burns/budgets are metered as
 ``model=lstm.zip|tenant=acme``; the fleet tier prefixes it with the
 scope, e.g. ``replica=r0|``).
 
-``DL4J_SLO=0`` (or :func:`set_enabled`) is the kill switch — the
-bench A/B lever (``bench_serving`` reports ``slo_overhead_pct``,
-required ≤ 5%).
+``DL4J_SLO=0`` (or :func:`set_enabled`) is the kill switch and the
+overhead A/B lever.
 
 **Alert delivery**: burn states that only live in ``/metrics`` page
 nobody.  ``SloTracker(alert_sink=...)`` delivers every
@@ -67,7 +66,7 @@ _flags = {"enabled": None}
 
 def set_enabled(on: Optional[bool]) -> None:
     """Force SLO evaluation on/off; ``None`` restores the env default
-    (``DL4J_SLO``) — the bench A/B lever, mirroring
+    (``DL4J_SLO``) — the overhead A/B lever, mirroring
     ``events.set_enabled``."""
     _flags["enabled"] = None if on is None else bool(on)
 
@@ -397,9 +396,7 @@ class SloTracker:
         """Snapshot ONLY the families the objectives read — a full
         ``registry.snapshot()`` runs every scrape-time collector (host
         RSS, device memory) and walks every family, which at a tight
-        evaluation cadence measurably taxes a busy serving box (the
-        bench A/B caught ~15% at 20 Hz; this holds it under the 5%
-        budget)."""
+        evaluation cadence measurably taxes a busy serving box."""
         needed = set()
         for obj in self.objectives:
             for fam in (obj.family, obj.good_family, obj.bad_family):

@@ -26,7 +26,7 @@ Two optional bridges into JAX's own profiler:
   (monitor/profile.py: device time by scope, idle time by host phase).
 
 ``DL4J_SPANS=0`` turns span timing into a no-op (the A/B lever for
-measuring span overhead; see bench.py's serving workload).
+measuring span overhead).
 """
 
 from __future__ import annotations

@@ -372,12 +372,9 @@ class ConvolutionLayer(Layer):
     def forward(self, params, state, x, *, train, rng, mask=None):
         x = self._maybe_dropout(x, train, rng)
         p = self._maybe_drop_connect(params, train, rng)
-        # helper selection (ops/helpers.py): the dense conv-HLO → bias →
-        # activation chain, fused and laid out by XLA; the conv tier's
-        # Pallas pass only under DL4J_PALLAS_CONV=1
-        y = helper_ops.conv2d_bias_act(
+        y = self._act(conv_ops.conv2d(
             x, p["W"], p["b"], self.stride, self.padding, self.dilation,
-            self.convolution_mode, self.activation or "identity")
+            self.convolution_mode))
         return y, state, mask
 
     def output_type(self, input_type):

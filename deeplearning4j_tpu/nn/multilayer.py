@@ -779,7 +779,7 @@ class MultiLayerNetwork:
     def _build_fused_step(self, k: int):
         """K train steps as one compiled program: lax.scan over the raw
         step with the batch axis stacked in front.  Dispatch once, step
-        K times — the bench's scan-fused ceiling as an engine feature."""
+        K times."""
         raw = self._build_step_raw()
 
         def strip_rnn(state):

@@ -26,9 +26,8 @@ for hardware-limit throughput; this module enforces it:
 * **Retrace telemetry** (:class:`CompileTelemetry`): each network counts
   distinct jit-entry signatures (shape/dtype/mask-presence — exactly
   what XLA keys its trace cache on) and per-bucket hit counts, surfaced
-  through ``nn/listeners.CompileTelemetryListener`` and ``bench.py``'s
-  ``bench_ragged`` workload, so compile-behavior regressions are
-  measurable instead of anecdotal.
+  through ``nn/listeners.CompileTelemetryListener``, so compile-behavior
+  regressions are measurable instead of anecdotal.
 
 * **Persistent compilation cache** (:func:`configure_compile_cache`):
   JAX's on-disk compilation cache at ``$JAX_COMPILATION_CACHE_DIR`` or,

@@ -10,7 +10,6 @@ Every op with a fused implementation registers a :class:`Helper` here:
 ==============  ======  =============================  =====================
 op              tier    fused kernel (pallas_kernels)  dense XLA fallback
 ==============  ======  =============================  =====================
-``conv2d``      conv*   fused_conv2d_bias_act          ops/convolution.conv2d + activation
 ``lstm_step``   lstm    fused_lstm_step                ops/recurrent._lstm_cell_pre
 ``dropout``     dropout fused_threshold_dropout        ops/normalization.dropout
 ``softmax_xent`` xent   softmax_xent_rows              stable logsumexp form in ops/losses
@@ -25,16 +24,14 @@ by op).  Off-TPU nothing fuses by default — the fallback IS the
 pre-helper code path, byte-identical — but each tier can be forced for
 testing (the kernels then run under ``interpret=True``).
 
-(*) The conv tier is the exception on a TPU too: nothing selects it unless
-``DL4J_PALLAS_CONV=1`` forces it.  XLA's own convolution, which lays
-out the whole graph itself, beat the kernel with its NCHW/NHWC copies
-wherever both were measured (PERF.md section 6, PR 28), so every
-``ConvolutionLayer`` takes the dense chain by default.
+Convolutions have no entry: XLA's own convolution beat a fused conv + bias +
+activation kernel wherever both were measured (the trial is PERF.md section
+6, PR 28), so ``ConvolutionLayer`` calls ``ops/convolution.conv2d`` itself.
 
 Kill switches, most-specific wins:
 
 * ``DL4J_PALLAS=0`` — global: every tier falls back.
-* ``DL4J_PALLAS_{CONV,LSTM,DROPOUT,XENT,FLASH}=0|1`` — per tier:
+* ``DL4J_PALLAS_{LSTM,DROPOUT,XENT,FLASH}=0|1`` — per tier:
   ``0`` forces the fallback, ``1`` forces the fused path even off-TPU
   (interpret mode; how the parity tests exercise the kernels through
   the public ``fit``/``output`` path).
@@ -42,8 +39,8 @@ Kill switches, most-specific wins:
   runtime per-tier switch :func:`kernel_self_test` flips when a Mosaic
   compile fails on the real chip, so one bad kernel degrades to XLA
   without taking down the healthy tiers.  The flip is logged at
-  WARNING with the compiler's message; ``chip_smoke.py`` and
-  ``bench.py`` treat any disabled tier as a failed run.
+  WARNING with the compiler's message; ``chip_smoke.py`` treats
+  any disabled tier as a failed run.
 
 :func:`ensure_validated` is the warm-validation hook both engines call
 at the top of ``fit()``: the first time any fused tier could engage it
@@ -68,16 +65,14 @@ log = logging.getLogger(__name__)
 
 class Helper(NamedTuple):
     """One fused-implementation registration."""
-    op: str                      # registry key (conv2d, lstm_step, ...)
-    tier: str                    # kill-switch tier name (conv, lstm, ...)
+    op: str                      # registry key (lstm_step, dropout, ...)
+    tier: str                    # kill-switch tier name (lstm, dropout, ...)
     test_name: str               # key in the kernel_self_test() report
     self_test: Callable[[], None]  # small-shape compile+run validation
-    on_tpu_default: bool = True  # selected on a TPU when nothing forces it
 
 
-_ENV_TIER = {"conv": "DL4J_PALLAS_CONV", "lstm": "DL4J_PALLAS_LSTM",
-             "dropout": "DL4J_PALLAS_DROPOUT", "xent": "DL4J_PALLAS_XENT",
-             "flash": "DL4J_PALLAS_FLASH"}
+_ENV_TIER = {"lstm": "DL4J_PALLAS_LSTM", "dropout": "DL4J_PALLAS_DROPOUT",
+             "xent": "DL4J_PALLAS_XENT", "flash": "DL4J_PALLAS_FLASH"}
 
 
 def _registry():
@@ -108,11 +103,9 @@ def record_selection(op: str, fused: bool) -> None:
 def available(op: str) -> bool:
     """Is the fused tier for ``op`` eligible at all (before the per-call
     shape/dtype predicate)?  Order: global kill → runtime kill switch →
-    per-tier env force → platform, and there only a tier that is on by
-    default (every one but conv) outside a step that GSPMD partitions
+    per-tier env force → platform, outside a step that GSPMD partitions
     (pallas_kernels.partitioned_trace)."""
-    helper = _HELPERS[op]
-    tier = helper.tier
+    tier = _HELPERS[op].tier
     if os.environ.get("DL4J_PALLAS") == "0":  # dl4j: noqa[DL4J103] env kill switch read at trace time by design (fixed per process)
         return False
     if tier in pk._disabled:
@@ -122,35 +115,12 @@ def available(op: str) -> bool:
         return False
     if env == "1":
         return True
-    return (helper.on_tpu_default and pk._on_tpu()
-            and not pk.partitioned_trace_active())
+    return pk._on_tpu() and not pk.partitioned_trace_active()
 
 
 # ---------------------------------------------------------------------------
 # Per-op selection wrappers — the call sites layers/ops route through.
 # ---------------------------------------------------------------------------
-
-def conv2d_bias_act(x, w, b, stride=(1, 1), pad=(0, 0), dilation=(1, 1),
-                    border_mode: str = "truncate",
-                    activation: Optional[str] = "identity"):
-    """Conv + bias + activation for ConvolutionLayer.forward: the dense
-    conv-HLO → bias-add → activation chain, which XLA fuses and lays
-    out itself (byte-identical to the pre-helper path), unless
-    ``DL4J_PALLAS_CONV=1`` forces the conv tier's one fused VMEM pass
-    for a shape it supports."""
-    act = (activation or "identity").lower()
-    if available("conv2d") and pk.conv_fused_supported(
-            x.shape, w.shape, x.dtype, stride, dilation, act, pad,
-            border_mode):
-        record_selection("conv2d", True)
-        return pk.fused_conv2d_bias_act(x, w, b, stride, pad, dilation,
-                                        border_mode, act)
-    record_selection("conv2d", False)
-    from deeplearning4j_tpu.ops import activations as act_ops
-    from deeplearning4j_tpu.ops import convolution as conv_ops
-    return act_ops.get(act)(conv_ops.conv2d(x, w, b, stride, pad, dilation,
-                                            border_mode))
-
 
 def dropout(x, rate: float, rng):
     """Inverted dropout for Layer._maybe_dropout: in-kernel threshold
@@ -262,29 +232,6 @@ def _selftest_xent():
             raise FloatingPointError("non-finite fused xent loss")
 
 
-def _selftest_conv():
-    import numpy as np
-    rng = np.random.default_rng(0)
-
-    def loss(x, w, b):
-        y = pk.fused_conv2d_bias_act(
-            x, w, b, border_mode="same", activation="relu")
-        return jnp.sum(y.astype(jnp.float32) ** 2)
-    vg = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
-    # f32 AND bf16: bf16 is what the chip's default policy feeds the
-    # tier, and its backward (the reference conv's transpose) is a
-    # different trace from the f32 one
-    for dtype in (jnp.float32, jnp.bfloat16):
-        x = jnp.asarray(rng.normal(size=(2, 3, 10, 10)), dtype)
-        w = jnp.asarray(rng.normal(size=(8, 3, 3, 3)) * 0.2, dtype)
-        b = jnp.asarray(rng.normal(size=(8,)), dtype)
-        out, grads = vg(x, w, b)
-        jax.block_until_ready(grads)
-        if not bool(jnp.isfinite(out)):
-            raise FloatingPointError(
-                f"non-finite fused conv loss ({jnp.dtype(dtype).name})")
-
-
 def _selftest_lstm():
     import numpy as np
     rng = np.random.default_rng(0)
@@ -321,8 +268,6 @@ def _selftest_dropout():
 
 
 _HELPERS: Dict[str, Helper] = {
-    "conv2d": Helper("conv2d", "conv", "conv2d_bias_act", _selftest_conv,
-                     on_tpu_default=False),
     "lstm_step": Helper("lstm_step", "lstm", "lstm_step", _selftest_lstm),
     "dropout": Helper("dropout", "dropout", "dropout", _selftest_dropout),
     "softmax_xent": Helper("softmax_xent", "xent", "softmax_xent",

@@ -7,7 +7,7 @@ per-layer selection between a kernel and its dense XLA fallback lives in
 ``ops/helpers.py`` (the cuDNN-helper-selection tier: registry, per-tier
 kill switches, warm validation, ``dl4j_pallas_*`` selection metrics).
 
-Five kernels:
+Four kernels:
 
 * **flash_attention** — block-wise online-softmax attention.  The dense
   XLA path materializes the [B, H, T, T] score matrix in HBM; this
@@ -26,17 +26,6 @@ Five kernels:
   VMEM pass per row block.  The char-RNN/output-layer hot op: avoids
   writing the [N, V] probability matrix to HBM twice (once for loss,
   once for grad).
-
-* **fused_conv2d_bias_act** — stride-1 2D convolution + bias + an
-  elementwise activation in one VMEM pass (the Pallas analog of the
-  reference's CudnnConvolutionHelper fused conv+bias+act path,
-  ConvolutionLayer.java:171-212): the KH·KW input patches stream
-  through the MXU as back-to-back [OH·OW, Cin]·[Cin, Cout] tiles and
-  the bias-add + activation happen on the accumulator before it ever
-  leaves VMEM — the unfused chain writes the conv result, the biased
-  result AND the activated result to HBM.  Backward recomputes via the
-  XLA reference (``jax.vjp``), so gradients are exactly the dense
-  gradients.
 
 * **fused_lstm_step** — one peephole-LSTM timestep (the scan body of
   ``ops/recurrent.lstm_scan``) in one VMEM pass: the [N, H]·[H, 4H]
@@ -67,8 +56,6 @@ import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 
-from deeplearning4j_tpu.ops import dtypes as dtype_ops
-
 NEG_INF = -1e30
 
 
@@ -78,7 +65,7 @@ NEG_INF = -1e30
 # cuDNN-helper-with-builtin-fallback pattern, ref
 # ConvolutionLayer.java:157-212).  DL4J_PALLAS=0 disables everything;
 # per-tier state is read by ops/helpers.available().
-ALL_TIERS = ("flash", "xent", "conv", "lstm", "dropout")
+ALL_TIERS = ("flash", "xent", "lstm", "dropout")
 _disabled: dict = {}  # tier -> reason
 
 
@@ -105,7 +92,7 @@ _PARTITIONED = False
 @contextlib.contextmanager
 def partitioned_trace():
     """Scope under which the fused tiers leave AUTOMATIC selection on
-    the chip — all five, by name: flash, xent, conv, lstm, dropout.  A
+    the chip — all four, by name: flash, xent, lstm, dropout.  A
     Mosaic kernel cannot be partitioned automatically (the TPU lowering
     raises "Mosaic kernels cannot be automatically partitioned. Please
     wrap the call in a shard_map"), so a step jitted with mesh shardings
@@ -609,163 +596,6 @@ softmax_xent_rows.defvjp(_sxr_fwd, _sxr_bwd)
 
 
 # ===========================================================================
-# Fused conv2d + bias + activation (stride-1) — the CudnnConvolutionHelper
-# analog.  Forward is one Pallas pass (patch matmuls accumulate in VMEM,
-# bias+activation applied before the single HBM write); backward
-# recomputes through the XLA reference conv via jax.vjp, so training
-# gradients are exactly the dense-path gradients.
-# ===========================================================================
-
-# Elementwise activations the kernel can fuse (cross-feature ones like
-# softmax stay on the dense path).  Names resolve via ops/activations.
-CONV_FUSED_ACTS = frozenset((
-    "identity", "linear", "relu", "relu6", "tanh", "sigmoid", "leakyrelu",
-    "elu", "gelu", "softplus", "softsign", "swish", "selu", "hardsigmoid",
-    "hardtanh"))
-
-_VMEM_BUDGET = 10 << 20  # bytes of live f32 buffers one program may hold
-
-
-def _act_fn(name: str):
-    from deeplearning4j_tpu.ops import activations as act_ops
-    return act_ops.get(name or "identity")
-
-
-def _conv_pads(H, W, KH, KW, pad, border_mode):
-    """Explicit ((top, bottom), (left, right)) pads for stride 1.  'same'
-    matches XLA's SAME split: total = K-1, low = (K-1)//2, high = rest
-    (the extra row/col goes HIGH, as lax.conv does)."""
-    if border_mode == "same":
-        return (((KH - 1) // 2, KH - 1 - (KH - 1) // 2),
-                ((KW - 1) // 2, KW - 1 - (KW - 1) // 2))
-    return ((pad[0], pad[0]), (pad[1], pad[1]))
-
-
-def _conv_bias_act_kernel(x_ref, w_ref, b_ref, out_ref, *, act_name: str):
-    """One batch element: x [Hp, Wp, Cin] NHWC, w [KH, KW, Cin, Cout]
-    HWIO, b [1, Cout] → out [OH, OW, Cout].  The KH·KW patch matmuls
-    accumulate into one f32 VMEM buffer; bias + activation run on the
-    accumulator before the single output write."""
-    KH, KW, Cin, Cout = w_ref.shape
-    OH, OW = out_ref.shape[0], out_ref.shape[1]
-    acc = jnp.zeros((OH * OW, Cout), jnp.float32)
-    for kh in range(KH):
-        for kw in range(KW):
-            patch = x_ref[pl.dslice(kh, OH), pl.dslice(kw, OW), :].astype(
-                jnp.float32)                              # [OH, OW, Cin]
-            acc = acc + jax.lax.dot_general(
-                patch.reshape(OH * OW, Cin),
-                w_ref[kh, kw].astype(jnp.float32),
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-    y = acc + b_ref[...].astype(jnp.float32)              # [OH*OW, Cout]
-    y = _act_fn(act_name)(y)
-    out_ref[...] = y.reshape(OH, OW, Cout).astype(out_ref.dtype)
-
-
-def _conv_forward(xp, w, b2, act_name: str):
-    """xp [N, Hp, Wp, Cin] (already padded), w [KH, KW, Cin, Cout],
-    b2 [1, Cout] → [N, OH, OW, Cout]."""
-    N, Hp, Wp, Cin = xp.shape
-    KH, KW, _, Cout = w.shape
-    OH, OW = Hp - KH + 1, Wp - KW + 1
-    return pl.pallas_call(
-        functools.partial(_conv_bias_act_kernel, act_name=act_name),
-        grid=(N,),
-        in_specs=[
-            pl.BlockSpec((None, Hp, Wp, Cin), lambda n: (n, 0, 0, 0)),
-            pl.BlockSpec((KH, KW, Cin, Cout), lambda n: (0, 0, 0, 0)),
-            pl.BlockSpec((1, Cout), lambda n: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, OH, OW, Cout), lambda n: (n, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((N, OH, OW, Cout), xp.dtype),
-        name="dl4j_conv_bias_act",
-        interpret=_interpret(),
-    )(xp, w, b2)
-
-
-def _conv_ref_nhwc(xp, w, b2, act_name: str):
-    """Dense XLA reference of the SAME math (stride-1 VALID conv on the
-    pre-padded input) — the backward pass differentiates this.  The
-    result dtype follows ops/convolution.conv2d (bf16 stays bf16: a
-    widened result does not transpose against bf16 operands)."""
-    y = lax.conv_general_dilated(
-        xp, w, window_strides=(1, 1), padding=[(0, 0), (0, 0)],
-        dimension_numbers=("NHWC", "HWIO", "NHWC"),
-        preferred_element_type=dtype_ops.accum_dtype_for(xp.dtype))
-    y = _act_fn(act_name)(y + b2.reshape(1, 1, 1, -1))
-    return y.astype(xp.dtype)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _conv_core(xp, w, b2, act_name: str):
-    return _conv_forward(xp, w, b2, act_name)
-
-
-def _conv_vjp_fwd(xp, w, b2, act_name):
-    return _conv_forward(xp, w, b2, act_name), (xp, w, b2)
-
-
-def _conv_vjp_bwd(act_name, res, g):
-    # Recompute-through-reference: one extra conv in the backward buys
-    # gradients that are EXACTLY the dense path's (the cuDNN helpers
-    # similarly run distinct bwd algorithms against the same math).
-    xp, w, b2 = res
-    _, vjp = jax.vjp(
-        lambda x_, w_, b_: _conv_ref_nhwc(x_, w_, b_, act_name), xp, w, b2)
-    return vjp(g)
-
-
-_conv_core.defvjp(_conv_vjp_fwd, _conv_vjp_bwd)
-
-
-def fused_conv2d_bias_act(x, w, b, stride=(1, 1), pad=(0, 0),
-                          dilation=(1, 1), border_mode: str = "truncate",
-                          activation: str = "identity"):
-    """Fused conv+bias+activation, NCHW in / OIHW weights (the
-    ops/convolution.conv2d surface plus the activation).  Only valid for
-    shapes conv_fused_supported() accepts — callers go through
-    ops/helpers.conv2d_bias_act, which falls back to the dense chain."""
-    N, Cin, H, W = x.shape
-    Cout, _, KH, KW = w.shape
-    (pt, pb), (pl_, pr) = _conv_pads(H, W, KH, KW, pad, border_mode)
-    xp = jnp.transpose(x, (0, 2, 3, 1))                   # NCHW → NHWC
-    xp = jnp.pad(xp, ((0, 0), (pt, pb), (pl_, pr), (0, 0)))
-    whwio = jnp.transpose(w, (2, 3, 1, 0))                # OIHW → HWIO
-    y = _conv_core(xp, whwio, b.reshape(1, -1), activation)
-    return jnp.transpose(y, (0, 3, 1, 2))                 # back to NCHW
-
-
-def conv_fused_supported(x_shape, w_shape, dtype, stride=(1, 1),
-                         dilation=(1, 1), activation: str = "identity",
-                         pad=(0, 0), border_mode: str = "truncate") -> bool:
-    """Support predicate for the conv tier: stride-1/dilation-1 convs
-    with an elementwise activation whose whole working set (one image +
-    the filter + accumulator + output) fits the per-program VMEM
-    budget.  Strided/dilated convs and f64 (CPU gradient checks) take
-    the dense path."""
-    if len(x_shape) != 4 or len(w_shape) != 4:
-        return False
-    if tuple(stride) != (1, 1) or tuple(dilation) != (1, 1):
-        return False
-    if (activation or "identity").lower() not in CONV_FUSED_ACTS:
-        return False
-    if jnp.dtype(dtype) not in (jnp.dtype(jnp.float32),
-                                jnp.dtype(jnp.bfloat16)):
-        return False
-    N, Cin, H, W = x_shape
-    Cout, _, KH, KW = w_shape
-    (pt, pb), (pl_, pr) = _conv_pads(H, W, KH, KW, pad, border_mode)
-    Hp, Wp = H + pt + pb, W + pl_ + pr
-    OH, OW = Hp - KH + 1, Wp - KW + 1
-    if OH <= 0 or OW <= 0:
-        return False
-    live = (Hp * Wp * Cin + KH * KW * Cin * Cout
-            + 2 * OH * OW * Cout + Cout) * 4
-    return live <= _VMEM_BUDGET
-
-
-# ===========================================================================
 # Fused LSTM cell — one VMEM pass for the recurrent matmul + gate math
 # inside the lax.scan of ops/recurrent.lstm_scan (the cudnnRNN analog).
 # ===========================================================================
@@ -838,6 +668,9 @@ def _lstm_vjp_bwd(res, g):
 
 
 fused_lstm_step.defvjp(_lstm_vjp_fwd, _lstm_vjp_bwd)
+
+
+_VMEM_BUDGET = 10 << 20  # bytes of live f32 buffers one program may hold
 
 
 def lstm_fused_supported(n: int, h: int, dtype) -> bool:
@@ -999,15 +832,3 @@ def dropout_fused_supported(shape, dtype) -> bool:
         n *= int(d)
     return n >= (1 << 12)
 
-
-def kernel_self_test(disable_on_error: bool = True) -> dict:
-    """Compile+run each registered kernel once on small shapes through
-    the REAL dispatch path (interpret only off-TPU) and report
-    per-kernel status — delegates to the helper-selection tier
-    (ops/helpers.kernel_self_test), which covers EVERY registered
-    helper, disables a failing tier via :func:`disable_kernels` and
-    mirrors verdicts into ``dl4j_pallas_selftest_ok``.  Ref analog:
-    ConvolutionLayer's cuDNN-helper-try/builtin-fallback,
-    ConvolutionLayer.java:67,157-212."""
-    from deeplearning4j_tpu.ops import helpers
-    return helpers.kernel_self_test(disable_on_error=disable_on_error)

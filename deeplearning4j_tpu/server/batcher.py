@@ -21,8 +21,7 @@ grouped and run separately rather than failing the whole batch.
 
 Telemetry: per-request queue/compute/total latency
 (``nn/listeners.LatencyHistogram`` percentile snapshots) and a
-batch-size histogram, surfaced through the gateway's ``stats`` RPC and
-``bench.py``'s ``bench_serving`` A/B.
+batch-size histogram, surfaced through the gateway's ``stats`` RPC.
 """
 
 from __future__ import annotations

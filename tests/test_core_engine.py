@@ -164,7 +164,7 @@ class TestCnn:
 class TestConvInternalLayout:
     def test_nhwc_internal_matches_nchw(self, monkeypatch):
         """DL4J_CONV_LAYOUT=nhwc is a pure layout change: forward AND
-        gradients must match the NCHW path (bench A/B prerequisite)."""
+        gradients must match the NCHW path."""
         import jax
         import jax.numpy as jnp
         from deeplearning4j_tpu.ops import convolution as conv_ops
@@ -204,6 +204,104 @@ class TestConvInternalLayout:
         assert y0.shape == y1.shape == (1, 4, 7, 7)
         np.testing.assert_allclose(np.asarray(y0), np.asarray(y1),
                                    rtol=1e-5, atol=1e-5)
+
+
+# ConvolutionLayer.forward is the only convolution the benchmark's cells
+# run.  The reference below shares nothing with it: no lax.conv, patches
+# gathered by strided slices and summed in float32, activations spelled out.
+_REF_ACTS = {"identity": lambda y: y, "relu": lambda y: jnp.maximum(y, 0.0),
+             "tanh": jnp.tanh}
+
+
+def _conv_layer_reference(x, w, b, stride, pad, dilation, mode, act):
+    """NCHW x, OIHW w -> act(conv(x, w) + b), all float32."""
+    (sh, sw), (dh, dw) = stride, dilation
+    kh, kw = w.shape[2:]
+    pads = []
+    for size, k, s, d, p in zip(x.shape[2:], (kh, kw), stride, dilation, pad):
+        if mode == "same":      # XLA's SAME: the odd cell goes high
+            total = max((-(-size // s) - 1) * s + (k - 1) * d + 1 - size, 0)
+            pads.append((total // 2, total - total // 2))
+        else:
+            pads.append((p, p))
+    xp = jnp.pad(x, ((0, 0), (0, 0), pads[0], pads[1]))
+    oh = (xp.shape[2] - (kh - 1) * dh - 1) // sh + 1
+    ow = (xp.shape[3] - (kw - 1) * dw - 1) // sw + 1
+    y = 0.0
+    for i in range(kh):
+        for j in range(kw):
+            patch = xp[:, :, i * dh:i * dh + (oh - 1) * sh + 1:sh,
+                       j * dw:j * dw + (ow - 1) * sw + 1:sw]
+            y = y + jnp.einsum("nchw,oc->nohw", patch, w[:, :, i, j])
+    return _REF_ACTS[act](y + b[None, :, None, None])
+
+
+_CONV_SHAPES = [       # the grid the fused kernel was held to, stride 1
+    ((2, 3, 10, 10), (3, 3), (0, 0), "truncate"),
+    ((2, 3, 10, 10), (3, 3), (1, 1), "truncate"),
+    ((1, 1, 28, 28), (5, 5), (0, 0), "truncate"),
+    ((2, 4, 9, 7), (3, 3), (0, 0), "same"),
+    ((2, 2, 8, 8), (2, 2), (0, 0), "same"),   # even kernel: SAME pads high
+]
+_CONV_CASES = [
+    pytest.param(shape, kernel, (1, 1), pad, (1, 1), mode, act, "float32",
+                 id=f"{'x'.join(map(str, shape))}-k{kernel[0]}-p{pad[0]}-"
+                    f"{mode}-{act}")
+    for shape, kernel, pad, mode in _CONV_SHAPES
+    for act in ("identity", "relu", "tanh")
+] + [
+    pytest.param((2, 3, 9, 8), (3, 3), (s, s), (1, 1), (d, d), mode, "relu",
+                 dtype, id=f"s{s}-d{d}-{mode}-{dtype}")
+    for s in (1, 2) for d in (1, 2) for mode in ("same", "truncate")
+    for dtype in ("float32", "bfloat16")
+]
+
+
+class TestConvolutionLayerForward:
+    @pytest.mark.parametrize(
+        "shape,kernel,stride,pad,dilation,mode,act,dtype", _CONV_CASES)
+    def test_forward_and_grad_match_reference(self, shape, kernel, stride,
+                                              pad, dilation, mode, act,
+                                              dtype):
+        rng = np.random.default_rng(11)
+        cout = 6
+        layer = ConvolutionLayer(
+            n_in=shape[1], n_out=cout, kernel=kernel, stride=stride,
+            padding=pad, dilation=dilation, convolution_mode=mode,
+            activation=act)
+        # values a bfloat16 holds exactly, so that both sides see the same
+        # operands and differ only in how they round on the way
+        x, w, b = (jnp.asarray(a, jnp.bfloat16).astype(jnp.float32)
+                   for a in (rng.normal(size=shape),
+                             rng.normal(size=(cout, shape[1]) + kernel) * 0.2,
+                             rng.normal(size=(cout,))))
+        ref = _conv_layer_reference(x, w, b, stride, pad, dilation, mode, act)
+        out = layer.output_type(
+            InputType.convolutional(shape[2], shape[3], shape[1]))
+        assert (out.channels, out.height, out.width) == ref.shape[1:]
+        cot = jnp.asarray(rng.normal(size=ref.shape), jnp.float32)
+
+        def ours(x, w, b):
+            y, _, _ = layer.forward(
+                {"W": w.astype(dtype), "b": b.astype(dtype)}, {},
+                x.astype(dtype), train=False, rng=None)
+            assert y.dtype == jnp.dtype(dtype)
+            return y.astype(jnp.float32)
+
+        got = ours(x, w, b)
+        assert got.shape == ref.shape
+        g_got = jax.grad(lambda *a: jnp.sum(ours(*a) * cot), (0, 1, 2))(x, w, b)
+        g_ref = jax.grad(lambda *a: jnp.sum(_conv_layer_reference(
+            *a, stride, pad, dilation, mode, act) * cot), (0, 1, 2))(x, w, b)
+        for a, r in zip((got,) + g_got, (ref,) + g_ref):
+            a, r = np.asarray(a), np.asarray(r)
+            if dtype == "float32":
+                np.testing.assert_allclose(a, r, rtol=1e-5, atol=1e-5)
+            else:
+                # 8 bits of mantissa: 2^-8 an operation, over a sum of up
+                # to 27 products and a rounded result; and a relu whose
+                # input rounds across zero moves single entries
+                assert np.linalg.norm(a - r) <= 2e-2 * np.linalg.norm(r)
 
 
 class TestFusedSteps:

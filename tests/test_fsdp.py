@@ -108,8 +108,7 @@ def test_unsatisfiable_mesh_degrades_with_warning():
 def test_single_device_degrades_to_replica_subprocess():
     """conf.sharding(fsdp=8) on a 1-device host must be inert: plan
     None, fit() trains, params finite — the tier-1 graceful-degrade
-    smoke (DL4J_BENCH_DRY_RUN honored by the bench registration is
-    asserted in test_input_pipeline's dry-run case)."""
+    smoke."""
     code = """
 import numpy as np
 from deeplearning4j_tpu.datasets.iterators import ListDataSetIterator
@@ -312,6 +311,28 @@ def test_updater_bytes_shrink_by_fsdp_degree():
     assert axes["fsdp"] == 8 and axes["data"] == 1
 
 
+def test_collective_bytes_gauges_are_the_sharded_remainder():
+    """What a step moves between chips, from the gauges: every parameter
+    byte a device does not hold is gathered for the forward and its
+    gradient scattered after the backward.  Over fsdp=4 both weights and
+    the 32-wide bias shard; the 4-wide bias (under ``replicate_below``)
+    stays whole."""
+    net = _net(True)
+    rng = np.random.default_rng(0)
+    net.fit(rng.normal(size=(16, 16)).astype(np.float32),
+            np.eye(4, dtype=np.float32)[rng.integers(0, 4, 16)])
+
+    def gauge(name):
+        return _gauge(f"dl4j_sharding_{name}")[0]["value"]
+
+    assert (gauge("params_sharded"), gauge("params_replicated")) == (3, 1)
+    sharded = (16 * 32 + 32 + 32 * 4) * 4        # bytes, float32
+    assert gauge("param_bytes_total") == sharded + 4 * 4
+    assert gauge("param_bytes_per_device") == sharded / 4 + 4 * 4
+    assert gauge("allgather_bytes_per_step") == sharded * 3 / 4
+    assert gauge("reducescatter_bytes_per_step") == sharded * 3 / 4
+
+
 # ---------------------------------------------------------------------------
 # mesh-reshape-tolerant checkpoints
 # ---------------------------------------------------------------------------
@@ -403,15 +424,3 @@ print("RESHAPE_OK")
                        text=True, timeout=300, env=env, cwd=ROOT)
     assert p.returncode == 0, p.stderr[-2000:]
     assert "RESHAPE_OK" in p.stdout
-
-
-def test_flops_model_counts_dense_gemms():
-    from deeplearning4j_tpu.ops import flops as flops_model
-    net = _net(False)
-    fwd = flops_model.forward_flops(net, batch=32)
-    # two GEMMs: 32x16x32 and 32x32x4
-    assert fwd == 2 * 32 * (16 * 32) + 2 * 32 * (32 * 4)
-    step = flops_model.train_step_flops(net, batch=32)
-    assert step == 3 * fwd
-    est = flops_model.mfu(net, 32, step_seconds=0.001, peak_flops=1e12)
-    assert 0 < est["mfu_estimate"] < 1

@@ -46,15 +46,15 @@ class TestAvailability:
 
     def test_global_kill_beats_force(self, monkeypatch):
         monkeypatch.setenv("DL4J_PALLAS", "0")
-        monkeypatch.setenv("DL4J_PALLAS_CONV", "1")
-        assert not helpers.available("conv2d")
+        monkeypatch.setenv("DL4J_PALLAS_DROPOUT", "1")
+        assert not helpers.available("dropout")
 
     def test_per_tier_force_on_and_off(self, monkeypatch):
-        monkeypatch.setenv("DL4J_PALLAS_CONV", "1")
-        assert helpers.available("conv2d")
+        monkeypatch.setenv("DL4J_PALLAS_DROPOUT", "1")
+        assert helpers.available("dropout")
         assert not helpers.available("lstm_step")  # other tiers untouched
-        monkeypatch.setenv("DL4J_PALLAS_CONV", "0")
-        assert not helpers.available("conv2d")
+        monkeypatch.setenv("DL4J_PALLAS_DROPOUT", "0")
+        assert not helpers.available("dropout")
 
     def test_runtime_kill_switch_beats_force(self, monkeypatch):
         monkeypatch.setenv("DL4J_PALLAS_LSTM", "1")
@@ -64,17 +64,8 @@ class TestAvailability:
 
     @pytest.mark.parametrize("op", helpers.OPS)
     def test_fake_tpu_default(self, monkeypatch, op):
-        # on a TPU every tier selects by default but conv: XLA's own
-        # convolution is the default there too (PERF.md 6, PR 28)
         monkeypatch.setenv("DL4J_TPU", "1")
-        assert helpers.available(op) == (op != "conv2d")
-
-    def test_fake_tpu_conv_only_by_force(self, monkeypatch):
-        monkeypatch.setenv("DL4J_TPU", "1")
-        monkeypatch.setenv("DL4J_PALLAS_CONV", "1")
-        assert helpers.available("conv2d")
-        monkeypatch.setenv("DL4J_PALLAS", "0")    # the global kill still wins
-        assert not helpers.available("conv2d")
+        assert helpers.available(op)
 
     def test_disable_all_tiers(self, monkeypatch):
         monkeypatch.setenv("DL4J_TPU", "1")
@@ -89,38 +80,6 @@ class TestAvailability:
 # ---------------------------------------------------------------------------
 
 class TestSelection:
-    def test_conv_selection_counts(self, monkeypatch):
-        rng = np.random.default_rng(0)
-        x = jnp.asarray(rng.normal(size=(2, 3, 8, 8)), jnp.float32)
-        w = jnp.asarray(rng.normal(size=(4, 3, 3, 3)) * 0.2, jnp.float32)
-        b = jnp.zeros((4,), jnp.float32)
-
-        before_f = _counter_value("dl4j_pallas_fallback_total", "conv2d")
-        dense = helpers.conv2d_bias_act(x, w, b, activation="relu")
-        assert _counter_value("dl4j_pallas_fallback_total",
-                              "conv2d") == before_f + 1
-
-        monkeypatch.setenv("DL4J_PALLAS_CONV", "1")
-        before_s = _counter_value("dl4j_pallas_selected_total", "conv2d")
-        fused = helpers.conv2d_bias_act(x, w, b, activation="relu")
-        assert _counter_value("dl4j_pallas_selected_total",
-                              "conv2d") == before_s + 1
-        np.testing.assert_allclose(np.asarray(fused), np.asarray(dense),
-                                   rtol=1e-5, atol=1e-5)
-
-    def test_conv_unsupported_shape_falls_back_even_forced(self, monkeypatch):
-        monkeypatch.setenv("DL4J_PALLAS_CONV", "1")
-        rng = np.random.default_rng(0)
-        x = jnp.asarray(rng.normal(size=(2, 3, 8, 8)), jnp.float32)
-        w = jnp.asarray(rng.normal(size=(4, 3, 3, 3)) * 0.2, jnp.float32)
-        b = jnp.zeros((4,), jnp.float32)
-        before = _counter_value("dl4j_pallas_fallback_total", "conv2d")
-        y = helpers.conv2d_bias_act(x, w, b, stride=(2, 2),
-                                    activation="relu")   # strided: dense
-        assert y.shape == (2, 4, 3, 3)
-        assert _counter_value("dl4j_pallas_fallback_total",
-                              "conv2d") == before + 1
-
     def test_dropout_selection(self, monkeypatch):
         x = jnp.ones((64, 128), jnp.float32)
         key = jax.random.PRNGKey(0)
@@ -199,12 +158,12 @@ class TestWarmValidation:
     def test_failing_helper_disables_only_its_tier(self, monkeypatch):
         def boom(*a, **k):
             raise RuntimeError("mosaic rejected")
-        monkeypatch.setattr(pk, "fused_conv2d_bias_act", boom)
+        monkeypatch.setattr(pk, "fused_threshold_dropout", boom)
         st = helpers.kernel_self_test()
-        assert st["conv2d_bias_act"].startswith("error")
+        assert st["dropout"].startswith("error")
         assert st["lstm_step"] == "ok"
-        assert st["dropout"] == "ok"
-        assert "conv" in pk._disabled
+        assert st["softmax_xent"] == "ok"
+        assert "dropout" in pk._disabled
         assert "lstm" not in pk._disabled and "flash" not in pk._disabled
 
     def test_ensure_validated_cheap_off_tpu(self):
@@ -216,25 +175,7 @@ class TestWarmValidation:
         monkeypatch.setenv("DL4J_PALLAS_DROPOUT", "1")
         res = helpers.ensure_validated()
         assert res["dropout"] == "ok"
-        assert "conv2d_bias_act" not in res        # only eligible tiers run
-
-    def test_fake_tpu_validation_leaves_conv_out(self, monkeypatch):
-        # what fit/setup runs on a chip; the kernels themselves need the
-        # chip's compiler (tests/test_tpu_compile.py), so stand-ins here
-        monkeypatch.setenv("DL4J_TPU", "1")
-        for op in helpers.OPS:
-            monkeypatch.setitem(
-                helpers._HELPERS, op,
-                helpers.helper_for(op)._replace(self_test=lambda: None))
-        res = helpers.ensure_validated()
-        ran = {k for k, v in res.items() if v == "ok"}
-        assert ran == {helpers.helper_for(op).test_name
-                       for op in helpers.OPS if op != "conv2d"}
-        assert "conv2d_bias_act" not in res
-        monkeypatch.setenv("DL4J_PALLAS_CONV", "1")
-        helpers.reset_validation()
-        assert helpers.ensure_validated()["conv2d_bias_act"] == "ok"
-
+        assert "lstm_step" not in res              # only eligible tiers run
 
 # ---------------------------------------------------------------------------
 # Fallback equivalence through the public fit()/output() path
@@ -311,19 +252,8 @@ class TestFallbackEquivalence:
     def test_conv_net_tier_disable_is_byte_identical(self, monkeypatch):
         p_base, o_base = _fit_conv_net(monkeypatch, {})
         p_off, o_off = _fit_conv_net(monkeypatch, {"DL4J_PALLAS": "0"})
-        p_tier, o_tier = _fit_conv_net(monkeypatch,
-                                       {"DL4J_PALLAS_CONV": "0"})
         assert np.array_equal(p_base, p_off)
         assert np.array_equal(o_base, o_off)
-        assert np.array_equal(p_base, p_tier)
-        assert np.array_equal(o_base, o_tier)
-
-    def test_conv_net_fused_matches_dense(self, monkeypatch):
-        p_base, o_base = _fit_conv_net(monkeypatch, {})
-        p_fused, o_fused = _fit_conv_net(monkeypatch,
-                                         {"DL4J_PALLAS_CONV": "1"})
-        np.testing.assert_allclose(p_fused, p_base, rtol=1e-5, atol=1e-5)
-        np.testing.assert_allclose(o_fused, o_base, rtol=1e-5, atol=1e-5)
 
     def test_lstm_net_tier_disable_is_byte_identical(self, monkeypatch):
         p_base, o_base = _fit_lstm_net(monkeypatch, {})
@@ -365,17 +295,14 @@ class TestFallbackEquivalence:
 # runs are NOT the ones platform.is_tpu() picks.
 # ---------------------------------------------------------------------------
 
-@pytest.fixture(params=["conv-forced", "conv-default"])
-def chip_default_selections(monkeypatch, request):
-    """bf16 and every tier a chip selects, forced (interpret mode); the
-    conv tier, which no chip selects by default, forced too or left."""
+@pytest.fixture
+def chip_default_selections(monkeypatch):
+    """bf16 and every tier a chip selects, forced (interpret mode)."""
     from deeplearning4j_tpu.ops import dtypes
-    conv_forced = request.param == "conv-forced"
-    for tier, env in helpers._ENV_TIER.items():
-        if tier != "conv" or conv_forced:
-            monkeypatch.setenv(env, "1")
+    for env in helpers._ENV_TIER.values():
+        monkeypatch.setenv(env, "1")
     dtypes.set_default_policy(dtypes.BF16)
-    yield conv_forced
+    yield
     dtypes.set_default_policy(None)
 
 
@@ -406,18 +333,8 @@ class TestChipDefaultSelections:
         rng = np.random.default_rng(0)
         x = rng.normal(size=(8,) + in_shape).astype(np.float32)
         y = np.eye(10, dtype=np.float32)[rng.integers(0, 10, 8)]
-        before = _counter_value("dl4j_pallas_selected_total", "conv2d")
-        fallback = _counter_value("dl4j_pallas_fallback_total", "conv2d")
         net.fit(DataSet(x, y))
         assert np.isfinite(float(net.score()))
-        selected = _counter_value("dl4j_pallas_selected_total",
-                                  "conv2d") - before
-        if chip_default_selections:
-            assert selected > 0
-        else:
-            assert selected == 0
-            assert _counter_value("dl4j_pallas_fallback_total",
-                                  "conv2d") > fallback
         assert not pk._disabled       # the warm self-test passed in bf16 too
 
     def test_fake_tpu_cnn_step_holds_no_pallas_call(self, monkeypatch):
@@ -432,28 +349,25 @@ class TestChipDefaultSelections:
         jaxpr = str(jax.make_jaxpr(net._build_step_raw())(*args))
         assert "conv_general_dilated" in jaxpr and "bf16" in jaxpr
         assert "pallas_call" not in jaxpr
-        monkeypatch.setenv("DL4J_PALLAS_CONV", "1")      # the force still works
-        forced = str(jax.make_jaxpr(net._build_step_raw())(*args))
-        assert "pallas_call" in forced
 
     def test_disabled_tier_is_logged_once_with_the_message(
             self, monkeypatch, caplog):
         def boom(*a, **k):
             raise RuntimeError("mosaic rejected block shape")
-        monkeypatch.setattr(pk, "fused_conv2d_bias_act", boom)
+        monkeypatch.setattr(pk, "fused_lstm_step", boom)
         with caplog.at_level("WARNING", logger=helpers.log.name):
             helpers.kernel_self_test()
             helpers.kernel_self_test()
-        said = [r for r in caplog.records if "conv" in r.getMessage()]
+        said = [r for r in caplog.records if "lstm" in r.getMessage()]
         assert len(said) == 1 and said[0].levelname == "WARNING"
         assert "mosaic rejected block shape" in said[0].getMessage()
 
     def test_self_test_can_report_without_disabling(self, monkeypatch):
         def boom(*a, **k):
             raise RuntimeError("mosaic rejected")
-        monkeypatch.setattr(pk, "fused_conv2d_bias_act", boom)
+        monkeypatch.setattr(pk, "fused_lstm_step", boom)
         st = helpers.kernel_self_test(disable_on_error=False)
-        assert st["conv2d_bias_act"].startswith("error")
+        assert st["lstm_step"].startswith("error")
         assert not pk._disabled and "disabled" not in st
 
 
@@ -466,12 +380,11 @@ class TestPartitionedTrace:
 
     def test_tiers_leave_selection_inside_the_scope(self, monkeypatch):
         monkeypatch.setenv("DL4J_TPU", "1")
-        assert all(helpers.available(op) for op in helpers.OPS
-                   if op != "conv2d")
+        assert all(helpers.available(op) for op in helpers.OPS)
         with pk.partitioned_trace():
             assert not any(helpers.available(op) for op in helpers.OPS)
-            monkeypatch.setenv("DL4J_PALLAS_CONV", "1")
-            assert helpers.available("conv2d")   # an explicit force wins
+            monkeypatch.setenv("DL4J_PALLAS_DROPOUT", "1")
+            assert helpers.available("dropout")  # an explicit force wins
         assert helpers.available("lstm_step")
 
     @pytest.mark.parametrize("how", ["parallel_wrapper", "conf_sharding"])
@@ -494,12 +407,15 @@ class TestPartitionedTrace:
             # fit() warm-validates eligible tiers first; on the CPU that
             # self-test cannot pass, and it is not what is under test
             monkeypatch.setattr(helpers, "ensure_validated", lambda: {})
+        # the dense layer's dropout sees [16, 256]: a size the dropout
+        # tier takes on a chip
         conf = (b.list()
-                .layer(L.ConvolutionLayer(n_out=8, kernel=(3, 3),
+                .layer(L.ConvolutionLayer(n_out=16, kernel=(3, 3),
                                           activation="relu",
                                           convolution_mode="same"))
                 .layer(L.SubsamplingLayer())
-                .layer(L.DenseLayer(n_out=16, activation="relu"))
+                .layer(L.DenseLayer(n_out=16, activation="relu",
+                                    dropout=0.5))
                 .layer(L.OutputLayer(n_out=10, activation="softmax",
                                      loss="mcxent"))
                 .set_input_type(InputType.convolutional(8, 8, 1))
@@ -508,8 +424,8 @@ class TestPartitionedTrace:
         rng = np.random.default_rng(0)
         ds = DataSet(rng.normal(size=(16, 1, 8, 8)).astype(np.float32),
                      np.eye(10, dtype=np.float32)[rng.integers(0, 10, 16)])
-        selected = _counter_value("dl4j_pallas_selected_total", "conv2d")
-        fallback = _counter_value("dl4j_pallas_fallback_total", "conv2d")
+        selected = _counter_value("dl4j_pallas_selected_total", "dropout")
+        fallback = _counter_value("dl4j_pallas_fallback_total", "dropout")
         if how == "parallel_wrapper":
             mesh = make_mesh(MeshConfig(data=2, fsdp=2),
                              devices=jax.devices()[:4])
@@ -521,6 +437,6 @@ class TestPartitionedTrace:
         assert np.isfinite(net.score(ds))
         assert np.all(np.isfinite(np.asarray(net.output(ds.features))))
         assert _counter_value("dl4j_pallas_selected_total",
-                              "conv2d") == selected
+                              "dropout") == selected
         assert _counter_value("dl4j_pallas_fallback_total",
-                              "conv2d") >= fallback + 3
+                              "dropout") >= fallback + 1

@@ -1,13 +1,9 @@
 """Parallel input pipeline (datasets/iterators.AsyncDataSetIterator):
 deterministic ordering, sync-vs-async parity, lifecycle/thread hygiene,
-staging bounds, vectorized record ETL, streaming normalizer fit, and
-the bench record/registry smoke path."""
+staging bounds, vectorized record ETL and streaming normalizer fit."""
 
 import gc
 import json
-import os
-import subprocess
-import sys
 import threading
 import time
 
@@ -457,7 +453,7 @@ def test_cg_fit_accepts_plain_iterable():
 
 
 # ---------------------------------------------------------------------------
-# Conf plumbing + bench smoke
+# Conf plumbing
 # ---------------------------------------------------------------------------
 def test_conf_pipeline_settings_roundtrip():
     conf = (NeuralNetConfiguration.builder()
@@ -477,56 +473,3 @@ def test_conf_pipeline_settings_roundtrip():
         d["global"].pop(k)
     g2 = MultiLayerConfiguration.from_dict(d).global_conf
     assert g2.pipeline_workers == 1 and g2.pipeline_prefetch == 4
-
-
-def test_bench_dry_run_emits_record_on_cpu():
-    """bench.py asked for the CPU by name (DL4J_BENCH_PLATFORM=cpu)
-    emits its JSON record.  Dry-run skips every config but walks the
-    whole record/registry path."""
-    env = dict(os.environ)
-    env.update({"JAX_PLATFORMS": "cpu", "DL4J_BENCH_PLATFORM": "cpu",
-                "DL4J_BENCH_DRY_RUN": "1"})
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    p = subprocess.run([sys.executable, os.path.join(root, "bench.py")],
-                       capture_output=True, text=True, timeout=240,
-                       env=env, cwd=root)
-    assert p.returncode == 0, p.stderr[-2000:]
-    line = p.stdout.strip().splitlines()[-1]
-    rec = json.loads(line)
-    assert "fatal_error" not in rec, rec
-    assert rec["configs"], "config registry empty"
-    assert all(c.get("skipped") == "dry-run" for c in rec["configs"].values())
-    assert "bench_pipeline" in rec["configs"]
-    assert "bench_sharded" in rec["configs"]
-    assert "bench_fleet" in rec["configs"]
-    assert "bench_spec" in rec["configs"]
-    assert "bench_elastic" in rec["configs"]
-    assert rec.get("machine", {}).get("host"), "machine fingerprint missing"
-    assert "metrics_registry" in rec
-    # the dry run also gates dl4j-lint: zero unsuppressed findings
-    assert rec.get("lint", {}).get("exit_code") == 0, rec.get("lint")
-    assert rec["lint"]["gating"] == 0
-    assert rec.get("platform_forced") == "cpu" or "cpu" in str(
-        rec.get("platform", ""))
-
-
-@pytest.mark.parametrize("env_update,needle", [
-    ({"DL4J_BENCH_PLATFORM": "bogus"}, "bogus"),     # backend cannot init
-    ({"JAX_PLATFORMS": "cpu"}, "needs a TPU"),       # no chip, cpu not named
-])
-def test_bench_fails_without_a_chip(env_update, needle):
-    """No chip is an error, never a quiet move to the CPU: unless the
-    CPU is asked for by name, bench.py exits non-zero, and its one JSON
-    line carries the error and no config."""
-    env = dict(os.environ, DL4J_BENCH_DRY_RUN="1")
-    env.pop("JAX_PLATFORMS", None)
-    env.pop("DL4J_BENCH_PLATFORM", None)
-    env.update(env_update)
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    p = subprocess.run([sys.executable, os.path.join(root, "bench.py")],
-                       capture_output=True, text=True, timeout=240,
-                       env=env, cwd=root)
-    assert p.returncode != 0, p.stdout[-2000:]
-    rec = json.loads(p.stdout.strip().splitlines()[-1])
-    assert needle in rec["fatal_error"]
-    assert "platform" not in rec and not rec.get("configs")

@@ -120,6 +120,44 @@ def test_arena_exhaustion_sheds_retryably_and_close_unblocks():
         pool.stop()
 
 
+def test_paged_arena_admits_twice_the_dense_sessions_at_equal_kv_tokens():
+    """Capacity by tokens resident: a mixed load (every fourth stream
+    fills the window, the rest hold an eighth of it) is pushed into the
+    dense pool and into an arena of the same KV tokens until each sheds.
+    The dense pool stops at its slots; the arena at its tokens."""
+    window, short, chunk, slots = 32, 4, 4, 4
+    net = _attn_mln(seed=13, window=window)
+    x = _seq(1, window, seed=17)
+
+    def admit_mixed(pool):
+        """Sessions whose whole stream landed before the first shed."""
+        admitted = 0
+        for i in range(64):
+            try:
+                sid = pool.open_session()
+                for c0 in range(0, window if i % 4 == 0 else short, chunk):
+                    pool.step(sid, x[0, c0:c0 + chunk])
+            except OverloadedError:
+                break
+            admitted += 1
+        return admitted
+
+    dense = DecodePool(net, name="pp-adm-d", max_slots=slots,
+                       max_wait_ms=0.5)
+    paged = _paged(net, "pp-adm-p", max_slots=24,
+                   kv_arena_tokens=slots * window)
+    try:
+        n_dense, n_paged = admit_mixed(dense), admit_mixed(paged)
+        st = paged.stats()["kv_arena"]
+    finally:
+        dense.stop()
+        paged.stop()
+    assert n_dense == slots
+    # 3 long + 8 short streams are 3*32 + 8*4 = 128 tokens: the arena
+    assert n_paged == 11 and n_paged >= 2 * n_dense
+    assert st["blocks_free"] == 0
+
+
 def test_kv_dtype_bf16_bounded_parity():
     net = _attn_mln(seed=31)
     x = _seq(1, 10, seed=7)

@@ -685,10 +685,6 @@ def _tier_lowerings():
     def xent(lg, lb):
         return pk.softmax_xent_rows(lg, lb).mean()
 
-    def conv(x, w, b):
-        return pk.fused_conv2d_bias_act(x, w, b, border_mode="same",
-                                        activation="relu").sum()
-
     def lstm(zx, h, c, rw, p3):
         c_new, h_new = pk.fused_lstm_step(zx, h, c, rw, p3)
         return c_new.sum() + h_new.sum()
@@ -701,8 +697,6 @@ def _tier_lowerings():
                   ("dl4j_flash_fwd", "dl4j_flash_dq", "dl4j_flash_dkv")),
         "xent": (xent, (f32(256, 512), f32(256, 512)),
                  ("dl4j_softmax_xent",)),
-        "conv": (conv, (f32(2, 3, 10, 10), f32(8, 3, 3, 3), f32(8)),
-                 ("dl4j_conv_bias_act",)),
         "lstm": (lstm, (f32(4, 64), f32(4, 16), f32(4, 16), f32(16, 64),
                         f32(3, 16)), ("dl4j_lstm_step",)),
         "dropout": (dropout, (f32(64, 128),
@@ -711,7 +705,7 @@ def _tier_lowerings():
     }
 
 
-@pytest.mark.parametrize("tier", ["flash", "xent", "conv", "lstm", "dropout"])
+@pytest.mark.parametrize("tier", ["flash", "xent", "lstm", "dropout"])
 def test_pallas_tier_lowering_carries_its_kernel_name(tier):
     import jax
     fn, args, names = _tier_lowerings()[tier]

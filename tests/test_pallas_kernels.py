@@ -7,6 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from deeplearning4j_tpu.ops import helpers
 from deeplearning4j_tpu.ops import pallas_kernels as pk
 
 
@@ -301,7 +302,7 @@ def test_fused_softmax_xent_ragged_rows():
 
 
 class TestKernelSelfTest:
-    """Round-4 bench preflight: per-kernel compile check + per-tier kill
+    """Per-kernel compile check + per-tier kill
     switch (the cuDNN-try/builtin-fallback pattern,
     ref ConvolutionLayer.java:67,157-212)."""
 
@@ -309,7 +310,7 @@ class TestKernelSelfTest:
         pk._disabled.clear()
 
     def test_self_test_ok(self):
-        st = pk.kernel_self_test()
+        st = helpers.kernel_self_test()
         assert st["flash_attention"] == "ok"
         assert st["softmax_xent"] == "ok"
         assert st["interpret_mode"] is True  # CPU test mesh
@@ -334,98 +335,10 @@ class TestKernelSelfTest:
         def boom(*a, **k):
             raise RuntimeError("mosaic rejected")
         monkeypatch.setattr(pk, "flash_attention", boom)
-        st = pk.kernel_self_test()
+        st = helpers.kernel_self_test()
         assert st["flash_attention"].startswith("error")
         assert st["softmax_xent"] == "ok"
         assert "flash" in st["disabled"] and "xent" not in st["disabled"]
-
-
-# ===========================================================================
-# Fused conv2d + bias + activation (the CudnnConvolutionHelper analog)
-# ===========================================================================
-
-def _conv_ref(x, w, b, pad, mode, act):
-    from deeplearning4j_tpu.ops import activations as act_ops
-    from deeplearning4j_tpu.ops import convolution as conv_ops
-    return act_ops.get(act)(
-        conv_ops.conv2d(x, w, b, (1, 1), pad, (1, 1), mode))
-
-
-class TestFusedConv:
-    """Numerics-parity grid: fused vs the dense XLA chain, forward AND
-    gradient (jax.grad) at <= 1e-5, over shape/pad-mode/activation."""
-
-    @pytest.mark.parametrize("shape,kernel,pad,mode", [
-        ((2, 3, 10, 10), (3, 3), (0, 0), "truncate"),
-        ((2, 3, 10, 10), (3, 3), (1, 1), "truncate"),
-        ((1, 1, 28, 28), (5, 5), (0, 0), "truncate"),
-        ((2, 4, 9, 7), (3, 3), (0, 0), "same"),
-        ((2, 2, 8, 8), (2, 2), (0, 0), "same"),  # even kernel: SAME pads high
-    ])
-    @pytest.mark.parametrize("act", ["identity", "relu", "tanh"])
-    def test_forward_and_grad_parity(self, shape, kernel, pad, mode, act):
-        rng = np.random.default_rng(11)
-        N, Cin, H, W = shape
-        Cout = 6
-        x = jnp.asarray(rng.normal(size=shape), jnp.float32)
-        w = jnp.asarray(rng.normal(size=(Cout, Cin) + kernel) * 0.2,
-                        jnp.float32)
-        b = jnp.asarray(rng.normal(size=(Cout,)), jnp.float32)
-        assert pk.conv_fused_supported(x.shape, w.shape, x.dtype,
-                                       activation=act, pad=pad,
-                                       border_mode=mode)
-        fused = pk.fused_conv2d_bias_act(x, w, b, pad=pad, border_mode=mode,
-                                         activation=act)
-        ref = _conv_ref(x, w, b, pad, mode, act)
-        np.testing.assert_allclose(np.asarray(fused), np.asarray(ref),
-                                   rtol=1e-5, atol=1e-5)
-
-        def lf(x, w, b):
-            return jnp.sum(pk.fused_conv2d_bias_act(
-                x, w, b, pad=pad, border_mode=mode, activation=act) ** 2)
-
-        def lr(x, w, b):
-            return jnp.sum(_conv_ref(x, w, b, pad, mode, act) ** 2)
-
-        gf = jax.grad(lf, argnums=(0, 1, 2))(x, w, b)
-        gr = jax.grad(lr, argnums=(0, 1, 2))(x, w, b)
-        for a, r in zip(gf, gr):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(r),
-                                       rtol=1e-5, atol=1e-5)
-
-    def test_bf16_smoke(self):
-        rng = np.random.default_rng(2)
-        x = jnp.asarray(rng.normal(size=(2, 3, 8, 8)), jnp.bfloat16)
-        w = jnp.asarray(rng.normal(size=(4, 3, 3, 3)) * 0.2, jnp.bfloat16)
-        b = jnp.asarray(rng.normal(size=(4,)), jnp.bfloat16)
-        fused = pk.fused_conv2d_bias_act(x, w, b, border_mode="same",
-                                         activation="relu")
-        ref = _conv_ref(x, w, b, (0, 0), "same", "relu")
-        assert fused.dtype == jnp.bfloat16
-        np.testing.assert_allclose(
-            np.asarray(fused, np.float32), np.asarray(ref, np.float32),
-            rtol=2e-2, atol=2e-2)
-
-    def test_supported_predicate_edges(self):
-        f32 = jnp.float32
-        ok = pk.conv_fused_supported((2, 3, 10, 10), (6, 3, 3, 3), f32)
-        assert ok
-        # strided / dilated convs keep the dense path
-        assert not pk.conv_fused_supported((2, 3, 10, 10), (6, 3, 3, 3),
-                                           f32, stride=(2, 2))
-        assert not pk.conv_fused_supported((2, 3, 10, 10), (6, 3, 3, 3),
-                                           f32, dilation=(2, 2))
-        # cross-feature activation: not fusable elementwise
-        assert not pk.conv_fused_supported((2, 3, 10, 10), (6, 3, 3, 3),
-                                           f32, activation="softmax")
-        # f64 (CPU gradient checks) keeps the dense path
-        assert not pk.conv_fused_supported((2, 3, 10, 10), (6, 3, 3, 3),
-                                           jnp.float64)
-        # VMEM budget: a 512-channel 128x128 image blows the window
-        assert not pk.conv_fused_supported((1, 512, 128, 128),
-                                           (512, 512, 3, 3), f32)
-        # degenerate output extent
-        assert not pk.conv_fused_supported((1, 1, 2, 2), (1, 1, 5, 5), f32)
 
 
 # ===========================================================================

@@ -429,6 +429,22 @@ def test_grad_quant_cluster_parity(_clean_tiers):
                           dtype="int8") > int8_before
 
 
+def test_grad_quant_wire_bytes_shrink_3_5x(_clean_tiers):
+    """The same steps over the f32 wire and over the int8 wire (codes
+    plus one f32 scale a block): the engine's own byte meter reads at
+    least 3.5x fewer bytes contributed to the barrier."""
+    def wire_bytes(quant, dtype):
+        before = _counter_value("dl4j_precision_grad_bytes_total",
+                                dtype=dtype)
+        _run_quant_cluster(quant, epochs=1)
+        return _counter_value("dl4j_precision_grad_bytes_total",
+                              dtype=dtype) - before
+
+    dense = wire_bytes(None, "float32")
+    int8 = wire_bytes("int8", "int8")
+    assert int8 > 0 and dense >= 3.5 * int8, (dense, int8)
+
+
 def test_grad_quant_kill_switch_byte_identical(_clean_tiers, monkeypatch):
     """DL4J_DIST_QUANT=0 forces the dense wire even when the conf asks
     for int8 — the cluster result is bit-identical to a dense cluster."""

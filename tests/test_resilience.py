@@ -488,6 +488,33 @@ def _chaos_batches():
             for _ in range(8)]
 
 
+def _counter_total(name):
+    from deeplearning4j_tpu import monitor
+    fam = monitor.get_registry().get(name)
+    return sum(s["value"] for s in fam.samples()) if fam else 0.0
+
+
+def test_absorbed_reader_faults_are_counted_as_retries():
+    """Chaos at a rate the feeder's retries cover is absorbed, not
+    surfaced: fit() takes every step, and each injected reader fault is
+    one counted retry and no exhaustion."""
+    from deeplearning4j_tpu.datasets.iterators import ListDataSetIterator
+    batches = _chaos_batches()
+    retries = _counter_total("dl4j_resilience_retries_total")
+    exhausted = _counter_total("dl4j_resilience_retry_exhausted_total")
+    faults.arm({"site": "reader.next_raw", "mode": "fail",
+                "probability": 0.25, "seed": 5, "exc": "TransientError"})
+    net = MultiLayerNetwork(_ft_conf()).init()
+    net.fit(ListDataSetIterator(list(batches)), epochs=2)
+    injected = faults.armed("reader.next_raw")[0]["injected"]
+    assert net.iteration == 2 * len(batches)
+    assert injected > 0
+    assert _counter_total("dl4j_resilience_retries_total") \
+        == retries + injected
+    assert _counter_total("dl4j_resilience_retry_exhausted_total") \
+        == exhausted
+
+
 def test_chaos_crash_resume_parity(tmp_path):
     """Acceptance: with a fault plan crashing fit mid-run and seeded
     transient reader faults, a restart with resume=True completes and

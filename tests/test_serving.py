@@ -171,6 +171,38 @@ def test_concurrent_batched_predict_matches_serial(tmp_path, bucketing):
     ep.close()
 
 
+def test_concurrent_predicts_compile_within_the_ladder(tmp_path):
+    """The ladder, not the traffic, bounds the output programs: ragged
+    requests from concurrent clients coalesce into batches that land on
+    the rungs warmed at load, and compile nothing."""
+    path = _write_mlp(tmp_path / "m.zip")
+    ep = DeepLearning4jEntryPoint(max_batch=16, max_wait_ms=2.0, min_batch=4)
+    rng = np.random.default_rng(5)
+    rows = [[rng.normal(size=(int(n), F)).astype(np.float32)
+             for n in rng.integers(1, 4, 6)] for _ in range(4)]
+    ep.predict(path, features=rows[0][0])     # load + warm the ladder
+    model = ep.model_cache.peek(path)
+    ladder = next(iter(ep.model_cache.stats()["models"].values()))[
+        "warmup"]["buckets"]
+    warmed = model.compile_telemetry.snapshot()["by_kind"]["output"]
+    assert 0 < warmed <= len(ladder)
+
+    def client(rs):
+        for r in rs:
+            ep.predict(path, features=r, argmax_only=True)
+
+    threads = [threading.Thread(target=client, args=(rs,)) for rs in rows]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(60)
+        assert not t.is_alive()
+    s = next(iter(ep.stats()["serving"].values()))
+    assert s["requests"] == 4 * 6 + 1
+    assert model.compile_telemetry.snapshot()["by_kind"]["output"] == warmed
+    ep.close()
+
+
 def test_lone_request_not_stuck_waiting_for_full_batch():
     """max_wait_ms bounds the coalescing window: with min_batch > 1 a
     single request must be dispatched when the window expires, not wait

@@ -348,6 +348,31 @@ def test_spec_generate_byte_identical_ngram_and_scripted():
         pool.stop()
 
 
+def test_ngram_draft_commits_two_tokens_a_dispatch_on_a_repeating_stream():
+    """What speculation is for: once the greedy stream repeats, the
+    n-gram draft's K proposals agree, and one verify dispatch commits
+    several tokens: at least two a dispatch over the run, the same
+    tokens as plain greedy decode."""
+    net = _vocab_mln(seed=13)
+    prompt = [2, 0, 4]
+    N = 48
+    pool = DecodePool(net, max_slots=4, max_wait_ms=0.5)
+    try:
+        ref = _greedy_ref(pool, prompt, N)
+        assert len(set(ref[8:])) == 1          # the stream has settled
+        sid = pool.open_session()
+        (o,) = pool.step(sid, one_hot(prompt, V))
+        first = int(np.argmax(o[-1]))
+        dec = SpeculativeDecoder(pool, vocab=V, k=3,
+                                 draft=NGramDraft(order=3))
+        res = dec.generate(sid, first, N)
+        assert res["tokens"] == ref
+        assert 2 * res["dispatches"] <= N
+        pool.close_session(sid)
+    finally:
+        pool.stop()
+
+
 def test_model_draft_proposes_and_stays_exact():
     net = _vocab_mln(seed=17)
     # the draft model IS a copy of the target here — proposals are

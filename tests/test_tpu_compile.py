@@ -65,26 +65,6 @@ def _sq(y):
     return jnp.sum(y.astype(jnp.float32) ** 2)
 
 
-@pytest.mark.parametrize("x_shape,w_shape,dtype", [
-    ((2, 3, 10, 10), (8, 3, 3, 3), jnp.float32),       # self-test shape
-    ((2, 3, 10, 10), (8, 3, 3, 3), jnp.bfloat16),
-    ((32, 64, 32, 32), (64, 64, 3, 3), jnp.float32),   # a VGG block
-    ((32, 64, 32, 32), (64, 64, 3, 3), jnp.bfloat16),
-], ids=["selftest-f32", "selftest-bf16", "vgg-f32", "vgg-bf16"])
-def test_conv_bias_act_compiles(compile_for_chip, x_shape, w_shape, dtype):
-    assert pk.conv_fused_supported(x_shape, w_shape, dtype,
-                                   activation="relu", border_mode="same")
-
-    def step(x, w, b):
-        return jax.value_and_grad(
-            lambda x, w, b: _sq(pk.fused_conv2d_bias_act(
-                x, w, b, border_mode="same", activation="relu")),
-            argnums=(0, 1, 2))(x, w, b)
-    hlo = compile_for_chip(step, (x_shape, dtype), (w_shape, dtype),
-                           ((w_shape[0],), dtype))
-    assert "tpu_custom_call" in hlo
-
-
 @pytest.mark.parametrize("n,h", [(4, 16), (32, 200)],
                          ids=["selftest", "charrnn-32x200"])
 def test_lstm_step_compiles(compile_for_chip, n, h):
