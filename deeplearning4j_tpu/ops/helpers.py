@@ -196,16 +196,19 @@ def attention_wanted(q) -> bool:
 # ---------------------------------------------------------------------------
 
 def _selftest_flash():
+    # what the chip's policy runs: bfloat16 operands, a head of 64,
+    # causal, forward and all three gradients
     import numpy as np
     rng = np.random.default_rng(0)
     B, H, T, D = 1, 2, 256, 64
-    q = jnp.asarray(rng.normal(size=(B, H, T, D)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(B, H, T, D)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(B, H, T, D)), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(B, H, T, D)), jnp.bfloat16)
+    k = jnp.asarray(rng.normal(size=(B, H, T, D)), jnp.bfloat16)
+    v = jnp.asarray(rng.normal(size=(B, H, T, D)), jnp.bfloat16)
     km = jnp.ones((B, T), jnp.float32)
 
     def loss(q, k, v):
-        return pk.flash_attention(q, k, v, km, causal=True).sum()
+        return pk.flash_attention(q, k, v, km, causal=True).astype(
+            jnp.float32).sum()
     vg = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
     out, grads = vg(q, k, v)
     jax.block_until_ready(grads)

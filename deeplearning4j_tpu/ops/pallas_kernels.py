@@ -19,8 +19,10 @@ Four kernels:
   blockwise too (FlashAttention-2 recomputation from the saved per-row
   logsumexp): dq and dk/dv kernels rebuild each [BQ, BK] probability
   tile on the fly, so TRAINING memory is O(T·D) as well — no dense
-  [T, T] rematerialization.  Head dims that aren't multiples of the
-  128-lane width are zero-padded outside the custom_vjp.
+  [T, T] rematerialization.  Products run in the operands' dtype
+  (bfloat16 on the chip) with float32 accumulation; head dims that
+  aren't multiples of the 128-lane width are zero-padded outside the
+  custom_vjp.
 
 * **fused_softmax_xent** — softmax + cross-entropy + gradient in one
   VMEM pass per row block.  The char-RNN/output-layer hot op: avoids
@@ -55,6 +57,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -141,266 +144,291 @@ def _interpret() -> bool:
 # Forward saves per-row logsumexp; backward recomputes attention weights
 # block-by-block from (q, k, lse) — the FlashAttention-2 recomputation
 # scheme — so training never materializes the [T, T] score matrix.
+#
+# The kernels take the operands as the layer has them.  DTYPE: every
+# product multiplies q, k, v, dO tiles in their own dtype (bfloat16
+# under the chip's policy: one MXU pass; float32 in, float32 products)
+# and accumulates in float32; the scores, the softmax, its statistics
+# and the dq/dk/dv accumulators are float32 until the final store, and
+# p and ds are cast to the dtype of the operand they meet.  HEAD WIDTH:
+# D is the blocks' last dimension whatever it is; flash_attention says
+# why it still pads it to 128 lanes.  TILES: square, see _flash_block;
+# the causal comparison runs only on the one tile a q block has on the
+# diagonal.
 # ===========================================================================
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, out_ref, lse_ref, *,
-                      block_k: int, causal: bool, scale: float):
-    """One (batch*head, q-block) program: stream K/V blocks with online
-    softmax.  Block shapes: q [BQ, D], k/v [T, D], mask [1, T]; outputs
-    out [BQ, D] and per-row logsumexp lse [BQ, 1] (a column: Mosaic
-    wants the last two block dims tileable, and the kernels consume the
-    per-row stats as columns anyway)."""
-    q = q_ref[...].astype(jnp.float32) * scale            # [BQ, D]
-    T = k_ref.shape[0]
-    BQ = q.shape[0]
-    qi = pl.program_id(1)
-    q_pos = qi * BQ + lax.broadcasted_iota(jnp.int32, (BQ, 1), 0)
+LANE = 128
+# contract the last dimension of both operands: A · Bᵀ
+_NT = (((1,), (1,)), ((), ()))
+# the per-row logsumexp of a row with no live key: large and POSITIVE,
+# so that the backward's exp(s − lse) is an exact 0 for the whole row
+# with no test per element (T-pad and fully-masked query rows)
+_LSE_DEAD = 1e30
+# q, k, v, dO whole-sequence blocks are double-buffered in VMEM (1 MB an
+# array at T 4,096 x D 64 bfloat16, 4 MB at T 8,192 x D 128 float32) next
+# to a few [block_q, block_k] float32 temporaries; v5e has 128 MiB
+_FLASH_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel"),
+    vmem_limit_bytes=64 << 20)
 
-    def body(s, carry):
+# The square tile's cap: what one v5e measured best, forward and
+# forward + backward, at B 2, H 32 over 8, T 4,096, D 64, bfloat16,
+# causal, among ten tilings from 128 x 128 to 2,048 x 512, and no loss
+# against 128 x 128 at T 384 to 2,048 (PERF.md 6, PR 32).
+_FLASH_BLOCK_CAP = 512
+
+
+def _flash_block(T: int) -> int:
+    """Rows of q a program holds and keys a tile holds, for a sequence of
+    T (a multiple of 128, as flash_attention pads it): the largest
+    multiple of 128 that divides T and is at most the cap, so no
+    sequence computes rows beyond its own padding."""
+    return max(b for b in range(LANE, min(_FLASH_BLOCK_CAP, T) + 1, LANE)
+               if T % b == 0)
+
+
+def _diagonal_tile_live(block: int, transposed: bool = False):
+    """[block, block] of the square tile ON the diagonal: query position
+    >= key position, queries along the rows (along the columns if
+    ``transposed``).  With q blocks and key tiles of one size, tile i of
+    q block i is the only one that crosses the diagonal; tiles before it
+    lie wholly below (no comparison), tiles after it wholly above (never
+    visited)."""
+    rows = lax.broadcasted_iota(jnp.int32, (block, 1), 0)
+    cols = lax.broadcasted_iota(jnp.int32, (1, block), 1)
+    return rows <= cols if transposed else rows >= cols
+
+
+def _flash_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, out_ref, lse_ref, *,
+                      causal: bool, scale: float):
+    """One (batch*head, q-block) program: stream K/V tiles with online
+    softmax.  Block shapes: q [BQ, D], k/v [T, D], mask [1, T]; outputs
+    out [BQ, D] and the per-row logsumexp lse [1, BQ], a lane-dense ROW
+    (a [BQ, 1] column would spend a 128-lane tile on every number in
+    HBM)."""
+    q = q_ref[...]                                        # [BQ, D]
+    BQ, D = q.shape
+    qi = pl.program_id(1)
+
+    def tile(s, carry, *, diagonal=False):
         m, l, acc = carry
-        k_blk = k_ref[pl.dslice(s * block_k, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[pl.dslice(s * block_k, block_k), :].astype(jnp.float32)
-        msk = mask_ref[:, pl.dslice(s * block_k, block_k)]  # [1, BK]
+        start = pl.multiple_of(s * BQ, BQ)
+        k_blk = k_ref[pl.ds(start, BQ), :]
+        v_blk = v_ref[pl.ds(start, BQ), :]
+        live = mask_ref[:, pl.ds(start, BQ)] > 0          # [1, BK]
+        if diagonal:
+            live = jnp.logical_and(live, _diagonal_tile_live(BQ))
         scores = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)           # [BQ, BK]
-        k_pos = s * block_k + lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
-        if causal:
-            scores = jnp.where(q_pos >= k_pos, scores, NEG_INF)
-        scores = jnp.where(msk > 0, scores, NEG_INF)
+            q, k_blk, _NT, preferred_element_type=jnp.float32) * scale
+        scores = jnp.where(live, scores, NEG_INF)         # [BQ, BK]
         m_new = jnp.maximum(m, scores.max(axis=1, keepdims=True))
         alpha = jnp.exp(jnp.maximum(m - m_new, NEG_INF * 0.5))
         p = jnp.exp(scores - m_new)
         l_new = l * alpha + p.sum(axis=1, keepdims=True)
         acc = acc * alpha + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
+            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         return m_new, l_new, acc
 
-    D = q.shape[1]
-    m0 = jnp.full((BQ, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((BQ, 1), jnp.float32)
-    acc0 = jnp.zeros((BQ, D), jnp.float32)
-    n_blocks = T // block_k
+    carry = (jnp.full((BQ, 1), NEG_INF, jnp.float32),
+             jnp.zeros((BQ, 1), jnp.float32),
+             jnp.zeros((BQ, D), jnp.float32))
     if causal:
-        # only blocks whose start <= this q block's end can contribute
-        n_blocks_live = jnp.minimum(
-            n_blocks, (qi + 1) * BQ // block_k + 1)
+        carry = tile(qi, lax.fori_loop(0, qi, tile, carry), diagonal=True)
     else:
-        n_blocks_live = n_blocks
-    m, l, acc = lax.fori_loop(0, n_blocks_live, body, (m0, l0, acc0))
+        carry = lax.fori_loop(0, k_ref.shape[0] // BQ, tile, carry)
+    m, l, acc = carry
     out_ref[...] = (acc / jnp.maximum(l, 1e-30)).astype(out_ref.dtype)
-    # lse for backward recomputation; fully-masked rows get NEG_INF (the
-    # backward kernels re-apply the mask so these rows contribute nothing)
-    lse_ref[...] = jnp.where(
-        l > 0, m + jnp.log(jnp.maximum(l, 1e-30)), NEG_INF)
+    # lse for backward recomputation; a row with no live key (fully
+    # masked, or a T-pad row) is marked dead: the backward drops it
+    lse = jnp.where(m > NEG_INF * 0.5,
+                    m + jnp.log(jnp.maximum(l, 1e-30)), _LSE_DEAD)
+    lse_ref[...] = lse.reshape(1, BQ)
 
 
-def _flash_fwd(q, k, v, key_mask, *, causal: bool, scale: float,
-               block_q: int = 128, block_k: int = 128):
+def _flash_fwd(q, k, v, key_mask, *, causal: bool, scale: float):
     B, H, T, D = q.shape
-    block_q = min(block_q, T)
-    block_k = min(block_k, T)
-    if T % block_q or T % block_k:
-        raise ValueError(f"T={T} must divide block sizes "
-                         f"({block_q}, {block_k})")
+    block = _flash_block(T)
     qf = q.reshape(B * H, T, D)
     kf = k.reshape(B * H, T, D)
     vf = v.reshape(B * H, T, D)
-    # mask per batch → per (batch, head) row, [BH, 1, T] blocks of [1, T]
-    mask = jnp.broadcast_to(key_mask[:, None, :], (B, H, T)).reshape(
-        B * H, 1, T).astype(jnp.float32)
+    mask = key_mask.astype(jnp.float32).reshape(B, 1, T)
+    whole = pl.BlockSpec((None, T, D), lambda b, i: (b, 0, 0))
+    block_rows = pl.BlockSpec((None, block, D), lambda b, i: (b, i, 0))
 
-    grid = (B * H, T // block_q)
     out, lse = pl.pallas_call(
-        functools.partial(_flash_fwd_kernel, block_k=block_k, causal=causal,
-                          scale=scale),
-        grid=grid,
+        functools.partial(_flash_fwd_kernel, causal=causal, scale=scale),
+        grid=(B * H, T // block),
         in_specs=[
-            pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, T, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, T, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, 1, T), lambda b, i: (b, 0, 0)),
+            block_rows,                                             # q
+            whole,                                                  # k
+            whole,                                                  # v
+            pl.BlockSpec((None, 1, T), lambda b, i: (b // H, 0, 0)),  # mask
         ],
         out_specs=[
-            pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, block_q, 1), lambda b, i: (b, i, 0)),
+            block_rows,
+            pl.BlockSpec((None, 1, block), lambda b, i: (b, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
-            jax.ShapeDtypeStruct((B * H, T, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B * H, 1, T), jnp.float32),
         ],
         name="dl4j_flash_fwd",
+        compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=_interpret(),
     )(qf, kf, vf, mask)
     return out.reshape(B, H, T, D), lse
 
 
-def _recompute_p(q_blk, k_blk, lse_blk, mask_blk, q_pos, k_pos, causal,
-                 scale):
-    """Shared backward helper: rebuild the softmax probabilities for one
-    (q-block, k-block) tile from saved logsumexp (lse_blk is the
-    [BQ, 1] column).  All f32."""
-    s = jax.lax.dot_general(q_blk, k_blk, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    live = mask_blk > 0                                   # [1, BK]
-    if causal:
-        live = jnp.logical_and(live, q_pos >= k_pos)      # [BQ, BK]
-    # Fully-masked/T-pad query rows carry lse = NEG_INF; exponentiating
-    # s - (-1e30) would overflow to inf and 0·inf = NaN would leak into
-    # dk/dv, so clamp the EXPONENT (not the result) to NEG_INF wherever
-    # the tile is dead — exp then yields an exact 0.
-    row_live = lse_blk > NEG_INF * 0.5                    # [BQ, 1]
-    expo = jnp.where(jnp.logical_and(live, row_live),
-                     s - lse_blk, NEG_INF)
-    return jnp.exp(expo)
-
-
 def _flash_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
-                     delta_ref, dq_ref, *, block_k: int, causal: bool,
-                     scale: float):
-    """dQ for one q block: stream K/V blocks, recompute p, accumulate
-    dq += (p ∘ (dO·Vᵀ − δ)) · K · scale."""
-    q = q_ref[...].astype(jnp.float32)                    # [BQ, D]
-    do = do_ref[...].astype(jnp.float32)                  # [BQ, D]
-    lse = lse_ref[...]                                    # [BQ, 1]
-    delta = delta_ref[...]                                # [BQ, 1]
-    T = k_ref.shape[0]
+                     delta_ref, dq_ref, *, causal: bool, scale: float):
+    """dQ for one q block: stream K/V tiles, recompute p, accumulate
+    dq += (p ∘ (dO·Vᵀ − δ)) · K · scale.  lse and δ arrive as [1, BQ]
+    rows and are turned into columns once a program."""
+    q = q_ref[...]                                        # [BQ, D]
+    do = do_ref[...]                                      # [BQ, D]
     BQ, D = q.shape
+    lse = lse_ref[...].reshape(BQ, 1)
+    delta = delta_ref[...].reshape(BQ, 1)
     qi = pl.program_id(1)
-    q_pos = qi * BQ + lax.broadcasted_iota(jnp.int32, (BQ, 1), 0)
 
-    def body(s, dq):
-        k_blk = k_ref[pl.dslice(s * block_k, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[pl.dslice(s * block_k, block_k), :].astype(jnp.float32)
-        msk = mask_ref[:, pl.dslice(s * block_k, block_k)]  # [1, BK]
-        k_pos = s * block_k + lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
-        p = _recompute_p(q, k_blk, lse, msk, q_pos, k_pos, causal, scale)
-        dp = jax.lax.dot_general(do, v_blk, (((1,), (1,)), ((), ())),
+    def tile(s, dq, *, diagonal=False):
+        start = pl.multiple_of(s * BQ, BQ)
+        k_blk = k_ref[pl.ds(start, BQ), :]
+        v_blk = v_ref[pl.ds(start, BQ), :]
+        live = mask_ref[:, pl.ds(start, BQ)] > 0          # [1, BK]
+        if diagonal:
+            live = jnp.logical_and(live, _diagonal_tile_live(BQ))
+        s_ = jax.lax.dot_general(
+            q, k_blk, _NT, preferred_element_type=jnp.float32) * scale
+        # the EXPONENT is clamped, not the result: a dead tile gives an
+        # exact 0, and a dead row's lse (_LSE_DEAD) does by itself
+        p = jnp.exp(jnp.where(live, s_ - lse, NEG_INF))   # [BQ, BK]
+        dp = jax.lax.dot_general(do, v_blk, _NT,
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)                             # [BQ, BK]
+        ds = p * (dp - delta)
         return dq + jax.lax.dot_general(
-            ds, k_blk, (((1,), (0,)), ((), ())),
+            ds.astype(k_blk.dtype), k_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    n_blocks = T // block_k
+    dq = jnp.zeros((BQ, D), jnp.float32)
     if causal:
-        n_blocks_live = jnp.minimum(n_blocks, (qi + 1) * BQ // block_k + 1)
+        dq = tile(qi, lax.fori_loop(0, qi, tile, dq), diagonal=True)
     else:
-        n_blocks_live = n_blocks
-    dq = lax.fori_loop(0, n_blocks_live, body, jnp.zeros((BQ, D), jnp.float32))
+        dq = lax.fori_loop(0, k_ref.shape[0] // BQ, tile, dq)
     dq_ref[...] = (dq * scale).astype(dq_ref.dtype)
 
 
 def _flash_dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
-                      delta_ref, dk_ref, dv_ref, *, block_q: int,
-                      causal: bool, scale: float):
-    """dK/dV for one k block: stream Q/dO blocks, recompute pᵀ,
-    dv += pᵀ·dO and dk += (p ∘ (dO·Vᵀ − δ))ᵀ·Q · scale."""
-    k_blk = k_ref[...].astype(jnp.float32)                # [BK, D]
-    v_blk = v_ref[...].astype(jnp.float32)                # [BK, D]
-    msk = mask_ref[...]                                   # [1, BK]
-    T = q_ref.shape[0]
+                      delta_ref, dk_ref, dv_ref, *, causal: bool,
+                      scale: float):
+    """dK/dV for one k block: stream Q/dO tiles and recompute the
+    TRANSPOSED tile pᵀ [BK, BQ], so that the per-query statistics are
+    [1, BQ] rows and every product is a plain one:
+    dv += pᵀ·dO and dk += (pᵀ ∘ (V·dOᵀ − δ))·Q · scale."""
+    k_blk = k_ref[...]                                    # [BK, D]
+    v_blk = v_ref[...]                                    # [BK, D]
+    key_live = mask_ref[...] > 0                          # [BK, 1]
     BK, D = k_blk.shape
     ki = pl.program_id(1)
-    k_pos = ki * BK + lax.broadcasted_iota(jnp.int32, (1, BK), 1)
 
-    def body(s, carry):
+    def tile(s, carry, *, diagonal=False):
         dk, dv = carry
-        q_blk = q_ref[pl.dslice(s * block_q, block_q), :].astype(jnp.float32)
-        do_blk = do_ref[pl.dslice(s * block_q, block_q), :].astype(jnp.float32)
-        lse_blk = lse_ref[pl.dslice(s * block_q, block_q), :]
-        delta_blk = delta_ref[pl.dslice(s * block_q, block_q), :]
-        q_pos = s * block_q + lax.broadcasted_iota(
-            jnp.int32, (block_q, 1), 0)
-        p = _recompute_p(q_blk, k_blk, lse_blk, msk, q_pos, k_pos, causal,
-                         scale)                            # [BQ, BK]
+        start = pl.multiple_of(s * BK, BK)
+        q_blk = q_ref[pl.ds(start, BK), :]
+        do_blk = do_ref[pl.ds(start, BK), :]
+        lse_row = lse_ref[:, pl.ds(start, BK)]            # [1, BQ]
+        delta_row = delta_ref[:, pl.ds(start, BK)]        # [1, BQ]
+        live = key_live
+        if diagonal:
+            live = jnp.logical_and(
+                live, _diagonal_tile_live(BK, transposed=True))
+        st = jax.lax.dot_general(
+            k_blk, q_blk, _NT, preferred_element_type=jnp.float32) * scale
+        pt = jnp.exp(jnp.where(live, st - lse_row, NEG_INF))  # [BK, BQ]
         dv = dv + jax.lax.dot_general(
-            p, do_blk, (((0,), (0,)), ((), ())),
+            pt.astype(do_blk.dtype), do_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)            # [BK, D]
-        dp = jax.lax.dot_general(do_blk, v_blk, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_blk)
+        dpt = jax.lax.dot_general(v_blk, do_blk, _NT,
+                                  preferred_element_type=jnp.float32)
+        dst = pt * (dpt - delta_row)
         dk = dk + jax.lax.dot_general(
-            ds, q_blk, (((0,), (0,)), ((), ())),
+            dst.astype(q_blk.dtype), q_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)            # [BK, D]
         return dk, dv
 
-    n_blocks = T // block_q
+    carry = (jnp.zeros((BK, D), jnp.float32), jnp.zeros((BK, D), jnp.float32))
+    n_blocks = q_ref.shape[0] // BK
     if causal:
-        # q blocks strictly before this k block contribute nothing
-        start = ki * BK // block_q
+        # q tiles before this k block see none of it
+        carry = lax.fori_loop(ki + 1, n_blocks, tile,
+                              tile(ki, carry, diagonal=True))
     else:
-        start = 0
-    dk, dv = lax.fori_loop(start, n_blocks, body,
-                           (jnp.zeros((BK, D), jnp.float32),
-                            jnp.zeros((BK, D), jnp.float32)))
+        carry = lax.fori_loop(0, n_blocks, tile, carry)
+    dk, dv = carry
     dk_ref[...] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[...] = dv.astype(dv_ref.dtype)
 
 
 def _flash_bwd(q, k, v, key_mask, out, lse, g, *, causal: bool,
-               scale: float, block_q: int = 128, block_k: int = 128):
+               scale: float):
     B, H, T, D = q.shape
-    block_q = min(block_q, T)
-    block_k = min(block_k, T)
+    block = _flash_block(T)
     qf = q.reshape(B * H, T, D)
     kf = k.reshape(B * H, T, D)
     vf = v.reshape(B * H, T, D)
     dof = g.reshape(B * H, T, D)
-    mask = jnp.broadcast_to(key_mask[:, None, :], (B, H, T)).reshape(
-        B * H, 1, T).astype(jnp.float32)
+    mask = key_mask.astype(jnp.float32)
     # δ_i = Σ_d dO·O — a cheap elementwise reduction XLA fuses on its own
     delta = jnp.sum(dof.astype(jnp.float32) *
-                    out.reshape(B * H, T, D).astype(jnp.float32), axis=-1,
-                    keepdims=True)                        # [BH, T, 1]
+                    out.reshape(B * H, T, D).astype(jnp.float32),
+                    axis=-1)[:, None, :]                  # [BH, 1, T]
 
-    common_specs = [
-        pl.BlockSpec((None, T, D), lambda b, i: (b, 0, 0)),      # k or q
-        pl.BlockSpec((None, T, D), lambda b, i: (b, 0, 0)),      # v
-        pl.BlockSpec((None, 1, T), lambda b, i: (b, 0, 0)),      # mask
-    ]
+    whole = pl.BlockSpec((None, T, D), lambda b, i: (b, 0, 0))
+    whole_row = pl.BlockSpec((None, 1, T), lambda b, i: (b, 0, 0))
+    block_rows = pl.BlockSpec((None, block, D), lambda b, i: (b, i, 0))
+    block_row = pl.BlockSpec((None, 1, block), lambda b, i: (b, 0, i))
     dq = pl.pallas_call(
-        functools.partial(_flash_dq_kernel, block_k=block_k, causal=causal,
-                          scale=scale),
-        grid=(B * H, T // block_q),
+        functools.partial(_flash_dq_kernel, causal=causal, scale=scale),
+        grid=(B * H, T // block),
         in_specs=[
-            pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),  # q
-            *common_specs,                                             # k,v,mask
-            pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),  # do
-            pl.BlockSpec((None, block_q, 1), lambda b, i: (b, i, 0)),  # lse
-            pl.BlockSpec((None, block_q, 1), lambda b, i: (b, i, 0)),  # delta
+            block_rows,                                             # q
+            whole,                                                  # k
+            whole,                                                  # v
+            pl.BlockSpec((None, 1, T), lambda b, i: (b // H, 0, 0)),  # mask
+            block_rows,                                             # do
+            block_row,                                              # lse
+            block_row,                                              # delta
         ],
-        out_specs=pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
+        out_specs=block_rows,
         out_shape=jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
         name="dl4j_flash_dq",
+        compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=_interpret(),
-    )(qf, kf, vf, mask, dof, lse, delta)
+    )(qf, kf, vf, mask.reshape(B, 1, T), dof, lse, delta)
 
     dk, dv = pl.pallas_call(
-        functools.partial(_flash_dkv_kernel, block_q=block_q, causal=causal,
-                          scale=scale),
-        grid=(B * H, T // block_k),
+        functools.partial(_flash_dkv_kernel, causal=causal, scale=scale),
+        grid=(B * H, T // block),
         in_specs=[
-            pl.BlockSpec((None, T, D), lambda b, i: (b, 0, 0)),        # q
-            pl.BlockSpec((None, block_k, D), lambda b, i: (b, i, 0)),  # k
-            pl.BlockSpec((None, block_k, D), lambda b, i: (b, i, 0)),  # v
-            pl.BlockSpec((None, 1, block_k), lambda b, i: (b, 0, i)),  # mask
-            pl.BlockSpec((None, T, D), lambda b, i: (b, 0, 0)),        # do
-            pl.BlockSpec((None, T, 1), lambda b, i: (b, 0, 0)),        # lse
-            pl.BlockSpec((None, T, 1), lambda b, i: (b, 0, 0)),        # delta
+            whole,                                                  # q
+            block_rows,                                             # k
+            block_rows,                                             # v
+            pl.BlockSpec((None, block, 1),
+                         lambda b, i: (b // H, i, 0)),              # mask
+            whole,                                                  # do
+            whole_row,                                              # lse
+            whole_row,                                              # delta
         ],
-        out_specs=[
-            pl.BlockSpec((None, block_k, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, block_k, D), lambda b, i: (b, i, 0)),
-        ],
+        out_specs=[block_rows, block_rows],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, T, D), k.dtype),
             jax.ShapeDtypeStruct((B * H, T, D), v.dtype),
         ],
         name="dl4j_flash_dkv",
+        compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=_interpret(),
-    )(qf, kf, vf, mask, dof, lse, delta)
+    )(qf, kf, vf, mask.reshape(B, T, 1), dof, lse, delta)
     return (dq.reshape(B, H, T, D), dk.reshape(B, H, T, D),
             dv.reshape(B, H, T, D))
 
@@ -437,48 +465,48 @@ def _flash_vjp_bwd(causal, scale, res, g):
 
 _flash_core.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
-LANE = 128
-
 
 def flash_attention(q, k, v, key_mask, causal: bool = False,
                     scale: Optional[float] = None):
     """Memory-efficient exact attention, differentiable with O(T) HBM in
-    both directions.  q,k,v: [B,H,T,D]; key_mask [B,T] (1=keep).  scale
-    defaults to 1/sqrt(D) of the ORIGINAL head dim; head dims that are
-    not lane-tileable (64, 96, ...) are zero-padded to the next multiple
-    of 128, and sequence lengths that don't tile into the 128-row blocks
-    (ragged/bucketed ladders) are zero-padded along T with a ZEROED key
-    mask — masked keys change no real row, and fully-masked pad query
-    rows come out 0 with lse = NEG_INF so the backward recomputation
-    drops them (see _recompute_p).  Both pad/slice pairs sit outside the
-    custom_vjp so gradients pass through."""
+    both directions.  q,k,v: [B,H,T,D]; key_mask [B,T] (1=keep).
+    Products run in the operands' dtype with float32 accumulation
+    (bfloat16 in: one MXU pass; float32 in: float32 products), softmax
+    in float32.  scale defaults to 1/sqrt(D) of the ORIGINAL head dim
+    and multiplies the float32 scores.  Head dims that are not a
+    multiple of 128 (64, 96, ...) are zero-padded to the next one: the
+    kernels take any width as it is (the chip's compiler took, and the
+    chip computed, every D tried from 33 to 256), and alone they are
+    faster so, but in the language-model cell the step was 0.5 to 0.8%
+    faster with the pad, twice (PERF.md 6, PR 32), so it stays.
+    Sequence lengths that are not a multiple of 128 (ragged/bucketed
+    ladders) are zero-padded along T with a ZEROED key mask — masked
+    keys change no real row, and pad query rows' cotangents are zero —
+    and the tile follows the padded T (``_flash_block``).  Both
+    pad/slice pairs sit outside the custom_vjp so gradients pass
+    through."""
     D = q.shape[-1]
     T = q.shape[2]
     s = scale if scale is not None else 1.0 / (D ** 0.5)
     pad_d = (-D) % LANE
     pad_t = (-T) % LANE
-    if pad_d:
-        widths = [(0, 0)] * 3 + [(0, pad_d)]
+    if pad_d or pad_t:
+        widths = [(0, 0), (0, 0), (0, pad_t), (0, pad_d)]
         q = jnp.pad(q, widths)
         k = jnp.pad(k, widths)
         v = jnp.pad(v, widths)
-    if pad_t:
-        widths_t = [(0, 0), (0, 0), (0, pad_t), (0, 0)]
-        q = jnp.pad(q, widths_t)
-        k = jnp.pad(k, widths_t)
-        v = jnp.pad(v, widths_t)
         key_mask = jnp.pad(key_mask, [(0, 0), (0, pad_t)])  # pads masked out
     out = _flash_core(q, k, v, key_mask, causal, s)
     return out[:, :, :T, :D] if (pad_d or pad_t) else out
 
 
 def flash_attention_supported(q, block: int = 128) -> bool:
-    """Shape gate: any T >= one block works (shorter-than-block pads
-    would waste most of the MXU and dense attention is cheap there) —
-    ragged/bucketed lengths that aren't 128-multiples are zero-padded
-    inside flash_attention, like head-dim lane padding.  Any head dim
-    works too (lane padding), but tiny ones waste >4x MXU lanes — fall
-    back to dense."""
+    """Shape gate: any T >= 128 works (a shorter sequence would be
+    padded to one 128-row tile, mostly dead, and dense attention is
+    cheap there); lengths that aren't 128-multiples are zero-padded
+    inside flash_attention, like the head dim to 128 lanes.  Any head
+    dim works, but under 32 three quarters of the padded products are
+    zeros — fall back to dense."""
     B, H, T, D = q.shape
     return T >= block and D >= 32
 

@@ -89,7 +89,11 @@ def test_flash_supported_gate():
 
 @pytest.mark.parametrize("T,causal,pad_from", [
     (200, False, None), (200, True, 180), (130, True, None),
-    (384 + 64, False, 300)])
+    (384 + 64, False, 300),
+    # multiples of 128 that the 512-row tile does not divide: the tile
+    # follows T (384: one tile of 384; 640: five of 128)
+    (384, False, None), (384, True, 300), (640, False, 500),
+    (640, True, None)])
 def test_flash_ragged_T_padding_matches_dense(T, causal, pad_from):
     """Sequence lengths that don't tile into 128-row blocks pad (masked)
     inside flash_attention — bucketed ladders that aren't 128-multiples
@@ -116,7 +120,15 @@ def test_flash_ragged_T_padding_matches_dense(T, causal, pad_from):
                                    rtol=1e-3, atol=1e-3)
 
 
-@pytest.mark.parametrize("D", [64, 96])
+def _all_eqns(jaxpr):
+    """Every equation of a jaxpr, those inside its kernels and loops too."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _all_eqns(sub)
+
+
+@pytest.mark.parametrize("D", [64, 96, 100])
 def test_flash_head_dim_padding_matches_dense(D):
     q, k, v = _qkv(D=D, seed=4)
     km = _mask()
@@ -139,6 +151,32 @@ def test_flash_head_dim_padding_matches_dense(D):
                                    rtol=1e-3, atol=1e-3)
 
 
+@pytest.mark.parametrize("D", [64, 96])
+def test_flash_kernels_take_the_head_at_its_own_width(D):
+    """The three kernels need no 128-lane head (a block whose last
+    dimension is the array's own is legal): called under the pad that
+    flash_attention keeps for the cell's sake, forward and gradients."""
+    q, k, v = _qkv(B=1, H=2, D=D, seed=14)
+    km = _mask(B=1, pad_from=250)
+    scale = 1.0 / (D ** 0.5)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: jnp.sum(pk._flash_core(q, k, v, km, True, scale) ** 2),
+        argnums=(0, 1, 2)))(q, k, v)
+    widths = {var.aval.shape[-1] for e in _all_eqns(jaxpr.jaxpr)
+              for var in e.outvars if getattr(var.aval, "ndim", 0) >= 2}
+    assert D in widths and 128 not in widths, widths
+    got = jax.grad(
+        lambda q, k, v: jnp.sum(pk._flash_core(q, k, v, km, True, scale) ** 2),
+        argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(
+        lambda q, k, v: jnp.sum(
+            pk._dense_reference(q, k, v, km, True, scale) ** 2),
+        argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-3, atol=1e-3)
+
+
 def test_flash_grads_with_key_mask():
     q, k, v = _qkv(B=1, H=1, seed=5)
     km = _mask(B=1, pad_from=150)
@@ -155,6 +193,85 @@ def test_flash_grads_with_key_mask():
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-3, atol=1e-3)
+
+
+def _rel(a, b):
+    a, b = (np.asarray(x, np.float32) for x in (a, b))
+    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("pad_from", [None, 200], ids=["whole", "key-mask"])
+def test_flash_bfloat16_operands_match_float32_reference(causal, pad_from):
+    """bfloat16 q, k, v (what the chip's policy hands the core): the
+    products run in bfloat16 with float32 accumulation, the softmax in
+    float32.  Against the dense reference in float32 on the SAME rounded
+    inputs, forward and all three gradients, at bfloat16's tolerance (8
+    bits: the output, p and ds are each rounded once)."""
+    T, D = 384, 64
+    q, k, v = (a.astype(jnp.bfloat16)
+               for a in _qkv(B=2, H=2, T=T, D=D, seed=11))
+    km = _mask(B=2, T=T, pad_from=pad_from)
+    w = _qkv(B=2, H=2, T=T, D=D, seed=12)[0]
+
+    def loss_flash(q, k, v):
+        out = pk.flash_attention(q, k, v, km, causal)
+        assert out.dtype == jnp.bfloat16
+        return jnp.sum(out.astype(jnp.float32) * w)
+
+    def loss_ref(q, k, v):
+        q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+        return jnp.sum(
+            pk._dense_reference(q, k, v, km, causal, 1.0 / (D ** 0.5)) * w)
+
+    out = pk.flash_attention(q, k, v, km, causal)
+    ref = pk._dense_reference(*(a.astype(jnp.float32) for a in (q, k, v)),
+                              km, causal, 1.0 / (D ** 0.5))
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref),
+                               rtol=2e-2, atol=2e-2)
+    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(gf, gr):
+        assert a.dtype == jnp.bfloat16
+        assert _rel(a, b) < 1e-2
+        np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(b),
+                                   rtol=5e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_flash_products_run_in_the_operands_dtype(dtype):
+    """No tile is lifted to float32 ahead of a product: every
+    dot_general inside the three kernels multiplies operands of the
+    input's dtype (float32 in, float32 products, as before) and
+    accumulates in float32."""
+    q, k, v = (a.astype(dtype) for a in _qkv(B=1, H=2, T=256, D=64, seed=13))
+    km = _mask(B=1)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: jnp.sum(
+            pk.flash_attention(q, k, v, km, True).astype(jnp.float32) ** 2),
+        argnums=(0, 1, 2)))(q, k, v)
+    kernels = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert len(kernels) == 3
+    dots = [e for kern in kernels
+            for sub in jax.core.jaxprs_in_params(kern.params)
+            for e in _all_eqns(sub) if e.primitive.name == "dot_general"]
+    # forward, dq, dk/dv; twice, the tiles that cross the diagonal
+    # and those below it being two loops over the same body
+    assert len(dots) == 2 * (2 + 3 + 4)
+    for e in dots:
+        assert [x.aval.dtype for x in e.invars] == [dtype, dtype]
+        assert e.outvars[0].aval.dtype == jnp.float32
+
+
+@pytest.mark.parametrize("T,block", [
+    (128, 128), (256, 256), (384, 384), (512, 512), (640, 128), (768, 384),
+    (1024, 512), (4096, 512), (4096 + 128, 384), (128 * 7, 128)])
+def test_flash_tile_follows_T(T, block):
+    """The square tile is the largest multiple of 128 under the cap
+    that divides the (128-padded) T, so no sequence pays for rows beyond
+    its own padding."""
+    assert pk._flash_block(T) == block
 
 
 def _assert_no_dense_tt(jaxpr, T):

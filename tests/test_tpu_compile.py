@@ -174,7 +174,10 @@ def test_threshold_dropout_compiles(compile_for_chip, shape):
     ((2, 4, 512, 64), True),
     ((2, 4, 512, 64), False),
     ((2, 4, 200, 64), True),      # ragged T: padded to 256 inside
-], ids=["selftest", "T512-causal", "T512-full", "ragged-T200"])
+    ((1, 2, 4096, 64), True),     # the tiles of a long sequence
+    ((1, 2, 640, 96), True),      # five tiles of 128, a head of 96
+], ids=["selftest", "T512-causal", "T512-full", "ragged-T200",
+        "T4096-causal", "T640-D96"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 def test_flash_attention_compiles(compile_for_chip, shape, causal, dtype):
@@ -190,3 +193,29 @@ def test_flash_attention_compiles(compile_for_chip, shape, causal, dtype):
                            (shape, dtype), ((B, T), jnp.float32))
     # forward, dq and dk/dv kernels
     assert hlo.count("tpu_custom_call") >= 3
+
+
+def test_flash_attention_compiles_at_the_language_model_cell_shape(
+        compile_for_chip):
+    """The attention core of ``lfm2_8b_a1b.fit_seq4k_b2`` as the layer
+    hands it over: bfloat16, 32 heads of 64 (K and V repeated from 8 by
+    the layer), 2 sequences of 4,096, causal, forward and the three
+    gradients, through ``flash_attention``'s pad of the head to 128
+    lanes and, the kernels alone, at the head's own 64."""
+    shape, bf16 = (2, 32, 4096, 64), jnp.bfloat16
+
+    def step(q, k, v, km):
+        return jax.value_and_grad(
+            lambda q, k, v: _sq(pk.flash_attention(q, k, v, km,
+                                                   causal=True)),
+            argnums=(0, 1, 2))(q, k, v)
+    def core_step(q, k, v, km):
+        return jax.value_and_grad(
+            lambda q, k, v: _sq(pk._flash_core(q, k, v, km, True, 0.125)),
+            argnums=(0, 1, 2))(q, k, v)
+    for fn in (step, core_step):
+        hlo = compile_for_chip(fn, (shape, bf16), (shape, bf16),
+                               (shape, bf16), ((2, 4096), jnp.float32))
+        assert hlo.count("tpu_custom_call") == 3
+        for name in ("dl4j_flash_fwd", "dl4j_flash_dq", "dl4j_flash_dkv"):
+            assert name in hlo
