@@ -23,7 +23,9 @@ the ``fit()`` returns.  Per chip it holds
   of the instructions it runs, which are on the same line, so it counts
   as busy time and is left out of every sum.  An operation the compiler
   expands into kernels of its own name loses its scope (XLA's grouped
-  matrix product, ``ragged-dot-*``).
+  matrix product, ``ragged-dot-*``).  ``recomputed_s`` is the part of
+  ``device_s["bwd"]``, by layer type, that is a forward pass run again
+  under ``jax.checkpoint`` (``rematted_computation`` in the name).
   The layer classes declare both, their parts and the kernels they
   claim for a part (:func:`layer_tables`); a claimed kernel reads under
   the direction ``kernel``, since forward and backward cannot be told
@@ -75,6 +77,9 @@ UPDATE_SCOPE = re.compile(r"(?:^|[/(])update(?:[/)]|$)")
 LOSS_SCOPE = re.compile(r"(?:^|[/(])loss(?:[/)]|$)")
 #: an instruction that runs other instructions: its event spans theirs
 CONTAINER = re.compile(r"\s(?:while|conditional|call)\(")
+#: how ``jax.checkpoint`` names the forward it runs again in the backward
+#: pass (the backward's own operations stand beside it, not under it)
+REMATTED = "rematted_computation"
 #: the op_name such an event goes by: counted as busy, summed nowhere
 SPANS_OTHERS = "<spans others>"
 OUTSIDE = "outside_fit"
@@ -195,11 +200,12 @@ Tables = Tuple[Dict[str, frozenset], Dict[str, Tuple[str, str]]]
 def layer_tables() -> Tables:
     """({layer type: the parts it names inside its scope}, {prefix of a
     kernel's name: (layer type, part) that claims it}), as the registered
-    layer classes declare them (``Layer.scope_parts``,
-    ``Layer.scope_kernels``)."""
+    layer and vertex classes declare them (``scope_parts``,
+    ``scope_kernels``)."""
+    from deeplearning4j_tpu.nn.conf.graph_conf import VERTEX_REGISTRY
     from deeplearning4j_tpu.nn.conf.layers import LAYER_REGISTRY
     parts, kernels = {}, {}
-    for kind, cls in LAYER_REGISTRY.items():
+    for kind, cls in {**LAYER_REGISTRY, **VERTEX_REGISTRY}.items():
         if cls.scope_parts:
             parts[kind] = frozenset(cls.scope_parts)
         for prefix, part in cls.scope_kernels.items():
@@ -368,12 +374,15 @@ def summarize(planes) -> dict:
         by_dir: Dict[str, Dict[str, float]] = {}
         by_scope: Dict[str, float] = {}
         by_sub: Dict[str, float] = {}
+        by_remat: Dict[str, float] = {}
         timed = [(d, op) for _, d, op in evs if op != SPANS_OTHERS]
         for d, op_name in timed:
             direction, kind, scope = classify(op_name, tables)
             by_kind = by_dir.setdefault(direction, {})
             by_kind[kind] = by_kind.get(kind, 0.0) + d / 1e9
             by_scope[scope] = by_scope.get(scope, 0.0) + d / 1e9
+            if REMATTED in op_name:
+                by_remat[kind] = by_remat.get(kind, 0.0) + d / 1e9
             sub = (sub_scope(op_name, tables)
                    if direction in ("fwd", "bwd", "kernel") else None)
             if sub:
@@ -398,6 +407,7 @@ def summarize(planes) -> dict:
             if ops_s else 0.0,
             "device_s": by_dir,
             "sub_scope_s": by_sub,
+            "recomputed_s": by_remat,
             "top_scopes": sorted(by_scope.items(),
                                  key=lambda kv: -kv[1])[:10],
             "idle_s": dict(sorted(idle.items(), key=lambda kv: -kv[1])),

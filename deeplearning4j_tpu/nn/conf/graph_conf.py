@@ -35,6 +35,11 @@ def register_vertex(cls):
 
 @dataclasses.dataclass
 class GraphVertexConf:
+    #: as ``Layer.scope_parts`` / ``Layer.scope_kernels``: what
+    #: monitor/profile.py reads of a vertex that names parts in its scope
+    scope_parts = ()
+    scope_kernels = {}
+
     def initialize(self, key, input_types: List[InputType], dtype=jnp.float32
                    ) -> Tuple[dict, dict, InputType]:
         return {}, {}, self.output_type(input_types)
@@ -50,6 +55,11 @@ class GraphVertexConf:
 
     def has_params(self) -> bool:
         return False
+
+    def updater_layer(self) -> Optional[Layer]:
+        """The layer conf whose updater fields rule this vertex's leaves;
+        None (a vertex without leaves) takes plain SGD."""
+        return None
 
     def to_dict(self) -> dict:
         d = dataclasses.asdict(self)
@@ -358,6 +368,181 @@ class ReshapeVertex(GraphVertexConf):
         return InputType.feed_forward(n)
 
 
+@register_vertex
+@dataclasses.dataclass
+class LoopVertex(GraphVertexConf):
+    """A body of vertices run ``passes`` times over THE SAME leaves:
+    ``h_0 = x;  h_r = Body(h_{r-1}), r = 1..passes``; the output is every
+    pass's, stacked ``[passes, ...]`` (for a sequence ``[R, N, T, C]``).
+
+    ``body`` is a whole graph configuration (serialized) with one input,
+    the carried value, and one output of the same shape and type.  The
+    vertex holds the body's leaves once, under ``"<body vertex>/<leaf>"``,
+    so the engine's updater, parameter count, summary and checkpoints
+    see each leaf once, as any vertex's; every pass reads them and their
+    gradient is the sum over the passes.  The step holds the body once:
+    the passes are a ``lax.scan``.  The body's leaves take the body's
+    global updater (no per-layer updater, l1 or l2 inside a loop), and a
+    body vertex that keeps state (batch-norm statistics, expert counts)
+    is refused: which pass's would it be.
+
+    ``recompute_blocks`` declares recomputation where the loop is
+    declared: the body's vertices in consecutive runs (a block of a
+    decoder, say), each run recomputed in the backward pass from what it
+    was handed, so that a block keeps one input a pass whatever it holds
+    inside.  None keeps every activation.  The global
+    ``gradient_checkpointing`` flag would keep the loop's input alone
+    and recompute all the passes in one piece.
+
+    The carried decode step cannot run a loop: each pass of each
+    attention layer would need a cache of its own."""
+
+    scope_parts = ("body",)
+
+    body: Optional[dict] = None
+    passes: int = 1
+    recompute_blocks: Optional[list] = None
+
+    @staticmethod
+    def of(body: "ComputationGraphConfiguration", passes: int,
+           recompute_blocks=None) -> "LoopVertex":
+        if len(body.network_inputs) != 1 or len(body.network_outputs) != 1:
+            raise ValueError("LoopVertex: the body takes the carried value "
+                             "and hands on its next: one input, one output")
+        v = LoopVertex(body=body.to_dict(), passes=int(passes),
+                       recompute_blocks=recompute_blocks)
+        v._runs()       # validate early
+        return v
+
+    def body_conf(self) -> "ComputationGraphConfiguration":
+        conf = self.__dict__.get("_parsed")
+        if conf is None:
+            conf = self.__dict__["_parsed"] = \
+                ComputationGraphConfiguration.from_dict(self.body)
+        return conf
+
+    def infer_body(self, input_types) -> None:
+        """Fill the body's unset n_in from the loop's input type (the
+        outer graph's pass calls this)."""
+        conf = ComputationGraphConfiguration.from_dict(self.body)
+        conf.input_types = [input_types[0]]
+        _infer_graph_nin(conf)
+        self.body = conf.to_dict()
+        self.__dict__.pop("_parsed", None)
+
+    def _runs(self) -> List[List[str]]:
+        """The body's vertices as the runs one pass takes them in."""
+        order = self.body_conf().topological_order()
+        if self.passes < 1:
+            raise ValueError(f"LoopVertex: passes={self.passes}")
+        if any("/" in n for n in order):
+            raise ValueError("LoopVertex: a body vertex's name may not "
+                             "hold '/' (it separates vertex from leaf)")
+        if not self.recompute_blocks:
+            return [order]
+        runs = [list(r) for r in self.recompute_blocks]
+        flat = [n for r in runs for n in r]
+        if sorted(flat) != sorted(order):
+            raise ValueError("LoopVertex: recompute_blocks must name every "
+                             "body vertex once")
+        seen = set(self.body_conf().network_inputs)
+        for n in flat:      # a run may use only what came before it
+            ins = self.body_conf().vertex_inputs[n]
+            if any(i not in seen for i in ins):
+                raise ValueError(f"LoopVertex: recompute_blocks out of "
+                                 f"order at '{n}' (inputs {ins})")
+            seen.add(n)
+        return runs
+
+    def has_params(self):
+        return any(v.has_params() for v in self.body_conf().vertices.values())
+
+    def updater_layer(self):
+        return merge_layer_conf(Layer(), self.body_conf().global_conf)
+
+    def output_type(self, input_types):
+        return input_types[0]
+
+    def initialize(self, key, input_types, dtype=jnp.float32):
+        conf = self.body_conf()
+        types = {conf.network_inputs[0]: input_types[0]}
+        params = {}
+        for name in conf.topological_order():
+            key, sub = jax.random.split(key)
+            p, s, types[name] = conf.vertices[name].initialize(
+                sub, [types[i] for i in conf.vertex_inputs[name]], dtype)
+            if s:
+                raise ValueError(
+                    f"LoopVertex: body vertex '{name}' keeps state "
+                    f"({sorted(s)}); a loop's body may not")
+            params.update({f"{name}/{k}": a for k, a in p.items()})
+        out = types[conf.network_outputs[0]]
+        if out != input_types[0]:
+            raise ValueError(f"LoopVertex: the body turns {input_types[0]} "
+                             f"into {out}; a pass must hand on what it took")
+        return (params, {"loop_passes": jnp.zeros((), jnp.int32)},
+                input_types[0])
+
+    def forward(self, params, state, inputs, *, train, rng, masks=None):
+        from deeplearning4j_tpu.parallel import sequence as seq_ops
+        if seq_ops.kv_decode_active() and not train:
+            raise NotImplementedError(
+                "LoopVertex: the carried decode step cannot run a looped "
+                "stack (a cache per pass per layer)")
+        conf = self.body_conf()
+        carried, out_name = conf.network_inputs[0], conf.network_outputs[0]
+        mask = masks[0] if masks else None
+        leaves: Dict[str, dict] = {n: {} for n in conf.vertices}
+        for path, a in params.items():
+            name, _, k = path.partition("/")
+            leaves[name][k] = a
+        index = {n: i for i, n in enumerate(conf.topological_order())}
+        runs = self._runs()
+        recompute = train and bool(self.recompute_blocks)
+
+        def run_fn(names):
+            inside = set(names)
+            later = {i for n in index if n not in inside
+                     for i in conf.vertex_inputs[n]} | {out_name}
+            gives = [n for n in names if n in later]
+
+            def run(p, acts, r):
+                acts = dict(acts)
+                for name in names:
+                    v = conf.vertices[name]
+                    ins = [acts[i] for i in conf.vertex_inputs[name]]
+                    kind = type(v.layer_conf() if isinstance(v, LayerVertex)
+                                else v).__name__
+                    with jax.named_scope(f"{kind}/{name}"):
+                        acts[name], _, _ = v.forward(
+                            p[name], {}, ins, train=train,
+                            rng=jax.random.fold_in(r, index[name]),
+                            masks=[mask] * len(ins))
+                return {n: acts[n] for n in gives}
+
+            needs = [i for i in dict.fromkeys(
+                i for n in names for i in conf.vertex_inputs[n])
+                if i not in inside]
+            return needs, (jax.checkpoint(run) if recompute else run)
+
+        fns = [(names,) + run_fn(names) for names in runs]
+
+        def one_pass(x, i):
+            r = jax.random.fold_in(rng, i)
+            acts = {carried: x}
+            for names, needs, fn in fns:
+                acts.update(fn({n: leaves[n] for n in names},
+                               {n: acts[n] for n in needs}, r))
+            y = acts[out_name].astype(x.dtype)
+            return y, y
+
+        with jax.named_scope("body"):
+            _, ys = jax.lax.scan(one_pass, inputs[0],
+                                 jnp.arange(self.passes))
+        return (ys, {**state, "loop_passes": jnp.asarray(self.passes,
+                                                         jnp.int32)}, mask)
+
+
 # ==========================================================================
 # Configuration + builder
 # ==========================================================================
@@ -536,6 +721,8 @@ def _infer_graph_nin(conf: ComputationGraphConfiguration) -> None:
         v = conf.vertices[name]
         in_names = conf.vertex_inputs[name]
         in_types = [types[i] for i in in_names]
+        if isinstance(v, LoopVertex):
+            v.infer_body(in_types)
         if isinstance(v, LayerVertex):
             layer = v.layer_conf()
             if _needs(layer) == "ff" and in_types[0].kind == "cnn":

@@ -86,6 +86,10 @@ class Layer:
     #: the compiler expands into kernels it names itself, dropping the
     #: scope; a prefix belongs to one layer type
     scope_kernels = {}
+    #: True on an output layer that owns its score from its INPUT
+    #: (``score_from_input``): the graph engine asks it for no
+    #: pre-activations, which then never exist whole
+    scores_from_input = False
 
     # ---- capability flags ----
     def has_params(self) -> bool:
@@ -224,6 +228,11 @@ class BaseOutputLayer(DenseLayer):
 
     def preoutput(self, params, x):
         return _affine(params, x)
+
+    def score_from_input(self, params, state, x, labels, mask=None):
+        """(per-example loss [N], new state) from the layer's input, for a
+        layer that sets ``scores_from_input``."""
+        raise NotImplementedError
 
 
 @register_layer
@@ -666,6 +675,116 @@ class RnnOutputLayer(BaseOutputLayer):
 
     def output_type(self, input_type):
         return InputType.recurrent(self.n_out, input_type.timesteps)
+
+
+@register_layer
+@dataclasses.dataclass
+class LoopExitOutputLayer(RnnOutputLayer):
+    """The head of a looped stack (``LoopVertex``): its input is every
+    pass's hidden state ``h [R, N, T, C]``, its leaves the head ``W``
+    [C, V] and an exit gate ``w_g`` [C], ``b_g`` [1].  Per token, with
+    CE_r the cross-entropy of pass r's logits ``h_r W``:
+
+        lam_r = sigmoid(h_r w_g + b_g)
+        p_1 = lam_1;  p_r = lam_r prod_{j<r}(1 - lam_j);
+        p_R = prod_{j<R}(1 - lam_j)          (the last pass takes the rest)
+        loss = sum_r p_r CE_r - entropy_weight H(p),  H(p) = -sum_r p_r log p_r
+
+    the expected loss under the exit distribution less an entropy bonus
+    (Zhu et al. 2025, arXiv:2510.25741).  An example's score is the mean
+    (``time_reduction``) over its unmasked tokens.  ``forward`` gives the
+    last pass's distribution.
+
+    It scores from its input (``scores_from_input``): R x N x T x V
+    logits never exist together.  One pass's logits at a time, through
+    the cross-entropy tier the registry selects, under recomputation, so
+    the backward pass computes them again and the forward keeps ``h``
+    alone.  The gate, ``p`` and the entropy are float32 whatever the
+    compute dtype.  It leaves in state the means over tokens of ``p_r``
+    and of ``CE_r`` (``loop_exit_mass``, ``loop_exit_loss``, [R] each),
+    which the fit loop publishes as ``dl4j_loop_exit_mass`` /
+    ``dl4j_loop_exit_loss``."""
+
+    scores_from_input = True
+    scope_parts = ("head", "gate")
+
+    passes: int = 1
+    entropy_weight: float = 0.1
+    time_reduction: str = "mean"
+    bias: bool = False
+
+    def initialize(self, key, input_type, dtype=jnp.float32):
+        n_in = self.n_in or input_type.size
+        kh, kg = jax.random.split(key)
+        params = self._affine_params(kh, n_in, dtype)
+        params["w_g"] = self._winit(kg, (n_in, 1), dtype)[:, 0]
+        params["b_g"] = jnp.zeros((1,), dtype)
+        zeros = jnp.zeros((self.passes,), dtype)
+        return (params, {"loop_exit_mass": zeros, "loop_exit_loss": zeros},
+                InputType.recurrent(self.n_out, input_type.timesteps))
+
+    def forward(self, params, state, x, *, train, rng, mask=None):
+        return self._act(_affine(params, x[-1])), state, mask
+
+    def exit_log_distribution(self, params, x):
+        """log p [R, N, T] in float32 (float64 under a gradient check),
+        from the gate's logits z_r = h_r w_g + b_g: log lam_r is
+        log_sigmoid(z_r) and log(1 - lam_j) is log_sigmoid(-z_j), so a
+        gate that saturates gives a large negative number and never
+        log 0."""
+        ft = jnp.promote_types(x.dtype, jnp.float32)
+        z = jnp.einsum("rntc,c->rnt", x.astype(ft), params["w_g"].astype(ft),
+                       precision=jax.lax.Precision.HIGHEST) \
+            + params["b_g"].astype(ft)
+        stay = jnp.cumsum(jax.nn.log_sigmoid(-z), axis=0)
+        before = jnp.concatenate([jnp.zeros_like(stay[:1]), stay[:-1]])
+        return jnp.concatenate(
+            [jax.nn.log_sigmoid(z[:-1]) + before[:-1], before[-1:]])
+
+    def exit_distribution(self, params, x):
+        """p [R, N, T]: it sums to 1 over the passes."""
+        return jnp.exp(self.exit_log_distribution(params, x))
+
+    def score_from_input(self, params, state, x, labels, mask=None):
+        if x.ndim != 4 or x.shape[0] != self.passes:
+            raise ValueError(f"LoopExitOutputLayer(passes={self.passes}) "
+                             f"wants [passes, N, T, C], got {x.shape}")
+        ft = jnp.promote_types(x.dtype, jnp.float32)
+        by_id = jnp.issubdtype(labels.dtype, jnp.integer)
+
+        with jax.named_scope("head"):
+            @jax.checkpoint
+            def pass_rows(h):
+                z = _affine({"W": params["W"]}, h).astype(ft)
+                if by_id:
+                    return loss_ops.mcxent_id_rows(labels, z)
+                return -jnp.sum(labels * jax.nn.log_softmax(z), axis=-1)
+            ce = jax.lax.map(pass_rows, x)                  # [R, N, T]
+        with jax.named_scope("gate"):
+            logp = self.exit_log_distribution(params, x)
+            p = jnp.exp(logp)
+            # p log p with log p finite: no 0 x inf where a gate saturates
+            entropy = -jnp.sum(p * logp, axis=0)
+            rows = jnp.sum(p * ce, axis=0) - self.entropy_weight * entropy
+            if mask is not None:
+                m = (mask[..., 0] if mask.ndim == 3 else mask).astype(ft)
+                rows, count = rows * m, jnp.maximum(jnp.sum(m), 1.0)
+                steps = jnp.maximum(jnp.sum(m, axis=1), 1.0)
+            else:
+                m, count, steps = 1.0, rows.size, rows.shape[1]
+            per_ex = jnp.sum(rows, axis=1)
+            if self.time_reduction == "mean":
+                per_ex = per_ex / steps
+            elif self.time_reduction != "sum":
+                raise ValueError(f"unknown time_reduction "
+                                 f"{self.time_reduction!r} (sum | mean)")
+            sd = state["loop_exit_mass"].dtype
+            new_state = {**state, **jax.lax.stop_gradient({
+                "loop_exit_mass": (jnp.sum(p * m, axis=(1, 2)) / count
+                                   ).astype(sd),
+                "loop_exit_loss": (jnp.sum(ce * m, axis=(1, 2)) / count
+                                   ).astype(sd)})}
+        return per_ex, new_state
 
 
 @register_layer

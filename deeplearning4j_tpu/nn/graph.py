@@ -104,9 +104,12 @@ class ComputationGraph:
         with monitor.span("net/init", phase="given_weights"):
             self.net_params = params if params is not None else ps
             self.net_state = ss
-            self.updaters = {name: _updater_for(self._vertex_layer(name))
-                             if isinstance(conf.vertices[name], LayerVertex)
-                             else upd_ops.make("sgd")
+            def updater(v):
+                lc = (v.layer_conf() if isinstance(v, LayerVertex)
+                      else v.updater_layer())
+                return _updater_for(lc) if lc is not None \
+                    else upd_ops.make("sgd")
+            self.updaters = {name: updater(conf.vertices[name])
                              for name in self.order}
             self.opt_states = {name: self.updaters[name].init(self.net_params[name])
                                for name in self.order}
@@ -162,6 +165,13 @@ class ComputationGraph:
                     x = ins[0]
                     if train:
                         x = lc._maybe_dropout(x, True, r)
+                    if lc.scores_from_input:
+                        # it scores from x itself (_output_score): no
+                        # pre-activations, and no activation either
+                        preouts[name] = x
+                        new_states[name] = state[name]
+                        out_masks[name] = ms[0] if ms else None
+                        continue
                     pre = lc.preoutput(
                         lc._maybe_drop_connect(params[name], train, r), x)
                     preouts[name] = pre
@@ -224,21 +234,41 @@ class ComputationGraph:
             lm = lm[..., 0]
         return lm
 
+    def _output_score(self, name, lc, cparams, states, pre, y, lm, out_mask):
+        """Per-example score [N] of output layer ``name`` from what
+        ``_forward_all(preout_for=...)`` recorded for it: its
+        pre-activations, or, for a layer that scores from its input
+        (``scores_from_input``), that input; such a layer reads its
+        leaves from ``cparams`` (as the forward pass had them) and may
+        leave a new state in ``states[name]``."""
+        if lc.scores_from_input:
+            if lm is None:
+                lm = out_mask
+            with jax.named_scope(f"fwd/{type(lc).__name__}/{name}"):
+                per_ex, states[name] = lc.score_from_input(
+                    cparams[name], states[name], pre, y, lm)
+            return per_ex
+        return lc.compute_score(
+            y, pre, self._resolve_label_mask(pre, lm, out_mask))
+
     def _assemble_training_score(self, params, preouts, new_states,
-                                 out_masks, ys, lmasks, out_confs, out_pos):
+                                 out_masks, ys, lmasks, out_confs, out_pos,
+                                 compute_params=None):
         """Multi-output training score from forward results: per-output
         loss (masked), minibatch reduction, regularization penalty, and
         layer-surfaced aux losses (MoE load balancing).  Single source of
-        truth for the step AND the gradient checker."""
+        truth for the step AND the gradient checker.  ``compute_params``
+        are the leaves as the forward pass had them (the policy's cast of
+        ``params``; ``params`` where none is given)."""
         g = self.conf.global_conf
         score = 0.0
         for name, lc in out_confs.items():
             oi = out_pos[name]
-            pre = preouts[name]
-            lm = self._resolve_label_mask(
-                pre, lmasks[oi] if lmasks is not None else None,
+            per_ex = self._output_score(
+                name, lc, params if compute_params is None
+                else compute_params, new_states, preouts[name], ys[oi],
+                lmasks[oi] if lmasks is not None else None,
                 out_masks.get(name))
-            per_ex = lc.compute_score(ys[oi], pre, lm)
             score = score + (jnp.mean(per_ex) if g.mini_batch
                              else jnp.sum(per_ex))
         score = score + self._reg_penalty(params)
@@ -272,12 +302,14 @@ class ComputationGraph:
                     if fmasks_c is not None else {}
                 acts, preouts, new_states, out_masks = self._forward_all(
                     pc, state, inputs, masks, True, rng, preout_for=out_names)
-                preouts = {n: policy.cast_to_accum(v) for n, v in preouts.items()}
+                preouts = {n: v if out_confs[n].scores_from_input
+                           else policy.cast_to_accum(v)
+                           for n, v in preouts.items()}
                 new_states = policy.cast_to_param(new_states)
                 with jax.named_scope("loss"):
                     score = self._assemble_training_score(
                         p, preouts, new_states, out_masks, ys, lmasks,
-                        out_confs, out_pos)
+                        out_confs, out_pos, compute_params=pc)
                     if not g.minimize:
                         score = -score  # maximize: parity with the MLN step
                 return score, new_states
@@ -1079,16 +1111,17 @@ class ComputationGraph:
                 masks = ({n: m for n, m in zip(self.conf.network_inputs,
                                                fm_c) if m is not None}
                          if fm_c is not None else {})
-                _, preouts, _, out_masks = self._forward_all(
+                _, preouts, states, out_masks = self._forward_all(
                     pc, state, inputs, masks, False, jax.random.PRNGKey(0),
                     preout_for=list(out_confs))
                 total = 0.0
                 for name, lc in out_confs.items():
-                    pre = policy.cast_to_accum(preouts[name])
-                    lm = self._resolve_label_mask(
-                        pre, lms[out_pos[name]] if lms is not None else None,
+                    pre = preouts[name] if lc.scores_from_input \
+                        else policy.cast_to_accum(preouts[name])
+                    per_ex = self._output_score(
+                        name, lc, pc, states, pre, ys[out_pos[name]],
+                        lms[out_pos[name]] if lms is not None else None,
                         out_masks.get(name))
-                    per_ex = lc.compute_score(ys[out_pos[name]], pre, lm)
                     total = total + (jnp.mean(per_ex) if g.mini_batch
                                      else jnp.sum(per_ex))
                 return total + self._reg_penalty(params)
@@ -1225,17 +1258,17 @@ class ComputationGraph:
                 inputs = dict(zip(self.conf.network_inputs, xs_c))
                 masks = dict(zip(self.conf.network_inputs, fm_c)) \
                     if fm_c is not None else {}
-                _, preouts, _, out_masks = self._forward_all(
+                _, preouts, states, out_masks = self._forward_all(
                     pc, state, inputs, masks, False, jax.random.PRNGKey(0),
                     preout_for=out_names)
                 total = 0.0
                 for name, lc in out_confs.items():
-                    pre = policy.cast_to_accum(preouts[name])
-                    lm = self._resolve_label_mask(
-                        pre, lmasks[out_pos[name]] if lmasks is not None
+                    pre = preouts[name] if lc.scores_from_input \
+                        else policy.cast_to_accum(preouts[name])
+                    total = total + self._output_score(
+                        name, lc, pc, states, pre, ys[out_pos[name]],
+                        lmasks[out_pos[name]] if lmasks is not None
                         else None, out_masks.get(name))
-                    total = total + lc.compute_score(ys[out_pos[name]], pre,
-                                                     lm)
                 return total + jnp.where(add_reg,
                                          self._reg_penalty(params), 0.0)
 

@@ -138,6 +138,59 @@ def publish_expert_load(net) -> None:
             skew.labels(vertex=str(k)).set(float(c.max() / c.mean()))
 
 
+def _loop_vertices(net) -> Tuple[list, list]:
+    """(the graph's looped stacks, the heads that score their exits),
+    by vertex name."""
+    if not hasattr(net, "order"):
+        return [], []
+    from deeplearning4j_tpu.nn.conf.graph_conf import LayerVertex, LoopVertex
+    from deeplearning4j_tpu.nn.conf.layers import LoopExitOutputLayer
+    vs = net.conf.vertices
+    return ([n for n, v in vs.items() if isinstance(v, LoopVertex)],
+            [n for n, v in vs.items() if isinstance(v, LayerVertex)
+             and isinstance(v.layer_conf(), LoopExitOutputLayer)])
+
+
+def publish_loop_exits(net) -> None:
+    """A looped stack's step, out of the state the step returned and
+    into ``dl4j_loop_passes_total{vertex}`` (passes run, last step of
+    each dispatch) and, of the head that scores its exits, the gauges
+    ``dl4j_loop_exit_mass{vertex, pass}`` (the exit distribution's mean
+    over tokens) and ``dl4j_loop_exit_loss{vertex, pass}`` (each pass's
+    mean cross-entropy): whether later passes still lower the loss, and
+    where the gate puts its mass.  A net without a loop publishes
+    nothing and pays one attribute read."""
+    found = getattr(net, "_loops", None)
+    if found is None:
+        found = net._loops = _loop_vertices(net)
+    loops, heads = found
+    if not loops and not heads:
+        return
+    reg = monitor.get_registry()
+    passes = reg.counter(
+        "dl4j_loop_passes_total",
+        "passes a looped stack ran, last step of each dispatch",
+        labels=("vertex",))
+    mass = reg.gauge(
+        "dl4j_loop_exit_mass",
+        "mean over tokens of the exit distribution, by pass, last step",
+        labels=("vertex", "pass"))
+    loss = reg.gauge(
+        "dl4j_loop_exit_loss",
+        "mean over tokens of each pass's cross-entropy, last step",
+        labels=("vertex", "pass"))
+    got = jax.device_get((
+        {n: net.net_state[n]["loop_passes"] for n in loops},
+        {n: (net.net_state[n]["loop_exit_mass"],
+             net.net_state[n]["loop_exit_loss"]) for n in heads}))
+    for n, r in got[0].items():
+        passes.labels(vertex=n).inc(int(r))
+    for n, (p, ce) in got[1].items():
+        for r in range(len(p)):
+            mass.labels(**{"vertex": n, "pass": str(r + 1)}).set(float(p[r]))
+            loss.labels(**{"vertex": n, "pass": str(r + 1)}).set(float(ce[r]))
+
+
 def dispatch_train_step(net, step_fn, kind, sig_args, batch, t_step,
                         bucket=None, k=1, repeats=1):
     """Launch ``step_fn`` on a staged ``batch`` and account for it: the
@@ -171,6 +224,7 @@ def dispatch_train_step(net, step_fn, kind, sig_args, batch, t_step,
             monitor.record_fit_step(net.last_batch_size,
                                     time.perf_counter() - t_step, score)
             publish_expert_load(net)
+            publish_loop_exits(net)
         with steps.span("fit/step", phase="listeners"):
             for lst in net.listeners:
                 lst.iteration_done(net, net.iteration)

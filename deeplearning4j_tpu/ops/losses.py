@@ -99,27 +99,34 @@ def _class_ids(labels, preout) -> bool:
             and labels.shape == preout.shape[:-1])
 
 
-def _mcxent_ids(ids, preout, activation, mask):
-    """``mcxent`` on integer class ids [...] against preout [..., V]:
-    what the one-hot labels would give, without the [..., V] label
-    array.  An id outside [0, V) is a row without a label: it scores 0
-    and sends no gradient, as an all-zero one-hot row does."""
+def mcxent_id_rows(ids, preout, activation="softmax", mask=None):
+    """The cross-entropy of every row, [...], of integer class ids [...]
+    against preout [..., V]: what ``mcxent`` sums, through the tier the
+    registry selects (``mask`` only tells the selection that no
+    per-class mask is in play; it is not applied).  An id outside
+    [0, V) is a row without a label: it scores 0 and sends no gradient,
+    as an all-zero one-hot row does."""
     V = preout.shape[-1]
     if activation == "softmax" and _fused_xent_wanted(ids, preout, mask):
         from deeplearning4j_tpu.ops import pallas_kernels as pk
-        rows = pk.softmax_xent_rows(
+        return pk.softmax_xent_rows(
             preout.reshape(-1, V), ids.reshape(-1)).reshape(ids.shape)
+    labelled = (ids >= 0) & (ids < V)
+    at = jnp.clip(ids, 0, V - 1)[..., None]
+    if activation == "softmax":
+        logp = jnp.take_along_axis(preout, at, axis=-1)[..., 0] \
+            - jax.nn.logsumexp(preout, axis=-1)
     else:
-        labelled = (ids >= 0) & (ids < V)
-        at = jnp.clip(ids, 0, V - 1)[..., None]
-        if activation == "softmax":
-            logp = jnp.take_along_axis(preout, at, axis=-1)[..., 0] \
-                - jax.nn.logsumexp(preout, axis=-1)
-        else:
-            out = jnp.clip(_activate(preout, activation), EPS, 1.0 - EPS)
-            logp = jnp.log(jnp.take_along_axis(out, at, axis=-1)[..., 0])
-        rows = jnp.where(labelled, -logp, 0.0)
-    return _sum_rows(rows, mask)
+        out = jnp.clip(_activate(preout, activation), EPS, 1.0 - EPS)
+        logp = jnp.log(jnp.take_along_axis(out, at, axis=-1)[..., 0])
+    return jnp.where(labelled, -logp, 0.0)
+
+
+def _mcxent_ids(ids, preout, activation, mask):
+    """``mcxent`` on integer class ids [...] against preout [..., V]:
+    what the one-hot labels would give, without the [..., V] label
+    array."""
+    return _sum_rows(mcxent_id_rows(ids, preout, activation, mask), mask)
 
 
 def _sum_rows(rows, mask):

@@ -21,6 +21,7 @@ through the host (ref: parallelism/ParallelWrapper.java:49-679,
 
 from __future__ import annotations
 
+import time
 from typing import Optional
 
 import jax
@@ -126,21 +127,19 @@ class ParallelWrapper:
 
     _host_batch = staticmethod(fsdp.host_batch)
 
-    def _run_sharded_step(self, batch, n):
-        m = self.model
-        batch_sh = mesh_util.data_sharded(self.mesh)
-        x, y, fm, lm = jax.tree_util.tree_map(
-            lambda a: self._put_batch(a, batch_sh), batch)
-        m._key, sub = jax.random.split(m._key)
-        (m.net_params, m.net_state, m.opt_states, score) = self._sharded_step(
-            m.net_params, m.net_state, m.opt_states, x, y, fm, lm,
-            jnp.asarray(m.iteration, jnp.int32), sub)
-        m._strip_rnn_state()
-        m._score = score
-        m.last_batch_size = n
-        m.iteration += 1
-        for lst in m.listeners:
-            lst.iteration_done(m, m.iteration)
+    def _run_sharded_step(self, batch, n, bucket=None):
+        """One step on one normalized host batch: scattered over the mesh
+        (phase ``shard_h2d``), then the engines' own dispatch, wait,
+        bookkeeping and listeners (``dispatch_train_step``), as the
+        engines' ``conf.sharding()`` path does."""
+        from deeplearning4j_tpu.nn.multilayer import dispatch_train_step
+        t_step = time.perf_counter()
+        with self.model._steps.span("fit/step", phase="shard_h2d"):
+            placed = fsdp.shard_put(self.plan, batch)
+        self.model.last_batch_size = n
+        dispatch_train_step(
+            self.model, self._sharded_step, "sharded_step", placed, placed,
+            t_step, bucket=bucket)
 
     def _run_fused_group(self, group):
         m = self.model
@@ -148,8 +147,7 @@ class ParallelWrapper:
         if self._sharded_fused is None:
             self._sharded_fused = {}
             # structure warmup (carried-state keys) through one per-step
-            batch, n = group[0]
-            self._run_sharded_step(batch, n)
+            self._run_sharded_step(*group[0])
             group = group[1:]
             k = len(group)
             if not k:
@@ -165,7 +163,7 @@ class ParallelWrapper:
         scan_sh = NamedSharding(self.mesh, P(None, ("data", "fsdp")))
         stacked = jax.tree_util.tree_map(
             lambda *leaves: self._put_batch(np.stack(leaves), scan_sh),
-            *[b for b, _ in group])
+            *[g[0] for g in group])
         xs, ys, fms, lms = stacked
         m._key, sub = jax.random.split(m._key)
         (m.net_params, m.net_state, m.opt_states,
@@ -187,6 +185,7 @@ class ParallelWrapper:
         return (treedef, tuple((a.shape, a.dtype) for a in leaves))
 
     def _fit_allreduce(self, iterator, epochs: int):
+        from deeplearning4j_tpu import monitor
         from deeplearning4j_tpu.datasets.iterators import AsyncDataSetIterator
         m = self.model
         is_graph = type(m).__name__ == "ComputationGraph"
@@ -196,32 +195,52 @@ class ParallelWrapper:
         if self._sharded_step is None:
             self._sharded_step = self._build_sharded_step()
             self._place()
-        it = AsyncDataSetIterator(iterator, queue_size=self.prefetch_buffer)
         fuse = self.fused_steps
+
+        def normalize(ds):
+            return fsdp.normalize_batch(m, ds, self.n_data, is_graph,
+                                        owner=self)
+
+        it = AsyncDataSetIterator(iterator, queue_size=self.prefetch_buffer)
         try:
-            for _ in range(epochs):
-                it.reset()
-                pending = []
-                while it.has_next():
-                    norm = self._normalize_batch(it.next(), is_graph)
-                    if norm is None:
-                        continue
-                    if fuse > 1:
-                        if pending and self._batch_sig(pending[0][0]) != \
-                                self._batch_sig(norm[0]):
-                            for b, n in pending:   # mixed shapes: per-step
-                                self._run_sharded_step(b, n)
+            # the phases of fit/step tile this loop as they tile the
+            # engines' own (what runs between two of them is ``glue``)
+            with monitor.profile_if_configured("fit") as profiling:
+                m._steps = steps = monitor.StepSpans(
+                    annotate=profiling or None)
+                for _ in range(epochs):
+                    with steps.span("fit/step", phase="epoch"):
+                        it.reset()
+                    pending = []
+                    while True:
+                        with steps.span("fit/step", phase="has_next"):
+                            more = it.has_next()
+                        if not more:
+                            break
+                        with steps.span("fit/step", phase="data_wait"):
+                            ds = it.next()
+                        with steps.span("fit/step", phase="bucket"):
+                            norm = normalize(ds)
+                        if norm is None:
+                            continue
+                        if fuse == 1:
+                            self._run_sharded_step(*norm)
+                            continue
+                        if pending and self._batch_sig(pending[0][0]) \
+                                != self._batch_sig(norm[0]):
+                            for g in pending:  # mixed shapes: per-step
+                                self._run_sharded_step(*g)
                             pending = []
                         pending.append(norm)
                         if len(pending) == fuse:
                             self._run_fused_group(pending)
                             pending = []
-                    else:
-                        self._run_sharded_step(*norm)
-                for b, n in pending:
-                    self._run_sharded_step(b, n)
+                    for g in pending:
+                        self._run_sharded_step(*g)
         finally:
             it.close()  # a producer blocked on a full queue must not leak
+            if m._steps is not None:
+                m._steps.close()
         return m
 
     # ------------------------------------------------------------------

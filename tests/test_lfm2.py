@@ -855,8 +855,12 @@ def test_profile_leaves_an_event_that_spans_others_out_of_the_sums():
 def test_the_parts_and_kernels_come_from_the_layer_classes():
     from deeplearning4j_tpu.monitor import profile
     parts, kernels = profile.layer_tables()
-    assert parts == {"MixtureOfExpertsLayer": frozenset(
-        L.MixtureOfExpertsLayer.scope_parts)}
+    assert parts["MixtureOfExpertsLayer"] == frozenset(
+        L.MixtureOfExpertsLayer.scope_parts)
+    # vertices declare parts as layers do
+    assert parts["LoopVertex"] == frozenset(("body",))
+    assert set(parts) == {"MixtureOfExpertsLayer", "LoopVertex",
+                          "LoopExitOutputLayer"}
     assert kernels == {"ragged-dot": ("MixtureOfExpertsLayer", "experts")}
 
     class Another(L.Layer):

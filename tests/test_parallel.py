@@ -326,3 +326,74 @@ def test_moe_net_falls_back_to_trim():
     net = MultiLayerNetwork(conf).init()
     pw = ParallelWrapper(net, make_mesh())
     assert not pw._pad_supported()
+
+
+# --- the all-reduce loop under the engines' phases (PR 33) --------------------
+def _phase_counts():
+    from deeplearning4j_tpu import monitor
+    fam = monitor.get_registry().snapshot().get("dl4j_phase_seconds", {})
+    return {s["labels"]["phase"]: int(s["count"])
+            for s in fam.get("samples", [])
+            if s["labels"].get("span") == "fit/step"}
+
+
+@pytest.mark.parametrize("graph", [False, True], ids=["list", "graph"])
+def test_allreduce_fit_runs_under_the_fit_step_phases(graph):
+    """ParallelWrapper.fit tiles its loop with the phases the engines'
+    own loops have, waits for every step and keeps their books: a host
+    batch is taken (``data_wait``), normalized to the data degree
+    (``bucket``) and scattered over the mesh (``shard_h2d``), then
+    dispatched as the engines dispatch."""
+    ds = _data()
+    if graph:
+        from deeplearning4j_tpu.nn.conf.graph_conf import GraphBuilder
+        from deeplearning4j_tpu.nn.conf.inputs import InputType
+        from deeplearning4j_tpu.nn.conf.network import GlobalConf
+        from deeplearning4j_tpu.nn.graph import ComputationGraph
+        b = (GraphBuilder(GlobalConf(seed=1, learning_rate=0.05,
+                                     updater="adam"))
+             .add_inputs("in")
+             .add_layer("d", DenseLayer(n_out=16, activation="relu"), "in")
+             .add_layer("out", OutputLayer(n_out=3, activation="softmax",
+                                           loss="mcxent"), "d"))
+        net = ComputationGraph(b.set_outputs("out").set_input_types(
+            InputType.feed_forward(4)).build()).init()
+    else:
+        net = _net()
+    seen = []
+
+    class Seen:
+        def iteration_done(self, model, iteration):
+            # the step is over when its listeners hear of it
+            seen.append((iteration, float(model._score)))
+    net.set_listeners(Seen())
+    before = _phase_counts()
+    ParallelWrapper(net, make_mesh()).fit(ListDataSetIterator(ds, 48),
+                                          epochs=2)
+    after = _phase_counts()
+    steps = 2 * 4                       # 150 rows in batches of 48, twice
+    assert [i for i, _ in seen] == list(range(1, steps + 1))
+    assert net.iteration == steps and net.last_batch_size == 150 - 3 * 48
+    moved = {p: after[p] - before.get(p, 0) for p in after}
+    for phase in ("data_wait", "bucket", "shard_h2d", "dispatch_prep",
+                  "jit_call", "block_until_ready", "bookkeeping",
+                  "listeners"):
+        assert moved[phase] == steps, (phase, moved)
+    assert moved["has_next"] == steps + 2 and moved["epoch"] == 2
+    assert net.compile_telemetry.retraces <= 2      # 48 rows, and the 6 padded to 8
+
+
+def test_allreduce_stream_goes_on_past_a_batch_that_is_dropped(monkeypatch):
+    """A batch whose rows would all be dropped runs no step; the stream
+    does not end there."""
+    from deeplearning4j_tpu.parallel import fsdp
+    ds = _data()
+    net = _net()
+    real, calls = fsdp.normalize_batch, []
+
+    def drop_the_second(model, batch, *a, **k):
+        calls.append(batch.num_examples())
+        return None if len(calls) == 2 else real(model, batch, *a, **k)
+    monkeypatch.setattr(fsdp, "normalize_batch", drop_the_second)
+    ParallelWrapper(net, make_mesh()).fit(ListDataSetIterator(ds, 48))
+    assert calls == [48, 48, 48, 6] and net.iteration == 3

@@ -176,8 +176,10 @@ def test_threshold_dropout_compiles(compile_for_chip, shape):
     ((2, 4, 200, 64), True),      # ragged T: padded to 256 inside
     ((1, 2, 4096, 64), True),     # the tiles of a long sequence
     ((1, 2, 640, 96), True),      # five tiles of 128, a head of 96
+    ((1, 2, 4096, 128), True),    # a head of 128 as the looped decoder
+                                  # has it: no pad, as many k/v heads
 ], ids=["selftest", "T512-causal", "T512-full", "ragged-T200",
-        "T4096-causal", "T640-D96"])
+        "T4096-causal", "T640-D96", "T4096-D128"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 def test_flash_attention_compiles(compile_for_chip, shape, causal, dtype):
