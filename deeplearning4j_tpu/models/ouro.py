@@ -18,7 +18,9 @@ head.  No layer has a bias but the gate.  Input is ``[B, T]`` int32
 token ids, labels are ``[B, T]`` int32 class ids, and ``output()``
 gives the last pass's distribution.  Each block is recomputed in the
 backward pass from its input (``recompute``): one saved input a block a
-pass.
+pass, and the two values its layers offer because they are dear to
+compute again (``ops/recompute.py``): the attention core's output with
+its row statistics, and the MLP's output, which ``N4``'s backward reads.
 
 ``layers`` builds a subset of the published layers (one pipeline
 stage's): what the passes loop over is then that stage's layers.  The

@@ -25,7 +25,12 @@ the ``fit()`` returns.  Per chip it holds
   expands into kernels of its own name loses its scope (XLA's grouped
   matrix product, ``ragged-dot-*``).  ``recomputed_s`` is the part of
   ``device_s["bwd"]``, by layer type, that is a forward pass run again
-  under ``jax.checkpoint`` (``rematted_computation`` in the name).
+  under ``jax.checkpoint`` (``rematted_computation`` in the name), and
+  ``recomputed_kernels_s`` the same by Mosaic kernel (a Pallas kernel,
+  or one the compiler emits itself, ``ragged-dot-*``), with an entry
+  for every kernel that ran: 0.0 says a kernel's launches were all
+  first ones (what a recomputed run keeps of a kernel's outputs,
+  ``ops/recompute.py``, it does not launch the kernel for again).
   The layer classes declare both, their parts and the kernels they
   claim for a part (:func:`layer_tables`); a claimed kernel reads under
   the direction ``kernel``, since forward and backward cannot be told
@@ -80,6 +85,9 @@ CONTAINER = re.compile(r"\s(?:while|conditional|call)\(")
 #: how ``jax.checkpoint`` names the forward it runs again in the backward
 #: pass (the backward's own operations stand beside it, not under it)
 REMATTED = "rematted_computation"
+#: a Mosaic kernel's event: a custom call whose instruction is named as
+#: the kernel is (``%dl4j_flash_fwd.3 = ... custom-call(...)``)
+PALLAS_CALL = "tpu_custom_call"
 #: the op_name such an event goes by: counted as busy, summed nowhere
 SPANS_OTHERS = "<spans others>"
 OUTSIDE = "outside_fit"
@@ -327,20 +335,41 @@ def host_phases(planes) -> List[Tuple[float, float, str]]:
     return out
 
 
-def device_events(planes, tables: Optional[Tables] = None
-                  ) -> Dict[int, List[Tuple[float, float, str]]]:
-    """{chip: [(start_ns, duration_ns, op_name)]} of the ops lines."""
-    out: Dict[int, List[Tuple[float, float, str]]] = {}
-    kernels = (tables or layer_tables())[1]
+def _ops_lines(planes) -> Dict[int, list]:
+    """{chip: its ops line's events as :func:`_plane` gives them}."""
+    out = {}
     for name, lines in planes:
         m = DEVICE_PLANE.match(name)
         if not m:
             continue
         for line_name, events in lines:
             if line_name == OPS_LINE:
-                out[int(m.group(1))] = [
-                    (s, d, _goes_by(text, op, kernels))
-                    for s, d, text, op in events]
+                out[int(m.group(1))] = events
+    return out
+
+
+def device_events(planes, tables: Optional[Tables] = None
+                  ) -> Dict[int, List[Tuple[float, float, str]]]:
+    """{chip: [(start_ns, duration_ns, op_name)]} of the ops lines."""
+    kernels = (tables or layer_tables())[1]
+    return {chip: [(s, d, _goes_by(text, op, kernels))
+                   for s, d, text, op in events]
+            for chip, events in _ops_lines(planes).items()}
+
+
+def recomputed_kernels(events) -> Dict[str, float]:
+    """{Mosaic kernel: seconds of its launches inside a forward pass run
+    again} over one ops line's events.  Every kernel that ran has an
+    entry; the instruction's number, which changes from compile to
+    compile, is cut from the name."""
+    out: Dict[str, float] = {}
+    for _, d, text, op_name in events:
+        if PALLAS_CALL not in text:
+            continue
+        name = re.sub(r"[.\d]+$", "", _instruction(text))
+        out.setdefault(name, 0.0)
+        if REMATTED in op_name:
+            out[name] += d / 1e9
     return out
 
 
@@ -367,6 +396,7 @@ def summarize(planes) -> dict:
         h[0] += (e - s) / 1e9
         h[1] += 1
     chips = {}
+    ops_lines = _ops_lines(planes)
     for chip, evs in sorted(device_events(planes, tables).items()):
         if not evs:
             continue
@@ -408,6 +438,7 @@ def summarize(planes) -> dict:
             "device_s": by_dir,
             "sub_scope_s": by_sub,
             "recomputed_s": by_remat,
+            "recomputed_kernels_s": recomputed_kernels(ops_lines[chip]),
             "top_scopes": sorted(by_scope.items(),
                                  key=lambda kv: -kv[1])[:10],
             "idle_s": dict(sorted(idle.items(), key=lambda kv: -kv[1])),

@@ -24,6 +24,7 @@ from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.conf.layers import Layer
 from deeplearning4j_tpu.nn.conf.network import GlobalConf, merge_layer_conf
 from deeplearning4j_tpu.nn.conf import preprocessors as pp
+from deeplearning4j_tpu.ops.recompute import keeping_offers
 
 VERTEX_REGISTRY: Dict[str, type] = {}
 
@@ -389,10 +390,14 @@ class LoopVertex(GraphVertexConf):
     ``recompute_blocks`` declares recomputation where the loop is
     declared: the body's vertices in consecutive runs (a block of a
     decoder, say), each run recomputed in the backward pass from what it
-    was handed, so that a block keeps one input a pass whatever it holds
-    inside.  None keeps every activation.  The global
-    ``gradient_checkpointing`` flag would keep the loop's input alone
-    and recompute all the passes in one piece.
+    was handed.  A block keeps one input a pass and what its layers
+    offer (``ops/recompute.py``: the flash attention core's output with
+    its row statistics, a gated MLP's output where a norm reads it),
+    each only where the backward pass reads it: 2 x ``[N, T, C]`` a
+    block a pass at most beside the input, for the attention kernel
+    and the MLP's last product not run a second time.  None keeps every
+    activation.  The global ``gradient_checkpointing`` flag would keep
+    the loop's input alone and recompute all the passes in one piece.
 
     The carried decode step cannot run a loop: each pass of each
     attention layer would need a cache of its own."""
@@ -523,7 +528,7 @@ class LoopVertex(GraphVertexConf):
             needs = [i for i in dict.fromkeys(
                 i for n in names for i in conf.vertex_inputs[n])
                 if i not in inside]
-            return needs, (jax.checkpoint(run) if recompute else run)
+            return needs, (keeping_offers(run) if recompute else run)
 
         fns = [(names,) + run_fn(names) for names in runs]
 

@@ -29,6 +29,7 @@ from deeplearning4j_tpu.ops import helpers as helper_ops
 from deeplearning4j_tpu.ops import initializers
 from deeplearning4j_tpu.ops import losses as loss_ops
 from deeplearning4j_tpu.ops import normalization as norm_ops
+from deeplearning4j_tpu.ops import recompute
 from deeplearning4j_tpu.ops import recurrent as rnn_ops
 from deeplearning4j_tpu.ops import row_segments
 
@@ -840,7 +841,14 @@ class RMSNormLayer(Layer):
 @dataclasses.dataclass
 class GatedDenseLayer(Layer):
     """Gated MLP (Shazeer 2020): y = (act(x W1) * (x W3)) W2, ``act``
-    the layer's activation (swish = SiLU by default), width ``hidden``."""
+    the layer's activation (swish = SiLU by default), width ``hidden``.
+
+    The output is offered to a recomputed run (``ops/recompute.py``).
+    Where a norm follows the layer (a sandwich block), the norm's
+    backward reads ``y``, and a run that kept nothing would do the
+    last product, the layer's dearest third, a second time for that
+    one ``[N, T, n_out]`` value.  Where nothing reads ``y`` (a pre-norm
+    block's residual add) the offer costs nothing."""
 
     activation: Optional[str] = "swish"
     n_in: Optional[int] = None
@@ -858,7 +866,7 @@ class GatedDenseLayer(Layer):
     def forward(self, params, state, x, *, train, rng, mask=None):
         x = self._maybe_dropout(x, train, rng)
         y = (self._act(x @ params["W1"]) * (x @ params["W3"])) @ params["W2"]
-        return y, state, mask
+        return recompute.offer(y), state, mask
 
     def output_type(self, input_type):
         if input_type.kind == "rnn":

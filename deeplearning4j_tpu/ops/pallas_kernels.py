@@ -59,6 +59,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deeplearning4j_tpu.ops import recompute
+
 NEG_INF = -1e30
 
 
@@ -453,6 +455,9 @@ def _flash_core(q, k, v, key_mask, causal: bool, scale: float):
 
 def _flash_vjp_fwd(q, k, v, key_mask, causal, scale):
     out, lse = _flash_fwd(q, k, v, key_mask, causal=causal, scale=scale)
+    # offered here, inside the rule: the backward reads these residuals,
+    # and a name on the layer's output would mark another variable
+    out, lse = recompute.offer(out), recompute.offer(lse)
     return out, (q, k, v, key_mask, out, lse)
 
 
@@ -484,7 +489,15 @@ def flash_attention(q, k, v, key_mask, causal: bool = False,
     keys change no real row, and pad query rows' cotangents are zero —
     and the tile follows the padded T (``_flash_block``).  Both
     pad/slice pairs sit outside the custom_vjp so gradients pass
-    through."""
+    through.
+
+    The core's output and its row statistics (logsumexp) are offered to
+    a recomputed run (``ops/recompute.py``), inside the forward rule
+    where they become the backward kernels' residuals: a run that keeps
+    them (``[B, H, T, D]`` in the operands' dtype and ``[B*H, 1, T]``
+    float32) does not launch the forward kernel a second time.  q, k
+    and v are not offered: three times the bytes for projections that
+    are cheap to run again."""
     D = q.shape[-1]
     T = q.shape[2]
     s = scale if scale is not None else 1.0 / (D ** 0.5)

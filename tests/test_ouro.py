@@ -306,27 +306,146 @@ def test_recomputation_per_block_changes_no_gradient(seeded):
         _close(grads[vertex][leaf], seeded["grads"][vertex][leaf], rtol=2e-6)
 
 
+def _loop_scans(net, x, y):
+    """The gradient's scans over the passes: the stack's forward (the
+    first) and its way back (the last; the head's two stand between)."""
+    jaxpr = jax.make_jaxpr(net._build_grad_raw())(
+        net.net_params, net.net_state, (jnp.asarray(x),), (jnp.asarray(y),),
+        None, None, jax.random.PRNGKey(0))
+    scans = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"]
+    return scans[0], scans[-1]
+
+
+def _stacked(scan, passes, like):
+    """How many [passes, N, T, C] values a forward scan hands on."""
+    shape = (passes,) + tuple(like)
+    return sum(1 for v in scan.outvars if tuple(v.aval.shape) == shape)
+
+
 def test_recomputation_keeps_one_input_a_block_a_pass(seeded):
     """What the backward pass is handed from the forward: with the blocks
-    recomputed, [passes, N, T, C] once a block; without, every
-    activation inside them as well."""
+    recomputed, [passes, N, T, C] once a block for its input and once for
+    the MLP's output it offers (``ops/recompute.py``; attention is dense
+    here and offers nothing); without, every activation inside them as
+    well."""
     def stacked(net):
-        x, y = (jnp.asarray(a) for a in seeded["batches"][0])
-        jaxpr = jax.make_jaxpr(net._build_grad_raw())(
-            net.net_params, net.net_state, (x,), (y,), None, None,
-            jax.random.PRNGKey(0))
-        shape = (CFG["total_ut_steps"], 2, CFG["seq_len"])
-        scans = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"]
-        fwd = scans[0]
-        return sum(1 for v in fwd.outvars
-                   if tuple(v.aval.shape[:3]) == shape)
+        fwd, _ = _loop_scans(net, *seeded["batches"][0])
+        return _stacked(fwd, CFG["total_ut_steps"],
+                        (2, CFG["seq_len"], CFG["hidden_size"]))
     kept = _net(recompute=False)
     kept.init(params=_copies(seeded["weights"]))
     n_blocks = len(CFG["layers_run"])
-    # the passes' outputs and one input a block (the first block's is the
-    # carried value itself)
-    assert stacked(seeded["net"]) <= n_blocks + 2
-    assert stacked(kept) > 4 * n_blocks
+    # the passes' outputs, one input a block (the first block's is the
+    # carried value itself) and one offered value a block
+    assert n_blocks < stacked(seeded["net"]) <= 2 * n_blocks + 2
+    assert stacked(kept) >= 2 * stacked(seeded["net"])
+
+
+def test_a_recomputed_block_does_not_run_the_mlps_last_product_again(
+        seeded, monkeypatch):
+    """N4's backward reads the MLP's output.  Kept, the second forward
+    stops before the product that made it: the way back holds one
+    ``dot_general`` a block fewer than under ``jax.checkpoint``'s default
+    policy, which keeps a run's inputs alone."""
+    def products(net):
+        _, bwd = _loop_scans(net, *seeded["batches"][0])
+        return str(bwd.params["jaxpr"]).count("dot_general")
+    offered = products(seeded["net"])
+    monkeypatch.setattr(G, "keeping_offers", jax.checkpoint)
+    plain = _net()
+    plain.init(params=_copies(seeded["weights"]))
+    assert products(plain) - offered == len(CFG["layers_run"])
+
+
+def test_a_recomputed_block_runs_the_attention_kernel_once(monkeypatch):
+    """Under the flash tier (interpret mode here) the core's output and
+    row statistics are kept as the backward kernels' residuals: one
+    ``dl4j_flash_fwd`` a block in the forward scan, none on the way back,
+    where the default policy launches it again; the gradient is the same
+    to the last bit, since a kept value is the value computed again."""
+    from deeplearning4j_tpu.ops import helpers
+    from deeplearning4j_tpu.ops import pallas_kernels as pk
+    cfg = dict(CFG, num_attention_heads=2, num_key_value_heads=2,
+               head_dim=32, layers_run=[0, 1], seq_len=128)
+    rng = np.random.default_rng(34)
+    ids = rng.integers(0, cfg["vocab_size"], (1, 129), dtype=np.int32)
+    x, y = ids[:, :-1], ids[:, 1:]
+
+    def case():
+        helpers.reset_validation()
+        pk._disabled.clear()
+        net = _net(cfg, seed=7)
+        net.init()
+        fwd, bwd = _loop_scans(net, x, y)
+        calls = [str(s.params["jaxpr"]).count("name=dl4j_flash_fwd")
+                 for s in (fwd, bwd)]
+        return calls, _grads(net, x, y)
+
+    monkeypatch.setenv("DL4J_PALLAS_FLASH", "1")
+    try:
+        calls, (score, _, grads) = case()
+        monkeypatch.setattr(G, "keeping_offers", jax.checkpoint)
+        plain_calls, (plain_score, _, plain_grads) = case()
+    finally:
+        helpers.reset_validation()
+    assert calls == [2, 0]
+    assert plain_calls == [2, 2]
+    assert float(score) == float(plain_score)
+    for path, g in grads["stack"].items():
+        np.testing.assert_array_equal(np.asarray(g),
+                                      np.asarray(plain_grads["stack"][path]))
+
+
+def _pre_norm_loop():
+    """x + MLP(N(x)) over [N, 8, 16], three passes: nothing reads the
+    MLP's output but the add."""
+    body = (G.GraphBuilder(GlobalConf(activation="identity",
+                                      weight_init="normal"))
+            .add_inputs("h")
+            .add_layer("norm", L.RMSNormLayer(), "h")
+            .add_layer("mlp", L.GatedDenseLayer(n_out=16, hidden=24), "norm")
+            .add_vertex("add", G.ElementWiseVertex(op="add"), "mlp", "h")
+            .set_outputs("add").build())
+    loop = G.LoopVertex.of(body, passes=3,
+                           recompute_blocks=[["norm", "mlp", "add"]])
+    kind = InputType.recurrent(16, 8)
+    loop.infer_body([kind])
+    params, state, _ = loop.initialize(jax.random.PRNGKey(0), [kind])
+    return loop, params, state
+
+
+def test_an_offer_without_a_reader_costs_no_residual(monkeypatch):
+    """A pre-norm body: the add's backward needs no value, so the MLP's
+    offered output is pruned with the product that made it, and the
+    forward scan hands on what it hands on under the default policy."""
+    x = jnp.ones((2, 8, 16), jnp.float32)
+
+    def handed_on():
+        loop, params, state = _pre_norm_loop()
+        jaxpr = jax.make_jaxpr(jax.grad(lambda p: jnp.sum(jnp.square(
+            loop.forward(p, state, [x], train=True,
+                         rng=jax.random.PRNGKey(1))[0]))))(params)
+        fwd = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"][0]
+        return _stacked(fwd, 3, x.shape), len(fwd.outvars)
+    offered = handed_on()
+    monkeypatch.setattr(G, "keeping_offers", jax.checkpoint)
+    assert offered == handed_on()
+    assert offered[0] == 2      # the passes' outputs and the block's input
+
+
+def test_an_offer_outside_a_recomputed_run_lowers_to_nothing():
+    """A layer offers without knowing who runs it: where no
+    ``jax.checkpoint`` takes the offer (every model but a looped one) the
+    gradient's program is the program without it, text for text."""
+    from deeplearning4j_tpu.ops import recompute
+
+    def program(offer):
+        def loss(x, w):
+            y = offer(jnp.tanh(x @ w))
+            return jnp.sum(y * y)
+        return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            jnp.ones((8, 8)), jnp.ones((8, 8))).as_text()
+    assert program(recompute.offer) == program(lambda y: y)
 
 
 def test_the_step_holds_the_body_once(seeded):
@@ -578,6 +697,38 @@ def test_profile_reads_the_loops_parts(op_name, want):
     direction, kind, _ = profile.classify(op_name)
     assert (direction, kind) == (op_name.count("transpose(") and "bwd"
                                  or "fwd", op_name.split("fwd/")[1].split("/")[0])
+
+
+def test_profile_tells_a_kernel_launched_again_from_its_first_launch():
+    """``recomputed_kernels_s``: a Pallas kernel's device time under
+    ``rematted_computation``, by kernel; a kernel that ran and was never
+    launched again reads 0.0, and an instruction that is no Pallas
+    kernel has no entry."""
+    from deeplearning4j_tpu.monitor import profile
+    stack = "jit(cg_train_step)/transpose(jvp(fwd/LoopVertex/stack))/body/while/body/"
+    call = ('%{}.{} = (bf16[16,4096,128]{{2,1,0}}, f32[16,1,4096]{{2,1,0}}) '
+            'custom-call(bf16[16,4096,128]{{2,1,0}} %p), '
+            'custom_call_target="tpu_custom_call"')
+    first = ("jit(cg_train_step)/jvp(fwd/LoopVertex/stack)/body/while/body/"
+             "checkpoint/SelfAttentionLayer/l0_attn/pallas_call")
+    again = (stack + "checkpoint/rematted_computation/SelfAttentionLayer/"
+             "l0_attn/pallas_call")
+    events = [
+        (0.0, 700.0, call.format("dl4j_flash_fwd", 3), first),
+        (1000.0, 300.0, call.format("dl4j_flash_dq", 1),
+         stack + "checkpoint/SelfAttentionLayer/l0_attn/pallas_call"),
+        (2000.0, 50.0, "%fusion.9 = bf16[8]{0} fusion(...)",
+         stack + "checkpoint/rematted_computation/RMSNormLayer/l0_n/mul")]
+    assert profile.recomputed_kernels(events) == {
+        "dl4j_flash_fwd": 0.0, "dl4j_flash_dq": 0.0}
+    events.append((3000.0, 650.0, call.format("dl4j_flash_fwd", 12), again))
+    assert profile.recomputed_kernels(events) == {
+        "dl4j_flash_fwd": pytest.approx(650e-9), "dl4j_flash_dq": 0.0}
+    chip = profile.summarize(
+        [("/device:TPU:0", [("XLA Ops", events)])])["chips"]["0"]
+    assert chip["recomputed_kernels_s"] == profile.recomputed_kernels(events)
+    # the sum by layer type counts the same launch, and the fusion
+    assert chip["recomputed_s"] == {"LoopVertex": pytest.approx(700e-9)}
 
 
 def test_the_step_names_the_loops_parts(seeded):
