@@ -331,6 +331,33 @@ class LastTimeStepVertex(GraphVertexConf):
 
 @register_vertex
 @dataclasses.dataclass
+class TimeRangeVertex(GraphVertexConf):
+    """Time steps ``from_step`` up to, not including, ``to_step`` (None:
+    to the end) of [N, T, C], with the same steps of the mask: what is
+    scored when only part of a sequence's rows carry a loss (the noised
+    half of ``[x_t ; x0]``), ahead of the final norm and the head, so
+    that no logits exist for the rest.  No reference analog
+    (``SubsetVertex`` cuts features, ``LastTimeStepVertex`` keeps one
+    step)."""
+
+    from_step: int = 0
+    to_step: Optional[int] = None
+
+    def forward(self, params, state, inputs, *, train, rng, masks=None):
+        sl = slice(self.from_step, self.to_step)
+        mask = masks[0] if masks else None
+        return (inputs[0][:, sl], state,
+                None if mask is None else mask[:, sl])
+
+    def output_type(self, input_types):
+        t = input_types[0]
+        n = None if t.timesteps is None else len(
+            range(t.timesteps)[self.from_step:self.to_step])
+        return InputType.recurrent(t.size, n)
+
+
+@register_vertex
+@dataclasses.dataclass
 class DuplicateToTimeSeriesVertex(GraphVertexConf):
     """[N,C] → [N,T,C] by duplication; T from a reference input
     (ref: rnn/DuplicateToTimeSeriesVertex.java).  The engine passes the

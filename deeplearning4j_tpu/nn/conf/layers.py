@@ -28,6 +28,7 @@ from deeplearning4j_tpu.ops import convolution as conv_ops
 from deeplearning4j_tpu.ops import helpers as helper_ops
 from deeplearning4j_tpu.ops import initializers
 from deeplearning4j_tpu.ops import losses as loss_ops
+from deeplearning4j_tpu.ops import mask_rules
 from deeplearning4j_tpu.ops import normalization as norm_ops
 from deeplearning4j_tpu.ops import recompute
 from deeplearning4j_tpu.ops import recurrent as rnn_ops
@@ -646,9 +647,13 @@ class RnnOutputLayer(BaseOutputLayer):
     int class ids [N, T] (``mcxent``).  ``time_reduction``: an example's
     score is the ``sum`` over its timesteps, as the reference scores, or
     their ``mean`` (over the unmasked ones), which makes the minibatch
-    score the mean over tokens when the sequences are equally long."""
+    score the mean over tokens when the sequences are equally long, or
+    the sum over the ``steps`` there are, T, whatever the mask: the
+    labels mask is then a row's WEIGHT, any float (a diffusion loss
+    weighs a masked token by 1 / t and the others by 0, and averages
+    over the sequence's length, not over the count of weighted rows)."""
 
-    time_reduction: str = "sum"     # sum | mean
+    time_reduction: str = "sum"     # sum | mean | steps
 
     def initialize(self, key, input_type, dtype=jnp.float32):
         n_in = self.n_in or input_type.size
@@ -663,6 +668,11 @@ class RnnOutputLayer(BaseOutputLayer):
         # labels/preout: [N, T, C]; mask [N, T].  Score per example sums
         # over time (masked), matching reference RnnOutputLayer scoring.
         m = mask[..., None] if mask is not None else None
+        if self.time_reduction == "steps":
+            with jax.named_scope("weighted_rows"):
+                return loss_ops.get(self.loss)(
+                    labels, preout, self.activation or "softmax", m) \
+                    / preout.shape[1]
         per_ex = loss_ops.get(self.loss)(labels, preout,
                                          self.activation or "softmax", m)
         if self.time_reduction == "mean":
@@ -671,7 +681,7 @@ class RnnOutputLayer(BaseOutputLayer):
             return per_ex / steps
         if self.time_reduction != "sum":
             raise ValueError(f"unknown time_reduction "
-                             f"{self.time_reduction!r} (sum | mean)")
+                             f"{self.time_reduction!r} (sum | mean | steps)")
         return per_ex
 
     def output_type(self, input_type):
@@ -945,12 +955,22 @@ class SelfAttentionLayer(Layer):
     ``dense_attention``; older tokens fall out of the ring (sliding
     window).  ``cache_window=None`` resolves to the declared input
     timesteps at init (128 when variable-length).
+
+    ``causal`` is the mask's rule (``ops/mask_rules.py``): False (every
+    pair lives), True (row i sees 0..i), or ``("block_diffusion",
+    seq_len, block_length)``: the 2 x seq_len rows are a noised and a
+    clean copy of one sequence, both at positions 0..seq_len-1, and a
+    row sees its own block of its own half and the clean blocks before
+    it.  The rule also gives the rotary embedding its positions.  The
+    core runs inside the layer's scope under the part ``attn_core``.
     """
+
+    scope_parts = ("attn_core",)
 
     n_in: Optional[int] = None
     n_out: int = 0
     n_heads: int = 1
-    causal: bool = False
+    causal: Any = False         # False | True | ("block_diffusion", L, b)
     strategy: str = "auto"      # auto | ring | ulysses | dense
     project_output: bool = True
     cache_window: Optional[int] = None   # KV-ring length for decode
@@ -961,10 +981,14 @@ class SelfAttentionLayer(Layer):
     # rotary position embedding over the whole head, halves paired
     # (i with i + Dh/2), base rotary_theta; None = no position signal
     rotary_theta: Optional[float] = None
-    # RMSNorm (eps RMS_EPS) with a learned weight over each head of q and
-    # of k, before the rotation ("q_norm", "k_norm" leaves of [Dh])
+    # RMSNorm (eps qk_norm_eps) with a learned weight over each head of q
+    # and of k, before the rotation ("q_norm", "k_norm" leaves of [Dh])
     qk_norm: bool = False
+    qk_norm_eps: float = RMS_EPS
     bias: bool = True           # False: no bq/bk/bv/bo leaves
+    # a head's width where it is not n_out / n_heads (q, k and v project
+    # to heads x head_dim, the output projection back to n_out)
+    head_dim: Optional[int] = None
 
     def initialize(self, key, input_type, dtype=jnp.float32):
         n_in = self.n_in or input_type.size
@@ -973,7 +997,12 @@ class SelfAttentionLayer(Layer):
         Hkv = self.n_kv_heads or self.n_heads
         if self.n_heads % Hkv:
             raise ValueError(f"n_heads={self.n_heads} % n_kv_heads={Hkv}")
-        Dh = self.n_out // self.n_heads
+        Dh = self._head_dim()
+        inner = self.n_heads * Dh
+        if inner != self.n_out and not self.project_output:
+            raise ValueError(f"head_dim={Dh} x n_heads={self.n_heads} is "
+                             f"not n_out={self.n_out}: the output "
+                             "projection is what brings it back")
         if self.rotary_theta is not None and Dh % 2:
             raise ValueError(f"rotary embedding pairs halves: head dim "
                              f"{Dh} is odd")
@@ -982,31 +1011,36 @@ class SelfAttentionLayer(Layer):
                                     or 128)
         kq, kk, kv, ko = jax.random.split(key, 4)
         params = {
-            "Wq": self._winit(kq, (n_in, self.n_out), dtype),
+            "Wq": self._winit(kq, (n_in, inner), dtype),
             "Wk": self._winit(kk, (n_in, Hkv * Dh), dtype),
             "Wv": self._winit(kv, (n_in, Hkv * Dh), dtype),
         }
         if self.bias:
-            params["bq"] = self._binit((self.n_out,), dtype)
+            params["bq"] = self._binit((inner,), dtype)
             params["bk"] = self._binit((Hkv * Dh,), dtype)
             params["bv"] = self._binit((Hkv * Dh,), dtype)
         if self.qk_norm:
             params["q_norm"] = jnp.ones((Dh,), dtype)
             params["k_norm"] = jnp.ones((Dh,), dtype)
         if self.project_output:
-            params["Wo"] = self._winit(ko, (self.n_out, self.n_out), dtype)
+            params["Wo"] = self._winit(ko, (inner, self.n_out), dtype)
             if self.bias:
                 params["bo"] = self._binit((self.n_out,), dtype)
         return params, {}, InputType.recurrent(self.n_out, input_type.timesteps)
 
+    def _head_dim(self) -> int:
+        return self.head_dim or self.n_out // self.n_heads
+
     @staticmethod
-    def _rotate(a, theta):
-        """Rotary embedding of [B, H, T, Dh] at positions 0..T-1, the
-        angles and the rotation in float32."""
+    def _rotate(a, theta, positions=None):
+        """Rotary embedding of [B, H, T, Dh] at ``positions`` [T]
+        (0..T-1 when None), the angles and the rotation in float32."""
         T, Dh = a.shape[2], a.shape[3]
         ft = jnp.promote_types(a.dtype, jnp.float32)
         inv = 1.0 / (theta ** (jnp.arange(0, Dh, 2, dtype=ft) / Dh))
-        ang = jnp.arange(T, dtype=ft)[:, None] * inv[None, :]   # [T, Dh/2]
+        pos = (jnp.arange(T, dtype=ft) if positions is None
+               else positions.astype(ft))
+        ang = pos[:, None] * inv[None, :]                       # [T, Dh/2]
         cos, sin = jnp.cos(ang), jnp.sin(ang)
         a1, a2 = jnp.split(a.astype(ft), 2, axis=-1)
         return jnp.concatenate([a1 * cos - a2 * sin, a2 * cos + a1 * sin],
@@ -1016,7 +1050,7 @@ class SelfAttentionLayer(Layer):
         from deeplearning4j_tpu.parallel import sequence as seq_ops
         x = self._maybe_dropout(x, train, rng)
         B, T, _ = x.shape
-        H, Dh = self.n_heads, self.n_out // self.n_heads
+        H, Dh = self.n_heads, self._head_dim()
         Hkv = self.n_kv_heads or H
 
         def heads(w, b, n):  # [B, T, F] -> [B, n, T, Dh]
@@ -1029,15 +1063,17 @@ class SelfAttentionLayer(Layer):
         k = heads("Wk", "bk", Hkv)
         v = heads("Wv", "bv", Hkv)
         if self.qk_norm:
-            q = _rms_norm(q, params["q_norm"])
-            k = _rms_norm(k, params["k_norm"])
+            q = _rms_norm(q, params["q_norm"], self.qk_norm_eps)
+            k = _rms_norm(k, params["k_norm"], self.qk_norm_eps)
         if self.rotary_theta is not None:
             if seq_ops.kv_decode_active() and not train:
                 raise NotImplementedError(
                     "SelfAttentionLayer: rotary positions are not carried "
                     "in the KV-ring decode step")
-            q = self._rotate(q, self.rotary_theta)
-            k = self._rotate(k, self.rotary_theta)
+            rule = mask_rules.resolve(self.causal)
+            pos = None if rule is None else rule.positions(T)
+            q = self._rotate(q, self.rotary_theta, pos)
+            k = self._rotate(k, self.rotary_theta, pos)
         if Hkv != H:
             # the attention core wants one key/value head a query head
             k = jnp.repeat(k, H // Hkv, axis=1)
@@ -1088,9 +1124,11 @@ class SelfAttentionLayer(Layer):
                 new_state = dict(state) if state else {}
                 new_state["rnn_state"] = ring
         else:
-            out = seq_ops.attention(q, k, v, causal=self.causal,
-                                    key_mask=mask, strategy=self.strategy)
-        out = out.transpose(0, 2, 1, 3).reshape(B, T, self.n_out)
+            with jax.named_scope("attn_core"):
+                out = seq_ops.attention(q, k, v, causal=self.causal,
+                                        key_mask=mask,
+                                        strategy=self.strategy)
+        out = out.transpose(0, 2, 1, 3).reshape(B, T, H * Dh)
         if self.project_output:
             out = out @ params["Wo"]
             if "bo" in params:
@@ -1102,6 +1140,25 @@ class SelfAttentionLayer(Layer):
 
     def output_type(self, input_type):
         return InputType.recurrent(self.n_out, input_type.timesteps)
+
+    def core_tiles(self, T: int) -> Optional[dict]:
+        """{"visited", "boundary", "skipped"}: of the tiles a head of
+        this layer's flash core has over ``T`` rows under its mask rule,
+        those the kernels visit whole, visit with the rule's comparison
+        inside and never touch (``mask_rules.tile_counts``: the plan the
+        kernels run); None where a single-device step runs the dense
+        core instead (helper selection, the shape gate)."""
+        from deeplearning4j_tpu.ops import helpers, mask_rules
+        from deeplearning4j_tpu.ops import pallas_kernels as pk
+        Dh = self.head_dim or self.n_out // self.n_heads
+        q = jax.ShapeDtypeStruct((1, self.n_heads, T, Dh), jnp.float32)
+        if self.strategy not in ("auto", "dense") \
+                or not helpers.available("attention") \
+                or not pk.flash_attention_supported(q):
+            return None
+        rule = mask_rules.resolve(self.causal)
+        Tp = T + (-T) % pk.LANE
+        return mask_rules.tile_counts(rule, Tp, pk._flash_block(Tp, rule))
 
 
 @register_layer
@@ -1127,11 +1184,13 @@ class MixtureOfExpertsLayer(Layer):
     the rounding of a score; the k experts with the largest score (plus,
     with ``expert_bias``, a constant per-expert bias held in state, which
     steers the selection and not the weights) are selected, and their
-    scores renormalised over the k (``norm_topk``).  The N·k assignments
+    scores renormalised over the k (``norm_topk``; over their sum + 1e-6
+    under sigmoid scoring).  The N·k assignments
     are sorted by held expert, the others behind them, into a buffer of
-    rows cut into segments of static shape (``segment_shape``: the
-    even-load share N·k·G/E of the G experts held, so the segment count
-    follows the share and nothing else; no argument sets it).  The group
+    rows cut into segments of static shape (``segment_shape``: twice the
+    even-load share N·k·G/E of the G experts held, so that an even load
+    lies inside the first and not on its edge; the segment count follows
+    the share and nothing else, no argument sets it).  The group
     sizes are data, and so is the number of segments that hold a row of
     a held expert: those run, forward and backward, and the rest are
     skipped on the device by a loop whose trip count it reads (no step
@@ -1159,7 +1218,10 @@ class MixtureOfExpertsLayer(Layer):
     the segments run and skipped under "moe_row_segments"
     (``dl4j_moe_row_segments_total``).
     ``residual=False`` returns the routed sum alone (a graph adds the
-    residual with an ElementWiseVertex).  The four parts are named
+    residual with an ElementWiseVertex).  ``recompute=True`` keeps none
+    of the top_k path's buffers for the backward pass, which runs the
+    path again (``jax.checkpoint``): they are tokens x k rows long
+    whatever share is held, 7/8 of them another holder's at 16 of 128.  The four parts are named
     inside the layer's scope (``scope_parts``), and the grouped product's
     kernels, which the chip's compiler names itself, are claimed for
     ``experts`` (``scope_kernels``)."""
@@ -1181,6 +1243,10 @@ class MixtureOfExpertsLayer(Layer):
     gated: bool = False
     experts_held: Optional[Tuple[int, ...]] = None
     residual: bool = True
+    # the top_k path under jax.checkpoint: the backward pass routes,
+    # gathers and multiplies again instead of keeping the row buffers,
+    # which are tokens x k rows long whatever share of them is held here
+    recompute: bool = False
 
     def _held(self) -> Tuple[int, ...]:
         held = tuple(range(self.n_experts)) if self.experts_held is None \
@@ -1267,7 +1333,11 @@ class MixtureOfExpertsLayer(Layer):
             _, top_e = jax.lax.top_k(select, k)                   # [N, k]
             w = jnp.take_along_axis(scores, top_e, axis=1)
             if self.norm_topk:
-                w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+                # sigmoid scores may all be near 0 (the family that scores
+                # so adds 1e-6); the k largest of a softmax sum to k / E
+                # at the least, and are divided as they are
+                w = w / (jnp.sum(w, axis=-1, keepdims=True)
+                         + (1e-6 if self.scoring == "sigmoid" else 0.0))
             if tok_mask is not None:
                 w = w * tok_mask[:, None]
         seg, n_seg = self.segment_shape(N * k)
@@ -1321,7 +1391,10 @@ class MixtureOfExpertsLayer(Layer):
     def forward(self, params, state, x, *, train, rng, mask=None):
         x = self._maybe_dropout(x, train, rng)
         if self.top_k is not None:
-            routed, new_state = self._forward_top_k(params, state, x, mask)
+            run = self._forward_top_k
+            if self.recompute and train:
+                run = jax.checkpoint(run)
+            routed, new_state = run(params, state, x, mask)
             out = self._act(x + routed if self.residual else routed)
             if mask is not None and out.ndim == 3:
                 out = out * mask[:, :, None].astype(out.dtype)

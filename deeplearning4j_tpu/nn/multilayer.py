@@ -191,6 +191,96 @@ def publish_loop_exits(net) -> None:
             loss.labels(**{"vertex": n, "pass": str(r + 1)}).set(float(ce[r]))
 
 
+def _diffusion_trained(net) -> bool:
+    """Does the graph hold an attention layer under the block-diffusion
+    mask rule (its batches are ``datasets/diffusion.py``'s)?"""
+    if not hasattr(net, "order"):
+        return False
+    from deeplearning4j_tpu.nn.conf.graph_conf import LayerVertex
+    from deeplearning4j_tpu.nn.conf.layers import SelfAttentionLayer
+    from deeplearning4j_tpu.ops import mask_rules
+    return any(isinstance(v, LayerVertex)
+               and isinstance(v.layer_conf(), SelfAttentionLayer)
+               and isinstance(mask_rules.resolve(v.layer_conf().causal),
+                              mask_rules.BlockDiffusion)
+               for v in net.conf.vertices.values())
+
+
+def publish_diffusion(net, batch) -> None:
+    """A block-diffusion step's noise, out of the weights the batch came
+    with (its labels mask: 1 / t on a masked token, 0 on a clean one)
+    and into ``dl4j_diffusion_tokens_total{kind=clean|masked}`` and
+    ``dl4j_diffusion_loss_weight_sum``: the masked share should sit at
+    the schedule's mean t, and the weights' sum near the tokens' count.
+    A fused group publishes its last batch's.  Any other net publishes
+    nothing and pays one attribute read."""
+    on = getattr(net, "_diffusion", None)
+    if on is None:
+        on = net._diffusion = _diffusion_trained(net)
+    if not on or len(batch) < 4 or not batch[3] or batch[3][0] is None:
+        return
+    w = jax.device_get(batch[3][0])
+    if w.ndim == 3:         # a fused group's stacked batches
+        w = w[-1]
+    reg = monitor.get_registry()
+    tokens = reg.counter(
+        "dl4j_diffusion_tokens_total",
+        "tokens of the noised copy by whether the noise masked them, "
+        "last batch of each dispatch", labels=("kind",))
+    masked = int(np.count_nonzero(w))
+    tokens.labels(kind="masked").inc(masked)
+    tokens.labels(kind="clean").inc(int(w.size) - masked)
+    reg.counter(
+        "dl4j_diffusion_loss_weight_sum",
+        "sum of the loss weights (1 / t on masked tokens), last batch of "
+        "each dispatch").inc(float(w.sum()))
+
+
+def _attention_layers(net) -> dict:
+    """{index or vertex name: the layer} of the net's attention
+    layers."""
+    from deeplearning4j_tpu.nn.conf.layers import SelfAttentionLayer
+    if hasattr(net, "order"):
+        from deeplearning4j_tpu.nn.conf.graph_conf import LayerVertex
+        confs = {n: v.layer_conf() for n, v in net.conf.vertices.items()
+                 if isinstance(v, LayerVertex)}
+    else:
+        confs = dict(enumerate(net.layers))
+    return {k: l for k, l in confs.items()
+            if isinstance(l, SelfAttentionLayer)}
+
+
+def publish_attention_tiles(net, batch) -> None:
+    """``dl4j_attention_tiles{vertex, outcome}``: of the tiles a head of
+    each attention layer's flash core has over the batch's time steps,
+    those the kernels visit whole (``visited``), with the mask rule's
+    comparison inside (``boundary``) and not at all (``skipped``), as the
+    layer counts them from the plan its kernels run
+    (``SelfAttentionLayer.core_tiles``).  Static per shape: set when the
+    time length changes, for a single-device step (a partitioned one
+    runs the dense core).  A net without such a layer publishes nothing
+    and pays one attribute read."""
+    layers = getattr(net, "_attention_layers", None)
+    if layers is None:
+        layers = net._attention_layers = _attention_layers(net)
+    if not layers or getattr(net, "_sharding_plan", None) is not None:
+        return
+    x = batch[0][0] if isinstance(batch[0], (list, tuple)) else batch[0]
+    # [.., T] token ids or [.., T, F] features (a fused group leads with k)
+    T = x.shape[-1] if jnp.issubdtype(x.dtype, jnp.integer) else x.shape[-2]
+    if getattr(net, "_attention_tiles_T", None) == T:
+        return
+    net._attention_tiles_T = T
+    g = monitor.get_registry().gauge(
+        "dl4j_attention_tiles",
+        "tiles a head's flash attention core visits whole, visits with "
+        "the mask's comparison inside, and skips; set when the time "
+        "length changes", labels=("vertex", "outcome"))
+    for k, layer in layers.items():
+        for outcome, n in (layer.core_tiles(int(T)) or {}).items():
+            g.labels(vertex=str(k), outcome=outcome).set(n)
+
+
 def dispatch_train_step(net, step_fn, kind, sig_args, batch, t_step,
                         bucket=None, k=1, repeats=1):
     """Launch ``step_fn`` on a staged ``batch`` and account for it: the
@@ -225,6 +315,8 @@ def dispatch_train_step(net, step_fn, kind, sig_args, batch, t_step,
                                     time.perf_counter() - t_step, score)
             publish_expert_load(net)
             publish_loop_exits(net)
+            publish_diffusion(net, batch)
+            publish_attention_tiles(net, batch)
         with steps.span("fit/step", phase="listeners"):
             for lst in net.listeners:
                 lst.iteration_done(net, net.iteration)

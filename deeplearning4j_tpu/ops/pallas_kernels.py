@@ -59,7 +59,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from deeplearning4j_tpu.ops import recompute
+from deeplearning4j_tpu.ops import mask_rules, recompute
 
 NEG_INF = -1e30
 
@@ -154,9 +154,13 @@ def _interpret() -> bool:
 # and the dq/dk/dv accumulators are float32 until the final store, and
 # p and ds are cast to the dtype of the operand they meet.  HEAD WIDTH:
 # D is the blocks' last dimension whatever it is; flash_attention says
-# why it still pads it to 128 lanes.  TILES: square, see _flash_block;
-# the causal comparison runs only on the one tile a q block has on the
-# diagonal.
+# why it still pads it to 128 lanes.  TILES: square, see _flash_block.
+# MASK: a rule fixed at trace time (ops/mask_rules.py: causal, block
+# diffusion; None lets every pair live) says which key tiles a q block
+# visits and which of those need a comparison inside the tile; the three
+# kernels run its steps as they stand (_run_visits) and touch no other
+# tile.  Under the causal rule that is every tile before the diagonal,
+# then the one on it with the comparison.
 # ===========================================================================
 
 LANE = 128
@@ -180,29 +184,46 @@ _FLASH_COMPILER_PARAMS = pltpu.CompilerParams(
 _FLASH_BLOCK_CAP = 512
 
 
-def _flash_block(T: int) -> int:
+def _flash_block(T: int, rule=None) -> int:
     """Rows of q a program holds and keys a tile holds, for a sequence of
     T (a multiple of 128, as flash_attention pads it): the largest
     multiple of 128 that divides T and is at most the cap, so no
-    sequence computes rows beyond its own padding."""
-    return max(b for b in range(LANE, min(_FLASH_BLOCK_CAP, T) + 1, LANE)
-               if T % b == 0)
+    sequence computes rows beyond its own padding; under a rule whose
+    tiles must not straddle a boundary of its own (block diffusion's
+    halves), the largest that the rule accepts too."""
+    return mask_rules.tile_for(rule, T, _FLASH_BLOCK_CAP)
 
 
-def _diagonal_tile_live(block: int, transposed: bool = False):
-    """[block, block] of the square tile ON the diagonal: query position
-    >= key position, queries along the rows (along the columns if
-    ``transposed``).  With q blocks and key tiles of one size, tile i of
-    q block i is the only one that crosses the diagonal; tiles before it
-    lie wholly below (no comparison), tiles after it wholly above (never
-    visited)."""
+def _tile_positions(block: int, transposed: bool = False):
+    """(query positions, key positions) of a square tile, counted from
+    its corner: queries along the rows [block, 1] and keys along the
+    columns [1, block], or the other way round if ``transposed``."""
     rows = lax.broadcasted_iota(jnp.int32, (block, 1), 0)
     cols = lax.broadcasted_iota(jnp.int32, (1, block), 1)
-    return rows <= cols if transposed else rows >= cols
+    return (cols, rows) if transposed else (rows, cols)
+
+
+def _run_visits(steps, tile, carry):
+    """Run a rule's steps (``mask_rules``) in their order: ``tile(s,
+    carry)`` over each range of whole tiles, ``tile(s, carry,
+    live_in_tile=...)`` on each boundary tile, once or as often (0 or 1)
+    as the device reads."""
+    for step in steps:
+        if step[0] == "range":
+            carry = lax.fori_loop(step[1], step[2], tile, carry)
+            continue
+        _, index, live_in_tile, trips = step
+        if trips is None:
+            carry = tile(index, carry, live_in_tile=live_in_tile)
+        else:
+            carry = lax.fori_loop(
+                0, trips, lambda _, c, index=index, live_in_tile=live_in_tile:
+                tile(index, c, live_in_tile=live_in_tile), carry)
+    return carry
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, out_ref, lse_ref, *,
-                      causal: bool, scale: float):
+                      rule, scale: float):
     """One (batch*head, q-block) program: stream K/V tiles with online
     softmax.  Block shapes: q [BQ, D], k/v [T, D], mask [1, T]; outputs
     out [BQ, D] and the per-row logsumexp lse [1, BQ], a lane-dense ROW
@@ -212,14 +233,15 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, out_ref, lse_ref, *,
     BQ, D = q.shape
     qi = pl.program_id(1)
 
-    def tile(s, carry, *, diagonal=False):
+    def tile(s, carry, *, live_in_tile=None):
         m, l, acc = carry
         start = pl.multiple_of(s * BQ, BQ)
         k_blk = k_ref[pl.ds(start, BQ), :]
         v_blk = v_ref[pl.ds(start, BQ), :]
         live = mask_ref[:, pl.ds(start, BQ)] > 0          # [1, BK]
-        if diagonal:
-            live = jnp.logical_and(live, _diagonal_tile_live(BQ))
+        if live_in_tile is not None:
+            live = jnp.logical_and(live,
+                                   live_in_tile(*_tile_positions(BQ)))
         scores = jax.lax.dot_general(
             q, k_blk, _NT, preferred_element_type=jnp.float32) * scale
         scores = jnp.where(live, scores, NEG_INF)         # [BQ, BK]
@@ -235,10 +257,11 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, out_ref, lse_ref, *,
     carry = (jnp.full((BQ, 1), NEG_INF, jnp.float32),
              jnp.zeros((BQ, 1), jnp.float32),
              jnp.zeros((BQ, D), jnp.float32))
-    if causal:
-        carry = tile(qi, lax.fori_loop(0, qi, tile, carry), diagonal=True)
+    n_tiles = k_ref.shape[0] // BQ
+    if rule is None:
+        carry = lax.fori_loop(0, n_tiles, tile, carry)
     else:
-        carry = lax.fori_loop(0, k_ref.shape[0] // BQ, tile, carry)
+        carry = _run_visits(rule.q_visits(qi, n_tiles, BQ), tile, carry)
     m, l, acc = carry
     out_ref[...] = (acc / jnp.maximum(l, 1e-30)).astype(out_ref.dtype)
     # lse for backward recomputation; a row with no live key (fully
@@ -248,9 +271,10 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, out_ref, lse_ref, *,
     lse_ref[...] = lse.reshape(1, BQ)
 
 
-def _flash_fwd(q, k, v, key_mask, *, causal: bool, scale: float):
+def _flash_fwd(q, k, v, key_mask, *, rule, scale: float):
     B, H, T, D = q.shape
-    block = _flash_block(T)
+    rule = mask_rules.resolve(rule)     # a caller's plain True is CAUSAL
+    block = _flash_block(T, rule)
     qf = q.reshape(B * H, T, D)
     kf = k.reshape(B * H, T, D)
     vf = v.reshape(B * H, T, D)
@@ -259,7 +283,7 @@ def _flash_fwd(q, k, v, key_mask, *, causal: bool, scale: float):
     block_rows = pl.BlockSpec((None, block, D), lambda b, i: (b, i, 0))
 
     out, lse = pl.pallas_call(
-        functools.partial(_flash_fwd_kernel, causal=causal, scale=scale),
+        functools.partial(_flash_fwd_kernel, rule=rule, scale=scale),
         grid=(B * H, T // block),
         in_specs=[
             block_rows,                                             # q
@@ -283,7 +307,7 @@ def _flash_fwd(q, k, v, key_mask, *, causal: bool, scale: float):
 
 
 def _flash_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
-                     delta_ref, dq_ref, *, causal: bool, scale: float):
+                     delta_ref, dq_ref, *, rule, scale: float):
     """dQ for one q block: stream K/V tiles, recompute p, accumulate
     dq += (p ∘ (dO·Vᵀ − δ)) · K · scale.  lse and δ arrive as [1, BQ]
     rows and are turned into columns once a program."""
@@ -294,13 +318,14 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
     delta = delta_ref[...].reshape(BQ, 1)
     qi = pl.program_id(1)
 
-    def tile(s, dq, *, diagonal=False):
+    def tile(s, dq, *, live_in_tile=None):
         start = pl.multiple_of(s * BQ, BQ)
         k_blk = k_ref[pl.ds(start, BQ), :]
         v_blk = v_ref[pl.ds(start, BQ), :]
         live = mask_ref[:, pl.ds(start, BQ)] > 0          # [1, BK]
-        if diagonal:
-            live = jnp.logical_and(live, _diagonal_tile_live(BQ))
+        if live_in_tile is not None:
+            live = jnp.logical_and(live,
+                                   live_in_tile(*_tile_positions(BQ)))
         s_ = jax.lax.dot_general(
             q, k_blk, _NT, preferred_element_type=jnp.float32) * scale
         # the EXPONENT is clamped, not the result: a dead tile gives an
@@ -314,16 +339,16 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
             preferred_element_type=jnp.float32)
 
     dq = jnp.zeros((BQ, D), jnp.float32)
-    if causal:
-        dq = tile(qi, lax.fori_loop(0, qi, tile, dq), diagonal=True)
+    n_tiles = k_ref.shape[0] // BQ
+    if rule is None:
+        dq = lax.fori_loop(0, n_tiles, tile, dq)
     else:
-        dq = lax.fori_loop(0, k_ref.shape[0] // BQ, tile, dq)
+        dq = _run_visits(rule.q_visits(qi, n_tiles, BQ), tile, dq)
     dq_ref[...] = (dq * scale).astype(dq_ref.dtype)
 
 
 def _flash_dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
-                      delta_ref, dk_ref, dv_ref, *, causal: bool,
-                      scale: float):
+                      delta_ref, dk_ref, dv_ref, *, rule, scale: float):
     """dK/dV for one k block: stream Q/dO tiles and recompute the
     TRANSPOSED tile pᵀ [BK, BQ], so that the per-query statistics are
     [1, BQ] rows and every product is a plain one:
@@ -334,7 +359,7 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
     BK, D = k_blk.shape
     ki = pl.program_id(1)
 
-    def tile(s, carry, *, diagonal=False):
+    def tile(s, carry, *, live_in_tile=None):
         dk, dv = carry
         start = pl.multiple_of(s * BK, BK)
         q_blk = q_ref[pl.ds(start, BK), :]
@@ -342,9 +367,9 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
         lse_row = lse_ref[:, pl.ds(start, BK)]            # [1, BQ]
         delta_row = delta_ref[:, pl.ds(start, BK)]        # [1, BQ]
         live = key_live
-        if diagonal:
+        if live_in_tile is not None:
             live = jnp.logical_and(
-                live, _diagonal_tile_live(BK, transposed=True))
+                live, live_in_tile(*_tile_positions(BK, transposed=True)))
         st = jax.lax.dot_general(
             k_blk, q_blk, _NT, preferred_element_type=jnp.float32) * scale
         pt = jnp.exp(jnp.where(live, st - lse_row, NEG_INF))  # [BK, BQ]
@@ -361,21 +386,19 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
 
     carry = (jnp.zeros((BK, D), jnp.float32), jnp.zeros((BK, D), jnp.float32))
     n_blocks = q_ref.shape[0] // BK
-    if causal:
-        # q tiles before this k block see none of it
-        carry = lax.fori_loop(ki + 1, n_blocks, tile,
-                              tile(ki, carry, diagonal=True))
-    else:
+    if rule is None:
         carry = lax.fori_loop(0, n_blocks, tile, carry)
+    else:
+        carry = _run_visits(rule.k_visits(ki, n_blocks, BK), tile, carry)
     dk, dv = carry
     dk_ref[...] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[...] = dv.astype(dv_ref.dtype)
 
 
-def _flash_bwd(q, k, v, key_mask, out, lse, g, *, causal: bool,
-               scale: float):
+def _flash_bwd(q, k, v, key_mask, out, lse, g, *, rule, scale: float):
     B, H, T, D = q.shape
-    block = _flash_block(T)
+    rule = mask_rules.resolve(rule)
+    block = _flash_block(T, rule)
     qf = q.reshape(B * H, T, D)
     kf = k.reshape(B * H, T, D)
     vf = v.reshape(B * H, T, D)
@@ -391,7 +414,7 @@ def _flash_bwd(q, k, v, key_mask, out, lse, g, *, causal: bool,
     block_rows = pl.BlockSpec((None, block, D), lambda b, i: (b, i, 0))
     block_row = pl.BlockSpec((None, 1, block), lambda b, i: (b, 0, i))
     dq = pl.pallas_call(
-        functools.partial(_flash_dq_kernel, causal=causal, scale=scale),
+        functools.partial(_flash_dq_kernel, rule=rule, scale=scale),
         grid=(B * H, T // block),
         in_specs=[
             block_rows,                                             # q
@@ -410,7 +433,7 @@ def _flash_bwd(q, k, v, key_mask, out, lse, g, *, causal: bool,
     )(qf, kf, vf, mask.reshape(B, 1, T), dof, lse, delta)
 
     dk, dv = pl.pallas_call(
-        functools.partial(_flash_dkv_kernel, causal=causal, scale=scale),
+        functools.partial(_flash_dkv_kernel, rule=rule, scale=scale),
         grid=(B * H, T // block),
         in_specs=[
             whole,                                                  # q
@@ -437,44 +460,46 @@ def _flash_bwd(q, k, v, key_mask, out, lse, g, *, causal: bool,
 
 def _dense_reference(q, k, v, key_mask, causal, scale):
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
-    if causal:
+    rule = mask_rules.resolve(causal)
+    if rule is not None:
         T = q.shape[2]
         qi = jnp.arange(T)[:, None]
         ki = jnp.arange(T)[None, :]
-        scores = jnp.where(qi >= ki, scores, NEG_INF)
+        scores = jnp.where(rule.live(qi, ki), scores, NEG_INF)
     scores = jnp.where(key_mask[:, None, None, :] > 0, scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _flash_core(q, k, v, key_mask, causal: bool, scale: float):
-    out, _ = _flash_fwd(q, k, v, key_mask, causal=causal, scale=scale)
+def _flash_core(q, k, v, key_mask, rule, scale: float):
+    out, _ = _flash_fwd(q, k, v, key_mask, rule=rule, scale=scale)
     return out
 
 
-def _flash_vjp_fwd(q, k, v, key_mask, causal, scale):
-    out, lse = _flash_fwd(q, k, v, key_mask, causal=causal, scale=scale)
+def _flash_vjp_fwd(q, k, v, key_mask, rule, scale):
+    out, lse = _flash_fwd(q, k, v, key_mask, rule=rule, scale=scale)
     # offered here, inside the rule: the backward reads these residuals,
     # and a name on the layer's output would mark another variable
     out, lse = recompute.offer(out), recompute.offer(lse)
     return out, (q, k, v, key_mask, out, lse)
 
 
-def _flash_vjp_bwd(causal, scale, res, g):
+def _flash_vjp_bwd(rule, scale, res, g):
     q, k, v, key_mask, out, lse = res
     dq, dk, dv = _flash_bwd(q, k, v, key_mask, out, lse, g,
-                            causal=causal, scale=scale)
+                            rule=rule, scale=scale)
     return dq, dk, dv, None
 
 
 _flash_core.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
-def flash_attention(q, k, v, key_mask, causal: bool = False,
+def flash_attention(q, k, v, key_mask, causal=False,
                     scale: Optional[float] = None):
     """Memory-efficient exact attention, differentiable with O(T) HBM in
-    both directions.  q,k,v: [B,H,T,D]; key_mask [B,T] (1=keep).
+    both directions.  q,k,v: [B,H,T,D]; key_mask [B,T] (1=keep);
+    ``causal``: False, True or any rule ``mask_rules.resolve`` knows.
     Products run in the operands' dtype with float32 accumulation
     (bfloat16 in: one MXU pass; float32 in: float32 products), softmax
     in float32.  scale defaults to 1/sqrt(D) of the ORIGINAL head dim
@@ -489,7 +514,9 @@ def flash_attention(q, k, v, key_mask, causal: bool = False,
     keys change no real row, and pad query rows' cotangents are zero —
     and the tile follows the padded T (``_flash_block``).  Both
     pad/slice pairs sit outside the custom_vjp so gradients pass
-    through.
+    through.  A rule that fixes the row count (block diffusion's 2L)
+    takes no padded rows: a length it cannot tile is refused, with the
+    reason.
 
     The core's output and its row statistics (logsumexp) are offered to
     a recomputed run (``ops/recompute.py``), inside the forward rule
@@ -501,15 +528,23 @@ def flash_attention(q, k, v, key_mask, causal: bool = False,
     D = q.shape[-1]
     T = q.shape[2]
     s = scale if scale is not None else 1.0 / (D ** 0.5)
+    rule = mask_rules.resolve(causal)
     pad_d = (-D) % LANE
     pad_t = (-T) % LANE
+    if rule is not None:
+        rule.check(T)
+        if pad_t and rule.tile_span(T) != T:
+            raise ValueError(
+                f"flash attention under {rule!r}: {T} rows are not a "
+                f"multiple of {LANE}, and padding would move the boundary "
+                "its tiles meet on")
     if pad_d or pad_t:
         widths = [(0, 0), (0, 0), (0, pad_t), (0, pad_d)]
         q = jnp.pad(q, widths)
         k = jnp.pad(k, widths)
         v = jnp.pad(v, widths)
         key_mask = jnp.pad(key_mask, [(0, 0), (0, pad_t)])  # pads masked out
-    out = _flash_core(q, k, v, key_mask, causal, s)
+    out = _flash_core(q, k, v, key_mask, rule, s)
     return out[:, :, :T, :D] if (pad_d or pad_t) else out
 
 

@@ -41,14 +41,17 @@ ROW_TILE = 16
 
 def segment_rows(rows: int, held: int, experts: int):
     """(rows of a segment, segments) for a sorted buffer of ``rows``
-    assignments of which ``held`` of ``experts`` experts' are here: the
-    even-load share ``rows * held / experts``, rounded up to the row
-    tile, so that an even load runs one or two segments of the E/G and
-    a layer that holds every expert runs its one."""
+    assignments of which ``held`` of ``experts`` experts' are here:
+    twice the even-load share ``rows * held / experts``, rounded up to
+    the row tile.  An even load then lies in the middle of the first
+    segment and not on its edge, where a row more or less from step to
+    step decides whether a second one runs (``PERF.md`` 6, PR 35); a
+    second segment runs when the load passes twice its even share, and
+    a layer that holds half the experts or more runs its one."""
     if held >= experts:
         return rows, 1
     share = -(-rows * held // experts)
-    seg = -(-share // ROW_TILE) * ROW_TILE
+    seg = -(-2 * share // ROW_TILE) * ROW_TILE
     return (rows, 1) if seg >= rows else (seg, -(-rows // seg))
 
 

@@ -392,15 +392,19 @@ def attend_cached(q, k_new, v_new, ring, *, key_mask=None,
 # Dense reference core (single device / no 'seq' axis).
 
 
-def dense_attention(q, k, v, *, causal: bool = False, key_mask=None,
+def dense_attention(q, k, v, *, causal=False, key_mask=None,
                     scale: Optional[float] = None, allow_flash: bool = True):
     """Plain softmax attention.  q,k,v: [B, H, T, D]; key_mask: [B, Tk]
     with 1=keep (the reference's feedForwardMaskArray convention,
-    ref: nn/api/Layer.java:309).  On TPU, tile-friendly shapes route to
-    the Pallas flash-attention kernel (ops/pallas_kernels.py) — O(T·D)
-    memory instead of the [T, T] score matrix in HBM."""
+    ref: nn/api/Layer.java:309).  ``causal`` is False, True or a mask
+    rule (``ops/mask_rules.py``: which pairs live, by position).  On
+    TPU, tile-friendly shapes route to the Pallas flash-attention kernel
+    (ops/pallas_kernels.py) — O(T·D) memory instead of the [T, T] score
+    matrix in HBM — which visits only the tiles the rule leaves live."""
+    from deeplearning4j_tpu.ops import mask_rules
     D = q.shape[-1]
     scale = scale if scale is not None else 1.0 / (D ** 0.5)
+    rule = mask_rules.resolve(causal)
     if allow_flash and q.shape[2] == k.shape[2]:
         # helper selection (ops/helpers.py): the attention tier routes
         # tile-friendly shapes to the flash kernel and meters the choice
@@ -409,14 +413,16 @@ def dense_attention(q, k, v, *, causal: bool = False, key_mask=None,
         if helpers.attention_wanted(q):
             km = (key_mask if key_mask is not None
                   else jnp.ones((q.shape[0], k.shape[2]), q.dtype))
-            return pk.flash_attention(q, k, v, km.astype(q.dtype), causal,
+            return pk.flash_attention(q, k, v, km.astype(q.dtype), rule,
                                       scale)
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
-    if causal:
+    if rule is not None:
         Tq, Tk = scores.shape[-2], scores.shape[-1]
+        if Tq == Tk:
+            rule.check(Tq)
         qi = jnp.arange(Tq)[:, None]
         ki = jnp.arange(Tk)[None, :]
-        scores = jnp.where(qi >= ki, scores, NEG_INF)
+        scores = jnp.where(rule.live(qi, ki), scores, NEG_INF)
     if key_mask is not None:
         scores = jnp.where(key_mask[:, None, None, :].astype(bool),
                            scores, NEG_INF)
@@ -558,11 +564,14 @@ def ulysses_attention(q, k, v, *, mesh: Mesh, causal: bool = False,
 # Strategy dispatch used by SelfAttentionLayer.
 
 
-def attention(q, k, v, *, causal: bool = False, key_mask=None,
+def attention(q, k, v, *, causal=False, key_mask=None,
               scale: Optional[float] = None, strategy: str = "auto"):
     """Attention core that is sequence-parallel whenever a mesh with a
     non-trivial 'seq' axis is active (see ``sequence_mesh``), dense
-    otherwise.  strategy: 'auto' | 'ring' | 'ulysses' | 'dense'."""
+    otherwise.  strategy: 'auto' | 'ring' | 'ulysses' | 'dense'.
+    ``causal``: False, True or a mask rule (``ops/mask_rules.py``); the
+    sequence-parallel strategies know False and True only and refuse
+    any other rule."""
     if strategy not in ("auto", "ring", "ulysses", "dense"):
         raise ValueError(f"unknown attention strategy {strategy!r} "
                          "(expected auto|ring|ulysses|dense)")
@@ -571,6 +580,15 @@ def attention(q, k, v, *, causal: bool = False, key_mask=None,
     if strategy == "dense" or seq == 1 or mesh is None:
         return dense_attention(q, k, v, causal=causal, key_mask=key_mask,
                                scale=scale)
+    from deeplearning4j_tpu.ops import mask_rules
+    rule = mask_rules.resolve(causal)
+    causal = rule is mask_rules.CAUSAL
+    if rule is not None and not causal:
+        raise NotImplementedError(
+            f"attention under the mask rule {rule!r} over a 'seq' mesh "
+            f"axis of {seq}: the ring and all-to-all strategies shard the "
+            "sequence by position and know causal masks only; run it with "
+            "strategy='dense' or without the axis")
     if q.shape[2] % seq:
         raise ValueError(
             f"sequence length {q.shape[2]} not divisible by the mesh 'seq' "

@@ -125,8 +125,8 @@ def test_grouped_expert_products_compile_to_a_kernel(compile_for_chip):
 def test_expert_layer_compiles_with_its_segments_skipped_on_the_chip(
         compile_for_chip):
     """The expert layer of the language-model cell, forward and backward
-    (8 of 32 experts held, top-4 over 8,192 tokens of 2,048: 4 segments
-    of 8,192 sorted rows): four loops whose trip count the chip reads,
+    (8 of 32 experts held, top-4 over 8,192 tokens of 2,048: 2 segments
+    of 16,384 sorted rows): four loops whose trip count the chip reads,
     the grouped products inside them and the weights' gradients outside
     as the compiler's own kernels."""
     from deeplearning4j_tpu.nn.conf import layers as L
@@ -134,7 +134,7 @@ def test_expert_layer_compiles_with_its_segments_skipped_on_the_chip(
         n_out=2048, n_experts=32, hidden=1792, top_k=4, scoring="sigmoid",
         expert_bias=True, gated=True, residual=False, activation="identity",
         experts_held=tuple(range(8)))
-    assert layer.segment_shape(8192 * 4) == (8192, 4)
+    assert layer.segment_shape(8192 * 4) == (16384, 2)
 
     def step(x, wg, w1, w2, w3, bias):
         def loss(p, x):
@@ -221,3 +221,27 @@ def test_flash_attention_compiles_at_the_language_model_cell_shape(
         assert hlo.count("tpu_custom_call") == 3
         for name in ("dl4j_flash_fwd", "dl4j_flash_dq", "dl4j_flash_dkv"):
             assert name in hlo
+
+
+@pytest.mark.parametrize("shape,rule", [
+    ((1, 32, 8192, 128), ("block_diffusion", 4096, 4)),   # the cell's core
+    ((1, 4, 768, 128), ("block_diffusion", 384, 3)),      # b no power of two
+    ((1, 4, 1024, 64), ("block_diffusion", 512, 512)),    # a block a tile
+], ids=["cell-L4096-b4", "L384-b3", "L512-b512"])
+def test_flash_attention_compiles_under_the_block_diffusion_rule(
+        compile_for_chip, shape, rule):
+    """The three kernels under a rule whose tile visits the device
+    derives from ``program_id``: ranges with computed bounds, a boundary
+    tile run 0 or 1 times, the block distance by shift or division."""
+    B, H, T, D = shape
+    bf16 = jnp.bfloat16
+
+    def step(q, k, v, km):
+        return jax.value_and_grad(
+            lambda q, k, v: _sq(pk.flash_attention(q, k, v, km, causal=rule)),
+            argnums=(0, 1, 2))(q, k, v)
+    hlo = compile_for_chip(step, (shape, bf16), (shape, bf16), (shape, bf16),
+                           ((B, T), jnp.float32))
+    assert hlo.count("tpu_custom_call") == 3
+    for name in ("dl4j_flash_fwd", "dl4j_flash_dq", "dl4j_flash_dkv"):
+        assert name in hlo
