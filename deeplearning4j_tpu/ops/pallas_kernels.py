@@ -17,12 +17,12 @@ Four kernels:
   therefore the per-shard core of Ulysses sequence parallelism; the
   ring path keeps its own block-streaming body) on TPU.  Backward is
   blockwise too (FlashAttention-2 recomputation from the saved per-row
-  logsumexp): dq and dk/dv kernels rebuild each [BQ, BK] probability
-  tile on the fly, so TRAINING memory is O(T·D) as well — no dense
-  [T, T] rematerialization.  Products run in the operands' dtype
-  (bfloat16 on the chip) with float32 accumulation; head dims that
-  aren't multiples of the 128-lane width are zero-padded outside the
-  custom_vjp.
+  logsumexp): one kernel rebuilds each [BQ, BK] probability tile on
+  the fly, once, and takes dq, dk and dv from it, so TRAINING memory
+  is O(T·D) as well — no dense [T, T] rematerialization.  Products
+  run in the operands' dtype (bfloat16 on the chip) with float32
+  accumulation; head dims that aren't multiples of the 128-lane width
+  are zero-padded outside the custom_vjp.
 
 * **fused_softmax_xent** — softmax + cross-entropy + gradient in one
   VMEM pass per row block.  The char-RNN/output-layer hot op: avoids
@@ -157,7 +157,7 @@ def _interpret() -> bool:
 # why it still pads it to 128 lanes.  TILES: square, see _flash_block.
 # MASK: a rule fixed at trace time (ops/mask_rules.py: causal, block
 # diffusion; None lets every pair live) says which key tiles a q block
-# visits and which of those need a comparison inside the tile; the three
+# visits and which of those need a comparison inside the tile; the two
 # kernels run its steps as they stand (_run_visits) and touch no other
 # tile.  Under the causal rule that is every tile before the diagonal,
 # then the one on it with the comparison.
@@ -173,9 +173,16 @@ _LSE_DEAD = 1e30
 # q, k, v, dO whole-sequence blocks are double-buffered in VMEM (1 MB an
 # array at T 4,096 x D 64 bfloat16, 4 MB at T 8,192 x D 128 float32) next
 # to a few [block_q, block_k] float32 temporaries; v5e has 128 MiB
+_FLASH_VMEM_LIMIT = 64 << 20
 _FLASH_COMPILER_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel"),
-    vmem_limit_bytes=64 << 20)
+    vmem_limit_bytes=_FLASH_VMEM_LIMIT)
+# the backward's key tiles run in order: dq gathers over them in a
+# float32 [T, D] of VMEM (4 MB at T 8,192 x D 128) beside the head's dq
+# block and the whole-sequence q and dO
+_FLASH_BWD_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary"),
+    vmem_limit_bytes=_FLASH_VMEM_LIMIT)
 
 # The square tile's cap: what one v5e measured best, forward and
 # forward + backward, at B 2, H 32 over 8, T 4,096, D 64, bfloat16,
@@ -306,86 +313,62 @@ def _flash_fwd(q, k, v, key_mask, *, rule, scale: float):
     return out.reshape(B, H, T, D), lse
 
 
-def _flash_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
-                     delta_ref, dq_ref, *, rule, scale: float):
-    """dQ for one q block: stream K/V tiles, recompute p, accumulate
-    dq += (p ∘ (dO·Vᵀ − δ)) · K · scale.  lse and δ arrive as [1, BQ]
-    rows and are turned into columns once a program."""
-    q = q_ref[...]                                        # [BQ, D]
-    do = do_ref[...]                                      # [BQ, D]
-    BQ, D = q.shape
-    lse = lse_ref[...].reshape(BQ, 1)
-    delta = delta_ref[...].reshape(BQ, 1)
-    qi = pl.program_id(1)
-
-    def tile(s, dq, *, live_in_tile=None):
-        start = pl.multiple_of(s * BQ, BQ)
-        k_blk = k_ref[pl.ds(start, BQ), :]
-        v_blk = v_ref[pl.ds(start, BQ), :]
-        live = mask_ref[:, pl.ds(start, BQ)] > 0          # [1, BK]
-        if live_in_tile is not None:
-            live = jnp.logical_and(live,
-                                   live_in_tile(*_tile_positions(BQ)))
-        s_ = jax.lax.dot_general(
-            q, k_blk, _NT, preferred_element_type=jnp.float32) * scale
-        # the EXPONENT is clamped, not the result: a dead tile gives an
-        # exact 0, and a dead row's lse (_LSE_DEAD) does by itself
-        p = jnp.exp(jnp.where(live, s_ - lse, NEG_INF))   # [BQ, BK]
-        dp = jax.lax.dot_general(do, v_blk, _NT,
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        return dq + jax.lax.dot_general(
-            ds.astype(k_blk.dtype), k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    dq = jnp.zeros((BQ, D), jnp.float32)
-    n_tiles = k_ref.shape[0] // BQ
-    if rule is None:
-        dq = lax.fori_loop(0, n_tiles, tile, dq)
-    else:
-        dq = _run_visits(rule.q_visits(qi, n_tiles, BQ), tile, dq)
-    dq_ref[...] = (dq * scale).astype(dq_ref.dtype)
-
-
-def _flash_dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
-                      delta_ref, dk_ref, dv_ref, *, rule, scale: float):
-    """dK/dV for one k block: stream Q/dO tiles and recompute the
-    TRANSPOSED tile pᵀ [BK, BQ], so that the per-query statistics are
-    [1, BQ] rows and every product is a plain one:
-    dv += pᵀ·dO and dk += (pᵀ ∘ (V·dOᵀ − δ))·Q · scale."""
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
+                      delta_ref, dq_ref, dk_ref, dv_ref, dq_acc, *,
+                      rule, scale: float):
+    """dQ, dK and dV from one build of each tile.  One (batch*head, k
+    block) program streams the Q/dO tiles that see this key tile and
+    recomputes the TRANSPOSED tile pᵀ [BK, BQ], so that the per-query
+    statistics are [1, BQ] rows and dk's and dv's products plain ones:
+    dv += pᵀ·dO, dk += dsᵀ·Q · scale with dsᵀ = pᵀ ∘ (V·dOᵀ − δ), and
+    dq[q tile] += ds·K · scale from the transpose of the dsᵀ already
+    cast for dk's product: five products and one exp a tile.  dq gathers
+    in ``dq_acc``, a float32 [T, D] that stays in VMEM over the head's
+    key tiles (the grid's second dimension runs in order): zeroed at the
+    first, written to the head's dq block at the last, so a q tile takes
+    its key tiles' terms in rising key order."""
     k_blk = k_ref[...]                                    # [BK, D]
     v_blk = v_ref[...]                                    # [BK, D]
     key_live = mask_ref[...] > 0                          # [BK, 1]
     BK, D = k_blk.shape
     ki = pl.program_id(1)
+    n_blocks = q_ref.shape[0] // BK
+
+    @pl.when(ki == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
 
     def tile(s, carry, *, live_in_tile=None):
         dk, dv = carry
-        start = pl.multiple_of(s * BK, BK)
-        q_blk = q_ref[pl.ds(start, BK), :]
-        do_blk = do_ref[pl.ds(start, BK), :]
-        lse_row = lse_ref[:, pl.ds(start, BK)]            # [1, BQ]
-        delta_row = delta_ref[:, pl.ds(start, BK)]        # [1, BQ]
+        rows = pl.ds(pl.multiple_of(s * BK, BK), BK)
+        q_blk = q_ref[rows, :]
+        do_blk = do_ref[rows, :]
+        lse_row = lse_ref[:, rows]                        # [1, BQ]
+        delta_row = delta_ref[:, rows]                    # [1, BQ]
         live = key_live
         if live_in_tile is not None:
             live = jnp.logical_and(
                 live, live_in_tile(*_tile_positions(BK, transposed=True)))
         st = jax.lax.dot_general(
             k_blk, q_blk, _NT, preferred_element_type=jnp.float32) * scale
+        # the EXPONENT is clamped, not the result: a dead tile gives an
+        # exact 0, and a dead row's lse (_LSE_DEAD) does by itself
         pt = jnp.exp(jnp.where(live, st - lse_row, NEG_INF))  # [BK, BQ]
         dv = dv + jax.lax.dot_general(
             pt.astype(do_blk.dtype), do_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)            # [BK, D]
         dpt = jax.lax.dot_general(v_blk, do_blk, _NT,
                                   preferred_element_type=jnp.float32)
-        dst = pt * (dpt - delta_row)
+        dst = (pt * (dpt - delta_row)).astype(q_blk.dtype)
         dk = dk + jax.lax.dot_general(
-            dst.astype(q_blk.dtype), q_blk, (((1,), (0,)), ((), ())),
+            dst, q_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)            # [BK, D]
+        dq_acc[rows, :] += jax.lax.dot_general(
+            dst.T, k_blk, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)            # [BQ, D]
         return dk, dv
 
     carry = (jnp.zeros((BK, D), jnp.float32), jnp.zeros((BK, D), jnp.float32))
-    n_blocks = q_ref.shape[0] // BK
     if rule is None:
         carry = lax.fori_loop(0, n_blocks, tile, carry)
     else:
@@ -393,6 +376,10 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
     dk, dv = carry
     dk_ref[...] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[...] = dv.astype(dv_ref.dtype)
+
+    @pl.when(ki == n_blocks - 1)
+    def _():
+        dq_ref[...] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
 def _flash_bwd(q, k, v, key_mask, out, lse, g, *, rule, scale: float):
@@ -403,7 +390,7 @@ def _flash_bwd(q, k, v, key_mask, out, lse, g, *, rule, scale: float):
     kf = k.reshape(B * H, T, D)
     vf = v.reshape(B * H, T, D)
     dof = g.reshape(B * H, T, D)
-    mask = key_mask.astype(jnp.float32)
+    mask = key_mask.astype(jnp.float32).reshape(B, T, 1)
     # δ_i = Σ_d dO·O — a cheap elementwise reduction XLA fuses on its own
     delta = jnp.sum(dof.astype(jnp.float32) *
                     out.reshape(B * H, T, D).astype(jnp.float32),
@@ -412,28 +399,8 @@ def _flash_bwd(q, k, v, key_mask, out, lse, g, *, rule, scale: float):
     whole = pl.BlockSpec((None, T, D), lambda b, i: (b, 0, 0))
     whole_row = pl.BlockSpec((None, 1, T), lambda b, i: (b, 0, 0))
     block_rows = pl.BlockSpec((None, block, D), lambda b, i: (b, i, 0))
-    block_row = pl.BlockSpec((None, 1, block), lambda b, i: (b, 0, i))
-    dq = pl.pallas_call(
-        functools.partial(_flash_dq_kernel, rule=rule, scale=scale),
-        grid=(B * H, T // block),
-        in_specs=[
-            block_rows,                                             # q
-            whole,                                                  # k
-            whole,                                                  # v
-            pl.BlockSpec((None, 1, T), lambda b, i: (b // H, 0, 0)),  # mask
-            block_rows,                                             # do
-            block_row,                                              # lse
-            block_row,                                              # delta
-        ],
-        out_specs=block_rows,
-        out_shape=jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
-        name="dl4j_flash_dq",
-        compiler_params=_FLASH_COMPILER_PARAMS,
-        interpret=_interpret(),
-    )(qf, kf, vf, mask.reshape(B, 1, T), dof, lse, delta)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_dkv_kernel, rule=rule, scale=scale),
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_flash_bwd_kernel, rule=rule, scale=scale),
         grid=(B * H, T // block),
         in_specs=[
             whole,                                                  # q
@@ -445,15 +412,19 @@ def _flash_bwd(q, k, v, key_mask, out, lse, g, *, rule, scale: float):
             whole_row,                                              # lse
             whole_row,                                              # delta
         ],
-        out_specs=[block_rows, block_rows],
+        # dq's block is the head's whole [T, D]: it leaves VMEM once, when
+        # the head's last key tile has written it
+        out_specs=[whole, block_rows, block_rows],
         out_shape=[
+            jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
             jax.ShapeDtypeStruct((B * H, T, D), k.dtype),
             jax.ShapeDtypeStruct((B * H, T, D), v.dtype),
         ],
-        name="dl4j_flash_dkv",
-        compiler_params=_FLASH_COMPILER_PARAMS,
+        scratch_shapes=[pltpu.VMEM((T, D), jnp.float32)],
+        name="dl4j_flash_bwd",
+        compiler_params=_FLASH_BWD_COMPILER_PARAMS,
         interpret=_interpret(),
-    )(qf, kf, vf, mask.reshape(B, T, 1), dof, lse, delta)
+    )(qf, kf, vf, mask, dof, lse, delta)
     return (dq.reshape(B, H, T, D), dk.reshape(B, H, T, D),
             dv.reshape(B, H, T, D))
 
@@ -520,7 +491,7 @@ def flash_attention(q, k, v, key_mask, causal=False,
 
     The core's output and its row statistics (logsumexp) are offered to
     a recomputed run (``ops/recompute.py``), inside the forward rule
-    where they become the backward kernels' residuals: a run that keeps
+    where they become the backward kernel's residuals: a run that keeps
     them (``[B, H, T, D]`` in the operands' dtype and ``[B*H, 1, T]``
     float32) does not launch the forward kernel a second time.  q, k
     and v are not offered: three times the bytes for projections that

@@ -694,7 +694,7 @@ def _tier_lowerings():
 
     return {
         "flash": (flash, ((f32(1, 2, 256, 64),) * 3, f32(1, 256)),
-                  ("dl4j_flash_fwd", "dl4j_flash_dq", "dl4j_flash_dkv")),
+                  ("dl4j_flash_fwd", "dl4j_flash_bwd")),
         "xent": (xent, (f32(256, 512), f32(256, 512)),
                  ("dl4j_softmax_xent",)),
         "lstm": (lstm, (f32(4, 64), f32(4, 16), f32(4, 16), f32(16, 64),

@@ -715,15 +715,15 @@ def test_profile_tells_a_kernel_launched_again_from_its_first_launch():
              "l0_attn/pallas_call")
     events = [
         (0.0, 700.0, call.format("dl4j_flash_fwd", 3), first),
-        (1000.0, 300.0, call.format("dl4j_flash_dq", 1),
+        (1000.0, 300.0, call.format("dl4j_flash_bwd", 1),
          stack + "checkpoint/SelfAttentionLayer/l0_attn/pallas_call"),
         (2000.0, 50.0, "%fusion.9 = bf16[8]{0} fusion(...)",
          stack + "checkpoint/rematted_computation/RMSNormLayer/l0_n/mul")]
     assert profile.recomputed_kernels(events) == {
-        "dl4j_flash_fwd": 0.0, "dl4j_flash_dq": 0.0}
+        "dl4j_flash_fwd": 0.0, "dl4j_flash_bwd": 0.0}
     events.append((3000.0, 650.0, call.format("dl4j_flash_fwd", 12), again))
     assert profile.recomputed_kernels(events) == {
-        "dl4j_flash_fwd": pytest.approx(650e-9), "dl4j_flash_dq": 0.0}
+        "dl4j_flash_fwd": pytest.approx(650e-9), "dl4j_flash_bwd": 0.0}
     chip = profile.summarize(
         [("/device:TPU:0", [("XLA Ops", events)])])["chips"]["0"]
     assert chip["recomputed_kernels_s"] == profile.recomputed_kernels(events)

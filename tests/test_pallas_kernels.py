@@ -153,7 +153,7 @@ def test_flash_head_dim_padding_matches_dense(D):
 
 @pytest.mark.parametrize("D", [64, 96])
 def test_flash_kernels_take_the_head_at_its_own_width(D):
-    """The three kernels need no 128-lane head (a block whose last
+    """The kernels need no 128-lane head (a block whose last
     dimension is the array's own is legal): called under the pad that
     flash_attention keeps for the cell's sake, forward and gradients."""
     q, k, v = _qkv(B=1, H=2, D=D, seed=14)
@@ -238,13 +238,107 @@ def test_flash_bfloat16_operands_match_float32_reference(causal, pad_from):
                                    rtol=5e-2, atol=2e-2)
 
 
+def _dense_grads(q, k, v, km, rule, w):
+    """dq, dk, dv of ``sum(out * w)`` through the dense reference in
+    float32, a query row with no live key giving an output of zeros (the
+    flash core's convention, where a softmax over nothing but masked
+    scores would spread itself evenly)."""
+    from deeplearning4j_tpu.ops import mask_rules
+    T, D = q.shape[2], q.shape[3]
+    pos = jnp.arange(T)
+    rule = mask_rules.resolve(rule)
+    pairs = jnp.ones((T, T), bool) if rule is None \
+        else rule.live(pos[:, None], pos[None, :])
+    row_lives = jnp.any(pairs[None] & (km[:, None, :] > 0), axis=-1)  # [B, T]
+
+    def loss(q, k, v):
+        q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+        out = pk._dense_reference(q, k, v, km, rule, 1.0 / (D ** 0.5))
+        return jnp.sum(jnp.where(row_lives[:, None, :, None], out, 0.0) * w)
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v), row_lives
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("D", [64, 128], ids=["D64-padded", "D128"])
+@pytest.mark.parametrize("rule,T", [
+    (False, 328), (True, 328), (("block_diffusion", 256, 4), 512)],
+    ids=["full-T328", "causal-T328", "block-diffusion-L256"])
+def test_flash_fused_backward_matches_dense_grads(monkeypatch, rule, T, D,
+                                                  dtype):
+    """The one backward kernel's dq, dk and dv against ``jax.grad`` of
+    the dense reference, over several tiles a head (the cap lowered to
+    128: T 328 pads to three tiles, 2 x 256 rows are four), B·H of 4, a
+    key mask with dead keys, and, where the rule lets a row see its own
+    first keys alone, query rows with no live key at all (``_LSE_DEAD``):
+    those take a dq of exact zeros and give dk and dv nothing."""
+    monkeypatch.setattr(pk, "_FLASH_BLOCK_CAP", 128)
+    q, k, v = (a.astype(dtype) for a in _qkv(B=2, H=2, T=T, D=D, seed=21))
+    w = _qkv(B=2, H=2, T=T, D=D, seed=22)[0]
+    km = np.ones((2, T), np.float32)
+    km[:, :4] = 0.0          # the only keys rows 0..3 see, under a rule
+    km[0, 140:150] = 0.0     # dead keys inside the second tile
+    km[1, 300:] = 0.0
+    km = jnp.asarray(km)
+    want, row_lives = _dense_grads(q, k, v, km, rule, w)
+    assert bool(jnp.all(row_lives[:, :4])) == (rule is False)
+
+    got = jax.grad(
+        lambda q, k, v: jnp.sum(
+            pk.flash_attention(q, k, v, km, rule).astype(jnp.float32) * w),
+        argnums=(0, 1, 2))(q, k, v)
+    dead = np.broadcast_to(~np.asarray(row_lives)[:, None, :, None], q.shape)
+    assert not np.asarray(got[0], np.float32)[dead].any()
+    for a, b in zip(got, want):
+        assert a.dtype == dtype and float(jnp.abs(b).max()) > 0
+        if dtype == jnp.float32:
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-3, atol=1e-4)
+        else:       # 8 bits: p and ds are each rounded once
+            assert _rel(a, b) < 1e-2
+            np.testing.assert_allclose(np.asarray(a, np.float32),
+                                       np.asarray(b), rtol=5e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+def test_flash_q_tile_dq_does_not_depend_on_how_many_key_tiles_the_grid_has(
+        monkeypatch, causal):
+    """dq gathers in one accumulator over a head's key tiles: zeroed at
+    the head's first, written at its last.  The same 256 rows inside a
+    sequence of 256 (two key tiles) and of 640 whose other keys are dead
+    (five) take the same dq, in each of B·H = 6 heads."""
+    monkeypatch.setattr(pk, "_FLASH_BLOCK_CAP", 128)
+    short, long_ = 256, 640
+    q, k, v = _qkv(B=2, H=3, T=long_, D=128, seed=23)
+    w = _qkv(B=2, H=3, T=long_, D=128, seed=24)[0]
+
+    def grads(T):
+        km = _mask(B=2, T=T, pad_from=short)
+        return jax.grad(
+            lambda q, k, v: jnp.sum(
+                (pk.flash_attention(q, k, v, km, causal) * w[:, :, :T])
+                [:, :, :short]),
+            argnums=(0, 1, 2))(q[:, :, :T], k[:, :, :T], v[:, :, :T])
+    few, many = grads(short), grads(long_)
+    assert pk._flash_block(short) == pk._flash_block(long_) == 128
+    for a, b in zip(few, many):
+        assert float(jnp.abs(a).max()) > 0
+        np.testing.assert_allclose(np.asarray(b[:, :, :short]), np.asarray(a),
+                                   rtol=1e-6, atol=1e-7)
+        assert not np.asarray(b[:, :, short:]).any()
+    heads = np.asarray(few[0]).reshape(6, -1)
+    assert len({h.tobytes() for h in heads}) == 6
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 def test_flash_products_run_in_the_operands_dtype(dtype):
     """No tile is lifted to float32 ahead of a product: every
-    dot_general inside the three kernels multiplies operands of the
+    dot_general inside the two kernels multiplies operands of the
     input's dtype (float32 in, float32 products, as before) and
-    accumulates in float32."""
+    accumulates in float32; and the backward builds a tile once: five
+    products and one exp, where a dq and a dk/dv kernel ran seven and
+    two."""
     q, k, v = (a.astype(dtype) for a in _qkv(B=1, H=2, T=256, D=64, seed=13))
     km = _mask(B=1)
     jaxpr = jax.make_jaxpr(jax.grad(
@@ -252,13 +346,17 @@ def test_flash_products_run_in_the_operands_dtype(dtype):
             pk.flash_attention(q, k, v, km, True).astype(jnp.float32) ** 2),
         argnums=(0, 1, 2)))(q, k, v)
     kernels = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
-    assert len(kernels) == 3
-    dots = [e for kern in kernels
-            for sub in jax.core.jaxprs_in_params(kern.params)
-            for e in _all_eqns(sub) if e.primitive.name == "dot_general"]
-    # forward, dq, dk/dv; twice, the tiles that cross the diagonal
-    # and those below it being two loops over the same body
-    assert len(dots) == 2 * (2 + 3 + 4)
+    assert [e.params["name"] for e in kernels] == [
+        "dl4j_flash_fwd", "dl4j_flash_bwd"]
+
+    def inside(kern, primitive):
+        return [e for sub in jax.core.jaxprs_in_params(kern.params)
+                for e in _all_eqns(sub) if e.primitive.name == primitive]
+    dots = [e for kern in kernels for e in inside(kern, "dot_general")]
+    # forward, backward; twice, the tiles that cross the diagonal and
+    # those below it being two loops over the same body
+    assert len(dots) == 2 * (2 + 5)
+    assert len(inside(kernels[1], "exp")) == 2 * 1
     for e in dots:
         assert [x.aval.dtype for x in e.invars] == [dtype, dtype]
         assert e.outvars[0].aval.dtype == jnp.float32

@@ -402,6 +402,47 @@ def test_the_kernels_honour_a_key_mask_under_the_rule():
     _close(got, want, rtol=1e-4)
 
 
+@pytest.mark.parametrize("which", range(3), ids=["dq", "dk", "dv"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_the_fused_backward_honours_a_key_mask_under_the_rule(
+        monkeypatch, dtype, which):
+    """Four tiles of 128 a head; dead keys inside a clean block, and the
+    whole of the first noisy block dead: its four rows, which no clean
+    block precedes, see no live key, take a dq of zeros and give dk and
+    dv nothing."""
+    monkeypatch.setattr(pk, "_FLASH_BLOCK_CAP", 128)
+    L_, b = 256, 4
+    rule = mask_rules.BlockDiffusion(L_, b)
+    q, k, v = (a.astype(dtype) for a in _qkv(2 * L_, H=3, D=64, seed=10))
+    w = _qkv(2 * L_, H=3, D=64, seed=11)[0]
+    km = jnp.ones((1, 2 * L_), jnp.float32).at[0, L_ + 133:L_ + 139].set(0.0)
+    km = km.at[0, :b].set(0.0)
+    dead_rows = np.zeros(2 * L_, bool)
+    dead_rows[:b] = True
+
+    def dense_loss(q, k, v):
+        out = seq_ops.dense_attention(
+            *(a.astype(jnp.float32) for a in (q, k, v)), causal=rule,
+            key_mask=km, allow_flash=False)
+        return jnp.sum(jnp.where(dead_rows[None, None, :, None], 0.0, out)
+                       * w)
+    want = jax.grad(dense_loss, argnums=which)(q, k, v)
+    # the kernel is given the dead rows' cotangent too: their p is 0
+    got = jax.grad(
+        lambda q, k, v: jnp.sum(
+            pk.flash_attention(q, k, v, km, rule).astype(jnp.float32) * w),
+        argnums=which)(q, k, v)
+    assert got.dtype == dtype and float(jnp.abs(want).max()) > 0
+    if which == 0:
+        assert not np.asarray(got, np.float32)[:, :, dead_rows].any()
+    if dtype == jnp.float32:
+        _close(got, want, rtol=1e-4)
+    else:
+        got, want = (np.asarray(a, np.float32) for a in (got, want))
+        assert np.linalg.norm(got - want) < 1e-2 * np.linalg.norm(want)
+
+
 def _count(jaxpr, primitive):
     n = 0
     for e in jaxpr.eqns:
@@ -416,12 +457,12 @@ def _count(jaxpr, primitive):
 
 @pytest.mark.parametrize("model", ["lfm2", "ouro"])
 def test_a_causal_layer_lowers_to_the_kernel_calls_it_had(model, monkeypatch):
-    """A causal layer's step holds the three kernels once a layer
+    """A causal layer's step holds the two kernels once a layer
     application, each with what the causal kernels had before the rule:
     one loop over the tiles before the diagonal and the diagonal tile
-    apart (2 + 2 products forward, 3 + 3 for dq, 4 + 4 for dk/dv), at
-    the tile the sequence gives, and the rule's tile count is the
-    triangle's."""
+    apart (2 + 2 products forward, 5 + 5 for the backward, which builds
+    a tile once for dq, dk and dv), at the tile the sequence gives, and
+    the rule's tile count is the triangle's."""
     from deeplearning4j_tpu.models import lfm2_moe, ouro
     monkeypatch.setenv("DL4J_PALLAS_FLASH", "1")     # interpret mode here
     helpers.reset_validation()
@@ -449,7 +490,7 @@ def test_a_causal_layer_lowers_to_the_kernel_calls_it_had(model, monkeypatch):
             jax.random.PRNGKey(0)))
     finally:
         helpers.reset_validation()
-    for name in ("dl4j_flash_fwd", "dl4j_flash_dq", "dl4j_flash_dkv"):
+    for name in ("dl4j_flash_fwd", "dl4j_flash_bwd"):
         assert text.count(f"name={name}") >= layers_with_attention, name
     # the kernels alone, at the same shape: products and loops a kernel
     q, k, v = _qkv(256, H=2, D=64)
@@ -462,16 +503,14 @@ def test_a_causal_layer_lowers_to_the_kernel_calls_it_had(model, monkeypatch):
     def walk(j):
         for e in j.eqns:
             if e.primitive.name == "pallas_call":
-                calls[[p for p in ("dl4j_flash_fwd", "dl4j_flash_dq",
-                                   "dl4j_flash_dkv")
-                       if f"name={p}" in str(e)][0]] = e
+                calls[e.params["name"]] = e
             for val in e.params.values():
                 for sub in (val if isinstance(val, (list, tuple)) else (val,)):
                     inner = getattr(sub, "jaxpr", sub)
                     if hasattr(inner, "eqns") and e.primitive.name != "pallas_call":
                         walk(inner)
     walk(jaxpr.jaxpr)
-    want = {"dl4j_flash_fwd": 4, "dl4j_flash_dq": 6, "dl4j_flash_dkv": 8}
+    want = {"dl4j_flash_fwd": 4, "dl4j_flash_bwd": 10}
     assert set(calls) == set(want)
     for name, e in calls.items():
         body = e.params["jaxpr"]
@@ -869,4 +908,4 @@ def test_the_step_names_the_core_the_cut_and_the_weighted_loss(seeded):
         == "fwd/SelfAttentionLayer/attn_core"
     assert profile.sub_scope(
         "jit(s)/transpose(jvp(fwd/SelfAttentionLayer/l0_attn))/attn_core/"
-        "dl4j_flash_dq") == "bwd/SelfAttentionLayer/attn_core"
+        "dl4j_flash_bwd") == "bwd/SelfAttentionLayer/attn_core"

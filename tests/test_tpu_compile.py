@@ -193,8 +193,8 @@ def test_flash_attention_compiles(compile_for_chip, shape, causal, dtype):
             argnums=(0, 1, 2))(q, k, v)
     hlo = compile_for_chip(step, (shape, dtype), (shape, dtype),
                            (shape, dtype), ((B, T), jnp.float32))
-    # forward, dq and dk/dv kernels
-    assert hlo.count("tpu_custom_call") >= 3
+    # the forward kernel and the backward's one
+    assert hlo.count("tpu_custom_call") == 2
 
 
 def test_flash_attention_compiles_at_the_language_model_cell_shape(
@@ -218,8 +218,8 @@ def test_flash_attention_compiles_at_the_language_model_cell_shape(
     for fn in (step, core_step):
         hlo = compile_for_chip(fn, (shape, bf16), (shape, bf16),
                                (shape, bf16), ((2, 4096), jnp.float32))
-        assert hlo.count("tpu_custom_call") == 3
-        for name in ("dl4j_flash_fwd", "dl4j_flash_dq", "dl4j_flash_dkv"):
+        assert hlo.count("tpu_custom_call") == 2
+        for name in ("dl4j_flash_fwd", "dl4j_flash_bwd"):
             assert name in hlo
 
 
@@ -230,7 +230,7 @@ def test_flash_attention_compiles_at_the_language_model_cell_shape(
 ], ids=["cell-L4096-b4", "L384-b3", "L512-b512"])
 def test_flash_attention_compiles_under_the_block_diffusion_rule(
         compile_for_chip, shape, rule):
-    """The three kernels under a rule whose tile visits the device
+    """The two kernels under a rule whose tile visits the device
     derives from ``program_id``: ranges with computed bounds, a boundary
     tile run 0 or 1 times, the block distance by shift or division."""
     B, H, T, D = shape
@@ -242,6 +242,41 @@ def test_flash_attention_compiles_under_the_block_diffusion_rule(
             argnums=(0, 1, 2))(q, k, v)
     hlo = compile_for_chip(step, (shape, bf16), (shape, bf16), (shape, bf16),
                            ((B, T), jnp.float32))
-    assert hlo.count("tpu_custom_call") == 3
-    for name in ("dl4j_flash_fwd", "dl4j_flash_dq", "dl4j_flash_dkv"):
+    assert hlo.count("tpu_custom_call") == 2
+    for name in ("dl4j_flash_fwd", "dl4j_flash_bwd"):
         assert name in hlo
+
+
+@pytest.mark.parametrize("shape,rule", [
+    ((2, 32, 4096, 64), True),
+    ((1, 16, 4096, 128), True),
+    ((1, 32, 8192, 128), ("block_diffusion", 4096, 4)),
+], ids=["lfm2_8b_a1b.fit_seq4k_b2", "ouro_2_6b.fit_seq4k_b1",
+        "sdar_30b_a3b.fit_bd4_seq4k_b1"])
+def test_the_fused_backward_compiles_inside_its_vmem_limit(
+        compile_for_chip, shape, rule):
+    """``dl4j_flash_bwd`` at the three language cells' cores, bfloat16:
+    one kernel behind the forward's, and what the chip's compiler
+    reserves of VMEM for it (the whole-sequence q and dO, the head's dq
+    block and the float32 accumulator under it, the tiles' temporaries)
+    lies inside the limit the kernel asks for."""
+    B, H, T, D = shape
+    bf16 = jnp.bfloat16
+
+    def step(q, k, v, km):
+        return jax.value_and_grad(
+            lambda q, k, v: _sq(pk.flash_attention(q, k, v, km, causal=rule)),
+            argnums=(0, 1, 2))(q, k, v)
+    hlo = compile_for_chip(step, (shape, bf16), (shape, bf16), (shape, bf16),
+                           ((B, T), jnp.float32))
+    calls = [line for line in hlo.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 2
+    (bwd,) = [line for line in calls if "dl4j_flash_bwd" in line]
+    asked = int(re.search(
+        r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', bwd).group(1))
+    used = int(re.search(
+        r'"used_scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', bwd).group(1))
+    assert asked == pk._FLASH_VMEM_LIMIT
+    # at least the accumulator and the q, dO and dq blocks it sits beside
+    lanes = -(-D // pk.LANE) * pk.LANE
+    assert T * lanes * (4 + 3 * 2) <= used <= asked
