@@ -24,7 +24,12 @@ request/session correlation IDs carried by contextvars) and the
 **flight recorder** (``monitor/flight.py`` — crash handlers dump the
 journal tail plus a registry snapshot to a timestamped JSON file;
 ``GET /trace`` / the ``trace_dump`` RPC serve the live journal and its
-Chrome trace-event export).
+Chrome trace-event export).  A plain ``monitor.span`` journals a
+``span.close`` when it ends; the phases of a training loop
+(``monitor.StepSpans``) journal nothing one by one: every step is ONE
+``fit.step`` event that holds its phases and what the host thread did
+meanwhile, and a step that ran late adds a ``fit.stall`` that names the
+phase that held it.
 
 Env knobs: ``DL4J_PROFILE=<dir>`` wraps every fit in
 ``jax.profiler.start_trace`` and writes its ``summary.json``
@@ -35,7 +40,7 @@ places flight-recorder dumps.  Full catalog: docs/OBSERVABILITY.md.
 """
 
 from deeplearning4j_tpu.monitor import (  # noqa: F401
-    compile_stages, events, flight)
+    compile_stages, events, flight, tracing)
 from deeplearning4j_tpu.monitor.events import (  # noqa: F401
     EventJournal, chrome_trace, chrome_trace_fleet, get_journal,
     new_request_id, request_scope)
@@ -55,6 +60,8 @@ from deeplearning4j_tpu.monitor.system import (  # noqa: F401
 get_registry().register_collector(memory_collector)
 # JAX's compile-stage timers land in dl4j_compile_seconds{stage,span}
 compile_stages.install()
+# the collector's pauses, for the step records of a StepSpans
+tracing.install_gc_hook()
 
 
 def record_fit_step(batch_size: int, seconds: float,

@@ -20,7 +20,10 @@ once, that land every stage in
   ``miss`` events;
 * the journal event ``compile.stage`` (function name, stage, seconds,
   span, and the iteration of a ``fit/step`` span; the ``fit_id`` rides
-  on the trace context): which step recompiled, and what it cost.
+  on the trace context): which step recompiled, and what it cost.  The
+  open span also takes the seconds (``Span.compile_s``), from which a
+  ``StepSpans`` charges them to the step's record: a step that paid for
+  a compilation is no stall.
 
 A stage is counted once per outermost call: every ``jit`` traced inside
 the step fires its own trace event, and an eager operation inside a
@@ -57,6 +60,8 @@ _installed = False
 def _record(stage: str, seconds: float, fun_name: str) -> None:
     cur = tracing.current()
     span = cur.name if cur is not None else ""
+    if cur is not None:
+        cur.compile_s += seconds    # a StepSpans charges it to the step
     get_registry().histogram(
         COMPILE_METRIC, "compile time by stage and paying span (seconds)",
         labels=("stage", "span"),

@@ -23,8 +23,9 @@ answers "what happened, in what order, to WHICH request".  Three pieces:
 * **Chrome trace export** — :func:`chrome_trace` renders journal events
   as Chrome trace-event JSON (Perfetto-loadable: open
   https://ui.perfetto.dev and drop the file): ``span.close`` events
-  become complete ("X") slices with real durations, everything else
-  becomes instant ("i") marks, correlation fields ride in ``args``.
+  become complete ("X") slices with real durations, a ``fit.step``
+  event the slices of its phases, everything else instant ("i") marks,
+  correlation fields ride in ``args``.
 
 ``DL4J_JOURNAL=0`` is the kill switch: :func:`emit` returns immediately
 — events become no-ops, not queued.  ``DL4J_JOURNAL_CAPACITY`` sizes
@@ -98,6 +99,8 @@ EVENT_TYPES = (
     "checkpoint.restored",
     "fit.start",
     "fit.end",
+    "fit.step",
+    "fit.stall",
     "compile.retrace",
     "compile.stage",
     "sanitizer.violation",
@@ -395,11 +398,18 @@ emit = _JOURNAL.emit
 _META_KEYS = ("type", "severity", "ts", "tid", "seq")
 
 
+def _slice(name, end_us, dur_us, pid, tid, args) -> dict:
+    return {"name": name, "cat": "span", "ph": "X", "ts": end_us - dur_us,
+            "dur": dur_us, "pid": pid, "tid": tid, "args": args}
+
+
 def _chrome_entries(events: List[dict], pid: int) -> tuple:
     """(trace entries, tids seen) for one process lane — the shared
     conversion: ``span.close`` → complete ("X") slices placed at their
-    start time, everything else → instant ("i") marks, correlation
-    fields in ``args``."""
+    start time; ``fit.step`` → one slice a phase, in the event's order,
+    each starting where the last ended (the phases tile the step, which
+    ended when the event was emitted); everything else → instant ("i")
+    marks; correlation fields in ``args``."""
     out: List[dict] = []
     tids: dict = {}
     for e in events:
@@ -408,13 +418,20 @@ def _chrome_entries(events: List[dict], pid: int) -> tuple:
         args = {k: v for k, v in e.items() if k not in _META_KEYS}
         ts_us = float(e.get("ts", 0.0)) * 1e6
         if e.get("type") == "span.close" and "duration_s" in e:
-            dur_us = max(0.0, float(e["duration_s"]) * 1e6)
             name = e.get("span", "span")
             if e.get("phase"):
                 name = f"{name}/{e['phase']}"
-            out.append({"name": name, "cat": "span", "ph": "X",
-                        "ts": ts_us - dur_us, "dur": dur_us,
-                        "pid": pid, "tid": tid, "args": args})
+            out.append(_slice(name, ts_us,
+                              max(0.0, float(e["duration_s"]) * 1e6),
+                              pid, tid, args))
+        elif e.get("type") == "fit.step" and "phases" in e:
+            args.pop("phases")
+            end_us = ts_us - float(e.get("step_s", 0.0)) * 1e6
+            for phase, seconds in e["phases"].items():
+                dur_us = max(0.0, float(seconds) * 1e6)
+                end_us += dur_us
+                out.append(_slice(f"{e.get('span', 'span')}/{phase}",
+                                  end_us, dur_us, pid, tid, args))
         else:
             out.append({"name": e.get("type", "event"),
                         "cat": str(e.get("type", "event")).split(".")[0],
@@ -427,8 +444,9 @@ def chrome_trace(events: Optional[List[dict]] = None) -> dict:
     """Render journal events as a Chrome trace-event JSON object
     (https://ui.perfetto.dev loads it directly; ``chrome://tracing``
     too).  ``span.close`` events become complete ("X") slices placed at
-    their start time with their measured duration; every other event is
-    an instant ("i") mark.  Correlation fields (request_id, session_id,
+    their start time with their measured duration, a ``fit.step`` event
+    the slices of its phases; every other event is an instant ("i")
+    mark.  Correlation fields (request_id, session_id,
     tenant, ...) ride in ``args`` so a slice can be found by searching
     for its request ID."""
     if events is None:

@@ -42,10 +42,13 @@ the ``fit()`` returns.  Per chip it holds
   ``outside_fit``;
 * ``wait_lag_s``: the median time from the device's last operation
   inside a ``block_until_ready`` phase to that phase's end: how late
-  the host learns that a step is done.  It is also the check on the one
-  clock: the profiler sets the device's timestamps against the host's
-  to about a millisecond, and a session whose lag reads a millisecond
-  more than another's has its idle time that much too early;
+  the host learns that a step is done;
+* ``clock_bounds_s``: the bounds causality sets on the offset of the
+  device's clock against the host's, the error bar on every idle gap
+  above; and, for the whole trace, ``steps`` and ``stalls``: the steps
+  that ran late by the fit loop's own rule, each with the device's busy
+  and idle time inside it and whether the device, the runtime or the
+  host was late (monitor/profile_steps.py);
 * ``host_s``: seconds and count of every ``fit/step`` phase.
 
 An operation's scope is its HLO ``op_name`` metadata.  The TPU runtime
@@ -397,7 +400,12 @@ def summarize(planes) -> dict:
         h[1] += 1
     chips = {}
     ops_lines = _ops_lines(planes)
-    for chip, evs in sorted(device_events(planes, tables).items()):
+    by_chip = device_events(planes, tables)
+    # imported here: profile_steps builds on this module's helpers
+    from deeplearning4j_tpu.monitor import profile_steps
+    by_step = profile_steps.steps_summary(planes, phases, by_chip)
+    clock_bounds = by_step.pop("clock_bounds_s")
+    for chip, evs in sorted(by_chip.items()):
         if not evs:
             continue
         busy, gaps = union((s, s + d) for s, d, _ in evs)
@@ -443,8 +451,9 @@ def summarize(planes) -> dict:
                                  key=lambda kv: -kv[1])[:10],
             "idle_s": dict(sorted(idle.items(), key=lambda kv: -kv[1])),
             "wait_lag_s": statistics.median(lags) if lags else None,
+            "clock_bounds_s": clock_bounds.get(str(chip)),
         }
-    return {"chips": chips, "host_s": host}
+    return {"chips": chips, "host_s": host, **by_step}
 
 
 def write_summary(trace_dir: str) -> str:

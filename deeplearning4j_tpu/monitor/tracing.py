@@ -12,7 +12,13 @@ A loop whose phases must leave no time unnamed owns a
 :class:`StepSpans` for its length: ``with steps.span("fit/step",
 phase="jit_call"):`` is the same span, and whatever runs between two
 phases is the phase ``glue``, so the phases tile the loop and there is
-no "between".
+no "between".  Such a phase journals nothing of its own: the loop closes
+every step with ``steps.step_done(iteration)``, and the step is ONE
+``fit.step`` event that holds its phases beside what the host thread did
+meanwhile; a step that ran late by :func:`stall_excess` names the phase
+that held it (``fit.stall``, ``dl4j_fit_stalls_total{phase}``).  A plain
+:func:`span` (serving, ``pipeline/batch``, ``net/init``) journals its
+``span.close`` as ever.
 
 Two optional bridges into JAX's own profiler:
 
@@ -31,10 +37,13 @@ measuring span overhead).
 
 from __future__ import annotations
 
+import gc
 import logging
 import os
+import resource
 import threading
 import time
+from collections import deque
 from contextlib import contextmanager
 from typing import Iterator, List, Optional
 
@@ -43,6 +52,37 @@ from deeplearning4j_tpu.monitor.registry import (
     MetricsRegistry, get_registry)
 
 PHASE_METRIC = "dl4j_phase_seconds"
+STALLS_METRIC = "dl4j_fit_stalls_total"
+STALL_SECONDS_METRIC = "dl4j_fit_stall_seconds_total"
+
+#: A step that did not compile is a stall when its wall time is over the
+#: median of the steps around it by more than the larger of these two:
+#: 2% is ``step_ms_p95``'s bound (one such step among the places the
+#: percentile reads breaches it alone), and 4 ms clears the 1 to 1.5 ms
+#: of standard deviation that a sparse-expert step's routing gives the
+#: steps proper.
+STALL_SHARE = 0.02
+STALL_FLOOR_S = 0.004
+#: steps a :class:`StepSpans` keeps, and how many of them must not have
+#: compiled before the first is judged
+RING_STEPS = 64
+JUDGED_FROM = 8
+
+
+def median(values) -> float:
+    ordered = sorted(values)
+    n = len(ordered)
+    return (ordered[(n - 1) // 2] + ordered[n // 2]) / 2
+
+
+def stall_excess(step_s: float, median_s: float) -> float:
+    """Seconds by which a step ran over the median of its neighbours,
+    or 0.0 where that is no stall.  The one rule: the loop applies it to
+    its ring of steps, ``monitor/profile_steps.py`` to a trace's."""
+    excess = step_s - median_s
+    return excess if excess > max(STALL_FLOOR_S,
+                                  STALL_SHARE * median_s) else 0.0
+
 
 log = logging.getLogger(__name__)
 
@@ -52,7 +92,8 @@ _profile = {"active": False, "count": 0, "lock": threading.Lock()}
 
 
 class Span:
-    __slots__ = ("name", "phase", "parent", "iteration", "duration")
+    __slots__ = ("name", "phase", "parent", "iteration", "duration",
+                 "compile_s")
 
     def __init__(self, name: str, phase: Optional[str],
                  parent: Optional["Span"], iteration: Optional[int] = None):
@@ -61,6 +102,8 @@ class Span:
         self.parent = parent
         self.iteration = iteration
         self.duration: Optional[float] = None
+        #: seconds monitor/compile_stages.py charged to this span
+        self.compile_s = 0.0
 
     def __repr__(self):
         return (f"Span({self.name!r}, phase={self.phase!r}, "
@@ -150,15 +193,14 @@ def _series(registry: Optional[MetricsRegistry], name: str,
     ).labels(span=name, phase=phase or "")
 
 
-def _end(s: Span, duration: float, series) -> None:
-    """Close a span opened by :func:`_begin`: histogram, journal."""
+def _close(s: Span, duration: float, series) -> None:
+    """Close a span opened by :func:`_begin`: off the stack, into its
+    histogram."""
     s.duration = duration
     st = _stack()
     if st and st[-1] is s:
         st.pop()
     series.observe(duration)
-    events.emit("span.close", span=s.name, phase=s.phase or "",
-                duration_s=duration)
 
 
 @contextmanager
@@ -179,31 +221,85 @@ def span(name: str, phase: Optional[str] = None,
     finally:
         duration = time.perf_counter() - t0
         _annotate_end(ann)
-        _end(s, duration, _series(registry, name, phase))
+        _close(s, duration, _series(registry, name, phase))
+        events.emit("span.close", span=name, phase=phase or "",
+                    duration_s=duration)
 
 
 GLUE = "glue"
 
+# the collector's pauses, process-wide: a running sum that every
+# StepSpans reads the difference of at its steps' ends
+_gc = {"t0": 0.0, "pause_s": 0.0, "collections": 0}
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    if phase == "start":
+        _gc["t0"] = time.perf_counter()
+    else:
+        _gc["pause_s"] += time.perf_counter() - _gc["t0"]
+        _gc["collections"] += 1
+
+
+def install_gc_hook() -> None:
+    """Time the collector's pauses through ``gc.callbacks``; idempotent
+    (``monitor`` installs it once at import)."""
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+
+
+#: what the host did, as a record names it: this thread's, then the
+#: process's collector
+_HOST_KEYS = ("cpu_s", "switches_voluntary", "switches_involuntary",
+              "major_faults", "gc_s", "gc_collections")
+
+
+def _host_now() -> tuple:
+    ru = resource.getrusage(resource.RUSAGE_THREAD)
+    return (time.thread_time(), ru.ru_nvcsw, ru.ru_nivcsw, ru.ru_majflt,
+            _gc["pause_s"], _gc["collections"])
+
 
 class StepSpans:
-    """The phases of one loop, with no time between them.
+    """The phases of one loop, with no time between them, and a record
+    of every step.
 
-    ``with steps.span(name, phase):`` is :func:`span`: the same
-    :class:`Span`, histogram series, journal event and trace annotation,
-    around the body alone.  What runs between one phase's end and the
-    next one's start (the loop's own statements, the spans' own
-    bookkeeping) is the phase ``glue`` of the same span: a histogram
-    observation at every start, and, when annotating, a region
-    ``<name>/glue`` that closes as the next phase's opens.  So the
-    phases and their glue tile the loop from this object's construction
-    to its last phase, in the registry and in the trace alike.  The loop
-    owns one for the length of a ``fit()``: ``DL4J_SPANS`` and
+    ``with steps.span(name, phase):`` is :func:`span` around the body
+    alone: the same :class:`Span` on the thread's stack, histogram
+    series and trace annotation, but no journal event of its own.  What
+    runs between one phase's end and the next one's start (the loop's
+    own statements, the spans' own bookkeeping) is the phase ``glue`` of
+    the same span: a histogram observation at every start, and, when
+    annotating, a region ``<name>/glue`` that closes as the next phase's
+    opens.  So the phases and their glue tile the loop from this
+    object's construction to its last phase, in the registry and in the
+    trace alike.
+
+    :meth:`step_done` closes the open step's record: its wall time since
+    the last close, its phases in loop order (they sum to the wall
+    time), and what the host did meanwhile as differences since the last
+    close (``cpu_s``, ``switches_voluntary``, ``switches_involuntary``,
+    ``major_faults`` of this thread; ``gc_s``, ``gc_collections`` of the
+    process; ``compile_s`` charged by ``monitor/compile_stages.py``).
+    The record is journalled as one ``fit.step`` event and kept among
+    the last ``RING_STEPS``.  A step that did not compile is judged by
+    :func:`stall_excess` against the median of the last ``RING_STEPS``
+    such steps, once ``JUDGED_FROM`` of them are there (the first ones
+    are judged then, together; the record closed first says
+    ``first_of_fit``).  For a stall, the phase furthest over its own
+    median in the ring is the holder: counters
+    ``dl4j_fit_stalls_total{phase}`` and
+    ``dl4j_fit_stall_seconds_total{phase}`` (registered here, so a loop
+    without a stall reads 0), and the event ``fit.stall``.
+
+    The loop owns one for the length of a ``fit()``: ``DL4J_SPANS`` and
     ``DL4J_TRACE_ANNOTATIONS`` are read once, here, and each series is
-    looked up once, not at every span.  Not thread-safe: one loop, one
-    thread."""
+    looked up once, not at every span.  With ``DL4J_SPANS=0`` nothing of
+    this runs.  Not thread-safe: one loop, one thread."""
 
     __slots__ = ("_on", "_annotating", "_registry", "_t", "_series",
-                 "_glue")
+                 "_glue", "_name", "_phases", "_compile_s", "_t0", "_host",
+                 "_ring", "_walls", "_unjudged", "_first", "_stalls")
 
     def __init__(self, annotate: Optional[bool] = None,
                  registry: Optional[MetricsRegistry] = None):
@@ -213,7 +309,26 @@ class StepSpans:
         self._registry = registry
         self._series: dict = {}
         self._glue = None                 # the open glue annotation
-        self._t = time.perf_counter()     # where the last phase ended
+        self._name = ""                   # the loop's span name
+        self._phases: dict = {}           # the open step's {phase: seconds}
+        self._compile_s = 0.0
+        self._ring: deque = deque(maxlen=RING_STEPS)
+        self._walls: deque = deque(maxlen=RING_STEPS)   # of quiet steps
+        self._unjudged: list = []         # closed before JUDGED_FROM were
+        self._first: Optional[dict] = None
+        self._stalls = None
+        if self._on:
+            reg = registry if registry is not None else get_registry()
+            self._stalls = (
+                reg.counter(STALLS_METRIC, "steps that ran over the median "
+                            "of the steps around them by more than 4 ms "
+                            "and 2%, by the phase that held them",
+                            labels=("phase",)),
+                reg.counter(STALL_SECONDS_METRIC, "seconds by which they "
+                            "ran over that median", labels=("phase",)))
+            self._host = _host_now()
+        # where the last phase ended, and the last step
+        self._t = self._t0 = time.perf_counter()
 
     def span(self, name: str, phase: str,
              iteration: Optional[int] = None) -> "_Phase":
@@ -227,8 +342,11 @@ class StepSpans:
 
     def restart(self) -> None:
         """Back from code that timed itself (after :meth:`close`): the
-        glue of the next phase starts now."""
-        self._t = time.perf_counter()
+        glue of the next phase starts now, and the stretch is no part of
+        the open step's wall time."""
+        t = time.perf_counter()
+        self._t0 += t - self._t
+        self._t = t
 
     def _get(self, name: str, phase: str):
         series = self._series.get((name, phase))
@@ -236,6 +354,60 @@ class StepSpans:
             series = self._series[name, phase] = _series(
                 self._registry, name, phase)
         return series
+
+    def _add_glue(self, name: str, t: float) -> None:
+        """What ran since the last phase ended, up to ``t``."""
+        glue = t - self._t
+        self._get(name, GLUE).observe(glue)
+        phases = self._phases
+        phases[GLUE] = phases.get(GLUE, 0.0) + glue
+        self._t = t
+
+    def step_done(self, iteration: int, compiling: bool = False,
+                  k: int = 1) -> None:
+        """The step is over (``k`` iterations of one launch, ending at
+        ``iteration``): close its record, journal it, judge it."""
+        if not self._on:
+            return
+        t = time.perf_counter()
+        self._add_glue(self._name, t)
+        host, last = _host_now(), self._host
+        rec = {"span": self._name, "iteration": iteration, "k": k,
+               "compiling": bool(compiling) or self._compile_s > 0.0,
+               "step_s": t - self._t0, "phases": self._phases,
+               "compile_s": self._compile_s}
+        for key, now, then in zip(_HOST_KEYS, host, last):
+            rec[key] = now - then
+        self._t0, self._host = t, host
+        self._phases, self._compile_s = {}, 0.0
+        if self._first is None:
+            self._first = rec
+        self._ring.append(rec)
+        events.emit("fit.step", **rec)
+        if rec["compiling"]:
+            return
+        self._walls.append(rec["step_s"])
+        if len(self._walls) < JUDGED_FROM:
+            self._unjudged.append(rec)
+            return
+        middle = median(self._walls)
+        held, self._unjudged = self._unjudged, []
+        for r in (*held, rec):
+            if stall_excess(r["step_s"], middle):
+                self._stall(r, middle)
+
+    def _stall(self, rec: dict, middle: float) -> None:
+        quiet = [r["phases"] for r in self._ring if not r["compiling"]]
+        over = {p: s - median([q.get(p, 0.0) for q in quiet])
+                for p, s in rec["phases"].items()}
+        holder = max(over, key=over.get)
+        excess = rec["step_s"] - middle
+        stalls, seconds = self._stalls
+        stalls.labels(phase=holder).inc()
+        seconds.labels(phase=holder).inc(excess)
+        events.emit("fit.stall", "warn", holder=holder, excess_s=excess,
+                    median_s=middle, phase_excess_s=over,
+                    first_of_fit=rec is self._first, **rec)
 
 
 class _Phase:
@@ -256,13 +428,10 @@ class _Phase:
         if steps._on:
             name, phase, iteration = self._args
             self._span = _begin(name, phase, iteration)
-            glue = steps._get(name, GLUE)
             if steps._annotating:
                 steps.close()
                 self._ann = _annotate(name, phase)
-            t = time.perf_counter()
-            glue.observe(t - steps._t)
-            steps._t = t
+            steps._add_glue(name, time.perf_counter())
         return self._span
 
     def __exit__(self, *exc) -> bool:
@@ -273,7 +442,12 @@ class _Phase:
             if steps._annotating:
                 _annotate_end(self._ann)
                 steps._glue = _annotate(s.name, GLUE)
-            _end(s, t - steps._t, steps._get(s.name, s.phase))
+            duration = t - steps._t
+            _close(s, duration, steps._get(s.name, s.phase))
+            phases = steps._phases
+            phases[s.phase] = phases.get(s.phase, 0.0) + duration
+            steps._compile_s += s.compile_s
+            steps._name = s.name
             steps._t = t
         return False
 

@@ -307,12 +307,18 @@ def dispatch_train_step(net, step_fn, kind, sig_args, batch, t_step,
                               net.opt_states, *batch, it_arr, sub)
         with steps.span("fit/step", phase="block_until_ready"):
             jax.block_until_ready(score)
+        # the tail's fetches under their own names: the score's
+        # transfer, then the host's own work, then the publishers'
+        # fetches of what the layers left in state
+        with steps.span("fit/step", phase="score_fetch"):
+            score_f = float(jax.device_get(score))
         with steps.span("fit/step", phase="bookkeeping"):
             net._strip_rnn_state()
             net._score = score
             net.iteration += k
             monitor.record_fit_step(net.last_batch_size,
-                                    time.perf_counter() - t_step, score)
+                                    time.perf_counter() - t_step, score_f)
+        with steps.span("fit/step", phase="publish"):
             publish_expert_load(net)
             publish_loop_exits(net)
             publish_diffusion(net, batch)
@@ -320,6 +326,7 @@ def dispatch_train_step(net, step_fn, kind, sig_args, batch, t_step,
         with steps.span("fit/step", phase="listeners"):
             for lst in net.listeners:
                 lst.iteration_done(net, net.iteration)
+        steps.step_done(net.iteration, compiling=fresh, k=k)
         t_step = time.perf_counter()
         fresh = False
 

@@ -175,6 +175,28 @@ def test_hot_span_transfer_sees_the_step_helpers_phases(tmp_path):
     assert [f.line for f in findings] == [7, 10]
 
 
+def test_hot_span_transfer_sees_the_tails_two_fetch_phases(tmp_path):
+    """``score_fetch`` and ``publish`` exist to hold the step's fetches:
+    an explicit ``jax.device_get`` there is the point, an implicit sync
+    still a finding."""
+    findings, _ = run_lint(tmp_path, {"m.py": """
+        import numpy as np
+        import jax
+
+        def tail(net, steps, score, counts):
+            with steps.span("fit/step", phase="score_fetch"):
+                bad = np.asarray(score)          # positive: implicit sync
+            with steps.span("fit/step", phase="score_fetch"):
+                ok = float(jax.device_get(score))        # negative
+            with steps.span("fit/step", phase="publish"):
+                worse = counts.item()            # positive
+            with steps.span("fit/step", phase="publish"):
+                fine = np.asarray(jax.device_get(counts))    # negative
+            return bad, ok, worse, fine
+    """}, rules=["DL4J105"])
+    assert [f.line for f in findings] == [7, 11]
+
+
 def test_fp64_promotion_positive_and_negative(tmp_path):
     findings, _ = run_lint(tmp_path, {"m.py": """
         import jax

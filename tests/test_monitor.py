@@ -360,7 +360,9 @@ def test_stats_listener_perf_memory_from_registry():
 # ---------------------------------------------------------------------------
 OLD_PHASES = {"data_wait", "bucket", "h2d", "jit_call",
               "block_until_ready", "listeners"}   # shard_h2d: sharded fits
-NEW_PHASES = {"epoch", "has_next", "dispatch_prep", "bookkeeping", "glue"}
+NEW_PHASES = {"epoch", "has_next", "dispatch_prep", "score_fetch",
+              "bookkeeping", "publish", "glue"}
+TAIL_PHASES = {"score_fetch", "bookkeeping", "publish"}
 FEATS, HIDDEN, CLASSES, ROWS = 6, 12, 3, 16
 
 
@@ -466,7 +468,7 @@ def test_fit_step_phases_tile_the_loop(engine):
     assert len(seen.iterations) == 2 * n
     present = {p for p, (_, c) in step.items() if c}
     assert OLD_PHASES | NEW_PHASES <= present
-    for phase in OLD_PHASES | {"bookkeeping"}:
+    for phase in OLD_PHASES | TAIL_PHASES:
         assert step[phase][1] == n, phase
     # the graph engine also asks, a step, whether its step function stands
     assert step["dispatch_prep"][1] == (2 * n if engine == "cg" else n)

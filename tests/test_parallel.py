@@ -376,8 +376,8 @@ def test_allreduce_fit_runs_under_the_fit_step_phases(graph):
     assert net.iteration == steps and net.last_batch_size == 150 - 3 * 48
     moved = {p: after[p] - before.get(p, 0) for p in after}
     for phase in ("data_wait", "bucket", "shard_h2d", "dispatch_prep",
-                  "jit_call", "block_until_ready", "bookkeeping",
-                  "listeners"):
+                  "jit_call", "block_until_ready", "score_fetch",
+                  "bookkeeping", "publish", "listeners"):
         assert moved[phase] == steps, (phase, moved)
     assert moved["has_next"] == steps + 2 and moved["epoch"] == 2
     assert net.compile_telemetry.retraces <= 2      # 48 rows, and the 6 padded to 8
