@@ -28,8 +28,8 @@ the noised half, the prediction at the masked position itself.
 subset of each layer's experts, and ``vocab_size`` may be a slice of the
 vocabulary: one chip's share of an expert-parallel job.
 ``recompute_experts`` has each expert layer run again in the backward
-pass instead of keeping its row buffers (tokens x k rows of hidden_size,
-whatever share of the experts is held).
+pass instead of keeping its first row segment (twice the held share of
+the tokens x k rows, at hidden_size + 2 moe_intermediate_size).
 """
 
 from __future__ import annotations

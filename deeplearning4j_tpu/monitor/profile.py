@@ -79,8 +79,12 @@ PHASE = re.compile(r"^fit/step/(\w+)$")
 #: the stat of a device event's metadata that holds the HLO op_name
 SCOPE_STATS = ("tf_op",)
 # JAX closes jvp( and transpose( right after the vertex's name: a part's
-# name follows the parentheses (``jvp(fwd/<Type>/<vertex>)/<part>/<op>``)
-LAYER_SCOPE = re.compile(r"fwd/(\w+)/([^/()]+)\)*(?:/(\w+))?")
+# name follows the parentheses (``jvp(fwd/<Type>/<vertex>)/<part>/<op>``),
+# or the body of a loop that runs the layer's parts once a trip
+# (``.../<vertex>)/while/body/<part>/<op>``: the expert layer's later
+# row segments)
+LAYER_SCOPE = re.compile(
+    r"fwd/(\w+)/([^/()]+)\)*(?:/while/body)*(?:/(\w+))?")
 UPDATE_SCOPE = re.compile(r"(?:^|[/(])update(?:[/)]|$)")
 LOSS_SCOPE = re.compile(r"(?:^|[/(])loss(?:[/)]|$)")
 #: an instruction that runs other instructions: its event spans theirs

@@ -1161,6 +1161,15 @@ class SelfAttentionLayer(Layer):
         return mask_rules.tile_counts(rule, Tp, pk._flash_block(Tp, rule))
 
 
+def _where_equal(index, ids, values):
+    """``values`` (one an id, broadcast against ``index[..., None]``)
+    where ``index`` equals the id, else 0, summed over the ids: a lookup
+    written as comparisons.  An index costs the chip as much to gather as
+    a whole row does (``PERF.md`` 6, PR 38), and tokens x k x ids
+    comparisons next to nothing."""
+    return jnp.sum(jnp.where(index[..., None] == ids, values, 0), axis=-1)
+
+
 @register_layer
 @dataclasses.dataclass
 class MixtureOfExpertsLayer(Layer):
@@ -1186,26 +1195,32 @@ class MixtureOfExpertsLayer(Layer):
     steers the selection and not the weights) are selected, and their
     scores renormalised over the k (``norm_topk``; over their sum + 1e-6
     under sigmoid scoring).  The N·k assignments
-    are sorted by held expert, the others behind them, into a buffer of
-    rows cut into segments of static shape (``segment_shape``: twice the
-    even-load share N·k·G/E of the G experts held, so that an even load
-    lies inside the first and not on its edge; the segment count follows
-    the share and nothing else, no argument sets it).  The group
-    sizes are data, and so is the number of segments that hold a row of
-    a held expert: those run, forward and backward, and the rest are
-    skipped on the device by a loop whose trip count it reads (no step
-    retraces whatever the routing, no token is dropped: with every
-    assignment on a held expert every segment runs).  Per segment the
-    rows are gathered, the expert products are grouped matrix products
-    over them (``jax.lax.ragged_dot`` with the global group sizes clipped
-    to the segment) and gated and masked; the weights' gradients are one
-    grouped product each over the whole buffer, and the way back is a
-    gather over every assignment, in both directions
-    (``ops/row_segments.py``).  A layer that holds every expert has one
-    segment: the same expressions once over the whole buffer, no loop.
-    Memory grows with N·k, not N·E·C.  ``gated`` experts are
-    ``(silu(x W1) * (x W3)) W2``, plain ones ``gelu(x W1) W2``; neither
-    has a bias.
+    are sorted by held expert, the others behind them, and the sorted
+    order is cut into segments of static shape (``segment_shape``: twice
+    the even-load share N·k·G/E of the G experts held, so that an even
+    load lies inside the first and not on its edge; the segment count
+    follows the share and nothing else, no argument sets it).  **No array
+    of the layer that is as wide as the model or as an expert has more
+    than one segment's rows**; only the index arrays are N·k long.  A
+    segment goes from the tokens to the tokens' sum in one piece
+    (``ops/row_segments.routed_sum``): its rows are gathered, the expert
+    products are grouped matrix products over them (``jax.lax.ragged_dot``
+    with the global group sizes clipped to the segment), gated, and
+    summed back into the tokens by k gathers of N rows in float32.  The
+    group sizes are data, and so is the number of segments that hold a
+    row of a held expert.  The first segment runs once outside any loop,
+    nothing zeroed first, and its rows and hidden rows are all the
+    backward keeps; the later ones run in a loop whose trip count the
+    device reads, forward adding into the tokens' sum, backward computing
+    their hidden rows again and adding their weights' gradients to the
+    first's (no step retraces whatever the routing, no token is dropped:
+    with every assignment on a held expert every segment runs; a later
+    segment runs when the load passes twice its even share, and is
+    counted as ``recomputed``).  A layer that holds every expert has one
+    segment: the same expressions once over all N·k rows, no loop.
+    Memory grows with the segment, N·k·2G/E, not with N·k or N·E·C.
+    ``gated`` experts are ``(silu(x W1) * (x W3)) W2``, plain ones
+    ``gelu(x W1) W2``; neither has a bias.
 
     ``experts_held`` names the experts whose weights this layer holds
     (expert parallelism's share of the layer; None = all): the router
@@ -1218,13 +1233,17 @@ class MixtureOfExpertsLayer(Layer):
     the segments run and skipped under "moe_row_segments"
     (``dl4j_moe_row_segments_total``).
     ``residual=False`` returns the routed sum alone (a graph adds the
-    residual with an ElementWiseVertex).  ``recompute=True`` keeps none
-    of the top_k path's buffers for the backward pass, which runs the
-    path again (``jax.checkpoint``): they are tokens x k rows long
-    whatever share is held, 7/8 of them another holder's at 16 of 128.  The four parts are named
-    inside the layer's scope (``scope_parts``), and the grouped product's
-    kernels, which the chip's compiler names itself, are claimed for
-    ``experts`` (``scope_kernels``)."""
+    residual with an ElementWiseVertex).  ``recompute=True`` runs the
+    top_k path again in the backward pass (``jax.checkpoint``) instead of
+    keeping the first segment's rows and hidden rows, (D + 2 H) values a
+    row of 2 N·k·G/E; what it keeps is the routing's integers (the
+    selection, the order and its inverse, the weights: a few numbers a
+    token, offered through ``ops/recompute.py``), so that the step
+    selects and sorts once.  The four parts are named
+    inside the layer's scope (``scope_parts``; the last three inside a
+    segment's body, so also inside the later segments' loop), and the
+    grouped product's kernels, which the chip's compiler names itself,
+    are claimed for ``experts`` (``scope_kernels``)."""
 
     scope_parts = ("route", "dispatch", "experts", "combine")
     scope_kernels = {"ragged-dot": "experts"}
@@ -1244,8 +1263,8 @@ class MixtureOfExpertsLayer(Layer):
     experts_held: Optional[Tuple[int, ...]] = None
     residual: bool = True
     # the top_k path under jax.checkpoint: the backward pass routes,
-    # gathers and multiplies again instead of keeping the row buffers,
-    # which are tokens x k rows long whatever share of them is held here
+    # gathers and multiplies again instead of keeping the first segment's
+    # rows and hidden rows
     recompute: bool = False
 
     def _held(self) -> Tuple[int, ...]:
@@ -1312,7 +1331,6 @@ class MixtureOfExpertsLayer(Layer):
     def _forward_top_k(self, params, state, x, mask):
         """The routed sum of the top_k path, [.., n_out], and the new
         state."""
-        import numpy as np
         shape = x.shape
         D = shape[-1]
         tokens = x.reshape(-1, D)                       # [N, D]
@@ -1331,7 +1349,10 @@ class MixtureOfExpertsLayer(Layer):
             if self.expert_bias:
                 select = scores + state["expert_bias"].astype(ft)
             _, top_e = jax.lax.top_k(select, k)                   # [N, k]
-            w = jnp.take_along_axis(scores, top_e, axis=1)
+            # a few integers a token, here and below: a recomputed run
+            # keeps them, and selects and sorts once a step
+            top_e = recompute.offer(top_e)
+            w = _where_equal(top_e, jnp.arange(E), scores[:, None, :])
             if self.norm_topk:
                 # sigmoid scores may all be near 0 (the family that scores
                 # so adds 1e-6); the k largest of a softmax sum to k / E
@@ -1344,9 +1365,9 @@ class MixtureOfExpertsLayer(Layer):
         with jax.named_scope("dispatch"):
             # the held experts' position in this layer's stacks; G = "not
             # here", which sorts last and belongs to no group
-            where_held = np.full((E,), G, np.int32)
-            where_held[list(held)] = np.arange(G, dtype=np.int32)
-            local = jnp.asarray(where_held)[top_e]                # [N, k]
+            local = G + _where_equal(
+                top_e, jnp.asarray(held, jnp.int32),
+                jnp.arange(G, dtype=jnp.int32) - G)               # [N, k]
             if tok_mask is not None:    # padding claims no expert
                 local = jnp.where(tok_mask[:, None], local, G)
             flat = local.reshape(-1)                              # [N·k]
@@ -1358,25 +1379,20 @@ class MixtureOfExpertsLayer(Layer):
                             axis=0, dtype=jnp.int32)              # [G + 1]
             group_sizes = sizes[:G]
             # row r of the sorted order is assignment perm[r] = token
-            # perm[r] // k, and back[n, j] the row of assignment (n, j).
-            # Rows beyond the groups belong to other holders: the grouped
-            # products leave them undefined, in both directions, so they
-            # are zeroed
+            # perm[r] // k, and back[n, j] the row of assignment (n, j)
             held_rows = jnp.sum(group_sizes)
-            back = jnp.zeros((n_seg * seg,), jnp.int32).at[perm].set(
-                jnp.arange(n_seg * seg, dtype=jnp.int32),
-                unique_indices=True)[:N * k].reshape(N, k)
-            rows = row_segments.gather_rows(tokens, perm, back, held_rows,
-                                            seg, k)
-        with jax.named_scope("experts"):
-            w_in = (params["W1"], params["W3"]) if self.gated \
-                else (params["W1"],)
-            y = row_segments.expert_products(
-                rows, w_in, params["W2"], group_sizes, seg,
-                row_segments.gated_silu if self.gated else jax.nn.gelu)
-        with jax.named_scope("combine"):
-            routed = row_segments.weighted_sum(
-                y, w.astype(y.dtype), perm, back, held_rows, seg)
+            # (the inverse of a permutation by sorting it: a sort of N·k
+            # integers costs the chip a seventh of their scatter)
+            back = jnp.argsort(perm)[:N * k].astype(jnp.int32).reshape(N, k)
+        w, perm, back, group_sizes = (
+            recompute.offer(v) for v in (w, perm, back, group_sizes))
+        # a segment at a time from the tokens to the tokens' sum, under the
+        # names "dispatch", "experts" and "combine" inside
+        routed = row_segments.routed_sum(
+            tokens, w.astype(tokens.dtype),
+            (params["W1"], params["W3"]) if self.gated else (params["W1"],),
+            params["W2"], perm, back, group_sizes, seg, k,
+            row_segments.gated_silu if self.gated else jax.nn.gelu)
         counted = top_e if tok_mask is None \
             else jnp.where(tok_mask[:, None], top_e, E)
         counts = jnp.sum(counted.reshape(-1)[:, None]
@@ -1393,7 +1409,7 @@ class MixtureOfExpertsLayer(Layer):
         if self.top_k is not None:
             run = self._forward_top_k
             if self.recompute and train:
-                run = jax.checkpoint(run)
+                run = recompute.keeping_offers(run)
             routed, new_state = run(params, state, x, mask)
             out = self._act(x + routed if self.residual else routed)
             if mask is not None and out.ndim == 3:

@@ -100,7 +100,8 @@ def publish_expert_load(net) -> None:
     assignments over the mean over all experts, this step) and
     ``dl4j_moe_row_segments_total{vertex, outcome}`` (the segments of its
     sorted rows the layer ran and skipped, as the device counted them in
-    "moe_row_segments").  The state
+    "moe_row_segments", and of those it ran the ones behind the first,
+    whose hidden rows the backward pass computed again: run - 1).  The state
     holds one step's counts, so a dispatch of ``fused_steps=k`` publishes
     its last step's and the counter reads a k-th of the routing.  A net
     without such a layer publishes nothing and pays one attribute
@@ -123,7 +124,9 @@ def publish_expert_load(net) -> None:
     segments = reg.counter(
         "dl4j_moe_row_segments_total",
         "segments of an expert layer's sorted rows, by whether the device "
-        "ran or skipped them, last step of each dispatch",
+        "ran or skipped them (recomputed: those it ran behind the first, "
+        "which keep nothing for the backward pass), last step of each "
+        "dispatch",
         labels=("vertex", "outcome"))
     counts = jax.device_get({k: (net.net_state[k]["moe_expert_counts"],
                                  net.net_state[k]["moe_row_segments"])
@@ -131,6 +134,7 @@ def publish_expert_load(net) -> None:
     for k, (c, (run, skipped)) in counts.items():
         segments.labels(vertex=str(k), outcome="run").inc(int(run))
         segments.labels(vertex=str(k), outcome="skipped").inc(int(skipped))
+        segments.labels(vertex=str(k), outcome="recomputed").inc(int(run) - 1)
         here = int(c[held[k]].sum())
         total.labels(vertex=str(k), held="1").inc(here)
         total.labels(vertex=str(k), held="0").inc(int(c.sum()) - here)

@@ -1,35 +1,45 @@
-"""Row buffers cut into segments, the empty ones skipped on the device.
+"""An expert layer's sorted rows taken a segment at a time, from the
+tokens to the tokens' sum, the empty segments skipped on the device.
 
 An expert layer that holds G of E experts sorts its N·k assignments by
 held expert: the rows that hold work are the first ``held`` of the sorted
-order, and ``held`` is data.  The functions here work on such a buffer in
-``n_seg`` segments of ``seg`` rows (both static) and run only the first
-``ceil(held / seg)`` of them, in a loop whose trip count the device reads
-(``lax.fori_loop`` with a traced bound: no retrace, no host round trip,
-no bound that truncates: with every row held every segment runs).  Rows
-of a segment that did not run read zero: a buffer is zeroed whole before
-the loop fills it, which costs the chip less than zeros written segment
-by segment after it (``PERF.md`` 6, PR 30).  A buffer of one segment, as
-of a layer that holds every expert, takes the loop's body once, with no
-loop and no zeros.
+order, and ``held`` is data.  The order is cut into ``n_seg`` segments of
+``seg`` rows (both static), and **no array here that is as wide as the
+model or as an expert has more than one segment's rows**: only the index
+arrays (``perm``, ``back``: N·k int32) are whole.  :func:`routed_sum`
+takes one segment at a time through all of the layer: the gather of its
+tokens' rows, the grouped products with the global group sizes clipped
+to the segment, the gate, and the way back, k gathers of N rows out of
+the segment's output summed in float32 over the assignments whose row
+lies in it.  What is carried from one segment to the next is shaped like
+the tokens (float32 until the one rounding) and, backward, like the
+weights.
 
-What runs by segment, over the rows that hold work: the gather of the
-tokens' rows into the sorted order, the grouped products forward and to
-the rows backward with their gate and masks, and the gather of the
-cotangents on the weighted sum's way back.  What runs once over the
-whole buffer: the weights' gradients (one grouped product each; rows
-beyond the groups are in no group, and a sum carried through the
-segments would read and write the weight stacks once a segment) and the
-way back from the sorted order to the tokens, a gather over every
-assignment (a row costs the chip several times as much to scatter as
-to gather: ``PERF.md`` 6, PR 30).  Each has its backward written out
-(``custom_vjp``), so that a loop's backward is such a loop and not a
-sum of whole buffers over the segments.
+Segment 0 runs once, outside any loop (it runs even with no row held):
+its arrays are written whole, nothing is zeroed first, and its rows and
+hidden rows are what the backward keeps.  Segments 1 to ``ceil(held /
+seg)`` - 1 run in a loop whose trip count the device reads
+(``lax.fori_loop`` with a traced bound: no retrace, no host round trip,
+no bound that truncates: with every row held every segment runs), adding
+into the tokens' sum; backward their hidden rows are computed again from
+the gathered rows and their weights' gradients added to segment 0's.
+That path costs a second forward of the segment and one read and write
+of the weight stacks a segment, and runs when a holder's load passes
+twice its even share (``segment_rows``).  A layer that holds every expert
+has one segment of N·k rows: the same body once, no loop.
+
+The backward is written out (``custom_vjp``), so that a loop's backward
+is such a loop and a gather's a gather (a row costs the chip several
+times as much to scatter as to gather: ``PERF.md`` 6, PR 30).  Rows of a
+segment beyond the held ones belong to other holders: the grouped
+products leave them undefined in both directions, and every sum that
+could read them selects them out.
 """
 
 from __future__ import annotations
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -62,121 +72,26 @@ def segments_run(held_rows, seg: int):
     return max(1, ceil) if isinstance(ceil, int) else jnp.maximum(1, ceil)
 
 
-def _rows_of(buf, s, seg):
-    return lax.dynamic_slice_in_dim(buf, s * seg, seg, axis=0)
+def tall_arrays(program: str, seg: int, widths, stacks=()):
+    """The array shapes in a lowered or compiled program's text (StableHLO
+    ``tensor<8x4xf32>`` or HLO ``f32[8,4]``) that break this module's
+    rule: one dimension is a width of ``widths`` (the model's, an
+    expert's) and the others multiply to more than ``seg`` rows.  Shapes
+    in ``stacks`` (the experts' weight stacks, in any order of
+    dimensions) are no rows."""
+    allowed = {tuple(sorted(shape)) for shape in stacks}
+    tall = set()
+    for dims in re.findall(r"(?:tensor<|\w\[)(\d+(?:[x,]\d+)+)", program):
+        shape = tuple(int(d) for d in re.split("[x,]", dims))
+        size = 1
+        for d in shape:
+            size *= d
+        if tuple(sorted(shape)) not in allowed and any(
+                d in widths and size // d > seg for d in shape):
+            tall.add(shape)
+    return sorted(tall)
 
 
-def _put(buf, s, seg, rows):
-    return lax.dynamic_update_slice_in_dim(buf, rows.astype(buf.dtype),
-                                           s * seg, axis=0)
-
-
-def _valid(s, seg, held_rows):
-    return (s * seg + jnp.arange(seg) < held_rows)[:, None]
-
-
-def _by_segment(held_rows, seg, body, bufs):
-    """The buffers ``bufs`` (shapes and dtypes, ``seg`` rows a segment)
-    filled with ``body(s)``'s rows of each over the segments s that hold
-    work, in order; the other segments read zero."""
-    if bufs[0].shape[0] == seg:
-        return tuple(v.astype(b.dtype) for v, b in zip(body(0), bufs))
-
-    def live(s, carry):
-        return tuple(_put(c, s, seg, v) for c, v in zip(carry, body(s)))
-
-    return lax.fori_loop(0, segments_run(held_rows, seg), live,
-                         tuple(jnp.zeros(b.shape, b.dtype) for b in bufs))
-
-
-def _like(rows, *tail, dtype):
-    return jax.ShapeDtypeStruct((rows,) + tail, dtype)
-
-
-# --- the rows out to the sorted order and back --------------------------------
-# ``perm[r]`` is the assignment (token perm[r] // k, choice perm[r] % k) that
-# sorted row r holds, ``back`` [n, k] its inverse: the row of each assignment.
-# Either way round a row moves by a gather: out along ``perm`` over the rows
-# that hold work, back along ``back`` over every assignment, whose rows
-# without work read zero.
-def _sum_over_choices(buf, back, w=None):
-    """[n, ...]: every token's sum over the sorted buffer's rows of its k
-    assignments, each times its weight ``w[n, j]`` if given; summed in
-    float32 and rounded once.  One gather of n rows a choice: gathered
-    as [n k, ...] and viewed as [n, k, ...] the rows would be laid out
-    anew on the chip, which costs as much as the gather."""
-    wide = jnp.promote_types(buf.dtype, jnp.float32)
-    total = 0
-    for j in range(back.shape[1]):
-        got = buf.at[back[:, j]].get(unique_indices=True,
-                                     mode="promise_in_bounds").astype(wide)
-        total = total + (got if w is None else got * w[:, j, None].astype(wide))
-    return total.astype(buf.dtype)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def gather_rows(tokens, perm, back, held_rows, seg, k):
-    """Sorted row r is token ``perm[r] // k`` for r < held_rows, else 0."""
-    n = tokens.shape[0]
-
-    def body(s):
-        index = jnp.minimum(_rows_of(perm, s, seg) // k, n - 1)
-        got = tokens.at[index].get(mode="promise_in_bounds")
-        return jnp.where(_valid(s, seg, held_rows), got, 0),
-
-    return _by_segment(held_rows, seg, body, (
-        _like(perm.shape[0], *tokens.shape[1:], dtype=tokens.dtype),))[0]
-
-
-def _gather_rows_fwd(tokens, perm, back, held_rows, seg, k):
-    return gather_rows(tokens, perm, back, held_rows, seg, k), back
-
-
-def _gather_rows_bwd(seg, k, res, d_rows):
-    # a token's cotangent is the sum over its k assignments' rows
-    return _sum_over_choices(d_rows, res), None, None, None
-
-
-gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def weighted_sum(y, w, perm, back, held_rows, seg):
-    """``sum_j w[n, j] * y[back[n, j]]``: every token's weighted sum
-    over the sorted rows of its k assignments."""
-    return _sum_over_choices(y, back, w)
-
-
-def _weighted_sum_fwd(y, w, perm, back, held_rows, seg):
-    return weighted_sum(y, w, perm, back, held_rows, seg), (
-        y, w, perm, back, held_rows)
-
-
-def _weighted_sum_bwd(seg, res, d_out):
-    y, w, perm, back, held_rows = res
-    n, k = w.shape
-    flat_w = w.reshape(-1)
-    wide = jnp.promote_types(y.dtype, jnp.float32)
-
-    def body(s):
-        a = jnp.minimum(_rows_of(perm, s, seg), n * k - 1)
-        valid = _valid(s, seg, held_rows)
-        # the token's cotangent row: times the assignment's weight it is
-        # the sorted row's cotangent, along the row's values the weight's
-        got = d_out.at[a // k].get(mode="promise_in_bounds")
-        return (jnp.where(valid, got * flat_w[a][:, None], 0),
-                jnp.sum(_rows_of(y, s, seg).astype(wide) * got.astype(wide),
-                        axis=-1, where=valid))
-
-    d_y, d_w = _by_segment(held_rows, seg, body,
-                           (y, _like(y.shape[0], dtype=wide)))
-    return d_y, d_w[back].astype(w.dtype), None, None, None
-
-
-weighted_sum.defvjp(_weighted_sum_fwd, _weighted_sum_bwd)
-
-
-# --- the grouped products ----------------------------------------------------
 def _segment_sizes(group_sizes, s, seg):
     """The part of each group that lies in segment s of the sorted
     order: the global sizes clipped to the segment."""
@@ -191,10 +106,14 @@ def gated_silu(h1, h3):
     return jax.nn.silu(h1) * h3
 
 
-def _to_rows(ct, like, w, sizes):
-    """JAX's transpose of ``rows -> ragged_dot(rows, w, sizes)``."""
-    return jax.linear_transpose(
-        lambda r: lax.ragged_dot(r, w, sizes), like)(ct)[0]
+def _transposed(w):
+    """The stack ``w`` [G, a, b] with each expert's matrix transposed:
+    ``ragged_dot(ct, _transposed(w), sizes)`` is the transpose of ``rows
+    -> ragged_dot(rows, w, sizes)``.  Made once a layer and handed to
+    every segment: the chip lays a transposed stack out anew, and a
+    transposition written inside the later segments' loop is moved out
+    of it by the compiler and paid whether the loop runs or not."""
+    return jnp.swapaxes(w, 1, 2)
 
 
 def _to_weights(ct, rows, w, sizes):
@@ -203,56 +122,160 @@ def _to_weights(ct, rows, w, sizes):
         lambda w: lax.ragged_dot(rows, w, sizes), w)(ct)[0]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def expert_products(rows, w_in, w2, group_sizes, seg, act):
-    """``act(rows W_in...) W2`` by group over the rows that hold work:
-    ``w_in`` is (W1,) or (W1, W3), ``act`` their elementwise gate."""
-    return _expert_products_fwd(rows, w_in, w2, group_sizes, seg, act)[0]
+# ``perm[r]`` is the assignment (token perm[r] // k, choice perm[r] % k) that
+# sorted row r holds, ``back`` [n, k] its inverse: the row of each assignment.
+# Either way round a row moves by a gather: out along ``perm`` over a
+# segment's rows, back along ``back`` over every assignment, of which those
+# whose row lies in another segment or holds no work are selected out.
+def _assignments(perm, s, seg, n_k):
+    """The assignment of each row of segment s (the filling behind the
+    last row reads the last assignment: no held row is filling)."""
+    return jnp.minimum(lax.dynamic_slice_in_dim(perm, s * seg, seg), n_k - 1)
 
 
-def _expert_products_fwd(rows, w_in, w2, group_sizes, seg, act):
+def _place(back, s, seg, held_rows):
+    """([n, k] index into segment s, [n, k] whether it is there and holds
+    work) of every assignment's sorted row."""
+    lo = s * seg
+    there = (back >= lo) & (back < jnp.minimum(lo + seg, held_rows))
+    return jnp.clip(back - lo, 0, seg - 1), there
+
+
+def _sum_over_choices(buf, index, there, w=None):
+    """[n, ...] float32: every token's sum over the rows ``index[n, j]``
+    of ``buf`` for the choices j that are ``there``, each times its weight
+    ``w[n, j]`` if given.  One gather of n rows a choice: gathered as
+    [n k, ...] and viewed as [n, k, ...] the rows would be laid out anew
+    on the chip, which costs as much as the gather."""
+    wide = jnp.promote_types(buf.dtype, jnp.float32)
+    total = 0
+    for j in range(index.shape[1]):
+        got = buf.at[index[:, j]].get(mode="promise_in_bounds").astype(wide)
+        got = jnp.where(there[:, j, None], got, 0)
+        total = total + (got if w is None else got * w[:, j, None].astype(wide))
+    return total
+
+
+def _hidden(s, seg, k, tokens, w_in, perm, sizes):
+    """Segment s up to its experts' hidden rows: (the tokens' rows in the
+    sorted order, the hidden rows of each of ``w_in``), what the backward
+    reads of a segment whose groups are ``sizes`` long."""
+    with jax.named_scope("dispatch"):
+        token_of = _assignments(perm, s, seg, tokens.shape[0] * k) // k
+        rows = tokens.at[token_of].get(mode="promise_in_bounds")
+    with jax.named_scope("experts"):
+        return rows, tuple(lax.ragged_dot(rows, w, sizes) for w in w_in)
+
+
+def _segment(s, seg, k, act, tokens, w, w_in, w2, perm, back, group_sizes):
+    """Segment s from the tokens to the tokens' sum: ([n, D] float32, its
+    rows' part of every token's weighted sum; what :func:`_hidden` gave)."""
+    sizes = _segment_sizes(group_sizes, s, seg)
+    kept = _hidden(s, seg, k, tokens, w_in, perm, sizes)
+    with jax.named_scope("experts"):
+        y = lax.ragged_dot(act(*kept[1]), w2, sizes)
+    with jax.named_scope("combine"):
+        part = _sum_over_choices(
+            y, *_place(back, s, seg, jnp.sum(group_sizes)), w)
+    return part, kept
+
+
+def _segment_back(s, seg, k, act, kept, d_out, w_in_t, w2_t,
+                  tokens, w, w_in, w2, perm, back, group_sizes):
+    """Segment s's part of the cotangents of ``(tokens, w, w_in, w2)``,
+    from its rows and hidden rows ``kept`` and the transposed stacks:
+    the first two float32 and shaped like ``tokens`` and ``w``."""
+    rows, hidden = kept
     held_rows = jnp.sum(group_sizes)
-
-    def body(s):
-        sizes = _segment_sizes(group_sizes, s, seg)
-        r = _rows_of(rows, s, seg)
-        h = tuple(lax.ragged_dot(r, w, sizes) for w in w_in)
-        # rows beyond the groups belong to other holders: the grouped
-        # products leave them undefined, in both directions
-        y = lax.ragged_dot(act(*h), w2, sizes)
-        return (jnp.where(_valid(s, seg, held_rows), y, 0),) + h
-
-    def like(w):
-        return _like(rows.shape[0], w.shape[-1], dtype=rows.dtype)
-    y, *hidden = _by_segment(
-        held_rows, seg, body, (like(w2),) + tuple(like(w) for w in w_in))
-    return y, (rows, tuple(hidden), w_in, w2, group_sizes)
-
-
-def _expert_products_bwd(seg, act, res, d_y):
-    """The rows' cotangents segment by segment, from the kept hidden
-    rows; the weights' in one grouped product each over the whole
-    buffer, whose rows beyond the groups are in no group: nothing is
-    summed over the segments."""
-    rows, hidden, w_in, w2, group_sizes = res
-    held_rows = jnp.sum(group_sizes)
-
-    def body(s):
-        sizes = _segment_sizes(group_sizes, s, seg)
-        valid = _valid(s, seg, held_rows)
-        h = tuple(_rows_of(b, s, seg) for b in hidden)
-        a, gate_back = jax.vjp(act, *h)
-        d_h = gate_back(_to_rows(jnp.where(valid, _rows_of(d_y, s, seg), 0),
-                                 a, w2, sizes))
-        r = _rows_of(rows, s, seg)
-        d_r = sum(_to_rows(d, r, w, sizes) for d, w in zip(d_h, w_in))
-        return (jnp.where(valid, d_r, 0), a) + tuple(d_h)
-
-    d_rows, gated, *d_hidden = _by_segment(
-        held_rows, seg, body, (rows, hidden[0]) + tuple(hidden))
-    d_w_in = tuple(_to_weights(d, rows, w, group_sizes)
-                   for d, w in zip(d_hidden, w_in))
-    return d_rows, d_w_in, _to_weights(d_y, gated, w2, group_sizes), None
+    sizes = _segment_sizes(group_sizes, s, seg)
+    wide = jnp.promote_types(rows.dtype, jnp.float32)
+    valid = (s * seg + jnp.arange(seg) < held_rows)[:, None]
+    index, there = _place(back, s, seg, held_rows)
+    with jax.named_scope("combine"):
+        # the token's cotangent row: times the assignment's weight it is
+        # the sorted row's cotangent
+        a = _assignments(perm, s, seg, w.size)
+        got = d_out.at[a // k].get(mode="promise_in_bounds")
+        w_row = w.reshape(-1)[a][:, None]
+    with jax.named_scope("experts"):
+        gated, gate_back = jax.vjp(act, *hidden)
+        # a row's weight is a scalar, so it commutes with the row's
+        # product: the unweighted cotangent of the gated rows serves the
+        # weight's own (below) and, times the weight, the way on
+        d_gated = lax.ragged_dot(got, w2_t, sizes)
+        d_hidden = gate_back(d_gated * w_row)
+        d_w2 = _to_weights(got * w_row, gated, w2, sizes)
+        d_w_in = tuple(_to_weights(d, rows, w_, sizes)
+                       for d, w_ in zip(d_hidden, w_in))
+        d_rows = sum(lax.ragged_dot(d, w_t, sizes)
+                     for d, w_t in zip(d_hidden, w_in_t))
+    with jax.named_scope("combine"):
+        # along the row's values the weight's cotangent: y . got, which
+        # is gated . (got W2^T) by group, without y
+        d_w_row = jnp.sum(gated.astype(wide) * d_gated.astype(wide),
+                          axis=-1, where=valid)
+        d_w = jnp.where(there, d_w_row[index], 0)
+    with jax.named_scope("dispatch"):
+        # a token's cotangent is the sum over its k assignments' rows
+        d_tokens = _sum_over_choices(d_rows, index, there)
+    return d_tokens, d_w, d_w_in, d_w2
 
 
-expert_products.defvjp(_expert_products_fwd, _expert_products_bwd)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def routed_sum(tokens, w, w_in, w2, perm, back, group_sizes, seg, k, act):
+    """``sum_j w[n, j] * (act(x W_in[e]...) W2[e])`` with x = tokens[n]
+    and e the held expert of assignment (n, j), over the assignments on
+    held experts: [n, D] in the tokens' dtype, summed in float32 and
+    rounded once.  ``w_in`` is (W1,) or (W1, W3), ``act`` their
+    elementwise gate; ``perm`` (whole segments long) sorts the
+    assignments by held expert, ``back`` [n, k] is its inverse and
+    ``group_sizes`` the rows of each held expert."""
+    return _routed_sum_fwd(tokens, w, w_in, w2, perm, back, group_sizes,
+                           seg, k, act)[0]
+
+
+def _later_segments(perm, group_sizes, seg, body, first):
+    """``first`` plus ``body(s)`` over the segments s >= 1 that hold
+    work, leaf by leaf."""
+    if perm.shape[0] == seg:
+        return first
+
+    def add(s, total):
+        return jax.tree_util.tree_map(
+            lambda t, part: t + part.astype(t.dtype), total, body(s))
+
+    return lax.fori_loop(
+        1, segments_run(jnp.sum(group_sizes), seg), add, first)
+
+
+def _routed_sum_fwd(tokens, w, w_in, w2, perm, back, group_sizes,
+                    seg, k, act):
+    args = (tokens, w, w_in, w2, perm, back, group_sizes)
+    first, kept = _segment(0, seg, k, act, *args)
+    total = _later_segments(
+        perm, group_sizes, seg,
+        lambda s: _segment(s, seg, k, act, *args)[0], first)
+    return total.astype(tokens.dtype), args + (kept,)
+
+
+def _routed_sum_bwd(seg, k, act, res, d_out):
+    """Segment 0's cotangents from the rows and hidden rows it kept; a
+    later segment's from its own computed again, added to them."""
+    *args, kept = res
+    tokens, w, w_in, w2, perm, _, group_sizes = args
+    with jax.named_scope("experts"):
+        turned = tuple(_transposed(w_) for w_ in w_in), _transposed(w2)
+
+    def later(s):
+        again = _hidden(s, seg, k, tokens, w_in, perm,
+                        _segment_sizes(group_sizes, s, seg))
+        return _segment_back(s, seg, k, act, again, d_out, *turned, *args)
+
+    d_tokens, d_w, d_w_in, d_w2 = _later_segments(
+        perm, group_sizes, seg, later,
+        _segment_back(0, seg, k, act, kept, d_out, *turned, *args))
+    return (d_tokens.astype(tokens.dtype), d_w.astype(w.dtype), d_w_in,
+            d_w2, None, None, None)
+
+
+routed_sum.defvjp(_routed_sum_fwd, _routed_sum_bwd)

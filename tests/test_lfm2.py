@@ -254,10 +254,10 @@ def test_gated_dense_layer(seeded):
 
 
 def _moe(held, **over):
-    return L.MixtureOfExpertsLayer(
-        n_out=64, n_experts=8, hidden=48, top_k=2, scoring="sigmoid",
-        expert_bias=True, gated=True, residual=False,
-        activation="identity", experts_held=held, **over)
+    return L.MixtureOfExpertsLayer(**dict(
+        dict(n_out=64, n_experts=8, hidden=48, top_k=2, scoring="sigmoid",
+             expert_bias=True, gated=True, residual=False,
+             activation="identity", experts_held=held), **over))
 
 
 def test_expert_layer_gives_the_held_experts_part(seeded):
@@ -322,29 +322,61 @@ ROUTINGS = {
 }
 
 
+def _share(p, held):
+    """The leaves of the layer that holds ``held`` of ``p``'s experts."""
+    return {"Wg": p["Wg"], **{k: p[k][jnp.asarray(held)]
+                              for k in ("W1", "W2", "W3")}}
+
+
+def _out_and_grads(layer, p, s, x, mask):
+    def loss(p, x):
+        y, st, _ = layer.forward(p, s, x, train=True, rng=None, mask=mask)
+        # a cotangent that differs from row to row
+        return jnp.sum(y * jnp.cos(jnp.arange(y.size).reshape(y.shape))), (y, st)
+    (_, (y, st)), (gp, gx) = jax.value_and_grad(
+        loss, argnums=(0, 1), has_aux=True)(p, x)
+    return y, st, {**gp, "x": gx}
+
+
+@pytest.mark.parametrize("recompute,masked", [
+    (False, False), (True, False), (False, True), (True, True)],
+    ids=["kept", "recomputed", "kept-masked", "recomputed-masked"])
 @pytest.mark.parametrize("bias", list(ROUTINGS.values()), ids=list(ROUTINGS))
-def test_the_four_shares_add_up_to_the_uncut_layer(whole_layer, bias):
+def test_the_four_shares_add_up_to_the_uncut_layer(whole_layer, bias,
+                                                   recompute, masked):
+    """Outputs and every leaf's gradient: a share's expert stacks take
+    the uncut layer's own slices, the router and the tokens the sum over
+    the shares."""
     layer, p, s = whole_layer
     s = {**s, "expert_bias": jnp.asarray(bias)}
     x = _u(7)
-    whole, st, _ = layer.forward(p, s, x, train=True, rng=None)
+    mask = jnp.ones((2, 32)).at[1, 16:].set(0.0) if masked else None
+    whole, st, want = _out_and_grads(layer, p, s, x, mask)
     assert layer.segment_shape(2 * 32 * 2) == (2 * 32 * 2, 1)
+    assigned = (48 if masked else 64) * 2
     total = 0.0
+    summed = {"Wg": 0.0, "x": 0.0}
     counted = 0
     ran = []
     for share in range(4):
         held = (2 * share, 2 * share + 1)
-        part, st_part, _ = _moe(held).forward(
-            {"Wg": p["Wg"], **{k: p[k][jnp.asarray(held)] for k in ("W1", "W2", "W3")}},
-            s, x, train=True, rng=None)
+        part_layer = _moe(held, recompute=recompute)
+        part, st_part, got = _out_and_grads(part_layer, _share(p, held), s, x,
+                                            mask)
         total = total + part
         # every share routes over all eight and counts them alike
         np.testing.assert_array_equal(np.asarray(st_part["moe_expert_counts"]),
                                       np.asarray(st["moe_expert_counts"]))
         counted += int(st_part["moe_expert_counts"][jnp.asarray(held)].sum())
-        ran.append(_segments_run(_moe(held), st_part, 2 * 32 * 2))
+        ran.append(_segments_run(part_layer, st_part, 2 * 32 * 2))
+        for leaf in ("W1", "W2", "W3"):
+            _close(got[leaf], want[leaf][jnp.asarray(held)])
+        summed = {leaf: summed[leaf] + got[leaf] for leaf in summed}
     _close(total, whole)
-    assert counted == 2 * 32 * 2            # each assignment held once
+    for leaf in summed:
+        assert float(jnp.abs(want[leaf]).max()) > 0
+        _close(summed[leaf], want[leaf])
+    assert counted == assigned              # each assignment held once
     assert float(jnp.abs(whole).max()) > 0.1
     assert all(n_seg == 2 for _, n_seg in ran)
     if bias[0] == 100.0:
@@ -406,13 +438,48 @@ def test_no_token_is_dropped_when_one_expert_is_sent_every_token(
         assert float(jnp.abs(y - alone).max()) > 0      # and its second expert
 
 
-@pytest.mark.parametrize("held", [None, (4,), (3, 4, 5)],
-                         ids=["whole", "held-1-of-8", "held-3-of-8"])
-def test_routing_changes_no_shape_and_retraces_nothing(whole_layer, held):
-    whole, p, s = whole_layer
-    layer = _moe(held)
+@pytest.fixture(scope="module")
+def sixteen():
+    """Sixteen experts, top-2: a layer that holds two of them cuts its
+    128 sorted rows into 4 segments of 32."""
+    layer = _moe(None, n_experts=16)
+    p, s, _ = layer.initialize(jax.random.PRNGKey(6), InputType.recurrent(64, 32))
+    return layer, p, {**s, "expert_bias": jnp.zeros((16,))}
+
+
+def _lift(experts, **lifted):
+    """An expert bias that lifts expert e by ``lifted["e<e>"]``."""
+    bias = jnp.zeros((experts,))
+    for name, by in lifted.items():
+        bias = bias.at[int(name[1:])].set(by)
+    return bias
+
+
+# held, experts, the biases to route by in turn, the segments they run
+RETRACE = {
+    "whole": (None, 8, [_lift(8, e2=100.0), _lift(8, e4=100.0),
+                        _lift(8, e4=100.0, e3=50.0)], {1}),
+    "held-1-of-8": ((4,), 8, [_lift(8, e2=100.0), _lift(8, e4=100.0),
+                              _lift(8, e4=100.0, e3=50.0)], {1, 2}),
+    "held-3-of-8": ((3, 4, 5), 8, [_lift(8, e2=100.0), _lift(8, e4=100.0),
+                                   _lift(8, e4=100.0, e3=50.0)], {1, 2}),
+    # expert 5 takes every token, expert 9 beside it none (64 rows, 2
+    # segments), the few whose second choice it is (3) or all (all 4)
+    "held-2-of-16-one-to-all-segments": (
+        (5, 9), 16, [_lift(16, e5=100.0, e9=-100.0), _lift(16, e5=100.0),
+                     _lift(16, e5=100.0, e9=50.0)], {1, 2, 3, 4}),
+}
+
+
+@pytest.mark.parametrize("recompute", [False, True], ids=["kept", "recomputed"])
+@pytest.mark.parametrize("case", list(RETRACE), ids=list(RETRACE))
+def test_routing_changes_no_shape_and_retraces_nothing(
+        whole_layer, sixteen, case, recompute):
+    held, experts, biases, want_ran = RETRACE[case]
+    _, p, s = whole_layer if experts == 8 else sixteen
+    layer = _moe(held, n_experts=experts, recompute=recompute)
     if held:
-        p = {"Wg": p["Wg"], **{k: p[k][jnp.asarray(held)] for k in ("W1", "W2", "W3")}}
+        p = _share(p, held)
 
     @jax.jit
     def fn(s, x):
@@ -424,14 +491,12 @@ def test_routing_changes_no_shape_and_retraces_nothing(whole_layer, held):
 
     ran = set()
     # loads on both sides of a segment's edge, and every segment
-    for bias in (s["expert_bias"], jnp.zeros((8,)).at[2].set(100.0),
-                 jnp.zeros((8,)).at[4].set(100.0),
-                 jnp.zeros((8,)).at[4].set(100.0).at[3].set(50.0)):
+    for bias in [s["expert_bias"]] + biases:
         st, g = fn({**s, "expert_bias": bias}, _u(len(ran) + 1))
         assert all(bool(jnp.isfinite(v).all()) for v in jax.tree_util.tree_leaves(g))
         ran.add(_segments_run(layer, st, 2 * 32 * 2)[0])
     assert fn._cache_size() == 1
-    assert len(ran) > 1 if held else ran == {1}
+    assert ran == want_ran
 
 
 @pytest.mark.parametrize("held", [None, (0, 1), (4, 5, 6, 7)],
@@ -459,34 +524,38 @@ def _unsegmented(monkeypatch):
                         lambda rows, held, experts: (rows, 1))
 
 
-def _out_and_grads(layer, p, s, x, mask):
-    def loss(p, x):
-        y, st, _ = layer.forward(p, s, x, train=True, rng=None, mask=mask)
-        # a cotangent that differs from row to row
-        return jnp.sum(y * jnp.cos(jnp.arange(y.size).reshape(y.shape))), (y, st)
-    (_, (y, st)), (gp, gx) = jax.value_and_grad(
-        loss, argnums=(0, 1), has_aux=True)(p, x)
-    return y, st, {**gp, "x": gx}
+# held, of how many experts, the bias that routes, a segment's rows, and the
+# edge (behind which segment) the held count is brought to
+EDGES = {
+    "two-held-edge64": ((4, 6), 8, _lift(8, e4=100.0), 64, 1),
+    "one-held-edge32": ((4,), 8, _lift(8, e4=100.0), 32, 1),
+    # 4 segments of 32: every token on expert 5, most on expert 9 beside it
+    "two-of-16-second-edge": ((5, 9), 16, _lift(16, e5=100.0, e9=0.5), 32, 2),
+    "two-of-16-third-edge": ((5, 9), 16, _lift(16, e5=100.0, e9=0.5), 32, 3),
+}
 
 
-@pytest.mark.parametrize("held,seg_want", [((4, 6), 64), ((4,), 32)],
-                         ids=["two-held-edge64", "one-held-edge32"])
+@pytest.mark.parametrize("recompute", [False, True], ids=["kept", "recomputed"])
+@pytest.mark.parametrize("case", list(EDGES), ids=list(EDGES))
 @pytest.mark.parametrize("off", [-1, 0, 1], ids=["one-under", "at", "one-over"])
 def test_segments_agree_with_the_uncut_buffers_at_an_edge(
-        whole_layer, monkeypatch, held, seg_want, off):
+        whole_layer, sixteen, monkeypatch, case, off, recompute):
     """Held experts 4 and 6 of 8: segments of 64 of the 128 sorted rows
-    (expert 4 alone: of 32).  Every token is sent to expert 4 and to its
-    best other, so a token holds one row or two, and the mask picks
-    tokens until the held count is the first segment's edge, one under,
-    or one over it."""
-    _, p, s = whole_layer
-    layer = _moe(held)
-    p = {"Wg": p["Wg"], **{k: p[k][jnp.asarray(held)] for k in ("W1", "W2", "W3")}}
-    s = {**s, "expert_bias": jnp.zeros((8,)).at[4].set(100.0)}
+    (expert 4 alone: of 32; 5 and 9 of 16: of 32).  Every token is sent
+    to the lifted expert and to its best other, so a token holds one row
+    or two, and the mask picks tokens until the held count is a
+    segment's edge, one under, or one over it: behind the first edge one
+    or two segments run, behind the third three or all four.  Outputs
+    and every leaf's gradient are those of the uncut buffer."""
+    held, experts, bias, seg_want, edge = EDGES[case]
+    _, p, s = whole_layer if experts == 8 else sixteen
+    layer = _moe(held, n_experts=experts, recompute=recompute)
+    p = _share(p, held)
+    s = {**s, "expert_bias": bias}
     x = _u(11)
     seg, n_seg = layer.segment_shape(2 * 32 * 2)
     assert (seg, n_seg) == (seg_want, 128 // seg_want)
-    target = seg + off
+    target = edge * seg + off
     scores = jax.nn.sigmoid(x.reshape(-1, 64) @ p["Wg"])
     _, sel = jax.lax.top_k(scores + s["expert_bias"], 2)
     rows_of = np.isin(np.asarray(sel), held).sum(axis=1)      # 1 or 2 a token
@@ -501,7 +570,8 @@ def test_segments_agree_with_the_uncut_buffers_at_an_edge(
     counts = np.asarray(st["moe_expert_counts"])
     assert int(counts[list(held)].sum()) == target
     assert (counts[list(held)] > 0).all()       # every held group has rows
-    assert _segments_run(layer, st, 128)[0] == -(-target // seg)
+    assert _segments_run(layer, st, 128)[0] == -(-target // seg) \
+        == edge + (off > 0)
     _unsegmented(monkeypatch)
     assert layer.segment_shape(128) == (128, 1)
     y1, st1, grads1 = _out_and_grads(layer, p, s, x, mask)
@@ -553,23 +623,49 @@ def test_the_segment_follows_the_share_held(rows, held, experts, want):
         1, 1, 1, min(2, n_seg), n_seg]
 
 
-def test_a_layer_that_holds_every_expert_lowers_without_control_flow(whole_layer):
+@pytest.mark.parametrize("recompute", [False, True], ids=["kept", "recomputed"])
+def test_a_layer_that_holds_every_expert_lowers_without_control_flow(
+        whole_layer, recompute):
+    """And one that holds a share lowers to loops over its later segments
+    and to no array longer than a segment and as wide as the model or an
+    expert: the sorted buffer of all 128 rows exists nowhere."""
+    from deeplearning4j_tpu.ops import row_segments
     layer, p, s = whole_layer
 
     def lowered(layer, p):
         def loss(p, x):
             return jnp.sum(layer.forward(p, s, x, train=True, rng=None)[0])
-        return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(p, _u()).as_text()
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+            p, _u()).as_text()
 
-    whole = lowered(layer, p)
+    whole = lowered(_moe(None, recompute=recompute), p)
     for word in ("while", "conditional", "stablehlo.case", "stablehlo.if"):
         assert word not in whole
+    # the one segment is the whole buffer, and the checker sees it
+    assert (128, 64) in row_segments.tall_arrays(whole, 64, (64, 48))
     held = (2, 3)
-    cut = lowered(_moe(held), {"Wg": p["Wg"], **{
-        k: p[k][jnp.asarray(held)] for k in ("W1", "W2", "W3")}})
-    # the rows out, the products forward and backward, the weighted sum's
-    # backward: four loops whose trip count is data
-    assert cut.count("stablehlo.while") == 4
+    part = _moe(held, recompute=recompute)
+    seg, n_seg = part.segment_shape(128)
+    assert (seg, n_seg) == (64, 2)
+    cut = lowered(part, _share(p, held))
+    # the later segments forward and backward: two loops whose trip count
+    # is data (the forward run again for the backward needs segment 0's
+    # hidden rows alone, and no sum)
+    assert cut.count("stablehlo.while") == 2
+    for word in ("conditional", "stablehlo.case", "stablehlo.if"):
+        assert word not in cut
+    # recomputed or not the step selects once and sorts twice (the order
+    # and its inverse): the routing's integers are offered to a recomputed
+    # run (``ops/recompute.py``); and nothing is looked up by an index
+    # but rows: no scatter at all
+    assert cut.count("chlo.top_k") == 1 and cut.count("stablehlo.sort") == 2
+    assert "stablehlo.scatter" not in cut
+    # (off the chip JAX itself writes a grouped product out group by group
+    # over the segment's rows, [2, seg, width]: the chip's compiler takes
+    # it whole, tests/test_tpu_compile.py)
+    assert row_segments.tall_arrays(
+        cut, seg, (64, 48),
+        stacks=[(2, 64, 48), (2, seg, 64), (2, seg, 48)]) == []
 
 
 def test_fit_counts_the_row_segments_run_and_skipped():
@@ -581,7 +677,7 @@ def test_fit_counts_the_row_segments_run_and_skipped():
                 "dl4j_moe_row_segments_total", {"samples": []})["samples"]:
             if s_["labels"]["vertex"] == "l3_moe":
                 out[s_["labels"]["outcome"]] = s_["value"]
-        return out.get("run", 0), out.get("skipped", 0)
+        return np.array([out.get(o, 0) for o in ("run", "skipped", "recomputed")])
 
     net = _net(dict(CFG, layers_run=[0, 3], num_experts=2, experts_held=[2, 5]))
     net.init()
@@ -589,21 +685,31 @@ def test_fit_counts_the_row_segments_run_and_skipped():
     seg, n_seg = layer.segment_shape(2 * 32 * 2)
     assert (seg, n_seg) == (64, 2)           # 2 of 8 held: twice their share of 128
     rng = np.random.default_rng(3)
-    # the last batch is half padding: the segments are still those of the
-    # 128 rows the device sorted, not of the 64 assignments counted
-    masks = [None, None, np.ones((2, 32), np.float32)]
-    masks[2][:, 16:] = 0.0
-    for mask in masks:
+    # the third batch is half padding: the segments are still those of the
+    # 128 rows the device sorted, not of the 64 assignments counted; for the
+    # last the bias sends every token to the two held experts, and the second
+    # segment runs, its hidden rows computed again in the backward pass
+    half = np.ones((2, 32), np.float32)
+    half[:, 16:] = 0.0
+    # (the step donates its state: a copy on the host outlives it)
+    seeded_bias = np.asarray(net.net_state["l3_moe"]["expert_bias"])
+    for mask, bias, want_run in [
+            (None, seeded_bias, 1), (None, seeded_bias, 1),
+            (half, seeded_bias, 1),
+            (None, _lift(8, e2=100.0, e5=50.0), 2)]:
+        net.net_state["l3_moe"] = {**net.net_state["l3_moe"],
+                                   "expert_bias": jnp.asarray(bias)}
         ids = rng.integers(0, CFG["vocab_size"], (2, 33), dtype=np.int32)
-        run0, skipped0 = read()
+        before = read()
         net.fit(ListDataSetIterator([DataSet(
             ids[:, :-1], ids[:, 1:], features_mask=mask, labels_mask=mask)]))
-        run1, skipped1 = read()
+        run, skipped, recomputed = read() - before
         counts = np.asarray(net.net_state["l3_moe"]["moe_expert_counts"])
         assert int(counts.sum()) == (128 if mask is None else 64)
         held = int(counts[list(layer._held())].sum())
-        assert run1 - run0 == -(-held // seg) and 0 < held < 128
-        assert (run1 - run0) + (skipped1 - skipped0) == n_seg
+        assert run == max(1, -(-held // seg)) == want_run
+        assert 0 < held < 128 or want_run == 2
+        assert run + skipped == n_seg and recomputed == run - 1
 
 
 @pytest.mark.parametrize("held", [(), (3, 1), (0, 0), (8,)])
@@ -808,9 +914,17 @@ def test_numeric_gradients_in_float64(layer):
      "bwd/MixtureOfExpertsLayer/experts"),
     ("jit(cg_train_step)/transpose(jvp(fwd/MixtureOfExpertsLayer/l2_moe))/"
      "dispatch/cond/branch_1_fun/scatter", "bwd/MixtureOfExpertsLayer/dispatch"),
-    # control flow that holds the parts is no part
+    # a loop that runs the parts once a trip (the later row segments):
+    # the part's, in both directions; the loop's own condition is no part
     ("jit(cg_train_step)/jvp(fwd/MixtureOfExpertsLayer/l2_moe)/while/body/"
-     "dispatch/gather", None),
+     "dispatch/gather", "fwd/MixtureOfExpertsLayer/dispatch"),
+    ("jit(cg_train_step)/transpose(jvp(fwd/MixtureOfExpertsLayer/l2_moe))/"
+     "while/body/experts/transpose(jvp())/ragged_dot_general",
+     "bwd/MixtureOfExpertsLayer/experts"),
+    ("jit(cg_train_step)/jvp(fwd/MixtureOfExpertsLayer/l2_moe)/while/cond/"
+     "lt", None),
+    ("jit(cg_train_step)/jvp(fwd/MixtureOfExpertsLayer/l2_moe)/while/body/"
+     "add", None),
     ("jit(cg_train_step)/jvp(fwd/DenseLayer/fc)/dot_general", None),
     ("jit(cg_train_step)/jvp(fwd/MixtureOfExpertsLayer/l2_moe)/eq", None),
     ("jit(cg_train_step)/update/mul", None),

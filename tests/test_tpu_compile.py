@@ -122,34 +122,47 @@ def test_grouped_expert_products_compile_to_a_kernel(compile_for_chip):
     assert "ragged-dot" in hlo
 
 
+@pytest.mark.parametrize("experts,held,k,hidden,scoring,recompute", [
+    (32, 8, 4, 1792, "sigmoid", False),
+    (128, 16, 8, 768, "softmax", True),
+], ids=["lfm2-8-of-32-top4", "sdar-16-of-128-top8-recomputed"])
 def test_expert_layer_compiles_with_its_segments_skipped_on_the_chip(
-        compile_for_chip):
-    """The expert layer of the language-model cell, forward and backward
-    (8 of 32 experts held, top-4 over 8,192 tokens of 2,048: 2 segments
-    of 16,384 sorted rows): four loops whose trip count the chip reads,
-    the grouped products inside them and the weights' gradients outside
-    as the compiler's own kernels."""
+        compile_for_chip, experts, held, k, hidden, scoring, recompute):
+    """The expert layer of the two expert cells, forward and backward
+    (8,192 tokens of 2,048; 8 of 32 experts held, top-4: 2 segments of
+    16,384 sorted rows; 16 of 128, top-8: 4 of 16,384): in the program
+    the chip's compiler makes of it **no array is longer than a segment
+    and as wide as the model or an expert** (the sorted buffer of tokens
+    x k rows exists nowhere), the later segments are loops whose trip
+    count the chip reads, no branch, and the grouped products the
+    compiler's own kernels."""
     from deeplearning4j_tpu.nn.conf import layers as L
+    from deeplearning4j_tpu.ops import row_segments
     layer = L.MixtureOfExpertsLayer(
-        n_out=2048, n_experts=32, hidden=1792, top_k=4, scoring="sigmoid",
-        expert_bias=True, gated=True, residual=False, activation="identity",
-        experts_held=tuple(range(8)))
-    assert layer.segment_shape(8192 * 4) == (16384, 2)
+        n_out=2048, n_experts=experts, hidden=hidden, top_k=k,
+        scoring=scoring, expert_bias=True, gated=True, residual=False,
+        activation="identity", experts_held=tuple(range(held)),
+        recompute=recompute)
+    seg, n_seg = layer.segment_shape(8192 * k)
+    assert (seg, n_seg) == (16384, 8192 * k // 16384)
 
     def step(x, wg, w1, w2, w3, bias):
         def loss(p, x):
             state = {"expert_bias": bias,
-                     "moe_expert_counts": jnp.zeros((32,), jnp.int32)}
+                     "moe_expert_counts": jnp.zeros((experts,), jnp.int32)}
             y, st, _ = layer.forward(p, state, x, train=True, rng=None)
             return _sq(y), st["moe_expert_counts"]
         return jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(
             {"Wg": wg, "W1": w1, "W2": w2, "W3": w3}, x)
     bf16 = jnp.bfloat16
     hlo = compile_for_chip(
-        step, ((2, 4096, 2048), bf16), ((2048, 32), bf16),
-        ((8, 2048, 1792), bf16), ((8, 1792, 2048), bf16),
-        ((8, 2048, 1792), bf16), ((32,), bf16))
-    assert len(re.findall(r"\s(while)\(", hlo)) == 4
+        step, ((2, 4096, 2048), bf16), ((2048, experts), bf16),
+        ((held, 2048, hidden), bf16), ((held, hidden, 2048), bf16),
+        ((held, 2048, hidden), bf16), ((experts,), bf16))
+    assert row_segments.tall_arrays(
+        hlo, seg, (2048, hidden), stacks=[(held, 2048, hidden)]) == []
+    # the later segments, forward and backward
+    assert len(re.findall(r"\s(while)\(", hlo)) >= 2
     assert " conditional(" not in hlo
     # three products forward, three to the rows and three to the weights
     assert len(set(re.findall(r"%(ragged-dot[\w.\-]*) = ", hlo))) >= 9
