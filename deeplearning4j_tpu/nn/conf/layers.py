@@ -98,6 +98,13 @@ class Layer:
     def has_params(self) -> bool:
         return True
 
+    def master_leaves(self) -> Tuple[str, ...]:
+        """Parameter leaves the training step hands this layer in their
+        master dtype under a mixed-precision policy
+        (``Policy.cast_params_to_compute``): the layer casts them itself,
+        or its kernels do, a block at a time in VMEM."""
+        return ()
+
     def is_pretrain_layer(self) -> bool:
         return False
 
@@ -1248,8 +1255,9 @@ class MixtureOfExpertsLayer(Layer):
     than one segment's rows**; only the index arrays are N·k long.  A
     segment goes from the tokens to the tokens' sum in one piece
     (``ops/row_segments.routed_sum``): its rows are gathered, the expert
-    products are grouped matrix products over them (``jax.lax.ragged_dot``
-    with the global group sizes clipped to the segment), gated, and
+    products are grouped matrix products over them (the gmm tier's
+    kernels, or ``jax.lax.ragged_dot`` where the tier is off, with the
+    global group sizes clipped to the segment), gated, and
     summed back into the tokens by k gathers of N rows in float32.  The
     group sizes are data, and so is the number of segments that hold a
     row of a held expert.  The first segment runs once outside any loop,
@@ -1286,11 +1294,14 @@ class MixtureOfExpertsLayer(Layer):
     selects and sorts once.  The four parts are named
     inside the layer's scope (``scope_parts``; the last three inside a
     segment's body, so also inside the later segments' loop), and the
-    grouped product's kernels, which the chip's compiler names itself,
-    are claimed for ``experts`` (``scope_kernels``)."""
+    grouped products' kernels are claimed for ``experts`` by name
+    (``scope_kernels``): XLA's, which the chip's compiler names itself,
+    and the gmm tier's, whose one lowering a program shares between its
+    call sites and so carries one site's scope."""
 
     scope_parts = ("route", "dispatch", "experts", "combine")
-    scope_kernels = {"ragged-dot": "experts"}
+    scope_kernels = {"ragged-dot": "experts", "dl4j_gmm": "experts",
+                     "dl4j_tgmm": "experts"}
 
     n_in: Optional[int] = None
     n_out: int = 0
@@ -1355,6 +1366,9 @@ class MixtureOfExpertsLayer(Layer):
             raise ValueError(f"unknown scoring {self.scoring!r} "
                              "(softmax | sigmoid)")
         G = len(self._held())
+        # the grouped products' tier checks its kernels beside the
+        # weights' set-up, not in the first step's trace
+        helper_ops.prevalidate("gmm")
         params = {
             "Wg": self._winit(kg, (n_in, E), dtype),
             "W1": self._winit(k1, (G, n_in, H), dtype, fan_in=n_in,
@@ -1370,6 +1384,13 @@ class MixtureOfExpertsLayer(Layer):
         if self.expert_bias:
             state["expert_bias"] = 0.01 * jax.random.normal(kb, (E,), dtype)
         return params, state, input_type
+
+    def master_leaves(self) -> Tuple[str, ...]:
+        """The top_k path's expert stacks reach the layer in float32 under
+        the bf16 policy: the gmm tier's kernels cast each block in VMEM
+        and give the stacks' gradients in float32, so no pass casts a
+        stack whole; XLA's products take them cast in ``routed_sum``."""
+        return ("W1", "W2", "W3") if self.top_k is not None else ()
 
     def segment_shape(self, rows: int) -> Tuple[int, int]:
         """(rows of a segment, segments) into which the top_k path cuts

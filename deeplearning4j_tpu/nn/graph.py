@@ -291,12 +291,16 @@ class ComputationGraph:
         # labels/masks arrive ordered by conf.network_outputs — index by that
         # position, NOT by position in the (filtered) out_confs dict
         out_pos = {n: self.conf.network_outputs.index(n) for n in out_names}
+        keep = {n: v.layer_conf().master_leaves()
+                for n, v in self.conf.vertices.items()
+                if isinstance(v, LayerVertex)
+                and v.layer_conf().master_leaves()}
 
         def grad_step(params, state, xs, ys, fmasks, lmasks, rng):
             xs_c, fmasks_c = policy.cast_to_compute((xs, fmasks))
 
             def loss_fn(p):
-                pc = policy.cast_to_compute(p)
+                pc = policy.cast_params_to_compute(p, keep)
                 inputs = dict(zip(self.conf.network_inputs, xs_c))
                 masks = dict(zip(self.conf.network_inputs, fmasks_c)) \
                     if fmasks_c is not None else {}

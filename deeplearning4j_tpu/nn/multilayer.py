@@ -614,12 +614,14 @@ class MultiLayerNetwork:
         out_layer = self.layers[-1]
         if not isinstance(out_layer, (BaseOutputLayer, LossLayer)):
             raise ValueError("Last layer must be an output/loss layer to fit()")
+        keep = {i: l.master_leaves() for i, l in enumerate(self.layers)
+                if l.master_leaves()}
 
         def grad_step(params, state, x, y, fmask, lmask, rng):
             xc, fmc = policy.cast_to_compute((x, fmask))
 
             def loss_fn(p):
-                pc = policy.cast_to_compute(p)
+                pc = policy.cast_params_to_compute(p, keep)
                 preout, new_states, m, feats = self._forward_to_preout(
                     pc, state, xc, fmc, True, rng,
                     stateful_rnn=(self.conf.backprop_type == "truncatedbptt"))

@@ -44,6 +44,22 @@ class Policy:
 
         return jax.tree_util.tree_map(cast, tree)
 
+    def cast_params_to_compute(self, params, keep):
+        """:meth:`cast_to_compute` of a network's parameters (a dict or a
+        list of per-layer dicts of leaves) but for the leaves ``keep``
+        names ({key or position: leaf names}, ``Layer.master_leaves``):
+        those reach their layer in the master dtype."""
+        if not keep or not self.is_mixed:
+            return self.cast_to_compute(params)
+
+        def layer(key, leaves):
+            kept = keep.get(key, ())
+            return {k: v if k in kept else self.cast_to_compute(v)
+                    for k, v in leaves.items()}
+        if isinstance(params, dict):
+            return {key: layer(key, v) for key, v in params.items()}
+        return type(params)(layer(i, v) for i, v in enumerate(params))
+
     def cast_to_param(self, tree):
         """Upcast compute-dtype leaves back to the master dtype (carried
         state: BN running stats, RNN carries, MoE aux loss)."""

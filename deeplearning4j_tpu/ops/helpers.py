@@ -14,6 +14,7 @@ op              tier    fused kernel (pallas_kernels)  dense XLA fallback
 ``dropout``     dropout fused_threshold_dropout        ops/normalization.dropout
 ``softmax_xent`` xent   softmax_xent_rows              stable logsumexp form in ops/losses
 ``attention``   flash   flash_attention                dense softmax attention
+``gmm``         gmm     gmm, tgmm                      ``lax.ragged_dot``
 ==============  ======  =============================  =====================
 
 Selection happens automatically AT TRACE TIME, per call site: each
@@ -31,7 +32,7 @@ activation kernel wherever both were measured (the trial is PERF.md section
 Kill switches, most-specific wins:
 
 * ``DL4J_PALLAS=0`` — global: every tier falls back.
-* ``DL4J_PALLAS_{LSTM,DROPOUT,XENT,FLASH}=0|1`` — per tier:
+* ``DL4J_PALLAS_{LSTM,DROPOUT,XENT,FLASH,GMM}=0|1`` — per tier:
   ``0`` forces the fallback, ``1`` forces the fused path even off-TPU
   (interpret mode; how the parity tests exercise the kernels through
   the public ``fit``/``output`` path).
@@ -69,10 +70,14 @@ class Helper(NamedTuple):
     tier: str                    # kill-switch tier name (lstm, dropout, ...)
     test_name: str               # key in the kernel_self_test() report
     self_test: Callable[[], None]  # small-shape compile+run validation
+    # validated when a trace first selects it, not at the top of every
+    # fit(): a tier that few networks use costs the others nothing
+    on_first_use: bool = False
 
 
 _ENV_TIER = {"lstm": "DL4J_PALLAS_LSTM", "dropout": "DL4J_PALLAS_DROPOUT",
-             "xent": "DL4J_PALLAS_XENT", "flash": "DL4J_PALLAS_FLASH"}
+             "xent": "DL4J_PALLAS_XENT", "flash": "DL4J_PALLAS_FLASH",
+             "gmm": "DL4J_PALLAS_GMM"}
 
 
 def _registry():
@@ -190,6 +195,81 @@ def attention_wanted(q) -> bool:
     return ok
 
 
+_FIRST_USE: Dict[str, bool] = {}
+_STARTED: Dict[str, tuple] = {}
+_FIRST_USE_LOCK = threading.Lock()
+# a self-test compiles a few small kernels: seconds, a minute when cold
+_FIRST_USE_TIMEOUT_S = 600.0
+
+
+def _start_self_test(op: str):
+    """(thread, verdict list) of ``op``'s self-test started on a thread of
+    its own, whose JAX state holds no trace."""
+    verdict = []
+
+    def run():
+        try:
+            verdict.append(_self_test_one(op, True))
+        except BaseException as e:  # the test's own are caught
+            verdict.append(f"error: {type(e).__name__}: {e}")
+
+    runner = threading.Thread(target=run, daemon=True,
+                              name=f"dl4j-selftest-{op}")
+    runner.start()
+    return runner, verdict
+
+
+def prevalidate(op: str) -> None:
+    """Start ``op``'s first-use self-test now, if its tier could engage,
+    so that it runs while the caller goes on (a layer that will select
+    the tier calls this when it is initialised, beside the weights' own
+    set-up); the first selection waits for its verdict."""
+    with _FIRST_USE_LOCK:
+        if op not in _FIRST_USE and op not in _STARTED and available(op):
+            _STARTED[op] = _start_self_test(op)
+
+
+def _validated_on_first_use(op: str) -> bool:
+    """Run ``op``'s self-test the first time a trace selects it (or take
+    the verdict of the one :func:`prevalidate` started), once a process,
+    and say whether the tier is still available after it.  The test runs
+    on a thread of its own: the caller is in the middle of a trace.  A
+    test that does not finish in time disables the tier as a failing one
+    does."""
+    with _FIRST_USE_LOCK:
+        if op not in _FIRST_USE:
+            runner, verdict = _STARTED.pop(op, None) or _start_self_test(op)
+            runner.join(_FIRST_USE_TIMEOUT_S)
+            _FIRST_USE[op] = verdict == ["ok"]
+            tier = _HELPERS[op].tier
+            if not _FIRST_USE[op] and tier not in pk._disabled:
+                log.warning("Pallas tier '%s' is DISABLED for this process: "
+                            "its self-test %s", tier,
+                            verdict[0] if verdict else
+                            f"did not finish in {_FIRST_USE_TIMEOUT_S} s")
+                pk.disable_kernels(f"{op} self-test: {verdict[:1]}",
+                                   tier=tier)
+    return available(op)
+
+
+def gmm_wanted(rows: int, dtype, stacks) -> bool:
+    """Trace-time decision for ops/row_segments.routed_sum: True runs an
+    expert layer's grouped products over segments of ``rows`` sorted rows
+    in ``dtype`` through the gmm tier's kernels (``stacks``: the layer's
+    [G, a, b] weight stacks, all in one dtype, ``dtype`` or float32
+    masters, every one a transpose of the first's shape or the first's
+    own); ``lax.ragged_dot`` otherwise.  One decision a layer a trace."""
+    G, k, n = stacks[0].shape
+    w_dtype = stacks[0].dtype
+    ok = (available("gmm")
+          and all(w.dtype == w_dtype and sorted(w.shape[1:]) == sorted((k, n))
+                  for w in stacks)
+          and pk.gmm_supported(rows, k, n, G, dtype, w_dtype)
+          and _validated_on_first_use("gmm"))
+    record_selection("gmm", ok)
+    return ok
+
+
 # ---------------------------------------------------------------------------
 # Warm validation — compile-check every registered helper once, through
 # the real dispatch path, BEFORE anything perf-critical traces it cold.
@@ -270,6 +350,38 @@ def _selftest_dropout():
         raise FloatingPointError("non-finite fused dropout loss")
 
 
+def _selftest_gmm():
+    # both kernels, a float32 stack under bfloat16 rows, with an empty
+    # group, a group that ends inside a row tile and rows behind the last
+    # group, against XLA's grouped product: one program, two kernels (a
+    # set-up pays each kernel's trace and lowering; the transposed read
+    # is the first kernel's with its block index turned)
+    import numpy as np
+    rng = np.random.default_rng(0)
+    m, k, n = 256, 256, 128
+    x = rng.normal(size=(m, k)).astype(jnp.bfloat16)
+    w = (rng.normal(size=(3, k, n)) * 0.1).astype(np.float32)
+    sizes = np.asarray([100, 0, 120], np.int32)
+
+    def run(x, w, sizes):
+        y = pk.gmm(x, w, sizes)
+        want = jax.lax.ragged_dot(x, w.astype(x.dtype), sizes)
+        dw = pk.tgmm(x, y, sizes, out_dtype=jnp.float32)
+        held = (jnp.arange(m) < jnp.sum(sizes))[:, None]
+        gap = jnp.max(jnp.where(held, jnp.abs(
+            y.astype(jnp.float32) - want.astype(jnp.float32)), 0))
+        return gap, jnp.max(jnp.abs(want.astype(jnp.float32))), jnp.all(
+            jnp.isfinite(dw))
+    products = jax.jit(run)
+    gap, scale, finite = (float(v) for v in jax.device_get(
+        products(x, w, sizes)))
+    if not gap <= 0.02 * scale:
+        raise FloatingPointError(
+            f"dl4j_gmm disagrees with ragged_dot by {gap} of {scale}")
+    if not finite:
+        raise FloatingPointError("non-finite grouped product")
+
+
 _HELPERS: Dict[str, Helper] = {
     "lstm_step": Helper("lstm_step", "lstm", "lstm_step", _selftest_lstm),
     "dropout": Helper("dropout", "dropout", "dropout", _selftest_dropout),
@@ -277,6 +389,7 @@ _HELPERS: Dict[str, Helper] = {
                            _selftest_xent),
     "attention": Helper("attention", "flash", "flash_attention",
                         _selftest_flash),
+    "gmm": Helper("gmm", "gmm", "gmm", _selftest_gmm, on_first_use=True),
 }
 
 OPS = tuple(_HELPERS)
@@ -299,38 +412,8 @@ def kernel_self_test(disable_on_error: bool = True,
     # snapshot BEFORE any test can flip a kill switch: the mode the
     # tests actually ran under, not the post-disable state
     interp = pk._interpret()
-    try:
-        gauge = _registry().gauge(
-            "dl4j_pallas_selftest_ok",
-            "last kernel_self_test verdict per helper (1 ok, 0 failed)",
-            labels=("op",))
-        tier_gauge = _registry().gauge(
-            "dl4j_pallas_tier_disabled",
-            "kernel-tier kill switch (1 = disabled)", labels=("tier",))
-    except Exception:
-        gauge = tier_gauge = None
-
     for op in (ops if ops is not None else OPS):
-        h = _HELPERS[op]
-        try:
-            h.self_test()
-            results[h.test_name] = "ok"
-            ok = 1
-        except Exception as e:  # Mosaic/XLA compile or runtime failure
-            results[h.test_name] = f"error: {type(e).__name__}: {e}"[:300]
-            ok = 0
-            if disable_on_error and h.tier not in pk._disabled:
-                log.warning(
-                    "Pallas tier '%s' failed its self-test and is DISABLED "
-                    "for this process; '%s' runs on dense XLA. %s: %s",
-                    h.tier, op, type(e).__name__, e)
-                pk.disable_kernels(
-                    f"{h.test_name} self-test failed: {e}", tier=h.tier)
-        if gauge is not None:
-            gauge.labels(op=op).set(ok)
-        if tier_gauge is not None:
-            tier_gauge.labels(tier=h.tier).set(
-                1 if h.tier in pk._disabled else 0)
+        results[_HELPERS[op].test_name] = _self_test_one(op, disable_on_error)
     results["interpret_mode"] = interp
     if pk._disabled:
         results["disabled"] = {t: r[:300] for t, r in pk._disabled.items()}
@@ -338,6 +421,37 @@ def kernel_self_test(disable_on_error: bool = True,
         _WARM["done"] = True
         _WARM["result"] = results
     return results
+
+
+def _self_test_one(op: str, disable_on_error: bool) -> str:
+    """One helper's self-test: its verdict ("ok" or the error), metered,
+    its tier disabled on error if so asked."""
+    h = _HELPERS[op]
+    try:
+        h.self_test()
+        verdict, ok = "ok", 1
+    except Exception as e:  # Mosaic/XLA compile or runtime failure
+        verdict, ok = f"error: {type(e).__name__}: {e}"[:300], 0
+        if disable_on_error and h.tier not in pk._disabled:
+            log.warning(
+                "Pallas tier '%s' failed its self-test and is DISABLED "
+                "for this process; '%s' runs on dense XLA. %s: %s",
+                h.tier, op, type(e).__name__, e)
+            pk.disable_kernels(
+                f"{h.test_name} self-test failed: {e}", tier=h.tier)
+    try:
+        _registry().gauge(
+            "dl4j_pallas_selftest_ok",
+            "last kernel_self_test verdict per helper (1 ok, 0 failed)",
+            labels=("op",)).labels(op=op).set(ok)
+        _registry().gauge(
+            "dl4j_pallas_tier_disabled",
+            "kernel-tier kill switch (1 = disabled)",
+            labels=("tier",)).labels(tier=h.tier).set(
+                1 if h.tier in pk._disabled else 0)
+    except Exception:
+        pass  # metering must never break validation
+    return verdict
 
 
 _WARM: dict = {"done": False, "result": None}
@@ -349,14 +463,17 @@ def ensure_validated() -> dict:
     engines' ``fit()``: when any fused tier could engage (on TPU, or a
     tier force env is set) run :func:`kernel_self_test` over the
     ELIGIBLE helpers so a bad kernel flips its kill switch before the
-    first real step compiles.  Off-TPU with nothing forced this is a
-    cheap no-op — the fallback paths need no validation."""
+    first real step compiles (a helper validated ``on_first_use`` runs
+    its self-test when a trace first selects it instead).  Off-TPU with
+    nothing forced this is a cheap no-op — the fallback paths need no
+    validation."""
     if _WARM["done"]:
         return _WARM["result"]
     with _WARM_LOCK:
         if _WARM["done"]:
             return _WARM["result"]
-    eligible = [op for op in OPS if available(op)]
+    eligible = [op for op in OPS
+                if available(op) and not _HELPERS[op].on_first_use]
     if not eligible:
         with _WARM_LOCK:
             _WARM["done"] = True
@@ -372,6 +489,9 @@ def reset_validation() -> None:
     with _WARM_LOCK:
         _WARM["done"] = False
         _WARM["result"] = None
+    with _FIRST_USE_LOCK:
+        _FIRST_USE.clear()
+        _STARTED.clear()
 
 
 # ---------------------------------------------------------------------------

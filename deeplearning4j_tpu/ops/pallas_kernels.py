@@ -7,7 +7,7 @@ per-layer selection between a kernel and its dense XLA fallback lives in
 ``ops/helpers.py`` (the cuDNN-helper-selection tier: registry, per-tier
 kill switches, warm validation, ``dl4j_pallas_*`` selection metrics).
 
-Four kernels:
+Five tiers:
 
 * **flash_attention** — block-wise online-softmax attention.  The dense
   XLA path materializes the [B, H, T, T] score matrix in HBM; this
@@ -42,6 +42,13 @@ Four kernels:
   materialized in HBM, and the backward pass re-derives the same mask
   from the seed instead of saving it.
 
+* **gmm** — the expert layer's grouped matrix products (``dl4j_gmm``
+  for rows by a stack of matrices, either way round, ``dl4j_tgmm`` for
+  the stack's gradient), over rows sorted by group, with the live tiles
+  planned on the device from the group sizes; each entry point is a
+  module-level ``jax.jit``, so a program traces and lowers each kernel
+  signature once however many call sites share it.
+
 All run under ``interpret=True`` off-TPU so the same code is testable
 on the CPU mesh (the reference's cuDNN-vs-builtin cross-check pattern,
 SURVEY.md §4)."""
@@ -50,14 +57,16 @@ from __future__ import annotations
 
 import contextlib
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
+import jax.extend as jex
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.interpreters import mlir
 
 from deeplearning4j_tpu.ops import mask_rules, recompute
 
@@ -70,7 +79,7 @@ NEG_INF = -1e30
 # cuDNN-helper-with-builtin-fallback pattern, ref
 # ConvolutionLayer.java:157-212).  DL4J_PALLAS=0 disables everything;
 # per-tier state is read by ops/helpers.available().
-ALL_TIERS = ("flash", "xent", "lstm", "dropout")
+ALL_TIERS = ("flash", "xent", "lstm", "dropout", "gmm")
 _disabled: dict = {}  # tier -> reason
 
 
@@ -97,7 +106,7 @@ _PARTITIONED = False
 @contextlib.contextmanager
 def partitioned_trace():
     """Scope under which the fused tiers leave AUTOMATIC selection on
-    the chip — all four, by name: flash, xent, lstm, dropout.  A
+    the chip — all five, by name: flash, xent, lstm, dropout, gmm.  A
     Mosaic kernel cannot be partitioned automatically (the TPU lowering
     raises "Mosaic kernels cannot be automatically partitioned. Please
     wrap the call in a shard_map"), so a step jitted with mesh shardings
@@ -878,4 +887,385 @@ def dropout_fused_supported(shape, dtype) -> bool:
     for d in shape:
         n *= int(d)
     return n >= (1 << 12)
+
+
+# ===========================================================================
+# Grouped matrix products — the expert layer's products over rows sorted by
+# group (ops/row_segments.py): rows [m, k] of which the first sizes[0] belong
+# to group 0, the next sizes[1] to group 1, and so on; rows behind the last
+# group are never read into a result and come out undefined.
+#
+# A kernel runs a grid of STEPS, one for each (row tile, group) pair that
+# holds a row of the group: a tile that two groups share runs once for each,
+# and no tile behind the last group runs at all.  The steps are planned on
+# the device from the group sizes (_tile_plan) and handed to the kernels by
+# scalar prefetch, with their count as the grid's dynamic extent, so a step
+# neither retraces nor waits for the host whatever the routing.  Products
+# multiply in the operands' dtype and accumulate in float32.
+#
+# dl4j_gmm holds a group's whole [k, n-tile] matrix while the steps of the
+# group run over its row tiles (the n tiles are the grid's outer dimension),
+# so the stack is read once a product whatever the rows; ``transpose_w``
+# reads w[g] as [n, k] through its own block index (x · w[g]ᵀ, the rows'
+# cotangent) without a transposed copy of the stack.  dl4j_tgmm gathers
+# x[rows of g]ᵀ · y[rows of g] over the group's row tiles in a float32
+# block of VMEM and stores it when the group ends.  An empty group has one
+# step, in which dl4j_gmm stores nothing and dl4j_tgmm stores zeros.  Rows
+# of a tile outside the step's group are left as they are by dl4j_gmm's
+# store and selected out of both of dl4j_tgmm's operands, so whatever an
+# undefined row holds never reaches a sum.
+#
+# Every tile follows from the shapes alone (_gmm_tiles, _tgmm_tiles), and
+# the plan of the steps is made once for all the products over the same
+# rows (group_plan).  Each kernel is a primitive of its own whose
+# parameters are hashable and whose lowering is emitted out of line: a call
+# site binds it (no kernel is traced in the caller's trace), and a program
+# traces, lowers and Mosaic-compiles each distinct (shapes, dtypes, tiles,
+# transpose flag) once, in a function every site with that signature calls.
+# A jax.jit entry would share its trace, but a loop body's dead-code pass
+# makes a copy of the callee that the lowering then takes for another
+# function: one more kernel a loop body, and a recomputed layer's more.
+# ===========================================================================
+
+# elements of a stack's block, or of the weights' gradient's float32
+# accumulator (a block is double-buffered: 16 MB of VMEM at float32)
+_GMM_BLOCK_ELEMS = 2 << 20
+_GMM_VMEM_LIMIT = 64 << 20
+# the row tile: a step's product holds at most this many rows, and a
+# segment whose groups are short takes fewer (a boundary tile runs once
+# for each of its groups)
+_GMM_ROWS_CAP = 512
+_GMM_ROWS_MIN = 128
+
+
+def _lane_tile(n: int, cap_elems: int) -> int:
+    """The widest tile of n that a block may have: n itself if it is no
+    multiple of 128 lanes or fits ``cap_elems``, else the largest multiple
+    of 128 that divides n and fits."""
+    if n % LANE or n <= cap_elems:
+        return n
+    best = LANE
+    for t in range(LANE, n + 1, LANE):
+        if n % t == 0 and t <= cap_elems:
+            best = t
+    return best
+
+
+def _row_tile(m: int, groups: int) -> int:
+    """Rows a step multiplies: the largest power of two from the cap down
+    whose boundary tiles (one a group, half dead on average) stay within
+    an eighth of the segment, and that divides it; a segment of fewer
+    rows than the smallest such tile is one tile."""
+    tm = _GMM_ROWS_CAP
+    while tm > _GMM_ROWS_MIN and groups * tm > m // 8:
+        tm //= 2
+    while tm > 8 and m % tm:
+        tm //= 2
+    return tm if m % tm == 0 else m
+
+
+def _gmm_tiles(m: int, k: int, n: int, groups: int):
+    """(row tile, column tile) of dl4j_gmm for x [m, k] by a stack of
+    groups x [k, n]: the column tile keeps a group's [k, tile] block in
+    the block budget."""
+    return (_row_tile(m, groups),
+            _lane_tile(n, max(LANE, _GMM_BLOCK_ELEMS // k)))
+
+
+def _tgmm_tiles(m: int, a: int, b: int, groups: int):
+    """(row tile, a tile, b tile) of dl4j_tgmm for x [m, a], y [m, b]: the
+    float32 accumulator [a tile, b tile] within half the block budget."""
+    ta = _lane_tile(a, 1024)
+    return (_row_tile(m, groups), ta,
+            _lane_tile(b, max(LANE, _GMM_BLOCK_ELEMS // (2 * ta))))
+
+
+def _tile_plan(sizes, m: int, tm: int):
+    """The steps of a grouped kernel over m rows in tiles of tm, from the
+    group sizes on the device: (offsets [G + 1], the group of each step,
+    the row tile of each step, the number of steps).  The step arrays are
+    m / tm + G - 1 long, the most any sizes need; a group's steps are its
+    tiles in order and the groups follow each other, so a tile that two
+    groups share is visited twice in a row; an empty group has one step,
+    whose rows all lie outside it.  Written in lax calls alone, a few
+    dozen operations over [G] and [steps, G] integers: an operator or a
+    jnp function is a jitted wrapper traced anew at each call, and a
+    jitted helper a function of its own in every loop body and
+    recomputed layer, and each plans once a segment a direction."""
+    i32 = jnp.int32
+    G = sizes.shape[0]
+    length = m // tm + G - 1
+    per_group = functools.partial(lax.full, (G,), dtype=i32)
+    sizes = lax.convert_element_type(sizes, i32)
+    ends = lax.cumsum(sizes, axis=0)
+    first = lax.div(lax.sub(ends, sizes), per_group(tm))
+    last = lax.div(lax.max(lax.sub(ends, per_group(1)), per_group(0)),
+                   per_group(tm))
+    tiles = lax.select(lax.gt(sizes, per_group(0)),
+                       lax.add(lax.sub(last, first), per_group(1)),
+                       per_group(1))
+    after = lax.cumsum(tiles, axis=0)
+    # a step's group: the groups whose steps all lie before it (one
+    # comparison of [length, G], where a repeat would be a scatter)
+    steps = lax.broadcasted_iota(i32, (length, G), 0)
+    group_of = lax.min(
+        lax.reduce_sum(lax.convert_element_type(lax.le(
+            lax.broadcast_in_dim(after, (length, G), (1,)), steps), i32),
+            (1,)),
+        lax.full((length,), G - 1, i32))
+    one_hot = lax.eq(lax.broadcast_in_dim(group_of, (length, G), (0,)),
+                     lax.broadcasted_iota(i32, (length, G), 1))
+
+    def of_group(v):
+        return lax.reduce_sum(lax.select(
+            one_hot, lax.broadcast_in_dim(v, (length, G), (1,)),
+            lax.full((length, G), 0, i32)), (1,))
+    tile_of = lax.min(
+        lax.add(of_group(first), lax.sub(lax.iota(i32, length),
+                                         of_group(lax.sub(after, tiles)))),
+        lax.full((length,), m // tm - 1, i32))
+    offsets = lax.concatenate([lax.full((1,), 0, i32), ends], 0)
+    return (offsets, group_of, tile_of,
+            lax.index_in_dim(after, G - 1, keepdims=False))
+
+
+class GroupPlan(NamedTuple):
+    """The steps of both kernels over m rows sorted into groups, planned
+    once for all the products over the same rows (:func:`group_plan`)."""
+    steps: jax.Array        # int32 []: how many steps run
+    offsets: jax.Array      # int32 [G + 1]: where each group starts
+    group_of: jax.Array     # int32 [m / tm + G - 1]: each step's group
+    tile_of: jax.Array      # int32 [m / tm + G - 1]: each step's row tile
+    tm: int                 # rows a step multiplies
+
+
+def group_plan(sizes, m: int) -> GroupPlan:
+    """The plan of the kernels' steps over m rows whose groups are
+    ``sizes`` [G] long, in the row tile the segment and the groups give
+    (every group, an empty one too, has a step)."""
+    tm = _row_tile(m, sizes.shape[0])
+    offsets, group_of, tile_of, steps = _tile_plan(sizes, m, tm)
+    return GroupPlan(steps, offsets, group_of, tile_of, tm)
+
+
+def _plan(groups, m: int) -> GroupPlan:
+    return groups if isinstance(groups, GroupPlan) else group_plan(groups, m)
+
+
+def _rows_in_group(offsets_ref, g, tile, tm: int):
+    """[tm, 1]: which rows of row tile ``tile`` belong to group g."""
+    rows = tile * tm + lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
+    return lax.bitwise_and(rows >= offsets_ref[g], rows < offsets_ref[g + 1])
+
+
+def _group_starts(group_ref, step):
+    """Whether grid step ``step`` is its group's first."""
+    return lax.bitwise_or(step == 0,
+                          group_ref[lax.max(step, 1) - 1] != group_ref[step])
+
+
+def _select_rows(live, block):
+    """``block`` with the rows outside ``live`` [tm, 1] zero."""
+    return lax.select(lax.broadcast_in_dim(live, block.shape, (0, 1)),
+                      block, lax.full_like(block, 0))
+
+
+def _gmm_kernel(offsets_ref, group_ref, tile_ref, x_ref, w_ref, out_ref,
+                *cast_ref, transpose_w: bool):
+    """One step: the row tile's product with its group's block, stored in
+    the rows of the group (another group's rows of the tile keep what
+    that group's step stores).  A block in another dtype than the rows'
+    is cast into ``cast_ref`` once, at the first step that holds it."""
+    step = pl.program_id(1)
+    w = w_ref
+    if cast_ref:
+        w, = cast_ref
+
+        @pl.when(_group_starts(group_ref, step))
+        def _():
+            w[...] = lax.convert_element_type(w_ref[...], w.dtype)
+    dims = _NT if transpose_w else (((1,), (0,)), ((), ()))
+    y = lax.convert_element_type(
+        lax.dot_general(x_ref[...], w[...], dims,
+                        preferred_element_type=jnp.float32), out_ref.dtype)
+    live = _rows_in_group(offsets_ref, group_ref[step], tile_ref[step],
+                          x_ref.shape[0])
+    out_ref[...] = lax.select(lax.broadcast_in_dim(live, y.shape, (0, 1)),
+                              y, out_ref[...])
+
+
+def _grouped_primitive(name: str, impl, out_aval):
+    """A primitive that runs ``impl(steps, *plan, x, w_or_y, **params)``
+    (the steps and the plan of :func:`_tile_plan`, planned by the caller
+    under its own scope) and lowers it as one out-of-line function a
+    program for each distinct operand shapes and params;
+    ``out_aval(*avals, **params)`` is its (shape, dtype)."""
+    prim = jex.core.Primitive(name)
+    prim.def_impl(impl)
+    prim.def_abstract_eval(
+        lambda *avals, **params: jax.core.ShapedArray(
+            *out_aval(*avals, **params)))
+    mlir.register_lowering(prim, mlir.lower_fun(impl, multiple_results=False),
+                           inline=False)
+    return prim
+
+
+def _gmm_call(steps, offsets, group_of, tile_of, x, w, *, transpose_w: bool,
+              tiles, interpret: bool):
+    m, k = x.shape
+    n = w.shape[1] if transpose_w else w.shape[2]
+    tm, tn = tiles
+    if transpose_w:
+        w_block = (tn, k)
+        w_spec = pl.BlockSpec((None, tn, k),
+                              lambda j, s, o, g, t: (g[s], j, 0))
+    else:
+        w_block = (k, tn)
+        w_spec = pl.BlockSpec((None, k, tn),
+                              lambda j, s, o, g, t: (g[s], 0, j))
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, transpose_w=transpose_w),
+        out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(n // tn, steps),
+            in_specs=[
+                pl.BlockSpec((tm, k), lambda j, s, o, g, t: (t[s], 0)),
+                w_spec,
+            ],
+            out_specs=pl.BlockSpec((tm, tn),
+                                   lambda j, s, o, g, t: (t[s], j)),
+            scratch_shapes=([] if w.dtype == x.dtype
+                            else [pltpu.VMEM(w_block, x.dtype)]),
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_GMM_VMEM_LIMIT),
+        name="dl4j_gmm",
+        interpret=interpret,
+    )(offsets, group_of, tile_of, x, w)
+
+
+_gmm_p = _grouped_primitive(
+    "grouped_matmul", _gmm_call,
+    lambda *plan_x_w, transpose_w, **_: (
+        (plan_x_w[4].shape[0],
+         plan_x_w[5].shape[1 if transpose_w else 2]), plan_x_w[4].dtype))
+
+
+def gmm(x, w, groups, transpose_w: bool = False):
+    """[m, n]: row r of group g times w[g] ([G, k, n]), or times w[g]ᵀ if
+    ``transpose_w`` (w [G, n, k]); ``groups`` the rows of each group in
+    order ([G] sizes) or their :func:`group_plan`.  Rows behind the last
+    group are undefined.  The product runs in x's dtype, which the result
+    has, and accumulates in float32; a stack in a wider dtype (float32
+    masters under bfloat16 rows) is cast a block at a time in VMEM, once
+    a block, and never in HBM."""
+    plan = _plan(groups, x.shape[0])
+    n = w.shape[1] if transpose_w else w.shape[2]
+    tiles = (plan.tm, _gmm_tiles(x.shape[0], x.shape[1], n, w.shape[0])[1])
+    return _gmm_p.bind(*plan[:4], x, w, transpose_w=transpose_w,
+                       tiles=tiles, interpret=_interpret())
+
+
+def _tgmm_kernel(offsets_ref, group_ref, tile_ref, x_ref, y_ref, out_ref,
+                 acc_ref):
+    """One step: the group's rows of the tile, xᵀ · y, added to the
+    group's float32 sum; zeroed at the group's first step, stored at its
+    last."""
+    step = pl.program_id(2)
+    last = pl.num_programs(2) - 1
+    g = group_ref[step]
+
+    @pl.when(_group_starts(group_ref, step))
+    def _():
+        acc_ref[...] = lax.full(acc_ref.shape, 0, acc_ref.dtype)
+
+    live = _rows_in_group(offsets_ref, g, tile_ref[step], x_ref.shape[0])
+    acc_ref[...] += lax.dot_general(
+        _select_rows(live, x_ref[...]), _select_rows(live, y_ref[...]),
+        (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+    @pl.when(lax.bitwise_or(step == last,
+                            group_ref[lax.min(step + 1, last)] != g))
+    def _():
+        out_ref[...] = lax.convert_element_type(acc_ref[...], out_ref.dtype)
+
+
+def _tgmm_call(steps, offsets, group_of, tile_of, x, y, *, out_dtype, tiles,
+               interpret: bool):
+    m, a = x.shape
+    b = y.shape[1]
+    G = offsets.shape[0] - 1
+    tm, ta, tb = tiles
+    return pl.pallas_call(
+        _tgmm_kernel,
+        out_shape=jax.ShapeDtypeStruct((G, a, b), out_dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(a // ta, b // tb, steps),
+            in_specs=[
+                pl.BlockSpec((tm, ta), lambda i, j, s, o, g, t: (t[s], i)),
+                pl.BlockSpec((tm, tb), lambda i, j, s, o, g, t: (t[s], j)),
+            ],
+            out_specs=pl.BlockSpec((None, ta, tb),
+                                   lambda i, j, s, o, g, t: (g[s], i, j)),
+            scratch_shapes=[pltpu.VMEM((ta, tb), jnp.float32)],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_GMM_VMEM_LIMIT),
+        name="dl4j_tgmm",
+        interpret=interpret,
+    )(offsets, group_of, tile_of, x, y)
+
+
+_tgmm_p = _grouped_primitive(
+    "grouped_matmul_t", _tgmm_call,
+    lambda steps, offsets, g, t, x, y, *, out_dtype, **_: (
+        (offsets.shape[0] - 1, x.shape[1], y.shape[1]), out_dtype))
+
+
+def tgmm(x, y, groups, out_dtype=None):
+    """[G, a, b]: for each group g, x[rows of g]ᵀ · y[rows of g] with x [m,
+    a], y [m, b] and ``groups`` the rows of each group in order ([G]
+    sizes) or their :func:`group_plan` (the weights' gradient of
+    :func:`gmm`); summed in float32 and stored in ``out_dtype`` (x's by
+    default).  An empty group gives zeros; rows behind the last group are
+    never read into a sum."""
+    plan = _plan(groups, x.shape[0])
+    G = plan.offsets.shape[0] - 1
+    tiles = (plan.tm,) + _tgmm_tiles(x.shape[0], x.shape[1], y.shape[1],
+                                     G)[1:]
+    return _tgmm_p.bind(*plan[:4], x, y,
+                        out_dtype=jnp.dtype(out_dtype or x.dtype),
+                        tiles=tiles, interpret=_interpret())
+
+
+def gmm_supported(m: int, k: int, n: int, groups: int, dtype,
+                  w_dtype=None) -> bool:
+    """Shape gate of the gmm tier for rows [m, k] in ``dtype`` by a stack
+    of groups x [k, n] in ``w_dtype`` (``dtype`` by default; float32
+    masters under bfloat16 rows are cast in the kernels), both
+    directions: float32 or bfloat16, rows in tiles of 8, and every block
+    of both kernels within the VMEM limit (a width that is no multiple of
+    128 lanes is one block whole)."""
+    floats = (jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16))
+    w_dtype = jnp.dtype(w_dtype or dtype)
+    if jnp.dtype(dtype) not in floats or w_dtype not in floats:
+        return False
+    if m % 8:
+        return False
+    size, w_size = jnp.dtype(dtype).itemsize, w_dtype.itemsize
+    need = 0
+    for kk, nn in ((k, n), (n, k)):
+        tm, tn = _gmm_tiles(m, kk, nn, groups)
+        need = max(need, 2 * (tm * kk * size + kk * tn * w_size
+                              + tm * tn * size)
+                   + kk * tn * size + 4 * tm * tn)
+    for aa, bb in ((k, n), (n, k)):
+        tm, ta, tb = _tgmm_tiles(m, aa, bb, groups)
+        need = max(need, 2 * (tm * ta + tm * tb) * size
+                   + 2 * ta * tb * w_size + 4 * ta * tb)
+    return need <= _GMM_VMEM_LIMIT // 2
 

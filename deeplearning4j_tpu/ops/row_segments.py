@@ -34,6 +34,12 @@ times as much to scatter as to gather: ``PERF.md`` 6, PR 30).  Rows of a
 segment beyond the held ones belong to other holders: the grouped
 products leave them undefined in both directions, and every sum that
 could read them selects them out.
+
+The grouped products are the gmm tier's Pallas kernels where
+``ops/helpers`` selects it (``dl4j_gmm`` for the rows, reading a stack
+transposed through its own block index, ``dl4j_tgmm`` for the weights'
+gradients), and ``lax.ragged_dot`` with JAX's transposes otherwise; the
+choice is made once a layer, when it is traced.
 """
 
 from __future__ import annotations
@@ -44,6 +50,9 @@ import re
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from deeplearning4j_tpu.ops import helpers
+from deeplearning4j_tpu.ops import pallas_kernels as pk
 
 #: rows of a segment are a multiple of this (a bf16 tile's sublanes)
 ROW_TILE = 16
@@ -102,6 +111,17 @@ def _segment_sizes(group_sizes, s, seg):
                 group_sizes.dtype)
 
 
+def _groups(group_sizes, s, seg, fused):
+    """Segment s's groups as its products take them: the kernels' plan of
+    their steps, made once for all the segment's products, or the sizes
+    for XLA's."""
+    sizes = _segment_sizes(group_sizes, s, seg)
+    if not fused:
+        return sizes
+    with jax.named_scope("experts"):
+        return pk.group_plan(sizes, seg)
+
+
 def gated_silu(h1, h3):
     return jax.nn.silu(h1) * h3
 
@@ -109,17 +129,36 @@ def gated_silu(h1, h3):
 def _transposed(w):
     """The stack ``w`` [G, a, b] with each expert's matrix transposed:
     ``ragged_dot(ct, _transposed(w), sizes)`` is the transpose of ``rows
-    -> ragged_dot(rows, w, sizes)``.  Made once a layer and handed to
-    every segment: the chip lays a transposed stack out anew, and a
-    transposition written inside the later segments' loop is moved out
-    of it by the compiler and paid whether the loop runs or not."""
+    -> ragged_dot(rows, w, sizes)``.  XLA's products only: made once a
+    layer and handed to every segment (the chip lays a transposed stack
+    out anew, and a transposition written inside the later segments'
+    loop is moved out of it by the compiler and paid whether the loop
+    runs or not); the kernels read a stack transposed in place."""
     return jnp.swapaxes(w, 1, 2)
 
 
-def _to_weights(ct, rows, w, sizes):
-    """JAX's transpose of ``w -> ragged_dot(rows, w, sizes)``."""
+def _by_group(rows, w, groups, fused):
+    """Each row times its group's matrix of the stack ``w`` [G, a, b];
+    ``groups`` is what :func:`_groups` gave."""
+    return pk.gmm(rows, w, groups) if fused else lax.ragged_dot(
+        rows, w, groups)
+
+
+def _back_through(ct, w, w_t, groups, fused):
+    """The rows' cotangent of ``rows -> _by_group(rows, w, groups)``: the
+    kernel reads ``w`` transposed, XLA's product takes ``w_t``, the
+    stack :func:`_transposed` made."""
+    return pk.gmm(ct, w, groups, transpose_w=True) if fused else \
+        lax.ragged_dot(ct, w_t, groups)
+
+
+def _to_weights(ct, rows, w, groups, fused):
+    """The stack's cotangent of ``w -> _by_group(rows, w, groups)``, in
+    w's dtype: the kernel sums it in float32, XLA's is JAX's transpose."""
+    if fused:
+        return pk.tgmm(rows, ct, groups, out_dtype=w.dtype)
     return jax.linear_transpose(
-        lambda w: lax.ragged_dot(rows, w, sizes), w)(ct)[0]
+        lambda w: lax.ragged_dot(rows, w, groups), w)(ct)[0]
 
 
 # ``perm[r]`` is the assignment (token perm[r] // k, choice perm[r] % k) that
@@ -156,38 +195,40 @@ def _sum_over_choices(buf, index, there, w=None):
     return total
 
 
-def _hidden(s, seg, k, tokens, w_in, perm, sizes):
+def _hidden(s, seg, k, fused, tokens, w_in, perm, groups):
     """Segment s up to its experts' hidden rows: (the tokens' rows in the
     sorted order, the hidden rows of each of ``w_in``), what the backward
-    reads of a segment whose groups are ``sizes`` long."""
+    reads of a segment whose groups are ``groups`` (:func:`_groups`)."""
     with jax.named_scope("dispatch"):
         token_of = _assignments(perm, s, seg, tokens.shape[0] * k) // k
         rows = tokens.at[token_of].get(mode="promise_in_bounds")
     with jax.named_scope("experts"):
-        return rows, tuple(lax.ragged_dot(rows, w, sizes) for w in w_in)
+        return rows, tuple(_by_group(rows, w, groups, fused) for w in w_in)
 
 
-def _segment(s, seg, k, act, tokens, w, w_in, w2, perm, back, group_sizes):
+def _segment(s, seg, k, act, fused, tokens, w, w_in, w2, perm, back,
+             group_sizes):
     """Segment s from the tokens to the tokens' sum: ([n, D] float32, its
     rows' part of every token's weighted sum; what :func:`_hidden` gave)."""
-    sizes = _segment_sizes(group_sizes, s, seg)
-    kept = _hidden(s, seg, k, tokens, w_in, perm, sizes)
+    groups = _groups(group_sizes, s, seg, fused)
+    kept = _hidden(s, seg, k, fused, tokens, w_in, perm, groups)
     with jax.named_scope("experts"):
-        y = lax.ragged_dot(act(*kept[1]), w2, sizes)
+        y = _by_group(act(*kept[1]), w2, groups, fused)
     with jax.named_scope("combine"):
         part = _sum_over_choices(
             y, *_place(back, s, seg, jnp.sum(group_sizes)), w)
     return part, kept
 
 
-def _segment_back(s, seg, k, act, kept, d_out, w_in_t, w2_t,
+def _segment_back(s, seg, k, act, fused, kept, d_out, w_in_t, w2_t,
                   tokens, w, w_in, w2, perm, back, group_sizes):
     """Segment s's part of the cotangents of ``(tokens, w, w_in, w2)``,
-    from its rows and hidden rows ``kept`` and the transposed stacks:
-    the first two float32 and shaped like ``tokens`` and ``w``."""
+    from its rows and hidden rows ``kept`` (and, for XLA's products, the
+    transposed stacks): the first two float32 and shaped like ``tokens``
+    and ``w``."""
     rows, hidden = kept
     held_rows = jnp.sum(group_sizes)
-    sizes = _segment_sizes(group_sizes, s, seg)
+    groups = _groups(group_sizes, s, seg, fused)
     wide = jnp.promote_types(rows.dtype, jnp.float32)
     valid = (s * seg + jnp.arange(seg) < held_rows)[:, None]
     index, there = _place(back, s, seg, held_rows)
@@ -202,13 +243,13 @@ def _segment_back(s, seg, k, act, kept, d_out, w_in_t, w2_t,
         # a row's weight is a scalar, so it commutes with the row's
         # product: the unweighted cotangent of the gated rows serves the
         # weight's own (below) and, times the weight, the way on
-        d_gated = lax.ragged_dot(got, w2_t, sizes)
+        d_gated = _back_through(got, w2, w2_t, groups, fused)
         d_hidden = gate_back(d_gated * w_row)
-        d_w2 = _to_weights(got * w_row, gated, w2, sizes)
-        d_w_in = tuple(_to_weights(d, rows, w_, sizes)
+        d_w2 = _to_weights(got * w_row, gated, w2, groups, fused)
+        d_w_in = tuple(_to_weights(d, rows, w_, groups, fused)
                        for d, w_ in zip(d_hidden, w_in))
-        d_rows = sum(lax.ragged_dot(d, w_t, sizes)
-                     for d, w_t in zip(d_hidden, w_in_t))
+        d_rows = sum(_back_through(d, w_, w_t, groups, fused)
+                     for d, w_, w_t in zip(d_hidden, w_in, w_in_t))
     with jax.named_scope("combine"):
         # along the row's values the weight's cotangent: y . got, which
         # is gated . (got W2^T) by group, without y
@@ -221,7 +262,6 @@ def _segment_back(s, seg, k, act, kept, d_out, w_in_t, w2_t,
     return d_tokens, d_w, d_w_in, d_w2
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
 def routed_sum(tokens, w, w_in, w2, perm, back, group_sizes, seg, k, act):
     """``sum_j w[n, j] * (act(x W_in[e]...) W2[e])`` with x = tokens[n]
     and e the held expert of assignment (n, j), over the assignments on
@@ -229,9 +269,24 @@ def routed_sum(tokens, w, w_in, w2, perm, back, group_sizes, seg, k, act):
     rounded once.  ``w_in`` is (W1,) or (W1, W3), ``act`` their
     elementwise gate; ``perm`` (whole segments long) sorts the
     assignments by held expert, ``back`` [n, k] is its inverse and
-    ``group_sizes`` the rows of each held expert."""
+    ``group_sizes`` the rows of each held expert.  The grouped products
+    are the gmm tier's kernels where ``helpers.gmm_wanted`` says so: they
+    take stacks in a wider dtype than the tokens' as they are (float32
+    masters, cast a block at a time in VMEM; their gradients come in
+    that dtype), where XLA's products take them cast here."""
+    fused = helpers.gmm_wanted(seg, tokens.dtype, (*w_in, w2))
+    if not fused:
+        w_in = tuple(w_.astype(tokens.dtype) for w_ in w_in)
+        w2 = w2.astype(tokens.dtype)
+    return _routed_sum(tokens, w, w_in, w2, perm, back, group_sizes,
+                       seg, k, act, fused)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10))
+def _routed_sum(tokens, w, w_in, w2, perm, back, group_sizes, seg, k, act,
+                fused):
     return _routed_sum_fwd(tokens, w, w_in, w2, perm, back, group_sizes,
-                           seg, k, act)[0]
+                           seg, k, act, fused)[0]
 
 
 def _later_segments(perm, group_sizes, seg, body, first):
@@ -249,33 +304,36 @@ def _later_segments(perm, group_sizes, seg, body, first):
 
 
 def _routed_sum_fwd(tokens, w, w_in, w2, perm, back, group_sizes,
-                    seg, k, act):
+                    seg, k, act, fused):
     args = (tokens, w, w_in, w2, perm, back, group_sizes)
-    first, kept = _segment(0, seg, k, act, *args)
+    first, kept = _segment(0, seg, k, act, fused, *args)
     total = _later_segments(
         perm, group_sizes, seg,
-        lambda s: _segment(s, seg, k, act, *args)[0], first)
+        lambda s: _segment(s, seg, k, act, fused, *args)[0], first)
     return total.astype(tokens.dtype), args + (kept,)
 
 
-def _routed_sum_bwd(seg, k, act, res, d_out):
+def _routed_sum_bwd(seg, k, act, fused, res, d_out):
     """Segment 0's cotangents from the rows and hidden rows it kept; a
     later segment's from its own computed again, added to them."""
     *args, kept = res
     tokens, w, w_in, w2, perm, _, group_sizes = args
-    with jax.named_scope("experts"):
-        turned = tuple(_transposed(w_) for w_ in w_in), _transposed(w2)
+    turned = (len(w_in) * (None,), None)
+    if not fused:
+        with jax.named_scope("experts"):
+            turned = tuple(_transposed(w_) for w_ in w_in), _transposed(w2)
 
     def later(s):
-        again = _hidden(s, seg, k, tokens, w_in, perm,
-                        _segment_sizes(group_sizes, s, seg))
-        return _segment_back(s, seg, k, act, again, d_out, *turned, *args)
+        again = _hidden(s, seg, k, fused, tokens, w_in, perm,
+                        _groups(group_sizes, s, seg, fused))
+        return _segment_back(s, seg, k, act, fused, again, d_out, *turned,
+                             *args)
 
     d_tokens, d_w, d_w_in, d_w2 = _later_segments(
         perm, group_sizes, seg, later,
-        _segment_back(0, seg, k, act, kept, d_out, *turned, *args))
+        _segment_back(0, seg, k, act, fused, kept, d_out, *turned, *args))
     return (d_tokens.astype(tokens.dtype), d_w.astype(w.dtype), d_w_in,
             d_w2, None, None, None)
 
 
-routed_sum.defvjp(_routed_sum_fwd, _routed_sum_bwd)
+_routed_sum.defvjp(_routed_sum_fwd, _routed_sum_bwd)

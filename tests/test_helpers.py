@@ -440,3 +440,74 @@ class TestPartitionedTrace:
                               "dropout") == selected
         assert _counter_value("dl4j_pallas_fallback_total",
                               "dropout") >= fallback + 1
+
+
+# ---------------------------------------------------------------------------
+# A tier validated on first use (gmm): its self-test runs when a trace first
+# selects it, once a process, and not at the top of every fit()
+# ---------------------------------------------------------------------------
+
+class TestFirstUseValidation:
+    STACKS = (jnp.zeros((4, 32, 48), jnp.float32),
+              jnp.zeros((4, 48, 32), jnp.float32))
+
+    @pytest.fixture
+    def counted(self, monkeypatch):
+        runs = []
+        h = helpers.helper_for("gmm")
+        monkeypatch.setitem(helpers._HELPERS, "gmm", h._replace(
+            self_test=lambda: runs.append(1)))
+        monkeypatch.setenv("DL4J_PALLAS_GMM", "1")
+        return runs
+
+    def test_fit_start_leaves_it_to_the_trace(self, counted):
+        res = helpers.ensure_validated()
+        assert "gmm" not in res and not counted
+        assert helpers.gmm_wanted(64, jnp.float32, self.STACKS)
+        assert helpers.gmm_wanted(64, jnp.float32, self.STACKS)
+        assert counted == [1]          # once a process
+        helpers.reset_validation()
+        assert helpers.gmm_wanted(64, jnp.float32, self.STACKS)
+        assert counted == [1, 1]
+
+    def test_a_selection_inside_a_trace_runs_it_outside(self, counted):
+        def traced(x):
+            assert helpers.gmm_wanted(64, jnp.float32, self.STACKS)
+            return x + 1
+        step = jax.jit(traced)
+        step(1.0)
+        assert counted == [1]
+
+    def test_a_failing_self_test_disables_the_tier(self, monkeypatch):
+        def boom():
+            raise RuntimeError("mosaic rejected the grouped product")
+        monkeypatch.setitem(helpers._HELPERS, "gmm", helpers.helper_for(
+            "gmm")._replace(self_test=boom))
+        monkeypatch.setenv("DL4J_TPU", "1")
+        fallback = _counter_value("dl4j_pallas_fallback_total", "gmm")
+        assert not helpers.gmm_wanted(64, jnp.bfloat16,
+                                      tuple(w.astype(jnp.bfloat16)
+                                            for w in self.STACKS))
+        assert "gmm" in pk._disabled and not helpers.available("gmm")
+        assert _counter_value("dl4j_pallas_fallback_total",
+                              "gmm") == fallback + 1
+
+    def test_an_expert_layer_starts_it_when_initialised(self, counted):
+        from deeplearning4j_tpu.nn.conf import layers as L
+        from deeplearning4j_tpu.nn.conf.inputs import InputType
+        layer = L.MixtureOfExpertsLayer(n_out=32, n_experts=8, hidden=48,
+                                        top_k=2, gated=True)
+        layer.initialize(jax.random.PRNGKey(0), InputType.recurrent(32, 8))
+        assert helpers.gmm_wanted(64, jnp.float32, self.STACKS)
+        assert counted == [1]          # started at init, waited for once
+        L.MixtureOfExpertsLayer(n_out=32, n_experts=8, hidden=48,
+                                top_k=2).initialize(
+            jax.random.PRNGKey(1), InputType.recurrent(32, 8))
+        assert counted == [1]
+
+    def test_stacks_the_kernels_do_not_take_fall_back(self, counted):
+        odd = (jnp.zeros((4, 32, 48), jnp.float32),
+               jnp.zeros((4, 48, 32), jnp.bfloat16))   # dtypes differ
+        assert not helpers.gmm_wanted(64, jnp.float32, odd)
+        assert not helpers.gmm_wanted(60, jnp.float32, self.STACKS)
+        assert not counted

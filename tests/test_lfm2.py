@@ -998,7 +998,9 @@ def test_the_parts_and_kernels_come_from_the_layer_classes():
     assert set(parts) == {"MixtureOfExpertsLayer", "LoopVertex",
                           "LoopExitOutputLayer", "SelfAttentionLayer"}
     assert parts["SelfAttentionLayer"] == frozenset(("attn_core", "attn_gate"))
-    assert kernels == {"ragged-dot": ("MixtureOfExpertsLayer", "experts")}
+    # XLA's grouped products and the gmm tier's kernels, by name
+    assert kernels == {name: ("MixtureOfExpertsLayer", "experts")
+                       for name in ("ragged-dot", "dl4j_gmm", "dl4j_tgmm")}
 
     class Another(L.Layer):
         scope_kernels = {"ragged-dot": "products"}

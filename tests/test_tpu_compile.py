@@ -51,7 +51,11 @@ def compile_for_chip(topo, monkeypatch):
     """compile(fn, (shape, dtype), ...) → HLO text of ``fn`` compiled
     for one described v5e chip, on the branches ``is_tpu()`` selects."""
     from jax.sharding import SingleDeviceSharding
+    from deeplearning4j_tpu.ops import helpers
     monkeypatch.setenv("DL4J_TPU", "1")
+    # a tier validated on first use would run its self-test, which only a
+    # chip can: compiling is all these ask
+    monkeypatch.setitem(helpers._FIRST_USE, "gmm", True)
     one_chip = SingleDeviceSharding(topo.devices[0])
 
     def compile_(fn, *specs):
@@ -134,8 +138,9 @@ def test_expert_layer_compiles_with_its_segments_skipped_on_the_chip(
     the chip's compiler makes of it **no array is longer than a segment
     and as wide as the model or an expert** (the sorted buffer of tokens
     x k rows exists nowhere), the later segments are loops whose trip
-    count the chip reads, no branch, and the grouped products the
-    compiler's own kernels."""
+    count the chip reads, no branch, and the grouped products the gmm
+    tier's kernels, the stacks read as the step holds them (float32
+    masters here, cast a block at a time in the kernels)."""
     from deeplearning4j_tpu.nn.conf import layers as L
     from deeplearning4j_tpu.ops import row_segments
     layer = L.MixtureOfExpertsLayer(
@@ -154,18 +159,23 @@ def test_expert_layer_compiles_with_its_segments_skipped_on_the_chip(
             return _sq(y), st["moe_expert_counts"]
         return jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(
             {"Wg": wg, "W1": w1, "W2": w2, "W3": w3}, x)
-    bf16 = jnp.bfloat16
+    bf16, f32 = jnp.bfloat16, jnp.float32
     hlo = compile_for_chip(
         step, ((2, 4096, 2048), bf16), ((2048, experts), bf16),
-        ((held, 2048, hidden), bf16), ((held, hidden, 2048), bf16),
-        ((held, 2048, hidden), bf16), ((experts,), bf16))
+        ((held, 2048, hidden), f32), ((held, hidden, 2048), f32),
+        ((held, 2048, hidden), f32), ((experts,), bf16))
     assert row_segments.tall_arrays(
         hlo, seg, (2048, hidden), stacks=[(held, 2048, hidden)]) == []
     # the later segments, forward and backward
     assert len(re.findall(r"\s(while)\(", hlo)) >= 2
     assert " conditional(" not in hlo
-    # three products forward, three to the rows and three to the weights
-    assert len(set(re.findall(r"%(ragged-dot[\w.\-]*) = ", hlo))) >= 9
+    # three products forward and three to the rows, three to the weights
+    assert len(set(re.findall(r"%(dl4j_gmm[\w.\-]*) = ", hlo))) >= 6
+    assert len(set(re.findall(r"%(dl4j_tgmm[\w.\-]*) = ", hlo))) >= 3
+    assert "ragged-dot" not in hlo
+    # no pass casts a stack whole: the kernels cast a block at a time
+    assert not re.search(
+        rf"= bf16\[{held},(2048,{hidden}|{hidden},2048)\]\S* convert\(", hlo)
 
 
 @pytest.mark.parametrize("shape", [(64, 128), (128, 4096)],
