@@ -31,7 +31,8 @@ from deeplearning4j_tpu.ops import bucketing
 from deeplearning4j_tpu.ops import dtypes as dtype_ops
 from deeplearning4j_tpu.ops import updaters as upd_ops
 from deeplearning4j_tpu.nn.multilayer import (
-    BIAS_KEYS, WEIGHT_KEYS, _updater_for, dispatch_train_step)
+    BIAS_KEYS, WEIGHT_KEYS, _updater_for, dispatch_train_step,
+    step_carry_in, step_carry_out)
 
 
 class ComputationGraph:
@@ -324,12 +325,12 @@ class ComputationGraph:
 
         return grad_step
 
-    def _build_step_raw(self):
+    def _build_step_body(self):
+        """One graph train step under a given key (see
+        MultiLayerNetwork._build_step_body)."""
         grad_step = self._build_grad_raw()
 
-        # the name is what a compile event and a trace show (jit_<name>)
-        def cg_train_step(params, state, opts, xs, ys, fmasks, lmasks, it,
-                          rng):
+        def step_body(params, state, opts, xs, ys, fmasks, lmasks, it, rng):
             score, new_states, grads = grad_step(params, state, xs, ys,
                                                  fmasks, lmasks, rng)
             with jax.named_scope("update"):
@@ -338,7 +339,21 @@ class ComputationGraph:
             return new_params, new_states, new_opts, score
 
         from deeplearning4j_tpu.parallel import fsdp
-        return fsdp.partitioned_if_sharded(self, cg_train_step)
+        return fsdp.partitioned_if_sharded(self, step_body)
+
+    def _build_step_raw(self):
+        """The graph's train step, carrying its key and iteration (see
+        MultiLayerNetwork._build_step_raw)."""
+        body = self._build_step_body()
+
+        # the name is what a compile event and a trace show (jit_<name>)
+        def cg_train_step(params, state, opts, xs, ys, fmasks, lmasks, it,
+                          key):
+            key, rng = jax.random.split(key)
+            return (*body(params, state, opts, xs, ys, fmasks, lmasks, it,
+                          rng), key, it + 1)
+
+        return cg_train_step
 
     def _apply_updates(self, params, opts, grads, it):
         """Traceable gradient→param update over the vertex dict (per-layer
@@ -561,23 +576,25 @@ class ComputationGraph:
         """K graph train steps in one lax.scan launch (see
         MultiLayerNetwork._build_fused_step — identical contract over
         the vertex-dict carry)."""
-        raw = self._build_step_raw()
+        body = self._build_step_body()
 
         def strip_rnn(state):
             return {n: {kk: v for kk, v in s.items() if kk != "rnn_state"}
                     for n, s in state.items()}
 
-        def cg_fused_steps(params, state, opts, xs, ys, fms, lms, it0, key):
-            def body(carry, inp):
+        def cg_fused_steps(params, state, opts, xs, ys, fms, lms, it, key):
+            key, sub = jax.random.split(key)
+
+            def step(carry, inp):
                 p, s, o = carry
                 i, x, y, fm, lm = inp
-                p, s, o, score = raw(p, s, o, x, y, fm, lm, it0 + i,
-                                     jax.random.fold_in(key, i))
+                p, s, o, score = body(p, s, o, x, y, fm, lm, it + i,
+                                      jax.random.fold_in(sub, i))
                 return (p, strip_rnn(s), o), score
             (params, state, opts), scores = jax.lax.scan(
-                body, (params, strip_rnn(state), opts),
+                step, (params, strip_rnn(state), opts),
                 (jnp.arange(k), xs, ys, fms, lms))
-            return params, state, opts, scores[-1]
+            return params, state, opts, scores[-1], key, it + k
 
         return jax.jit(cg_fused_steps, donate_argnums=(0, 1, 2))  # dl4j: noqa[DL4J104] one jitted fn per k, cached in _fused_fns[k]
 
@@ -803,11 +820,11 @@ class ComputationGraph:
             lm = (tuple(None if m is None else jnp.asarray(seg_mask(m, sl))
                         for m in mds.labels_masks)
                   if mds.labels_masks is not None else None)
-            self._key, sub = jax.random.split(self._key)
-            (self.net_params, self.net_state, self.opt_states,
-             score) = self._step_fn(
-                self.net_params, self.net_state, self.opt_states, xs, ys,
-                fm, lm, jnp.asarray(self.iteration, jnp.int32), sub)
+            it, key = step_carry_in(self)
+            (self.net_params, self.net_state, self.opt_states, score,
+             *carry) = self._step_fn(self.net_params, self.net_state,
+                                     self.opt_states, xs, ys, fm, lm, it, key)
+            step_carry_out(self, carry, 1)
             self._score = score
             self.iteration += 1
             for lst in self.listeners:

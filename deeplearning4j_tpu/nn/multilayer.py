@@ -92,6 +92,17 @@ def _expert_layers(net) -> dict:
             if isinstance(l, MixtureOfExpertsLayer) and l.top_k is not None}
 
 
+def _expert_leaves(net) -> dict:
+    """{vertex: (per-expert assignment counts, (segments run, skipped))}
+    of the state the last step left: what ``publish_expert_load``
+    fetches."""
+    layers = getattr(net, "_expert_layers", None)
+    if layers is None:
+        layers = net._expert_layers = _expert_layers(net)
+    return {k: (net.net_state[k]["moe_expert_counts"],
+                net.net_state[k]["moe_row_segments"]) for k in layers}
+
+
 def publish_expert_load(net) -> None:
     """The step's expert load, out of the state the step returned and
     into counters: ``dl4j_moe_assignments_total{vertex, held}`` (token x
@@ -110,11 +121,10 @@ def publish_expert_load(net) -> None:
     its last step's and the counter reads a k-th of the routing.  A net
     without such a layer publishes nothing and pays one attribute
     read."""
-    layers = getattr(net, "_expert_layers", None)
-    if layers is None:
-        layers = net._expert_layers = _expert_layers(net)
-    if not layers:
+    leaves = _expert_leaves(net)
+    if not leaves:
         return
+    layers = net._expert_layers
     held = {k: list(l._held()) for k, l in layers.items()}
     reg = monitor.get_registry()
     total = reg.counter(
@@ -141,10 +151,7 @@ def publish_expert_load(net) -> None:
         "dl4j_moe_bias_updates_total",
         "training steps that moved the expert bias with the load, last "
         "step of each dispatch", labels=("vertex",))
-    counts = jax.device_get({k: (net.net_state[k]["moe_expert_counts"],
-                                 net.net_state[k]["moe_row_segments"])
-                             for k in held})
-    for k, (c, (run, skipped)) in counts.items():
+    for k, (c, (run, skipped)) in jax.device_get(leaves).items():
         segments.labels(vertex=str(k), outcome="run").inc(int(run))
         segments.labels(vertex=str(k), outcome="skipped").inc(int(skipped))
         segments.labels(vertex=str(k), outcome="recomputed").inc(int(run) - 1)
@@ -171,6 +178,19 @@ def _loop_vertices(net) -> Tuple[list, list]:
              and isinstance(v.layer_conf(), LoopExitOutputLayer)])
 
 
+def _loop_leaves(net) -> Tuple[dict, dict]:
+    """({stack: passes run}, {head: (exit mass, exit loss by pass)}) of
+    the state the last step left: what ``publish_loop_exits``
+    fetches."""
+    found = getattr(net, "_loops", None)
+    if found is None:
+        found = net._loops = _loop_vertices(net)
+    loops, heads = found
+    return ({n: net.net_state[n]["loop_passes"] for n in loops},
+            {n: (net.net_state[n]["loop_exit_mass"],
+                 net.net_state[n]["loop_exit_loss"]) for n in heads})
+
+
 def publish_loop_exits(net) -> None:
     """A looped stack's step, out of the state the step returned and
     into ``dl4j_loop_passes_total{vertex}`` (passes run, last step of
@@ -180,11 +200,8 @@ def publish_loop_exits(net) -> None:
     mean cross-entropy): whether later passes still lower the loss, and
     where the gate puts its mass.  A net without a loop publishes
     nothing and pays one attribute read."""
-    found = getattr(net, "_loops", None)
-    if found is None:
-        found = net._loops = _loop_vertices(net)
-    loops, heads = found
-    if not loops and not heads:
+    passes_by, exits_by = _loop_leaves(net)
+    if not passes_by and not exits_by:
         return
     reg = monitor.get_registry()
     passes = reg.counter(
@@ -199,13 +216,10 @@ def publish_loop_exits(net) -> None:
         "dl4j_loop_exit_loss",
         "mean over tokens of each pass's cross-entropy, last step",
         labels=("vertex", "pass"))
-    got = jax.device_get((
-        {n: net.net_state[n]["loop_passes"] for n in loops},
-        {n: (net.net_state[n]["loop_exit_mass"],
-             net.net_state[n]["loop_exit_loss"]) for n in heads}))
-    for n, r in got[0].items():
+    got_passes, got_exits = jax.device_get((passes_by, exits_by))
+    for n, r in got_passes.items():
         passes.labels(vertex=n).inc(int(r))
-    for n, (p, ce) in got[1].items():
+    for n, (p, ce) in got_exits.items():
         for r in range(len(p)):
             mass.labels(**{"vertex": n, "pass": str(r + 1)}).set(float(p[r]))
             loss.labels(**{"vertex": n, "pass": str(r + 1)}).set(float(ce[r]))
@@ -226,6 +240,17 @@ def _diffusion_trained(net) -> bool:
                for v in net.conf.vertices.values())
 
 
+def _diffusion_weights(net, batch):
+    """The batch's loss weights, which ``publish_diffusion`` fetches, or
+    None for a net that does not train block diffusion."""
+    on = getattr(net, "_diffusion", None)
+    if on is None:
+        on = net._diffusion = _diffusion_trained(net)
+    if not on or len(batch) < 4 or not batch[3] or batch[3][0] is None:
+        return None
+    return batch[3][0]
+
+
 def publish_diffusion(net, batch) -> None:
     """A block-diffusion step's noise, out of the weights the batch came
     with (its labels mask: 1 / t on a masked token, 0 on a clean one)
@@ -234,12 +259,10 @@ def publish_diffusion(net, batch) -> None:
     the schedule's mean t, and the weights' sum near the tokens' count.
     A fused group publishes its last batch's.  Any other net publishes
     nothing and pays one attribute read."""
-    on = getattr(net, "_diffusion", None)
-    if on is None:
-        on = net._diffusion = _diffusion_trained(net)
-    if not on or len(batch) < 4 or not batch[3] or batch[3][0] is None:
+    w = _diffusion_weights(net, batch)
+    if w is None:
         return
-    w = jax.device_get(batch[3][0])
+    w = jax.device_get(w)
     if w.ndim == 3:         # a fused group's stacked batches
         w = w[-1]
     reg = monitor.get_registry()
@@ -301,6 +324,58 @@ def publish_attention_tiles(net, batch) -> None:
             g.labels(vertex=str(k), outcome=outcome).set(n)
 
 
+def _count_resync(what: str) -> None:
+    monitor.get_registry().counter(
+        "dl4j_fit_carry_resync_total",
+        "train-step launches that sent the host's key or iteration to the "
+        "device anew, because it no longer matched what the last step "
+        "returned", labels=("what",)).labels(what=what).inc()
+
+
+def step_carry_in(net):
+    """``(iteration, key)`` for the next launch of the train step, as
+    device arrays.  The step splits the key itself and returns the rest
+    of it with its iteration advanced (``_build_step_raw``): while the
+    host's ``net._key`` is still that key and ``net.iteration`` that
+    count, both go back as they are and nothing crosses.  Otherwise (a
+    fit's first launch, an iteration set by hand, a restored checkpoint,
+    another path that split ``net._key``) the host's own is sent and
+    ``dl4j_fit_carry_resync_total{what}`` counts it."""
+    held = getattr(net, "_carry", None)
+    if held is not None and held[0] is net._key:
+        key = net._key
+    else:
+        key = jnp.asarray(net._key)
+        _count_resync("key")
+    if held is not None and held[1] == net.iteration:
+        it = held[2]
+    else:
+        it = jnp.asarray(net.iteration, jnp.int32)
+        _count_resync("iteration")
+    return it, key
+
+
+def step_carry_out(net, carry, k: int) -> None:
+    """After a launch that advances ``k`` iterations, before the host
+    counts them: the key the step returned becomes ``net._key`` and,
+    with the iteration it returned, what the next launch passes back.
+    A step that returns no carry (one wrapped around the engine's that
+    hands back only its first four outputs) leaves the next launch to
+    send both."""
+    if carry:
+        net._key, it = carry
+        net._carry = (net._key, net.iteration + k, it)
+    else:
+        net._carry = None
+
+
+def _tail_leaves(net, batch):
+    """What the step's tail will fetch besides the score: the arrays the
+    publishers read of the state the step left and of the batch."""
+    return (_expert_leaves(net), _loop_leaves(net),
+            _diffusion_weights(net, batch))
+
+
 def dispatch_train_step(net, step_fn, kind, sig_args, batch, t_step,
                         bucket=None, k=1, repeats=1):
     """Launch ``step_fn`` on a staged ``batch`` and account for it: the
@@ -315,16 +390,22 @@ def dispatch_train_step(net, step_fn, kind, sig_args, batch, t_step,
             if fresh is None:
                 fresh = net.compile_telemetry.record(kind, sig_args,
                                                      bucket=bucket)
-            net._key, sub = jax.random.split(net._key)
-            # the iteration scalar moves H2D here, OUTSIDE the guarded
-            # dispatch — inside it every transfer is a bug
-            it_arr = jnp.asarray(net.iteration, jnp.int32)
+            # a key or iteration the host sends anew moves H2D here,
+            # OUTSIDE the guarded dispatch — inside it every transfer is
+            # a bug; a steady step sends neither
+            it_arr, key = step_carry_in(net)
         with steps.span("fit/step", phase="jit_call",
-                        iteration=net.iteration), \
-                sanitizer.guard_step(compiling=fresh):
-            (net.net_params, net.net_state, net.opt_states,
-             score) = step_fn(net.net_params, net.net_state,
-                              net.opt_states, *batch, it_arr, sub)
+                        iteration=net.iteration):
+            with sanitizer.guard_step(compiling=fresh):
+                (net.net_params, net.net_state, net.opt_states,
+                 score, *carry) = step_fn(net.net_params, net.net_state,
+                                          net.opt_states, *batch, it_arr,
+                                          key)
+            # what the tail reads leaves for the host as soon as the
+            # step has made it, while the host waits
+            for a in jax.tree_util.tree_leaves(
+                    (score, _tail_leaves(net, batch))):
+                a.copy_to_host_async()
         with steps.span("fit/step", phase="block_until_ready"):
             jax.block_until_ready(score)
         # the tail's fetches under their own names: the score's
@@ -333,6 +414,7 @@ def dispatch_train_step(net, step_fn, kind, sig_args, batch, t_step,
         with steps.span("fit/step", phase="score_fetch"):
             score_f = float(jax.device_get(score))
         with steps.span("fit/step", phase="bookkeeping"):
+            step_carry_out(net, carry, k)
             net._strip_rnn_state()
             net._score = score
             net.iteration += k
@@ -654,15 +736,16 @@ class MultiLayerNetwork:
 
         return grad_step
 
-    def _build_step_raw(self):
-        """The pure (un-jitted) train step — ParallelWrapper re-jits it
-        with mesh shardings or vmaps it for parameter-averaging compat.
-        Tracing inlines :meth:`_build_grad_raw`, so the compiled step is
-        byte-identical to the pre-split single-closure form."""
+    def _build_step_body(self):
+        """One train step under a given key, ``(params, state, opts, x,
+        y, fmask, lmask, it, rng) → (params, state, opts, score)``: what
+        the carried step and the fused scan run, and what ParallelWrapper
+        vmaps for parameter averaging (a key a replica).  Tracing inlines
+        :meth:`_build_grad_raw`, so the compiled step is byte-identical
+        to the pre-split single-closure form."""
         grad_step = self._build_grad_raw()
 
-        # the name is what a compile event and a trace show (jit_<name>)
-        def mln_train_step(params, state, opts, x, y, fmask, lmask, it, rng):
+        def step_body(params, state, opts, x, y, fmask, lmask, it, rng):
             score, new_states, grads = grad_step(params, state, x, y,
                                                  fmask, lmask, rng)
             with jax.named_scope("update"):
@@ -671,7 +754,24 @@ class MultiLayerNetwork:
             return new_params, new_states, new_opts, score
 
         from deeplearning4j_tpu.parallel import fsdp
-        return fsdp.partitioned_if_sharded(self, mln_train_step)
+        return fsdp.partitioned_if_sharded(self, step_body)
+
+    def _build_step_raw(self):
+        """The pure (un-jitted) train step, ``(params, state, opts, x, y,
+        fmask, lmask, it, key) → (params, state, opts, score, key, it +
+        1)``: it splits the carried key as the host once did before each
+        launch and returns the rest of it with the iteration advanced, so
+        a steady launch sends nothing (``dispatch_train_step``).
+        ParallelWrapper re-jits it with mesh shardings."""
+        body = self._build_step_body()
+
+        # the name is what a compile event and a trace show (jit_<name>)
+        def mln_train_step(params, state, opts, x, y, fmask, lmask, it, key):
+            key, rng = jax.random.split(key)
+            return (*body(params, state, opts, x, y, fmask, lmask, it, rng),
+                    key, it + 1)
+
+        return mln_train_step
 
     def _apply_updates(self, params, opts, grads, it):
         """Traceable gradient→param update: per-layer gradient
@@ -954,8 +1054,10 @@ class MultiLayerNetwork:
     def _build_fused_step(self, k: int):
         """K train steps as one compiled program: lax.scan over the raw
         step with the batch axis stacked in front.  Dispatch once, step
-        K times."""
-        raw = self._build_step_raw()
+        K times.  It carries the key and the iteration as the single
+        step does: one split a launch, ``fold_in(sub, i)`` the i-th
+        step's key."""
+        body = self._build_step_body()
 
         def strip_rnn(state):
             # in-trace equivalent of _strip_rnn_state: RNN layers emit a
@@ -965,17 +1067,19 @@ class MultiLayerNetwork:
             return [{kk: v for kk, v in s.items() if kk != "rnn_state"}
                     for s in state]
 
-        def mln_fused_steps(params, state, opts, xs, ys, fms, lms, it0, key):
-            def body(carry, inp):
+        def mln_fused_steps(params, state, opts, xs, ys, fms, lms, it, key):
+            key, sub = jax.random.split(key)
+
+            def step(carry, inp):
                 p, s, o = carry
                 i, x, y, fm, lm = inp
-                p, s, o, score = raw(p, s, o, x, y, fm, lm, it0 + i,
-                                     jax.random.fold_in(key, i))
+                p, s, o, score = body(p, s, o, x, y, fm, lm, it + i,
+                                      jax.random.fold_in(sub, i))
                 return (p, strip_rnn(s), o), score
             (params, state, opts), scores = jax.lax.scan(
-                body, (params, strip_rnn(state), opts),
+                step, (params, strip_rnn(state), opts),
                 (jnp.arange(k), xs, ys, fms, lms))
-            return params, state, opts, scores[-1]
+            return params, state, opts, scores[-1], key, it + k
 
         return jax.jit(mln_fused_steps, donate_argnums=(0, 1, 2))  # dl4j: noqa[DL4J104] one jitted fn per k, cached in _fused_fns[k]
 
@@ -1129,10 +1233,11 @@ class MultiLayerNetwork:
             l = ds.labels[:, seg] if ds.labels.ndim == 3 else ds.labels
             fm = ds.features_mask[:, seg] if ds.features_mask is not None else None
             lm = ds.labels_mask[:, seg] if ds.labels_mask is not None else None
-            self._key, sub = jax.random.split(self._key)
-            (self.net_params, self.net_state, self.opt_states, score) = self._step_fn(
-                self.net_params, self.net_state, self.opt_states,
-                f, l, fm, lm, jnp.asarray(self.iteration, jnp.int32), sub)
+            it, key = step_carry_in(self)
+            (self.net_params, self.net_state, self.opt_states, score,
+             *carry) = self._step_fn(self.net_params, self.net_state,
+                                     self.opt_states, f, l, fm, lm, it, key)
+            step_carry_out(self, carry, 1)
             self._score = score
             self.iteration += 1
             for lst in self.listeners:

@@ -176,9 +176,10 @@ def partitioned_if_sharded(model, raw):
 
 def jit_sharded_step(raw_step, plan: ShardingPlan, params, opts):
     """pjit the engines' raw train step with the plan's layouts:
-    params/updater sharded (fsdp/model/expert), carried state and score
-    replicated, the batch sharded over data(+fsdp), and params+state+
-    updater donated so the step updates buffers in place on device.
+    params/updater sharded (fsdp/model/expert), carried state, score,
+    key and iteration replicated, the batch sharded over data(+fsdp),
+    and params+state+updater donated so the step updates buffers in
+    place on device.
 
     net_state uses a PREFIX sharding (one spec for the whole subtree):
     an RNN step's output state gains carried keys the input structure
@@ -191,8 +192,8 @@ def jit_sharded_step(raw_step, plan: ShardingPlan, params, opts):
     return jax.jit(
         raw_step,
         in_shardings=(param_sh, repl, opt_sh, batch_sh, batch_sh,
-                      None, None, None, None),
-        out_shardings=(param_sh, repl, opt_sh, repl),
+                      None, None, repl, repl),
+        out_shardings=(param_sh, repl, opt_sh, repl, repl, repl),
         donate_argnums=(0, 1, 2))
 
 

@@ -142,6 +142,8 @@ class ParallelWrapper:
             t_step, bucket=bucket)
 
     def _run_fused_group(self, group):
+        from deeplearning4j_tpu.nn.multilayer import (
+            step_carry_in, step_carry_out)
         m = self.model
         k = len(group)
         if self._sharded_fused is None:
@@ -165,11 +167,12 @@ class ParallelWrapper:
             lambda *leaves: self._put_batch(np.stack(leaves), scan_sh),
             *[g[0] for g in group])
         xs, ys, fms, lms = stacked
-        m._key, sub = jax.random.split(m._key)
-        (m.net_params, m.net_state, m.opt_states,
-         score) = self._sharded_fused[k](
+        it, key = step_carry_in(m)
+        (m.net_params, m.net_state, m.opt_states, score,
+         *carry) = self._sharded_fused[k](
             m.net_params, m.net_state, m.opt_states, xs, ys, fms, lms,
-            jnp.asarray(m.iteration, jnp.int32), sub)
+            it, key)
+        step_carry_out(m, carry, k)
         m._strip_rnn_state()
         m._score = score
         m.iteration += k
@@ -249,12 +252,8 @@ class ParallelWrapper:
         device axis, sharded over 'data' → no cross-device traffic during
         local steps; averaging afterwards is the collective."""
         m = self.model
-        base_step = m._build_step_raw()
-
-        def local(params, state, opts, x, y, fm, lm, it, rng):
-            return base_step(params, state, opts, x, y, fm, lm, it, rng)
-
-        vstep = jax.vmap(local, in_axes=(0, 0, 0, 0, 0, 0, 0, None, 0))
+        vstep = jax.vmap(m._build_step_body(),
+                         in_axes=(0, 0, 0, 0, 0, 0, 0, None, 0))
         dev_axis = NamedSharding(self.mesh, P(("data", "fsdp")))
 
         jit_step = jax.jit(vstep, donate_argnums=(0, 1, 2))
